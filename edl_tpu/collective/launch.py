@@ -93,7 +93,21 @@ def launch(job: JobEnv, trainer_cmd: list[str], *, store: Store | None = None,
                     os.killpg(os.getpgid(tp.pid), signal.SIGKILL)
                 except (ProcessLookupError, PermissionError):
                     pass
+                tp.proc.wait()  # gone for certain before it is forgotten
                 lingering.remove(item)
+
+    def _start_trainer(cluster):
+        # A replacement is never started while a donor of this pod
+        # still lives: one trainer per host drives all its chips, and a
+        # lingering donor holds them until it exits. Its linger does
+        # not wait for this pod (migration._linger), and the reap
+        # bounds it.
+        while lingering:
+            _reap_lingering()
+            time.sleep(0.1)
+        env = trainer_environ(cluster, pod.pod_id, job)
+        return start_trainer(trainer_cmd, env, job.log_dir,
+                             rank=cluster.rank_of(pod.pod_id))
 
     try:
         while True:
@@ -108,10 +122,7 @@ def launch(job: JobEnv, trainer_cmd: list[str], *, store: Store | None = None,
                     timeout=job.barrier_timeout)
                 last_version = cluster.version
             if trainer is None:
-                rank = cluster.rank_of(pod.pod_id)
-                env = trainer_environ(cluster, pod.pod_id, job)
-                trainer = start_trainer(trainer_cmd, env, job.log_dir,
-                                        rank=rank)
+                trainer = _start_trainer(cluster)
             watcher = ClusterWatcher(store, cluster).start()
             generation_start = time.monotonic()
 
@@ -176,9 +187,11 @@ def launch(job: JobEnv, trainer_cmd: list[str], *, store: Store | None = None,
                     crashes = 0
                     continue  # same trainer; fresh watcher at loop top
                 # Adoption unavailable (trainer without the migration
-                # service, or it stalled): stop-resume — but keep the
-                # old trainer alive as a DONOR so the replacement can
-                # restore its state from memory instead of disk.
+                # service, or it stalled): stop-resume. The old trainer
+                # seals its live state and lingers as a DONOR for the
+                # pods of other hosts; this pod's replacement starts
+                # once it has exited (_start_trainer) and restores the
+                # sealed checkpoint.
                 log.info("in-place adoption unavailable — stop-resume "
                          "with donor linger (pid=%d)", trainer.pid)
                 release_trainer(trainer)
@@ -187,7 +200,7 @@ def launch(job: JobEnv, trainer_cmd: list[str], *, store: Store | None = None,
                                   + job.donor_linger_secs + 5.0])
                 trainer = None
                 crashes = 0
-                continue  # cluster already re-formed: respawn directly
+                continue  # cluster already re-formed: no second barrier
             terminate_trainer(trainer)
             trainer = None
             cluster = None

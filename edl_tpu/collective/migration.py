@@ -599,6 +599,10 @@ class MigrationService:
                "step": (snap["status"] or {}).get("step"),
                "generation": self.generation,
                "nbytes": snapshot_nbytes(snap),
+               # a donor in its linger: this pod's replacement starts
+               # only after the donor exits (launch.py), so no other
+               # donor should wait for this pod's ack
+               "stopping": self.stop_requested.is_set(),
                "ts": time.time()}
         with self._lock:
             self._advert_doc = doc
@@ -822,32 +826,44 @@ class MigrationService:
     def _linger(self) -> None:
         """Serve until the re-formed world acked or the deadline passes.
 
-        Early exits: every live rank claim has a fresh ack (the new
-        world is fully up), or there are no live claims at all (nobody
-        left to serve — e.g. the whole job is shutting down)."""
+        The pods to wait for are the live rank claims whose trainer can
+        come up while this donor lives: not this pod's own (its
+        launcher starts the replacement only after this process has
+        exited — on a TPU host the donor holds the chip until then) and
+        not a pod whose own donor is still stopping, for the same
+        reason. Early exit: each of them has a fresh ack, or there is
+        none (a one-pod world, a whole-world stop-resume, a job that is
+        shutting down) — then the sealed checkpoint on disk is what the
+        replacements restore from."""
         from edl_tpu.collective import register as reg
+        from edl_tpu.collective.cluster import Pod
         since = self._stop_ts or time.time()
         deadline = time.monotonic() + self.linger_s
+        self.flush_advert()  # republish with "stopping" set
         log.info("donor linger: serving peers up to %.1fs", self.linger_s)
         while time.monotonic() < deadline:
             try:
                 claims, _ = self.store.get_prefix(
                     reg.ranks_prefix(self.job_id))
                 acks, _ = self.store.get_prefix(ack_prefix(self.job_id))
+                stopping = {d.get("pod_id")
+                            for d in live_donors(self.store, self.job_id)
+                            if d.get("stopping")}
             except Exception:  # noqa: BLE001 — store gone: stop serving
                 return
-            fresh = 0
+            fresh = set()
             for rec in acks:
                 try:
-                    if float(json.loads(rec.value).get("ts", 0)) >= since:
-                        fresh += 1
+                    doc = json.loads(rec.value)
+                    if float(doc.get("ts", 0)) >= since:
+                        fresh.add(doc.get("pod_id"))
                 except (ValueError, TypeError):
                     continue
-            if not claims:
-                return
-            if fresh >= len(claims):
-                log.info("donor linger: %d/%d fresh acks — done", fresh,
-                         len(claims))
+            waiting = {Pod.from_json(r.value).pod_id for r in claims} \
+                - stopping - {self.pod_id}
+            if waiting <= fresh:
+                log.info("donor linger: %d pod(s) to serve, all acked "
+                         "— done", len(waiting))
                 return
             time.sleep(0.3)
 

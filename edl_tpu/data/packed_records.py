@@ -1,8 +1,8 @@
 """Packed pre-decoded record format: the zero-host-transform feed path.
 
-BENCH_r05 put the input plane's cost where the reference hid it behind
-worker count (`loader_cores_to_feed_headline` ~= 7.8 — the host needed
-~8 cores of JPEG decode + crop/flip to keep one chip busy; the
+The input plane's cost sits where the reference hid it behind worker
+count: the host needs several cores of JPEG decode + crop/flip to keep
+one chip busy (how many is not measured on today's host; the
 reference's DALI/`reader_cv2` stack papers over the same gap with
 threads, example/collective/resnet50/dali.py).  A packed record file
 removes the host work instead of parallelizing it:

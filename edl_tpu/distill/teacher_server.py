@@ -1109,7 +1109,8 @@ def main(argv=None) -> int:
         prog="edl_tpu.distill.teacher_server",
         description="Serve a zoo model as a distill teacher")
     parser.add_argument("--model", default="mlp",
-                        help="edl_tpu.models factory name (mlp, resnet50_vd, ...)")
+                        help="edl_tpu.models factory name, case-sensitive "
+                             "(mlp, ResNet50_vd, ...)")
     parser.add_argument("--num-classes", type=int, default=10)
     parser.add_argument("--params", default="",
                         help="checkpoint dir (or gs:///hdfs:// mirror URI) "
@@ -1154,6 +1155,10 @@ def main(argv=None) -> int:
                            compressed_meta=compressed_meta,
                            batching=args.batching or None)
     server.start()
+    import jax
+    dev = jax.devices()[0]
+    log.info("teacher %s serving from %d x %s (platform %s)", args.model,
+             jax.device_count(), dev.device_kind, dev.platform)
     try:
         threading.Event().wait()
     except KeyboardInterrupt:

@@ -346,7 +346,9 @@ def main(argv=None) -> int:
     else:
         tx = optax.adamw(schedule, weight_decay=0.01)
 
-    toks0 = jnp.zeros((1, args.seq_len), jnp.int32)
+    # one row per batch shard: the flash kernel runs under a shard_map
+    # over the batch axes, which a single row cannot be split over
+    toks0 = jnp.zeros((mesh_lib.dp_size(mesh), args.seq_len), jnp.int32)
     variables = shd.init_sharded(
         lambda: model.init(jax.random.PRNGKey(args.seed), toks0,
                            train=False), mesh)
@@ -397,6 +399,12 @@ def main(argv=None) -> int:
              world, rank, jax.device_count(),
              sum(p.size for p in jax.tree.leaves(state.params)),
              steps_per_epoch)
+    dev = jax.devices()[0]
+    log.info("device: platform=%s kind=%r count=%d attention=%s",
+             dev.platform, dev.device_kind, jax.device_count(),
+             "ring" if cfg.use_ring else
+             "flash" if cfg.use_flash(args.seq_len) else "dense")
+    log.info("state bytes per device: %s", shd.bytes_per_device(state))
 
     eval_toks = None
     val_path = os.path.join(args.data_dir, "val.npz")
@@ -433,10 +441,18 @@ def main(argv=None) -> int:
         epoch_t0[0] = time.perf_counter()
         return results
 
+    # a restored state goes where the initial one was: sharded leaves
+    # (fsdp parameters and moments, ep expert tables) stay sharded
+    placement = shd.placement_of(state, mesh)
     loop = TrainLoop(
         step, state, mesh=mesh, config=loop_cfg, eval_fn=eval_fn,
-        place_state=lambda t: mesh_lib.replicate_host_tree(mesh, t),
+        place_state=lambda t: jax.device_put(t, placement),
         batch_axes=("ep",) if args.moe else None)
+    # The loop owns the state from here. A resume replaces it with the
+    # restored one, and a reference kept here would hold the initial
+    # parameters and moments in device memory beside it for the whole
+    # run (at LM-large that is 6.5 GB, and the step no longer fits).
+    del state, variables
 
     def data_fn(epoch):
         return ({"tokens": b["tokens"]} for b in loader.epoch(epoch))

@@ -463,8 +463,9 @@ def choose_remat(cfg: TransformerConfig, batch_size: int,
     recomputes the rest. If the no-remat estimate exceeds
     ``budget_frac`` of what is left after params + fp32 moments, remat
     pays its ~30% recompute FLOPs. ``hbm_bytes`` defaults to the
-    backend device's reported memory, or a 16 GiB TPU-core default
-    when the backend (CPU harness) reports none.
+    backend device's reported memory; a TPU that reports none is an
+    error, and the CPU harness (which reports none) is sized as one
+    16 GiB chip so tests decide as a v5e would.
     """
     seq = seq_len or cfg.max_len
     itemsize = jnp.dtype(cfg.dtype).itemsize
@@ -480,8 +481,14 @@ def choose_remat(cfg: TransformerConfig, batch_size: int,
                                   + 2 * cfg.d_model * cfg.d_ff))
     resident = n_params * (4 + 8)                          # fp32 + adam
     if hbm_bytes is None:
-        stats = getattr(jax.devices()[0], "memory_stats", lambda: None)()
-        hbm_bytes = (stats or {}).get("bytes_limit", 16 * (1 << 30))
+        dev = jax.devices()[0]
+        hbm_bytes = (dev.memory_stats() or {}).get("bytes_limit")
+        if hbm_bytes is None:
+            if dev.platform == "tpu":
+                raise RuntimeError(
+                    f"{dev.device_kind} reports no bytes_limit: pass "
+                    "hbm_bytes, remat cannot be sized from a guess")
+            hbm_bytes = 16 * (1 << 30)
     return activations > budget_frac * max(hbm_bytes - resident,
                                            hbm_bytes // 8)
 

@@ -41,6 +41,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from edl_tpu.utils.logging import get_logger
+
+log = get_logger("edl_tpu.ops.flash_attention")
+
 _NEG_INF = -1e30
 
 
@@ -400,19 +404,30 @@ def force_interpret_kernels():
         _FORCE_INTERPRET = False
 
 
-def _use_kernels() -> bool:
-    """Off-TPU the compiled XLA blockwise paths run instead of
-    interpret-mode Pallas (orders of magnitude slower — it would
-    throttle the CPU elastic/multipod worlds)."""
-    return jax.default_backend() == "tpu" or _FORCE_INTERPRET
+def _kernel_interpret(what: str, q) -> bool | None:
+    """Which path this trace takes: the Pallas `interpret` flag (False
+    = compiled, on TPU; True = the test hook), or None for the compiled
+    XLA blockwise paths — off-TPU, where interpret-mode Pallas is
+    orders of magnitude slower and would throttle the CPU
+    elastic/multipod worlds. Logged per trace, so a trainer's log says
+    which attention its step was built from."""
+    if jax.default_backend() == "tpu":
+        mode, interpret = "pallas kernel, compiled", False
+    elif _FORCE_INTERPRET:
+        mode, interpret = "pallas kernel, interpret mode", True
+    else:
+        mode, interpret = "xla blockwise", None
+    log.info("flash attention %s %s: %s", what, tuple(q.shape), mode)
+    return interpret
 
 
 def _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal):
-    if not _use_kernels():
+    interpret = _kernel_interpret("fwd", q)
+    if interpret is None:
         return _fwd_blockwise(q, k, v, blk=blk_k, scale=scale,
                               causal=causal)
     return _fwd(q, k, v, blk_q=blk_q, blk_k=blk_k, scale=scale,
-                causal=causal, interpret=jax.default_backend() != "tpu")
+                causal=causal, interpret=interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -428,12 +443,13 @@ def _flash_lse_fwd(q, k, v, blk_q, blk_k, scale, causal):
 def _flash_lse_bwd(blk_q, blk_k, scale, causal, res, cotangents):
     q, k, v, o, lse = res
     do, dlse = cotangents
-    if not _use_kernels():
+    interpret = _kernel_interpret("bwd", q)
+    if interpret is None:
         return _bwd_blockwise(q, k, v, o, lse, do, blk=blk_k,
                               scale=scale, causal=causal, dlse=dlse)
     return _bwd_pallas(q, k, v, o, lse, do, blk_q=blk_q, blk_k=blk_k,
                        scale=scale, causal=causal, dlse=dlse,
-                       interpret=jax.default_backend() != "tpu")
+                       interpret=interpret)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)

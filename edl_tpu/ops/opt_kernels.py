@@ -1,12 +1,16 @@
 """Fused optimizer-update kernels over flat parameter buckets.
 
-One Pallas VMEM pass per bucket: grad + param + moments stream through
-VMEM once and the whole momentum-SGD / Adam update (including the
-dequant-update-requant round trip when moments are held quantized)
-happens in registers, instead of XLA's long chain of elementwise HLOs
-that re-reads HBM between every multiply. Buckets come from the same
+One row-block-gridded Pallas pass per bucket: grad + param + moments
+stream through VMEM a block at a time (ops/pack.py `row_grid`) and the
+whole momentum-SGD / Adam update happens in registers, instead of XLA's
+chain of elementwise HLOs. Quantized moments take two more passes per
+moment plane, because each of a plane's two per-tensor scales is a
+whole-bucket abs-max that must be known before anything is rounded
+under it: the update pass emits the new moment and its per-block
+abs-max, the requant pass rounds it and emits the residual and its
+abs-max, the last pass rounds the residual. Buckets come from the same
 planner as the DCN gradient path (train/comm.py plan_buckets): flat,
-dtype-grouped, lane-padded buffers a few MiB each — well inside VMEM.
+dtype-grouped, lane-padded buffers of any size.
 
 Backend split mirrors ops/pack.py exactly: the kernel path runs on TPU
 (or under `force_pallas_interpret()` in tests), everywhere else the
@@ -36,11 +40,11 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
-from edl_tpu.ops.pack import (dequantize_int8, quantize_int8,
-                              symmetric_scale)
+from edl_tpu.ops.pack import (_LANE, block_amax, dequantize_int8,
+                              quantize_int8, row_block_call, scale_of_amax)
 
-_LANE = 128         # TPU lane width: kernel operands reshape to (-1, 128)
 _FORCE_INTERPRET = False
 
 OPTIMIZERS = ("sgdm", "adam")
@@ -58,19 +62,14 @@ def _use_pallas() -> bool:
     return _FORCE_INTERPRET or jax.default_backend() == "tpu"
 
 
-# -- fp8 plane codec (rides the int8 wire) ----------------------------------
+# -- moment codecs (fp8 rides the int8 wire) ---------------------------------
 
 FP8_MAX = 448.0     # float8_e4m3fn finite max
+_QMAX = {"int8": 127.0, "fp8": FP8_MAX}
 
 
 def fp8_dtype():
-    """float8_e4m3fn if this jax build has it, else None."""
-    return getattr(jnp, "float8_e4m3fn", None)
-
-
-def _fp8_scale(x: jnp.ndarray) -> jnp.ndarray:
-    amax = jnp.max(jnp.abs(x))
-    return jnp.where(amax > 0, amax / FP8_MAX, 1.0).astype(jnp.float32)
+    return jnp.float8_e4m3fn
 
 
 def _quantize_fp8(x: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
@@ -81,6 +80,19 @@ def _quantize_fp8(x: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
 def _dequantize_fp8(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
     f8 = jax.lax.bitcast_convert_type(q, fp8_dtype())
     return f8.astype(jnp.float32) * scale.astype(jnp.float32)
+
+
+def _quantize(x, scale, quant: str):
+    return (quantize_int8 if quant == "int8" else _quantize_fp8)(x, scale)
+
+
+def _dequantize(q, scale, quant: str):
+    return (dequantize_int8 if quant == "int8"
+            else _dequantize_fp8)(q, scale)
+
+
+def _scale(x: jnp.ndarray, quant: str) -> jnp.ndarray:
+    return scale_of_amax(jnp.max(jnp.abs(x)), _QMAX[quant])
 
 
 # -- quantized moment plane --------------------------------------------------
@@ -112,38 +124,27 @@ class QPlane(NamedTuple):
 
 def _dq2(q, scale, rq, rscale, quant: str) -> jnp.ndarray:
     """Reassemble the full-precision moment: payload + residual."""
-    if quant == "int8":
-        return dequantize_int8(q, scale) + dequantize_int8(rq, rscale)
-    return _dequantize_fp8(q, scale) + _dequantize_fp8(rq, rscale)
+    return _dequantize(q, scale, quant) + _dequantize(rq, rscale, quant)
 
 
 def _rq2(m: jnp.ndarray, quant: str):
     """Requantize an updated moment; the rounding error becomes the new
     residual (itself quantized — that is what halves the bytes)."""
-    if quant == "int8":
-        scale = symmetric_scale(m)
-        q = quantize_int8(m, scale)
-        r = m - dequantize_int8(q, scale)
-        rscale = symmetric_scale(r)
-        rq = quantize_int8(r, rscale)
-    else:
-        scale = _fp8_scale(m)
-        q = _quantize_fp8(m, scale)
-        r = m - _dequantize_fp8(q, scale)
-        rscale = _fp8_scale(r)
-        rq = _quantize_fp8(r, rscale)
-    return q, scale, rq, rscale
+    scale = _scale(m, quant)
+    q = _quantize(m, scale, quant)
+    r = m - _dequantize(q, scale, quant)
+    rscale = _scale(r, quant)
+    return q, scale, _quantize(r, rscale, quant), rscale
 
 
 def quant_plane(m: jnp.ndarray, quant: str) -> QPlane:
     """Full-precision moment -> resident QPlane."""
-    q, scale, rq, rscale = _rq2(m.astype(jnp.float32), quant)
-    return QPlane(q=q, scale=scale, rq=rq, rscale=rscale)
+    return QPlane(*_rq2(m.astype(jnp.float32), quant))
 
 
 def dequant_plane(plane: QPlane, quant: str) -> jnp.ndarray:
     """Resident QPlane -> full-precision moment (payload + residual)."""
-    return _dq2(plane.q, plane.scale, plane.rq, plane.rscale, quant)
+    return _dq2(*plane, quant)
 
 
 def zero_plane(n: int, quant: str) -> QPlane:
@@ -187,65 +188,61 @@ def _adam_math(p, g, m, v, lr, c1, c2, b1: float, b2: float,
 
 
 # -- Pallas kernel bodies ----------------------------------------------------
-# Scalars ride as (1, 1) fp32 operands (SMEM-shaped); hyperparameters
-# that never change per step (mu, b1, ...) are compile-time statics.
+# Every kernel is one step of a 1-D grid over row blocks
+# (ops/pack.py `row_block_call`):
+# s_ref is the SMEM vector of this call's fp32 scalars, the other refs
+# are (block, 128) VMEM blocks, and an abs-max ref is the SMEM vector of
+# per-block partials. Hyperparameters that never change per step (mu,
+# b1, ...) are compile-time statics.
 
 
-def _sgdm_fp32_kernel(p_ref, g_ref, m_ref, lr_ref, po_ref, mo_ref,
-                      *, mu, wd):
-    p_new, m_new = _sgdm_math(p_ref[:], g_ref[:], m_ref[:],
-                              lr_ref[0, 0], mu, wd)
+def _sgdm_kernel(s_ref, p_ref, g_ref, *refs, mu, wd, quant, rows):
+    if quant == "off":
+        m_ref, po_ref, mo_ref = refs
+        m = m_ref[:]
+    else:
+        q_ref, rq_ref, po_ref, mo_ref, am_ref = refs
+        m = _dq2(q_ref[:], s_ref[1], rq_ref[:], s_ref[2], quant)
+    p_new, m_new = _sgdm_math(p_ref[:], g_ref[:], m, s_ref[0], mu, wd)
     po_ref[:] = p_new
     mo_ref[:] = m_new
+    if quant != "off":
+        am_ref[pl.program_id(0)] = block_amax(m_new, rows)
 
 
-def _sgdm_q_kernel(p_ref, g_ref, q_ref, s_ref, rq_ref, rs_ref, lr_ref,
-                   po_ref, qo_ref, so_ref, rqo_ref, rso_ref,
-                   *, mu, wd, quant):
-    m = _dq2(q_ref[:], s_ref[0, 0], rq_ref[:], rs_ref[0, 0], quant)
-    p_new, m_new = _sgdm_math(p_ref[:], g_ref[:], m, lr_ref[0, 0],
-                              mu, wd)
-    q, s, rq, rs = _rq2(m_new, quant)
-    po_ref[:] = p_new
-    qo_ref[:] = q
-    so_ref[0, 0] = s
-    rqo_ref[:] = rq
-    rso_ref[0, 0] = rs
-
-
-def _adam_fp32_kernel(p_ref, g_ref, m_ref, v_ref, lr_ref, c1_ref,
-                      c2_ref, po_ref, mo_ref, vo_ref,
-                      *, b1, b2, eps, wd):
+def _adam_kernel(s_ref, p_ref, g_ref, *refs, b1, b2, eps, wd, quant,
+                 rows):
+    if quant == "off":
+        m_ref, v_ref, po_ref, mo_ref, vo_ref = refs
+        m, v = m_ref[:], v_ref[:]
+    else:
+        (qm_ref, rqm_ref, qv_ref, rqv_ref, po_ref, mo_ref, vo_ref,
+         am_ref, av_ref) = refs
+        m = _dq2(qm_ref[:], s_ref[3], rqm_ref[:], s_ref[4], quant)
+        v = _dq2(qv_ref[:], s_ref[5], rqv_ref[:], s_ref[6], V_QUANT)
     p_new, m_new, v_new = _adam_math(
-        p_ref[:], g_ref[:], m_ref[:], v_ref[:], lr_ref[0, 0],
-        c1_ref[0, 0], c2_ref[0, 0], b1, b2, eps, wd)
+        p_ref[:], g_ref[:], m, v, s_ref[0], s_ref[1], s_ref[2], b1, b2,
+        eps, wd)
     po_ref[:] = p_new
     mo_ref[:] = m_new
     vo_ref[:] = v_new
+    if quant != "off":
+        am_ref[pl.program_id(0)] = block_amax(m_new, rows)
+        av_ref[pl.program_id(0)] = block_amax(v_new, rows)
 
 
-def _adam_q_kernel(p_ref, g_ref, qm_ref, sm_ref, rqm_ref, rsm_ref,
-                   qv_ref, sv_ref, rqv_ref, rsv_ref, lr_ref, c1_ref,
-                   c2_ref, po_ref, qmo_ref, smo_ref, rqmo_ref,
-                   rsmo_ref, qvo_ref, svo_ref, rqvo_ref, rsvo_ref,
-                   *, b1, b2, eps, wd, quant):
-    m = _dq2(qm_ref[:], sm_ref[0, 0], rqm_ref[:], rsm_ref[0, 0], quant)
-    v = _dq2(qv_ref[:], sv_ref[0, 0], rqv_ref[:], rsv_ref[0, 0],
-             V_QUANT)
-    p_new, m_new, v_new = _adam_math(
-        p_ref[:], g_ref[:], m, v, lr_ref[0, 0], c1_ref[0, 0],
-        c2_ref[0, 0], b1, b2, eps, wd)
-    qm, sm, rqm, rsm = _rq2(m_new, quant)
-    qv, sv, rqv, rsv = _rq2(v_new, V_QUANT)
-    po_ref[:] = p_new
-    qmo_ref[:] = qm
-    smo_ref[0, 0] = sm
-    rqmo_ref[:] = rqm
-    rsmo_ref[0, 0] = rsm
-    qvo_ref[:] = qv
-    svo_ref[0, 0] = sv
-    rqvo_ref[:] = rqv
-    rsvo_ref[0, 0] = rsv
+def _requant_kernel(s_ref, m_ref, q_ref, r_ref, ar_ref, *, quant, rows):
+    m = m_ref[:]
+    q = _quantize(m, s_ref[0], quant)
+    r = m - _dequantize(q, s_ref[0], quant)
+    q_ref[:] = q
+    r_ref[:] = r
+    ar_ref[pl.program_id(0)] = block_amax(r, rows)
+
+
+def _quantize_kernel(s_ref, x_ref, q_ref, *, quant, rows):
+    del rows
+    q_ref[:] = _quantize(x_ref[:], s_ref[0], quant)
 
 
 # -- jitted XLA fallbacks ----------------------------------------------------
@@ -286,66 +283,51 @@ def _adam_xla_q(p, g, qm, sm, rqm, rsm, qv, sv, rqv, rsv, lr, c1, c2,
 # -- pallas_call wrappers (jitted once per bucket shape) ---------------------
 
 
-def _shapes(*arrs):
-    return tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrs)
-
-
-_S11 = jax.ShapeDtypeStruct((1, 1), jnp.float32)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("mu", "wd", "interpret"))
-def _sgdm_fp32_pallas(p2, g2, m2, lr, *, mu, wd, interpret):
-    from jax.experimental import pallas as pl
-
-    return pl.pallas_call(
-        functools.partial(_sgdm_fp32_kernel, mu=mu, wd=wd),
-        out_shape=_shapes(p2, m2),
-        interpret=interpret,
-    )(p2, g2, m2, lr)
+def _requant_pallas(m_new, amax, quant: str, interpret: bool) -> QPlane:
+    """fp32 moment plane + its abs-max -> QPlane (the kernel-path twin
+    of `_rq2`, same expressions under the same two scales)."""
+    f32, i8 = jnp.float32, jnp.int8
+    scale = scale_of_amax(amax, _QMAX[quant])
+    q, r, rmax = row_block_call(
+        functools.partial(_requant_kernel, quant=quant), [scale], [m_new],
+        [i8, f32], 1, interpret)
+    rscale = scale_of_amax(rmax, _QMAX[quant])
+    (rq,) = row_block_call(
+        functools.partial(_quantize_kernel, quant=quant), [rscale], [r],
+        [i8], 0, interpret)
+    return QPlane(q=q, scale=scale, rq=rq, rscale=rscale)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("mu", "wd", "quant", "interpret"))
-def _sgdm_q_pallas(p2, g2, q2, s, rq2, rs, lr, *, mu, wd, quant,
-                   interpret):
-    from jax.experimental import pallas as pl
-
-    return pl.pallas_call(
-        functools.partial(_sgdm_q_kernel, mu=mu, wd=wd, quant=quant),
-        out_shape=_shapes(p2, q2) + (_S11,) + _shapes(rq2) + (_S11,),
-        interpret=interpret,
-    )(p2, g2, q2, s, rq2, rs, lr)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("b1", "b2", "eps", "wd", "interpret"))
-def _adam_fp32_pallas(p2, g2, m2, v2, lr, c1, c2, *, b1, b2, eps, wd,
-                      interpret):
-    from jax.experimental import pallas as pl
-
-    return pl.pallas_call(
-        functools.partial(_adam_fp32_kernel, b1=b1, b2=b2, eps=eps,
-                          wd=wd),
-        out_shape=_shapes(p2, m2, v2),
-        interpret=interpret,
-    )(p2, g2, m2, v2, lr, c1, c2)
+def _sgdm_pallas(p2, g2, m, lr, *, mu, wd, quant, interpret):
+    f32 = jnp.float32
+    kernel = functools.partial(_sgdm_kernel, mu=mu, wd=wd, quant=quant)
+    if quant == "off":
+        return row_block_call(kernel, [lr], [p2, g2, m], [f32, f32], 0,
+                              interpret)
+    p_new, m_new, amax = row_block_call(
+        kernel, [lr, m.scale, m.rscale], [p2, g2, m.q, m.rq], [f32, f32],
+        1, interpret)
+    return p_new, _requant_pallas(m_new, amax, quant, interpret)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("b1", "b2", "eps", "wd", "quant",
                                     "interpret"))
-def _adam_q_pallas(p2, g2, qm2, sm, rqm2, rsm, qv2, sv, rqv2, rsv, lr,
-                   c1, c2, *, b1, b2, eps, wd, quant, interpret):
-    from jax.experimental import pallas as pl
-
-    return pl.pallas_call(
-        functools.partial(_adam_q_kernel, b1=b1, b2=b2, eps=eps, wd=wd,
-                          quant=quant),
-        out_shape=(_shapes(p2, qm2) + (_S11,) + _shapes(rqm2) + (_S11,)
-                   + _shapes(qv2) + (_S11,) + _shapes(rqv2) + (_S11,)),
-        interpret=interpret,
-    )(p2, g2, qm2, sm, rqm2, rsm, qv2, sv, rqv2, rsv, lr, c1, c2)
+def _adam_pallas(p2, g2, m, v, lr, c1, c2, *, b1, b2, eps, wd, quant,
+                 interpret):
+    f32 = jnp.float32
+    kernel = functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps,
+                               wd=wd, quant=quant)
+    if quant == "off":
+        return row_block_call(kernel, [lr, c1, c2], [p2, g2, m, v],
+                              [f32, f32, f32], 0, interpret)
+    p_new, m_new, v_new, m_amax, v_amax = row_block_call(
+        kernel, [lr, c1, c2, m.scale, m.rscale, v.scale, v.rscale],
+        [p2, g2, m.q, m.rq, v.q, v.rq], [f32, f32, f32], 2, interpret)
+    return (p_new, _requant_pallas(m_new, m_amax, quant, interpret),
+            _requant_pallas(v_new, v_amax, V_QUANT, interpret))
 
 
 # -- per-bucket public entry points ------------------------------------------
@@ -354,12 +336,12 @@ def _adam_q_pallas(p2, g2, qm2, sm, rqm2, rsm, qv2, sv, rqv2, rsv, lr,
 # zero padding is a fixed point of both updates, so it never drifts).
 
 
-def _lanes(x: jnp.ndarray) -> jnp.ndarray:
-    return x.reshape(-1, _LANE)
-
-
-def _s11(x) -> jnp.ndarray:
-    return jnp.asarray(x, jnp.float32).reshape(1, 1)
+def _shaped(state, shape):
+    """A moment state (fp32 buffer or QPlane) with its planes reshaped."""
+    if isinstance(state, QPlane):
+        return state._replace(q=state.q.reshape(shape),
+                              rq=state.rq.reshape(shape))
+    return state.reshape(shape)
 
 
 def _interpret() -> bool:
@@ -374,25 +356,17 @@ def sgdm_bucket(p, g, m_state, lr, *, mu: float, wd: float,
     (p_new, m_state_new) in the same representation.
     """
     lr = jnp.asarray(lr, jnp.float32)
+    if _use_pallas():
+        lanes = (-1, _LANE)
+        p2, m_new = _sgdm_pallas(
+            p.reshape(lanes), g.reshape(lanes), _shaped(m_state, lanes),
+            lr, mu=mu, wd=wd, quant=quant, interpret=_interpret())
+        return p2.reshape(p.shape), _shaped(m_new, p.shape)
     if quant == "off":
-        if not _use_pallas():
-            return _sgdm_xla_fp32(p, g, m_state, lr, mu=mu, wd=wd)
-        p2, m2 = _sgdm_fp32_pallas(_lanes(p), _lanes(g),
-                                   _lanes(m_state), _s11(lr), mu=mu,
-                                   wd=wd, interpret=_interpret())
-        return p2.reshape(p.shape), m2.reshape(m_state.shape)
-    if not _use_pallas():
-        p_new, q, s, rq, rs = _sgdm_xla_q(
-            p, g, m_state.q, m_state.scale, m_state.rq,
-            m_state.rscale, lr, mu=mu, wd=wd, quant=quant)
-        return p_new, QPlane(q=q, scale=s, rq=rq, rscale=rs)
-    p2, q2, s, rq2, rs = _sgdm_q_pallas(
-        _lanes(p), _lanes(g), _lanes(m_state.q), _s11(m_state.scale),
-        _lanes(m_state.rq), _s11(m_state.rscale), _s11(lr), mu=mu,
-        wd=wd, quant=quant, interpret=_interpret())
-    return p2.reshape(p.shape), QPlane(
-        q=q2.reshape(p.shape), scale=s.reshape(()),
-        rq=rq2.reshape(p.shape), rscale=rs.reshape(()))
+        return _sgdm_xla_fp32(p, g, m_state, lr, mu=mu, wd=wd)
+    p_new, *plane = _sgdm_xla_q(p, g, *m_state, lr, mu=mu, wd=wd,
+                                quant=quant)
+    return p_new, QPlane(*plane)
 
 
 def adam_bucket(p, g, m_state, v_state, lr, c1, c2, *, b1: float,
@@ -406,32 +380,17 @@ def adam_bucket(p, g, m_state, v_state, lr, c1, c2, *, b1: float,
     lr = jnp.asarray(lr, jnp.float32)
     c1 = jnp.asarray(c1, jnp.float32)
     c2 = jnp.asarray(c2, jnp.float32)
+    hyper = dict(b1=b1, b2=b2, eps=eps, wd=wd)
+    if _use_pallas():
+        lanes = (-1, _LANE)
+        p2, m_new, v_new = _adam_pallas(
+            p.reshape(lanes), g.reshape(lanes), _shaped(m_state, lanes),
+            _shaped(v_state, lanes), lr, c1, c2, quant=quant,
+            interpret=_interpret(), **hyper)
+        return (p2.reshape(p.shape), _shaped(m_new, p.shape),
+                _shaped(v_new, p.shape))
     if quant == "off":
-        if not _use_pallas():
-            return _adam_xla_fp32(p, g, m_state, v_state, lr, c1, c2,
-                                  b1=b1, b2=b2, eps=eps, wd=wd)
-        p2, m2, v2 = _adam_fp32_pallas(
-            _lanes(p), _lanes(g), _lanes(m_state), _lanes(v_state),
-            _s11(lr), _s11(c1), _s11(c2), b1=b1, b2=b2, eps=eps, wd=wd,
-            interpret=_interpret())
-        return (p2.reshape(p.shape), m2.reshape(m_state.shape),
-                v2.reshape(v_state.shape))
-    if not _use_pallas():
-        (p_new, qm, sm, rqm, rsm, qv, sv, rqv, rsv) = _adam_xla_q(
-            p, g, m_state.q, m_state.scale, m_state.rq,
-            m_state.rscale, v_state.q, v_state.scale, v_state.rq,
-            v_state.rscale, lr, c1, c2, b1=b1, b2=b2, eps=eps, wd=wd,
-            quant=quant)
-        return (p_new, QPlane(q=qm, scale=sm, rq=rqm, rscale=rsm),
-                QPlane(q=qv, scale=sv, rq=rqv, rscale=rsv))
-    (p2, qm2, sm, rqm2, rsm, qv2, sv, rqv2, rsv) = _adam_q_pallas(
-        _lanes(p), _lanes(g), _lanes(m_state.q), _s11(m_state.scale),
-        _lanes(m_state.rq), _s11(m_state.rscale), _lanes(v_state.q),
-        _s11(v_state.scale), _lanes(v_state.rq), _s11(v_state.rscale),
-        _s11(lr), _s11(c1), _s11(c2), b1=b1, b2=b2, eps=eps, wd=wd,
-        quant=quant, interpret=_interpret())
-    mk = QPlane(q=qm2.reshape(p.shape), scale=sm.reshape(()),
-                rq=rqm2.reshape(p.shape), rscale=rsm.reshape(()))
-    vk = QPlane(q=qv2.reshape(p.shape), scale=sv.reshape(()),
-                rq=rqv2.reshape(p.shape), rscale=rsv.reshape(()))
-    return p2.reshape(p.shape), mk, vk
+        return _adam_xla_fp32(p, g, m_state, v_state, lr, c1, c2, **hyper)
+    p_new, *planes = _adam_xla_q(p, g, *m_state, *v_state, lr, c1, c2,
+                                 quant=quant, **hyper)
+    return p_new, QPlane(*planes[:4]), QPlane(*planes[4:])

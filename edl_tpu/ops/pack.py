@@ -1,12 +1,12 @@
 """int8 gradient-bucket pack/unpack for the compressed DCN leg.
 
-One fused pass over a flat gradient shard: abs-max -> symmetric scale ->
-round-to-nearest int8. On TPU this is a single-VMEM-resident Pallas
-kernel (the shard is a comm bucket slice, a few MiB — well under the
-~16 MiB VMEM bound; the abs-max reduction and the quantized store share
-one read of HBM instead of XLA's two). Everywhere else the plain-XLA
-expression is used — interpret-mode Pallas is orders of magnitude
-slower and this sits in the hot step (same split as
+Two row-block-gridded Pallas passes over a flat gradient shard on TPU:
+per-block abs-max partials (reduced to the one symmetric scale
+outside), then round-to-nearest int8 under that scale. The grid keeps
+each step's blocks a fixed size (`_BLOCK_ROWS` x 128 lanes), so any
+bucket size fits the scoped VMEM limit; scalars ride SMEM. Everywhere
+else the plain-XLA expression is used — interpret-mode Pallas is orders
+of magnitude slower and this sits in the hot step (same split as
 ops/flash_attention.py; `force_pallas_interpret()` is the test hook
 that runs the kernel path on CPU to pin equivalence).
 
@@ -25,9 +25,16 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _QMAX = 127.0
 _LANE = 128         # TPU lane width: kernel operands reshape to (-1, 128)
+# Rows per grid step. 1024 x 128 is 512 KiB of fp32 per operand: the
+# widest kernel (quantized Adam: 2 fp32 + 4 int8 planes in, 3 fp32
+# out, each double-buffered) stays under v5e's 16 MiB scoped-VMEM limit,
+# and a multiple of the (32, 128) int8 tile.
+_BLOCK_ROWS = 1024
 _FORCE_INTERPRET = False
 
 
@@ -51,11 +58,15 @@ def _use_pallas() -> bool:
 # and safe inside Pallas kernel bodies.
 
 
+def scale_of_amax(amax: jnp.ndarray, qmax: float = _QMAX) -> jnp.ndarray:
+    """fp32 scale mapping ``amax`` -> ``qmax``; 1.0 for an all-zero
+    input so q == 0 and dequantize is exact."""
+    return jnp.where(amax > 0, amax / qmax, 1.0).astype(jnp.float32)
+
+
 def symmetric_scale(x: jnp.ndarray) -> jnp.ndarray:
-    """fp32 scale mapping |x|max -> 127; 1.0 for an all-zero input so
-    q == 0 and dequantize is exact."""
-    amax = jnp.max(jnp.abs(x))
-    return jnp.where(amax > 0, amax / _QMAX, 1.0).astype(jnp.float32)
+    """fp32 scale mapping |x|max -> 127 (see :func:`scale_of_amax`)."""
+    return scale_of_amax(jnp.max(jnp.abs(x)))
 
 
 def quantize_int8(x: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
@@ -72,35 +83,77 @@ def dequantize_int8(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
 # -- plain-XLA reference (the non-TPU hot path) ------------------------------
 
 
-_scale_of = symmetric_scale  # original internal name (kept for callers)
-
-
 def _pack_xla(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     scale = symmetric_scale(x)
     return quantize_int8(x, scale), scale
 
 
-# -- Pallas kernel -----------------------------------------------------------
+# -- Pallas kernels ----------------------------------------------------------
+# Shared with ops/opt_kernels.py: operands are (rows, 128) planes walked
+# by a 1-D grid of row blocks; per-call scalars ride one SMEM vector;
+# whole-plane abs-max comes out as one SMEM partial per block (max is
+# exact, so reducing the partials outside equals the XLA reduction).
 
 
-def _pack_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[:].astype(jnp.float32)
-    scale = symmetric_scale(x)
-    s_ref[0, 0] = scale
-    q_ref[:] = quantize_int8(x, scale)
+def row_block_call(kernel, scalars, planes, out_dtypes, n_amax: int,
+                   interpret: bool) -> tuple:
+    """Run ``kernel`` over the row blocks of ``planes`` ((rows, 128)
+    each). The kernel sees: the SMEM vector of ``scalars`` (fp32; left
+    out when there are none), a VMEM block per plane, a VMEM block per
+    ``out_dtypes`` entry, then ``n_amax`` SMEM vectors holding one
+    partial per grid step (write them with `block_amax`). Returns the
+    output planes, then the ``n_amax`` whole-plane abs-maxes.
+
+    A plane shorter than one block is a single full-extent block
+    (always a legal block shape, whatever the dtype's tile)."""
+    rows = planes[0].shape[0]
+    blk = min(rows, _BLOCK_ROWS)
+    steps = pl.cdiv(rows, blk)
+    block = pl.BlockSpec((blk, _LANE), lambda i: (i, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    head = [jnp.stack(scalars)] if scalars else []
+    outs = pl.pallas_call(
+        functools.partial(kernel, rows=rows),
+        grid=(steps,),
+        in_specs=[smem] * len(head) + [block] * len(planes),
+        out_specs=[block] * len(out_dtypes) + [smem] * n_amax,
+        out_shape=[jax.ShapeDtypeStruct((rows, _LANE), d)
+                   for d in out_dtypes]
+        + [jax.ShapeDtypeStruct((steps,), jnp.float32)] * n_amax,
+        interpret=interpret,
+    )(*head, *planes)
+    n = len(out_dtypes)
+    return tuple(outs[:n]) + tuple(jnp.max(a) for a in outs[n:])
+
+
+def block_amax(x: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """abs-max of this grid step's block. The last block of a ragged
+    plane reads past ``rows``; those rows hold garbage and are masked
+    to zero (zero never wins an abs-max)."""
+    if rows % x.shape[0]:
+        row = (pl.program_id(0) * x.shape[0]
+               + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0))
+        x = jnp.where(row < rows, x, 0.0)
+    return jnp.max(jnp.abs(x))
+
+
+def _amax_kernel(x_ref, a_ref, *, rows):
+    a_ref[pl.program_id(0)] = block_amax(
+        x_ref[:].astype(jnp.float32), rows)
+
+
+def _quantize_kernel(s_ref, x_ref, q_ref, *, rows):
+    del rows
+    q_ref[:] = quantize_int8(x_ref[:], s_ref[0])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _pack_pallas(x2d: jnp.ndarray, interpret: bool):
-    from jax.experimental import pallas as pl
-
-    q, s = pl.pallas_call(
-        _pack_kernel,
-        out_shape=(jax.ShapeDtypeStruct(x2d.shape, jnp.int8),
-                   jax.ShapeDtypeStruct((1, 1), jnp.float32)),
-        interpret=interpret,
-    )(x2d)
-    return q, s[0, 0]
+    (amax,) = row_block_call(_amax_kernel, [], [x2d], [], 1, interpret)
+    scale = scale_of_amax(amax)
+    (q,) = row_block_call(_quantize_kernel, [scale], [x2d], [jnp.int8],
+                          0, interpret)
+    return q, scale
 
 
 # -- public API --------------------------------------------------------------

@@ -1,20 +1,12 @@
-"""jax API compatibility shims.
-
-The repo targets the modern ``jax.shard_map`` spelling; older jax
-releases (< 0.5) ship it as ``jax.experimental.shard_map.shard_map``
-with ``check_rep`` instead of ``check_vma``. Call sites import the one
-symbol here so the version split lives in exactly one place.
-"""
+"""The one import site of shard_map: ``jax.shard_map`` with the
+varying-axes check off by default — the manual collectives of
+train/comm.py pass ``axis_index_groups``, which that check does not
+implement."""
 
 from __future__ import annotations
 
+import functools
+
 import jax
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+shard_map = functools.partial(jax.shard_map, check_vma=False)

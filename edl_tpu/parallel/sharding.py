@@ -127,6 +127,31 @@ def init_sharded(init_fn, mesh: Mesh,
                    out_shardings=shardings)()
 
 
+def placement_of(tree: Any, mesh: Mesh) -> Any:
+    """The shardings a restored copy of ``tree`` is placed with: each
+    leaf's own where it has a mesh placement, replicated over ``mesh``
+    otherwise (eagerly created scalars such as step counters)."""
+    replicated = NamedSharding(mesh, P())
+
+    def one(leaf):
+        sharding = getattr(leaf, "sharding", None)
+        return sharding if isinstance(sharding, NamedSharding) \
+            else replicated
+
+    return jax.tree.map(one, tree)
+
+
+def bytes_per_device(tree: Any) -> dict[int, int]:
+    """Bytes of ``tree`` each local device holds: tells a sharded state
+    (a share each) from a replicated one (all of it on every device)."""
+    held: dict[int, int] = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            held[shard.device.id] = held.get(shard.device.id, 0) \
+                + shard.data.nbytes
+    return dict(sorted(held.items()))
+
+
 def dp_row_sharding(mesh: Mesh) -> NamedSharding:
     """One distinct row per dp position: ``(W, ...)`` arrays laid out
     ``P('dp')``. The placement of the comm plane's per-chip

@@ -92,16 +92,6 @@ class FusedOptimizer:
         if quant not in QUANT_MODES:
             raise ValueError(f"quant must be one of {QUANT_MODES}, "
                              f"got {quant!r}")
-        if quant == "fp8" and ok.fp8_dtype() is None:
-            raise ValueError("quant='fp8' needs a jax build with "
-                             "float8_e4m3fn; use quant='int8'")
-        if (optimizer == "adam" and quant != "off"
-                and ok.fp8_dtype() is None):
-            raise ValueError(
-                "quantized Adam needs a jax build with float8_e4m3fn: "
-                "the second moment always rides the fp8 codec "
-                "(ops/opt_kernels.V_QUANT — a linear int8 grid under "
-                "the update's sqrt denominator explodes)")
         if bucket_mb <= 0:
             raise ValueError(f"bucket_mb must be > 0, got {bucket_mb}")
         self.optimizer = optimizer
@@ -313,8 +303,7 @@ def update_parity_gate(seed: int = 0, steps: int = 3,
     report["adam_fp32_vs_optax_close"] = err <= 1e-5
 
     # kernel == XLA, every optimizer x quant mode
-    quants = ["off", "int8"] + (["fp8"] if ok.fp8_dtype() else [])
-    for q in quants:
+    for q in ok.QUANT_MODES:
         report[f"sgdm_{q}_kernel_bitwise"] = kernel_vs_xla(
             fused_sgd(lr, 0.9, wd, quant=q, bucket_mb=0.05))
         report[f"adam_{q}_kernel_bitwise"] = kernel_vs_xla(

@@ -20,7 +20,9 @@ import jax
 
 from edl_tpu.obs import recorder as flight
 from edl_tpu.obs import trace
+from edl_tpu.parallel import distributed
 from edl_tpu.parallel import mesh as mesh_lib
+from edl_tpu.parallel import sharding as sharding_lib
 from edl_tpu.train.checkpoint import CheckpointManager
 from edl_tpu.train.state import TrainStatus
 from edl_tpu.utils.config import field
@@ -45,11 +47,6 @@ class LoopConfig:
     # disk + mirror ride a background writer. False = the synchronous
     # escape hatch (every save is a full stall, bytes identical).
     ckpt_async: bool = field(True, env="EDL_TPU_CKPT_ASYNC")
-    # Persistent XLA compilation-cache dir: a re-formed world whose
-    # programs didn't change skips recompiling them on restart
-    # (parallel/distributed.enable_compilation_cache).
-    compile_cache_dir: str | None = field(None,
-                                          env="EDL_TPU_COMPILE_CACHE_DIR")
     # Sharded (per-process chunk) checkpoints — required once params are
     # fsdp/tp-sharded; replicated msgpack is the small-model default.
     ckpt_sharded: bool = field(False, env="EDL_TPU_CHECKPOINT_SHARDED")
@@ -158,9 +155,6 @@ class TrainLoop:
                                        sharded=self.config.ckpt_sharded,
                                        remote=self.config.ckpt_remote)
                      if self.config.ckpt_dir else None)
-        if self.config.compile_cache_dir:
-            from edl_tpu.parallel.distributed import enable_compilation_cache
-            enable_compilation_cache(self.config.compile_cache_dir)
         self.last_metrics: dict = {}
         self._profiling = False
         # Save-stall accounting (benchlog/timeline): step-loop-visible ms
@@ -265,6 +259,8 @@ class TrainLoop:
         self.state, self.status = restored
         if self.place_state is not None:
             self.state = self.place_state(self.state)
+        log.info("state bytes per device: %s",
+                 sharding_lib.bytes_per_device(self.state))
         # Preserve the save-time world size (the resharding/LR-rescale hint)
         # before stamping the current world for the next save.
         self.saved_world_size = self.status.world_size
@@ -713,6 +709,10 @@ class TrainLoop:
                          self.status.step + 1,
                          "%.3f" % self.restore_s
                          if self.restore_s is not None else "none")
+                log.info("first-step wall (trace+compile+run) %.3fs, "
+                         "persistent compile cache %s",
+                         time.perf_counter() - t_dispatch,
+                         distributed.compilation_cache_counts())
                 if self._migration is not None:
                     # restore ack: this pod is trained-and-running —
                     # what lingering donors and the resize audit key on

@@ -264,10 +264,7 @@ def restore_from_index(merged: dict[str, dict], load, target: Any,
                 raise ValueError(
                     f"{key}: target shape {tuple(leaf.shape)} != saved "
                     f"{shape}")
-            try:
-                idx_map = sharding.addressable_devices_indices_map(shape)
-            except AttributeError:  # older jax: no prefetch plan — the
-                idx_map = {}        # callback reads on demand (cached)
+            idx_map = sharding.addressable_devices_indices_map(shape)
             uniq = {_region_key(idx, shape): idx for idx in idx_map.values()}
             plans.append((key, entry, sharding, leaf, list(uniq.values())))
         else:

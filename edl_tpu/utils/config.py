@@ -77,7 +77,6 @@ ENV_VARS: dict[str, str] = {
     "EDL_TPU_SAVE_CHECKPOINT_INTER": "save every N epochs",
     "EDL_TPU_CKPT_RESTORE_THREADS": "parallel restore read threads",
     "EDL_TPU_CKPT_VERIFY": "chunk crc32 verification on restore (0 = off)",
-    "EDL_TPU_COMPILE_CACHE_DIR": "persistent XLA compilation cache dir",
     # -- p2p live state migration ------------------------------------------
     "EDL_TPU_RESIZE_P2P": "peer-to-peer live state migration (0 = "
                           "stop-resume from disk)",

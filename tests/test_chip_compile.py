@@ -1,0 +1,186 @@
+"""Compile for a described TPU v5e, with no chip attached.
+
+The TPU compiler is installed beside JAX and compiles for a topology
+that is only described (`jax.experimental.topologies`). It refuses what
+interpret-mode Pallas lets through: a block off the dtype's tile, more
+VMEM than a kernel may use, a scalar stored to VMEM, a step that does
+not fit the chip. Every Pallas kernel of `edl_tpu/ops/` is compiled
+here with ``interpret=False`` at the shapes the chip runs them at
+(LM-large attention, a 4 MiB optimizer bucket and a ragged one), and
+so is the whole LM-large train step. Nothing runs, so this says nothing
+about results or times: `chip_smoke.py` does, on the chip.
+
+Only one process may load the TPU library, so everything here happens
+in the test's own process, inside fixtures (never at import), and in
+this one file.
+"""
+
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from edl_tpu.models.transformer import (Transformer, TransformerConfig,
+                                        lm_loss_fused)
+from edl_tpu.ops import opt_kernels as ok
+from edl_tpu.ops import pack
+from edl_tpu.train.state import TrainState
+from edl_tpu.train.step import make_train_step
+
+BUCKET_ROWS = 8192            # 4 MiB of fp32 in 128-lane rows
+RAGGED_ROWS = 8192 + 37       # ends mid-block and mid-(32,128)-tile
+F32, I8 = jnp.float32, jnp.int8
+# the package re-exports the function under the module's name
+fa = importlib.import_module("edl_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without a chip (the next one warns
+    and compiles again): keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def placed(chip, tree):
+    """Abstract arguments like ``tree``, placed on ``chip``."""
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        tree)
+
+
+def compile_for(chip, fn, *shapes):
+    """Lower ``fn`` on abstract arguments placed on ``chip``; compile."""
+    return jax.jit(fn).lower(*placed(chip, shapes)).compile()
+
+
+def sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def custom_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# (batch, seq, heads, head dim): LM-large, and the d_model-1024 shape
+FLASH_SHAPES = [(8, 1024, 16, 128), (16, 1024, 16, 64)]
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_flash_kernels_compile(one_chip, no_persistent_cache, kernel,
+                               shape):
+    b, s, h, d = shape
+    blk = fa._fit_block(s, 512)
+    kw = dict(blk_q=blk, blk_k=blk, scale=d ** -0.5, causal=True,
+              interpret=False)
+    x = sds(shape, jnp.bfloat16)
+    lse = sds((b * h, s), F32)
+    if kernel == "fwd":
+        compiled = compile_for(one_chip, functools.partial(fa._fwd, **kw),
+                               x, x, x)
+        assert custom_calls(compiled) == 1
+    else:  # dK/dV and dQ: two kernels
+        compiled = compile_for(
+            one_chip,
+            lambda q, k, v, o, lse, do, dlse: fa._bwd_pallas(
+                q, k, v, o, lse, do, dlse=dlse, **kw),
+            x, x, x, x, lse, x, lse)
+        assert custom_calls(compiled) == 2
+
+
+@pytest.mark.parametrize("rows", [BUCKET_ROWS, RAGGED_ROWS])
+def test_pack_kernel_compiles(one_chip, no_persistent_cache, rows):
+    compiled = compile_for(
+        one_chip, functools.partial(pack._pack_pallas, interpret=False),
+        sds((rows, 128), F32))
+    assert custom_calls(compiled) == 2  # abs-max pass, quantize pass
+
+
+def _moment(rows, quant):
+    plane, scalar = sds((rows, 128), I8), sds((), F32)
+    return (sds((rows, 128), F32) if quant == "off"
+            else ok.QPlane(plane, scalar, plane, scalar))
+
+
+@pytest.mark.parametrize("rows", [BUCKET_ROWS, RAGGED_ROWS])
+@pytest.mark.parametrize("quant", ok.QUANT_MODES)
+@pytest.mark.parametrize("optimizer", ok.OPTIMIZERS)
+def test_optimizer_kernels_compile(one_chip, no_persistent_cache,
+                                   optimizer, quant, rows):
+    p, s, m = sds((rows, 128), F32), sds((), F32), _moment(rows, quant)
+    if optimizer == "sgdm":
+        compiled = compile_for(
+            one_chip, functools.partial(ok._sgdm_pallas, mu=0.9, wd=1e-4,
+                                        quant=quant, interpret=False),
+            p, p, m, s)
+        planes = 1
+    else:
+        compiled = compile_for(
+            one_chip, functools.partial(ok._adam_pallas, b1=0.9, b2=0.999,
+                                        eps=1e-8, wd=0.01, quant=quant,
+                                        interpret=False),
+            p, p, m, m, s, s, s)
+        planes = 2
+    # the update pass, then two requant passes per quantized moment
+    assert custom_calls(compiled) == (1 if quant == "off"
+                                      else 1 + 2 * planes)
+
+
+def test_lm_large_train_step_compiles(one_chip, no_persistent_cache,
+                                      monkeypatch):
+    """The step `lm_train --bf16 --fused-loss` builds at LM-large,
+    for one v5e chip: flash kernels in, and it fits 16 GB."""
+    # the program asks the backend which attention to build; here the
+    # answer is steered in the test, the program gets no option for it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = TransformerConfig(vocab_size=32768, d_model=2048, n_heads=16,
+                            n_layers=8, d_ff=8192, max_len=1024,
+                            dtype=jnp.bfloat16)
+    model = Transformer(cfg)
+    assert cfg.use_flash(1024)
+
+    def create():
+        from flax.core import meta
+        variables = meta.unbox(model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 1024), jnp.int32),
+            train=False))
+        return TrainState.create(apply_fn=model.apply,
+                                 params=variables["params"],
+                                 tx=optax.adamw(3e-4, weight_decay=0.01))
+
+    state = jax.eval_shape(create)
+    batch = {"tokens": sds((8, 1024), jnp.int32)}
+    step = make_train_step(lm_loss_fused, donate=True)  # jitted itself
+    compiled = step.lower(placed(one_chip, state),
+                          placed(one_chip, batch)).compile()
+    assert custom_calls(compiled) == 3 * cfg.n_layers  # fwd, dK/dV, dQ
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 16e9, (mem.argument_size_in_bytes,
+                         mem.temp_size_in_bytes)

@@ -189,3 +189,71 @@ def test_job_env_nodes_range(monkeypatch):
     job = JobEnv.from_environ()
     assert (job.min_nodes, job.max_nodes) == (2, 8)
     assert job.pod_id  # auto-generated
+
+
+_FAKE_TRAINER = '''
+import os, signal, sys, time
+events = sys.argv[1]
+def note(what):
+    with open(events, "a") as f:
+        f.write(f"{what} {os.getpid()} {time.monotonic()}\\n")
+def stop(signum, frame):  # a donor: lingers, then exits like a stopped trainer
+    time.sleep(1.5)
+    note("exit")
+    os._exit(143)
+signal.signal(signal.SIGTERM, stop)
+note("start")
+while True:
+    time.sleep(0.05)
+'''
+
+
+def test_replacement_starts_after_lingering_donor_exits(tmp_path):
+    """Stop-resume with a donor linger: one trainer per host drives all
+    its chips and a lingering donor still holds them, so the launcher
+    starts the replacement only once the donor has exited."""
+    import sys
+
+    from edl_tpu.collective.launch import launch
+
+    script, events = tmp_path / "trainer.py", tmp_path / "events"
+    script.write_text(_FAKE_TRAINER)
+    events.write_text("")
+    store = InMemStore()
+    job = JobEnv(job_id=JOB, pod_id="pod0", nodes_range="1:2",
+                 log_dir=str(tmp_path / "log"), lease_ttl=5.0,
+                 barrier_stable_secs=0.2, barrier_timeout=20.0,
+                 adopt_timeout_secs=0.3, donor_linger_secs=5.0)
+    done = {}
+    runner = threading.Thread(
+        target=lambda: done.update(rc=launch(
+            job, [sys.executable, str(script), str(events)], store=store,
+            poll=0.1)), daemon=True)
+    runner.start()
+
+    def seen(what):
+        return [(int(pid), float(t)) for w, pid, t in
+                (ln.split() for ln in events.read_text().splitlines())
+                if w == what]
+
+    def wait(cond, timeout=30.0):
+        deadline = time.monotonic() + timeout
+        while not cond():
+            assert time.monotonic() < deadline, events.read_text()
+            time.sleep(0.05)
+
+    wait(lambda: len(seen("start")) == 1)
+    # a pod joins: the fake trainer cannot adopt the new world in place,
+    # so the launcher releases it (SIGTERM -> linger) and respawns
+    joiner = reg.PodRegister(store, JOB, make_pod(1), max_nodes=2, ttl=5.0)
+    joiner.claim()
+    wait(lambda: len(seen("start")) == 2)
+    (donor, exited), = seen("exit")
+    (first, _), (second, started) = seen("start")
+    assert donor == first != second
+    assert started >= exited, "replacement started while the donor lived"
+    store.put(reg.complete_key(JOB), "1")
+    runner.join(20.0)
+    assert done == {"rc": 0}
+    wait(lambda: len(seen("exit")) == 2)  # the released replacement, too
+    joiner.release()
