@@ -36,6 +36,7 @@ from edl_tpu.collective.process import (start_trainer, release_trainer,
 from edl_tpu.collective.watcher import ClusterWatcher
 from edl_tpu.coord.client import StoreClient
 from edl_tpu.coord.store import Store
+from edl_tpu.obs import trace
 from edl_tpu.utils import net
 from edl_tpu.utils.config import describe
 from edl_tpu.utils.exceptions import EdlError
@@ -79,6 +80,10 @@ def launch(job: JobEnv, trainer_cmd: list[str], *, store: Store | None = None,
     # SIGTERM'd trainers that keep serving their sealed snapshot to the
     # re-formed world. Reaped each poll; force-killed past the deadline.
     lingering: list[list] = []  # [TrainerProc, kill_deadline]
+    # a crash's way back to a running trainer, by phase (trace.Phases):
+    # opened where the exit is seen, reported where the next trainer is
+    # started
+    reform = None
 
     def _reap_lingering() -> None:
         now = time.monotonic()
@@ -121,8 +126,15 @@ def launch(job: JobEnv, trainer_cmd: list[str], *, store: Store | None = None,
                     stable_secs=job.barrier_stable_secs,
                     timeout=job.barrier_timeout)
                 last_version = cluster.version
+                if reform is not None:
+                    reform.done("barrier")
             if trainer is None:
                 trainer = _start_trainer(cluster)
+                if reform is not None:
+                    reform.done("spawn", {"pid": trainer.pid})
+                    log.info("reform: exit_seen\u2192spawn %.3fs (%s)",
+                             *reform.emit())
+                    reform = None
             watcher = ClusterWatcher(store, cluster).start()
             generation_start = time.monotonic()
 
@@ -162,6 +174,8 @@ def launch(job: JobEnv, trainer_cmd: list[str], *, store: Store | None = None,
                             restart_reason = "crash_loop"
                         else:
                             restart_reason = "crash"
+                            reform = trace.Phases("launch.reform", "launch")
+                            reform.mark("exit_seen", {"rc": rc})
 
             watcher.stop()
             if restart_reason == "membership" and job.resize_p2p \
@@ -231,6 +245,8 @@ def launch(job: JobEnv, trainer_cmd: list[str], *, store: Store | None = None,
                                            max_nodes=job.max_nodes,
                                            ttl=job.lease_ttl)
                 register.claim()
+                if reform is not None:
+                    reform.done("rejoin_wait")
     except EdlError as exc:
         log.error("launcher failed: %s", exc)
         return 2

@@ -31,6 +31,7 @@ from edl_tpu.data.pipeline import DataLoader, FileSource
 from edl_tpu.models.transformer import (Transformer, TransformerConfig,
                                         lm_loss_fn, lm_loss_fused,
                                         lm_loss_moe)
+from edl_tpu.obs import trace
 from edl_tpu.parallel import distributed, mesh as mesh_lib, sharding as shd
 from edl_tpu.train import lr as lr_lib
 from edl_tpu.train.benchlog import BenchmarkLog
@@ -63,6 +64,11 @@ def make_synthetic_shards(data_dir: str, n_files: int, rows: int,
 
 
 def main(argv=None) -> int:
+    # start-up by phase, from the kernel's start of this process: the
+    # imports above are its age when this line runs
+    startup = trace.Phases("train.startup", "startup",
+                           age_s=trace.process_age_s() or 0.0)
+    startup.done("imports")
     parser = argparse.ArgumentParser(prog="edl_tpu.examples.lm_train")
     parser.add_argument("--data-dir", required=True)
     parser.add_argument("--make-synthetic", type=int, default=0)
@@ -173,6 +179,8 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     if args.fp16 and args.bf16:
         parser.error("--fp16 and --bf16 are mutually exclusive")
+    if args.profile:
+        trace.collect(args.profile)  # spans from here on, start-up's too
 
     if 0 < args.schedule_epochs < args.epochs:
         raise SystemExit(
@@ -180,8 +188,11 @@ def main(argv=None) -> int:
             f"{args.epochs}: epochs past the horizon would train at "
             "LR ~0 (the horizon is the job TOTAL; the stop point is "
             "--epochs)")
+    startup.done("args")
     distributed.force_platform_from_env()
     env = distributed.init_from_env()
+    jax.devices()  # the runtime starts here, not inside a later phase
+    startup.done("runtime")
     world = max(1, env.world_size)
     rank = max(0, env.rank)
     if args.make_synthetic and rank == 0:
@@ -348,12 +359,15 @@ def main(argv=None) -> int:
 
     # one row per batch shard: the flash kernel runs under a shard_map
     # over the batch axes, which a single row cannot be split over
+    startup.done("mesh_model")
     toks0 = jnp.zeros((mesh_lib.dp_size(mesh), args.seq_len), jnp.int32)
     variables = shd.init_sharded(
         lambda: model.init(jax.random.PRNGKey(args.seed), toks0,
                            train=False), mesh)
     state = TrainState.create(apply_fn=model.apply,
                               params=variables["params"], tx=tx)
+    jax.block_until_ready(state)
+    startup.done("state_init")
     loss = lm_loss_fused if args.fused_loss else lm_loss_fn
     if args.fp16:
         # TrainLoop's contract is step(state, batch); the loss-scale
@@ -458,6 +472,8 @@ def main(argv=None) -> int:
         return ({"tokens": b["tokens"]} for b in loader.epoch(epoch))
 
     data_fn.close = loader.close  # TrainLoop tears down the mp workers
+    startup.done("loop_init")
+    log.info("startup: process_start\u2192run %.3fs (%s)", *startup.emit())
     status = loop.run(data_fn)
     blog.extra(**loop.ckpt_stats())  # save-stall / restore accounting
     if comm_cfg is not None or args.moe:

@@ -308,20 +308,27 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = True):
         cfg = self.cfg
-        h = nn.LayerNorm(dtype=cfg.dtype, name="ln_attn")(x)
+        # The module names are scopes of a device trace already
+        # (`block<i>/attn/...`); `ln` and `mlp` group what has no module
+        # of its own. Scopes are metadata: no parameter path changes.
+        with jax.named_scope("ln"):
+            h = nn.LayerNorm(dtype=cfg.dtype, name="ln_attn")(x)
         h = Attention(cfg, name="attn")(h, train)
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout, deterministic=not train)(h)
         x = x + h
-        h = nn.LayerNorm(dtype=cfg.dtype, name="ln_mlp")(x)
+        with jax.named_scope("ln"):
+            h = nn.LayerNorm(dtype=cfg.dtype, name="ln_mlp")(x)
         if cfg.moe:
             h = MoEMLP(cfg, name="moe_mlp")(h)
         else:
-            h = _dense(cfg.d_ff, ("embed", "mlp"), cfg, name="mlp_in")(h)
-            h = nn.gelu(h)
-            h = cfg.constrain(h, ("batch", "seq", "mlp"))
-            h = _dense(cfg.d_model, ("mlp", "embed"), cfg,
-                       name="mlp_out")(h)
+            with jax.named_scope("mlp"):
+                h = _dense(cfg.d_ff, ("embed", "mlp"), cfg,
+                           name="mlp_in")(h)
+                h = nn.gelu(h)
+                h = cfg.constrain(h, ("batch", "seq", "mlp"))
+                h = _dense(cfg.d_model, ("mlp", "embed"), cfg,
+                           name="mlp_out")(h)
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout, deterministic=not train)(h)
         return x + h
@@ -355,15 +362,17 @@ class Transformer(nn.Module):
             nn.with_logical_partitioning(nn.initializers.normal(0.02),
                                          ("seq", "embed")),
             (cfg.max_len, cfg.d_model))
-        x = embed(tokens)
-        x = x + pos_embed[None, :tokens.shape[1]].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = embed(tokens)
+            x = x + pos_embed[None, :tokens.shape[1]].astype(cfg.dtype)
         x = cfg.constrain(x, ("batch", "seq", "embed"))
         block = Block
         if cfg.remat:
             block = nn.remat(Block, static_argnums=(2,))
         for i in range(cfg.n_layers):
             x = block(cfg, name=f"block{i}")(x, train)
-        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_final")(x)
+        with jax.named_scope("ln"):
+            x = nn.LayerNorm(dtype=cfg.dtype, name="ln_final")(x)
         if return_hidden:
             return x
         # Tied-untied head: separate projection, fp32 logits for stable CE.

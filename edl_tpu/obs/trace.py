@@ -17,25 +17,45 @@ linked tree across every process it touches:
           migrate.fetch x chunks                     <- tensor wire hop
             migrate.serve_fetch (donor process)
 
-Enablement: ``EDL_TPU_TRACE`` — unset/0 = off (spans are a single
-attribute read + ``if`` on the hot path), ``1`` = on with the default
-sink directory ``./edl_trace``, any other value = on with that value as
-the sink directory. Every process appends finished spans to its own
-``spans-<pid>.jsonl`` in the sink dir (timestamps are wall-clock so
-files from different processes merge); a bounded in-process ring keeps
-the most recent spans readable without file I/O (tests, resize_bench's
-phase column). ``python -m edl_tpu.obs trace <dir>`` merges the files
-into per-trace trees and exports Chrome-trace/Perfetto JSON.
+Two switches turn spans on; with neither, a span site is a single
+cached read + ``if`` (no record, no clock, no file):
+
+- ``EDL_TPU_TRACE`` — unset/0 = off, ``1`` = on with the default sink
+  directory ``./edl_trace``, any other value = on with that value as the
+  sink directory. Every process appends each finished span to its own
+  ``spans-<pid>.jsonl`` in the sink dir and flushes it (control-plane
+  use: pods die by signal).
+- a profile directory — ``EDL_TPU_PROFILE_DIR`` in the environment, or
+  :func:`collect` called by an entry point that parsed ``--profile``.
+  Finished spans are kept in memory from then on and written to
+  ``<profile_dir>/spans-<pid>.jsonl`` only by :func:`flush` (the train
+  loop calls it when its profiler stops; also at exit and on SIGTERM):
+  no write per span on the loop's thread.
+
+One clock: a span's ``t0`` is wall-clock (files of several processes
+merge) and its ``dur`` is a ``perf_counter`` difference. While a device
+profiler runs, whoever started it installs an annotator
+(:func:`set_annotator`) and every scoped span opened on any thread of
+the process is also an event on the host plane of the profiler's own
+file, on the clock its device lines use. ``obs/`` itself never imports
+JAX: the annotator is a callable ``(name, attrs) -> context manager``.
+
+A bounded in-process ring keeps the most recent spans readable without
+file I/O (tests, resize_bench's phase column). ``python -m edl_tpu.obs
+trace <dir>`` merges the files into per-trace trees and exports
+Chrome-trace/Perfetto JSON.
 
 Pure stdlib, jax/numpy-free (layers.toml obs row).
 """
 
 from __future__ import annotations
 
+import atexit
 import collections
 import contextlib
 import json
 import os
+import signal
 import threading
 import time
 from typing import Any
@@ -44,36 +64,82 @@ from edl_tpu.utils import config
 
 DEFAULT_DIR = "edl_trace"
 RING_CAP = 4096
+# spans kept for the next flush() under a profile directory: three a
+# step plus a few a save, so hours of steps; the oldest go first
+PENDING_CAP = 1 << 16
 
 _tls = threading.local()
-_lock = threading.Lock()
+# re-entrant: the SIGTERM handler flushes on whatever the main thread
+# was doing, an _emit included
+_lock = threading.RLock()
 _ring: collections.deque = collections.deque(maxlen=RING_CAP)
+_pending: collections.deque = collections.deque(maxlen=PENDING_CAP)
 _file = None          # guarded-by: _lock
 _file_pid = None      # guarded-by: _lock (fork detection)
-_cached: tuple[bool, str | None] | None = None
+# (enabled, sink_dir, buffered): buffered = the sink is a profile
+# directory, written by flush() alone
+_cached: tuple[bool, str | None, bool] | None = None
+_exit_armed = False
+_annotate = None      # (name, attrs) -> context manager, or None
 
 
-def _setting() -> tuple[bool, str | None]:
-    """(enabled, sink_dir) — parsed once per process; tests reset via
-    `reconfigure()`."""
+def _setting() -> tuple[bool, str | None, bool]:
+    """(enabled, sink_dir, buffered) — parsed once per process; tests
+    reset via `reconfigure()`."""
     global _cached
     if _cached is None:
         raw = (config.env_str("EDL_TPU_TRACE") or "").strip()
-        if not raw or raw.lower() in ("0", "false", "no", "off"):
-            _cached = (False, None)
-        elif raw.lower() in ("1", "true", "yes", "on"):
-            _cached = (True, DEFAULT_DIR)
+        profile_dir = (config.env_str("EDL_TPU_PROFILE_DIR") or "").strip()
+        if raw and raw.lower() not in ("0", "false", "no", "off"):
+            _cached = (True, DEFAULT_DIR if raw.lower() in (
+                "1", "true", "yes", "on") else raw, False)
+        elif profile_dir:
+            _cached = (True, profile_dir, True)
+            _arm_exit_flush()
         else:
-            _cached = (True, raw)
+            _cached = (False, None, False)
     return _cached
 
 
+def collect(profile_dir: str) -> None:
+    """Switch spans on for a process that was given a profile directory
+    on its command line (the environment's ``EDL_TPU_PROFILE_DIR`` needs
+    no call). ``EDL_TPU_TRACE``, where set, keeps its own sink."""
+    global _cached
+    if not _setting()[0]:
+        _cached = (True, profile_dir, True)
+        _arm_exit_flush()
+
+
+def _arm_exit_flush() -> None:
+    """flush() at interpreter exit, and on a SIGTERM nobody handles (a
+    trainer without a checkpoint directory dies of it)."""
+    global _exit_armed
+    if _exit_armed:
+        return
+    _exit_armed = True
+    atexit.register(flush)
+    try:
+        if signal.getsignal(signal.SIGTERM) is signal.SIG_DFL:
+            signal.signal(signal.SIGTERM, _flush_and_die)
+    except ValueError:  # not the main thread: exit alone flushes
+        pass
+
+
+def _flush_and_die(signum, frame) -> None:
+    flush()
+    signal.signal(signum, signal.SIG_DFL)
+    os.kill(os.getpid(), signum)
+
+
 def reconfigure() -> None:
-    """Re-read EDL_TPU_TRACE and drop the sink file handle + ring
-    (tests flip the env mid-process; real processes never need this)."""
-    global _cached, _file, _file_pid
+    """Re-read the environment and drop the sink file handle, the ring,
+    what waits for a flush and the annotator (tests flip the env
+    mid-process; real processes never need this)."""
+    global _cached, _file, _file_pid, _annotate
     with _lock:
         _cached = None
+        _annotate = None
         if _file is not None:
             try:
                 _file.close()
@@ -82,6 +148,7 @@ def reconfigure() -> None:
         _file = None
         _file_pid = None
         _ring.clear()
+        _pending.clear()
 
 
 def enabled() -> bool:
@@ -90,6 +157,15 @@ def enabled() -> bool:
 
 def sink_dir() -> str | None:
     return _setting()[1]
+
+
+def set_annotator(fn) -> None:
+    """Install (or with None remove) the callable that turns a scoped
+    span's ``(name, attrs)`` into a context manager entered with it.
+    The train loop installs ``jax.profiler.TraceAnnotation`` for as long
+    as its profiler runs, which puts the span into the profiler's file."""
+    global _annotate
+    _annotate = fn
 
 
 def _new_id() -> str:
@@ -102,12 +178,20 @@ def current() -> tuple[str, str] | None:
 
 
 def _emit(record: dict) -> None:
-    global _file, _file_pid
     _ring.append(record)
-    directory = sink_dir()
+    _, directory, buffered = _setting()
     if directory is None:
         return
-    line = json.dumps(record, separators=(",", ":"), default=str)
+    if buffered:
+        _pending.append(record)   # flush() writes it
+        return
+    _write(directory, [record])
+
+
+def _write(directory: str, records: list[dict]) -> None:
+    global _file, _file_pid
+    text = "".join(json.dumps(r, separators=(",", ":"), default=str) + "\n"
+                   for r in records)
     with _lock:
         if _file is None or _file_pid != os.getpid():
             # per-process file: concurrent writers never interleave, and
@@ -120,10 +204,25 @@ def _emit(record: dict) -> None:
             except OSError:
                 return
         try:
-            _file.write(line + "\n")
+            _file.write(text)
             _file.flush()   # pods die by signal mid-demo: don't buffer
         except (OSError, ValueError):
             pass
+
+
+def flush() -> None:
+    """Write the spans finished since the last flush to the profile
+    directory's ``spans-<pid>.jsonl``. A no-op under ``EDL_TPU_TRACE``
+    (every span is on disk already) and when spans are off."""
+    _, directory, buffered = _setting()
+    if not buffered:
+        return
+    with _lock:
+        records = []
+        while _pending:
+            records.append(_pending.popleft())
+        if records:
+            _write(directory, records)
 
 
 class Span:
@@ -132,7 +231,7 @@ class Span:
     thread or at a later callback (the in-place adoption gap)."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0",
-                 "attrs", "_done")
+                 "attrs", "_done", "_p0")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: str | None, attrs: dict | None):
@@ -140,7 +239,8 @@ class Span:
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
-        self.t0 = time.time()
+        self.t0 = time.time()             # wall: files of processes merge
+        self._p0 = time.perf_counter()    # the duration's clock
         self.attrs = dict(attrs or {})
         self._done = False
 
@@ -155,8 +255,10 @@ class Span:
         self.attrs.update(attrs)
         _emit({"tid": self.trace_id, "sid": self.span_id,
                "parent": self.parent_id, "name": self.name,
-               "pid": os.getpid(), "t0": round(self.t0, 6),
-               "dur": round(time.time() - self.t0, 6),
+               "pid": os.getpid(),
+               "thread": threading.current_thread().name,
+               "t0": round(self.t0, 6),
+               "dur": round(time.perf_counter() - self._p0, 6),
                "attrs": self.attrs})
 
 
@@ -186,8 +288,15 @@ def span(name: str, parent: tuple[str, str] | None = None,
     s = start_span(name, parent=parent, attrs=attrs)
     prev = current()
     _tls.ctx = s.context
+    annotate = _annotate
     try:
-        yield s
+        if annotate is None:
+            yield s
+        else:
+            # attributes known at entry ride into the profiler's file;
+            # those added through the yielded Span reach the record only
+            with annotate(name, s.attrs):
+                yield s
     finally:
         _tls.ctx = prev
         s.end()
@@ -203,22 +312,81 @@ def instant(name: str, parent: tuple[str, str] | None = None,
 
 def event(name: str, dur_s: float,
           parent: tuple[str, str] | None = None,
-          attrs: dict | None = None) -> None:
-    """Emit a pre-measured finished span (the utils/timeline shim's
-    path: the operation already happened, only its duration is known).
-    Parents onto the current/explicit context like any other span."""
+          attrs: dict | None = None,
+          t0: float | None = None) -> tuple[str, str] | None:
+    """Emit a pre-measured finished span (the operation already
+    happened: start-up phases, the launcher's reform, the timeline
+    shim). It ended now unless ``t0`` (wall-clock) says when it began.
+    Parents onto the current/explicit context like any other span and
+    returns its own context, so that children measured the same way can
+    be emitted under it (None when spans are off)."""
     if not enabled():
-        return
+        return None
     ctx = parent if parent is not None else current()
     if ctx is not None:
         trace_id, parent_id = ctx
     else:
         trace_id, parent_id = _new_id(), None
-    now = time.time()
-    _emit({"tid": trace_id, "sid": _new_id(), "parent": parent_id,
+    if t0 is None:
+        t0 = time.time() - dur_s
+    span_id = _new_id()
+    _emit({"tid": trace_id, "sid": span_id, "parent": parent_id,
            "name": name, "pid": os.getpid(),
-           "t0": round(now - dur_s, 6), "dur": round(dur_s, 6),
+           "thread": threading.current_thread().name,
+           "t0": round(t0, 6), "dur": round(dur_s, 6),
            "attrs": dict(attrs or {})})
+    return (trace_id, span_id)
+
+
+class Phases:
+    """Consecutive phases of one rare operation whose log line is
+    written whether spans are on or not (a trainer's start-up, a
+    launcher's reform): stamped as each ends, then emitted after the
+    fact as ``name`` with a child ``prefix.<phase>`` each. Unlike a span
+    site this reads the clock always, so it is not for a hot path."""
+
+    def __init__(self, name: str, prefix: str, age_s: float = 0.0,
+                 attrs: dict | None = None):
+        self.name, self.prefix, self.attrs = name, prefix, attrs
+        self._p0 = time.perf_counter() - age_s   # began ``age_s`` ago
+        self._t0 = time.time() - age_s
+        self._ends: list[tuple[str, float, dict | None, bool]] = []
+
+    def done(self, phase: str, attrs: dict | None = None) -> None:
+        """``phase`` began when the one before it ended, and ends now."""
+        self._ends.append((phase, time.perf_counter(), attrs, False))
+
+    def mark(self, what: str, attrs: dict | None = None) -> None:
+        """An instant inside the operation (a child of no duration)."""
+        self._ends.append((what, time.perf_counter(), attrs, True))
+
+    def emit(self) -> tuple[float, str]:
+        """(seconds in all, "<phase> <s>s, ..." for the log line)."""
+        total = (self._ends[-1][1] if self._ends else self._p0) - self._p0
+        ctx = event(self.name, total, t0=self._t0, attrs=self.attrs)
+        at, parts = self._p0, []
+        for phase, end, attrs, instant in self._ends:
+            begin = end if instant else at
+            event(f"{self.prefix}.{phase}", end - begin, parent=ctx,
+                  t0=self._t0 + begin - self._p0, attrs=attrs)
+            if not instant:
+                parts.append(f"{phase} {end - at:.3f}s")
+                at = end
+        return total, ", ".join(parts)
+
+
+def process_age_s() -> float | None:
+    """Seconds since the kernel started this process (its start time in
+    /proc/self/stat against the boot clock): what a span that begins at
+    process start needs. None where /proc does not say."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return age if 0 <= age < 86400 else None
 
 
 @contextlib.contextmanager

@@ -120,6 +120,7 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
             jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     o = o.reshape(b, h, s, d).transpose(0, 2, 1, 3)
     return o, lse[..., 0]
@@ -293,6 +294,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
             jax.ShapeDtypeStruct((b * h, s, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(*common_in)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, blk_k=blk_k, scale=scale,
@@ -309,6 +311,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
         out_specs=pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*common_in)
 
     def back(x):

@@ -50,6 +50,10 @@ def _chunk_cols(ci, chunk, v):
     return c0, start, cols
 
 
+# The scope names the two `while` loops (the forward's here, the
+# backward's below) in a device trace, which otherwise shows them as
+# anonymous fusions. Names are metadata: the compiled loops are the same.
+@jax.named_scope("xent")
 def _fwd_pass(hidden, kernel, targets, chunk):
     """Returns (lse (N,), target_logit (N,)) streaming vocab chunks."""
     n, d = hidden.shape
@@ -104,6 +108,7 @@ def _xent_fwd(hidden, kernel, targets, chunk):
     return jnp.mean(lse - tgt), (hidden, kernel, targets, lse)
 
 
+@jax.named_scope("xent")
 def _xent_bwd(chunk, res, g):
     hidden, kernel, targets, lse = res
     n, d = hidden.shape

@@ -289,12 +289,12 @@ def _requant_pallas(m_new, amax, quant: str, interpret: bool) -> QPlane:
     f32, i8 = jnp.float32, jnp.int8
     scale = scale_of_amax(amax, _QMAX[quant])
     q, r, rmax = row_block_call(
-        functools.partial(_requant_kernel, quant=quant), [scale], [m_new],
-        [i8, f32], 1, interpret)
+        "opt_requant", functools.partial(_requant_kernel, quant=quant),
+        [scale], [m_new], [i8, f32], 1, interpret)
     rscale = scale_of_amax(rmax, _QMAX[quant])
     (rq,) = row_block_call(
-        functools.partial(_quantize_kernel, quant=quant), [rscale], [r],
-        [i8], 0, interpret)
+        "opt_quantize", functools.partial(_quantize_kernel, quant=quant),
+        [rscale], [r], [i8], 0, interpret)
     return QPlane(q=q, scale=scale, rq=rq, rscale=rscale)
 
 
@@ -304,11 +304,11 @@ def _sgdm_pallas(p2, g2, m, lr, *, mu, wd, quant, interpret):
     f32 = jnp.float32
     kernel = functools.partial(_sgdm_kernel, mu=mu, wd=wd, quant=quant)
     if quant == "off":
-        return row_block_call(kernel, [lr], [p2, g2, m], [f32, f32], 0,
-                              interpret)
+        return row_block_call("opt_sgdm", kernel, [lr], [p2, g2, m],
+                              [f32, f32], 0, interpret)
     p_new, m_new, amax = row_block_call(
-        kernel, [lr, m.scale, m.rscale], [p2, g2, m.q, m.rq], [f32, f32],
-        1, interpret)
+        "opt_sgdm", kernel, [lr, m.scale, m.rscale],
+        [p2, g2, m.q, m.rq], [f32, f32], 1, interpret)
     return p_new, _requant_pallas(m_new, amax, quant, interpret)
 
 
@@ -321,10 +321,12 @@ def _adam_pallas(p2, g2, m, v, lr, c1, c2, *, b1, b2, eps, wd, quant,
     kernel = functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps,
                                wd=wd, quant=quant)
     if quant == "off":
-        return row_block_call(kernel, [lr, c1, c2], [p2, g2, m, v],
-                              [f32, f32, f32], 0, interpret)
+        return row_block_call("opt_adam", kernel, [lr, c1, c2],
+                              [p2, g2, m, v], [f32, f32, f32], 0,
+                              interpret)
     p_new, m_new, v_new, m_amax, v_amax = row_block_call(
-        kernel, [lr, c1, c2, m.scale, m.rscale, v.scale, v.rscale],
+        "opt_adam", kernel,
+        [lr, c1, c2, m.scale, m.rscale, v.scale, v.rscale],
         [p2, g2, m.q, m.rq, v.q, v.rq], [f32, f32, f32], 2, interpret)
     return (p_new, _requant_pallas(m_new, m_amax, quant, interpret),
             _requant_pallas(v_new, v_amax, V_QUANT, interpret))

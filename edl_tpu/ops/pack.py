@@ -95,10 +95,11 @@ def _pack_xla(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 # exact, so reducing the partials outside equals the XLA reduction).
 
 
-def row_block_call(kernel, scalars, planes, out_dtypes, n_amax: int,
-                   interpret: bool) -> tuple:
+def row_block_call(name: str, kernel, scalars, planes, out_dtypes,
+                   n_amax: int, interpret: bool) -> tuple:
     """Run ``kernel`` over the row blocks of ``planes`` ((rows, 128)
-    each). The kernel sees: the SMEM vector of ``scalars`` (fp32; left
+    each), as the Mosaic call ``name`` (what a device trace shows it
+    under). The kernel sees: the SMEM vector of ``scalars`` (fp32; left
     out when there are none), a VMEM block per plane, a VMEM block per
     ``out_dtypes`` entry, then ``n_amax`` SMEM vectors holding one
     partial per grid step (write them with `block_amax`). Returns the
@@ -121,6 +122,7 @@ def row_block_call(kernel, scalars, planes, out_dtypes, n_amax: int,
                    for d in out_dtypes]
         + [jax.ShapeDtypeStruct((steps,), jnp.float32)] * n_amax,
         interpret=interpret,
+        name=name,
     )(*head, *planes)
     n = len(out_dtypes)
     return tuple(outs[:n]) + tuple(jnp.max(a) for a in outs[n:])
@@ -149,10 +151,11 @@ def _quantize_kernel(s_ref, x_ref, q_ref, *, rows):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _pack_pallas(x2d: jnp.ndarray, interpret: bool):
-    (amax,) = row_block_call(_amax_kernel, [], [x2d], [], 1, interpret)
+    (amax,) = row_block_call("pack_amax", _amax_kernel, [], [x2d], [], 1,
+                             interpret)
     scale = scale_of_amax(amax)
-    (q,) = row_block_call(_quantize_kernel, [scale], [x2d], [jnp.int8],
-                          0, interpret)
+    (q,) = row_block_call("pack_quantize", _quantize_kernel, [scale],
+                          [x2d], [jnp.int8], 0, interpret)
     return q, scale
 
 

@@ -54,10 +54,10 @@ from flax import serialization
 
 from edl_tpu.obs import metrics as obs_metrics
 from edl_tpu.obs import recorder as flight
+from edl_tpu.obs import trace
 from edl_tpu.train import sharded_checkpoint as sc
 from edl_tpu.train.state import TrainStatus
 from edl_tpu.utils.logging import get_logger
-from edl_tpu.utils.timeline import timeline
 
 log = get_logger("edl_tpu.train.checkpoint")
 
@@ -69,6 +69,12 @@ class CheckpointWriteError(RuntimeError):
 
 _CKPT_RE = re.compile(r"^ckpt-(\d+)$")
 _INDEX_FILE_RE = re.compile(r"^index\.(\d+)\.json$")
+
+
+def _nbytes(arrays) -> int:
+    """Bytes of the array leaves (a span attribute, so only computed
+    while spans are on)."""
+    return int(sum(getattr(a, "nbytes", 0) for a in arrays))
 
 
 def _local_sharded_complete(path: str) -> bool:
@@ -136,12 +142,11 @@ class CheckpointManager:
         # called (no args, outside the lock) after each retention update;
         # the migration service republishes its advert from here
         self.on_sealed = None
-        self._tl = timeline("ckpt")
         self._stats = {  # guarded-by: _cond
             "saves_async": 0, "saves_sync": 0, "superseded": 0,
             "writes": 0, "errors": 0, "state_bytes_last": 0,
             "snapshot_ms_last": 0.0, "save_stall_ms_total": 0.0,
-            "write_s_last": 0.0, "write_s_total": 0.0}
+            "write_s_last": 0.0, "write_s_total": 0.0, "files_last": 0}
         # the stats() dict stays the benchlog API; the per-process obs
         # registry serves the same counters as gauges (close() drops it)
         self._obs = obs_metrics.register_stats("ckpt", self.stats)
@@ -190,7 +195,10 @@ class CheckpointManager:
         t0 = time.perf_counter()
         try:
             if self.sharded:
-                return self._save_sharded(state, status)
+                # a synchronous sharded save takes its snapshot inside
+                # the write (the world's barriers come first)
+                with trace.span("ckpt.write", attrs={"step": status.step}):
+                    return self._save_sharded(state, status)
             if self.process_index != 0:
                 # Non-writers still accumulate sealed ckpt-N dirs locally
                 # via restore-time mirror fetches — prune them
@@ -198,8 +206,14 @@ class CheckpointManager:
                 # but keep symmetry with the sharded branch).
                 self._gc(sealed_only=True)
                 return None
-            host_state = jax.device_get(state)
-            version = self._write_replicated(host_state, status)
+            with trace.span("ckpt.snapshot",
+                            attrs={"step": status.step}) as sp:
+                host_state = jax.device_get(state)
+                if sp is not None:
+                    sp.attrs["bytes"] = _nbytes(
+                        jax.tree_util.tree_leaves(host_state))
+            with trace.span("ckpt.write", attrs={"step": status.step}):
+                version = self._write_replicated(host_state, status)
             self._retain("replicated", host_state, version, status)
             return version
         finally:
@@ -234,7 +248,10 @@ class CheckpointManager:
             meta = {"version": version, "status": status.to_dict()}
             with open(os.path.join(tmp, "meta.json"), "w") as f:
                 json.dump(meta, f)
-            os.rename(tmp, self._path(version))
+            with trace.span("ckpt.seal"):
+                os.rename(tmp, self._path(version))
+            with self._cond:
+                self._stats["files_last"] = 1
         except BaseException:
             shutil.rmtree(tmp, ignore_errors=True)
             raise
@@ -348,8 +365,15 @@ class CheckpointManager:
         owns_snap = snap is None
         try:
             if owns_snap:
-                snap = sc.snapshot_shards(state)
+                with trace.span("ckpt.snapshot",
+                                attrs={"step": status.step}) as sp:
+                    snap = sc.snapshot_shards(state)
+                    if sp is not None:
+                        sp.attrs["bytes"] = _nbytes(
+                            a for _, a in snap["chunks"])
             my_files = sc.write_snapshot(tmp, snap)
+            with self._cond:
+                self._stats["files_last"] = len(my_files)
         except BaseException as exc:  # noqa: BLE001 — re-raised below
             failure = exc
             try:
@@ -387,9 +411,10 @@ class CheckpointManager:
                         "format": "sharded",
                         "world": {"process_count": jax.process_count(),
                                   "device_count": jax.device_count()}}
-                with open(os.path.join(tmp, "meta.json"), "w") as f:
-                    json.dump(meta, f)
-                os.rename(tmp, self._path(version))
+                with trace.span("ckpt.seal"):
+                    with open(os.path.join(tmp, "meta.json"), "w") as f:
+                        json.dump(meta, f)
+                    os.rename(tmp, self._path(version))
         except BaseException:
             shutil.rmtree(tmp, ignore_errors=True)
             raise
@@ -607,31 +632,45 @@ class CheckpointManager:
             self._gc(sealed_only=True)
             return
         t0 = time.perf_counter()
-        with self._tl.span("snapshot"):
+        with trace.span("ckpt.snapshot", attrs={"step": status.step}) as sp:
             # Supersede BEFORE staging so the dropped snapshot's arena is
             # recycled into this copy (true double buffering: at most one
             # in-flight + one pending arena live).
+            superseded = False
             with self._cond:
                 if self._pending is not None:
                     old = self._pending
                     self._pending = None
                     self._stats["superseded"] += 1
                     self._recycle_arena(old)
+                    superseded = True
             status = TrainStatus.from_dict(status.to_dict())  # isolate the
             # snapshot from the loop's live, mutating status cursor
+            # The two halves of the stall get a span each: the device's
+            # copy to the host, and the host's copy into the arena.
             if self.sharded:
-                snap = sc.snapshot_shards(state)
+                with trace.span("ckpt.d2h"):
+                    snap = sc.snapshot_shards(state)
                 names = [n for n, _ in snap["chunks"]]
-                staged, arena = self._stage([a for _, a in snap["chunks"]])
+                with trace.span("ckpt.stage"):
+                    staged, arena = self._stage(
+                        [a for _, a in snap["chunks"]])
                 snap["chunks"] = list(zip(names, staged))
                 job = {"kind": "sharded", "snap": snap}
             else:
                 leaves, treedef = jax.tree_util.tree_flatten(state)
-                staged, arena = self._stage(jax.device_get(leaves))
+                with trace.span("ckpt.d2h"):
+                    fetched = jax.device_get(leaves)
+                with trace.span("ckpt.stage"):
+                    staged, arena = self._stage(fetched)
+                del fetched
                 job = {"kind": "replicated",
                        "tree": jax.tree_util.tree_unflatten(treedef, staged)}
             job.update(status=status, arena=arena,
                        arena_key=self._staging_key)
+            if sp is not None:
+                job["bytes"] = _nbytes(staged)
+                sp.attrs.update(bytes=job["bytes"], superseded=superseded)
         stall_ms = (time.perf_counter() - t0) * 1e3
         with self._cond:
             if self._closed:
@@ -693,7 +732,9 @@ class CheckpointManager:
                 self._inflight = True
             try:
                 t0 = time.perf_counter()
-                with self._tl.span("write"):
+                with trace.span("ckpt.write", attrs={
+                        "step": job["status"].step,
+                        "bytes": job.get("bytes", 0)}) as sp:
                     if job["kind"] == "sharded":
                         ver = self._save_sharded(None, job["status"],
                                                  snap=job["snap"])
@@ -704,7 +745,14 @@ class CheckpointManager:
                                                      job["status"])
                         self._retain("replicated", job["tree"], ver,
                                      job["status"])
+                    if sp is not None:
+                        with self._cond:
+                            sp.attrs["files"] = self._stats["files_last"]
                 dt = time.perf_counter() - t0
+                # a profiled process keeps its spans in memory: this
+                # thread writes files anyway, so it also writes those
+                # (a SIGKILL then loses the spans since the last seal)
+                trace.flush()
                 with self._cond:
                     self._stats["writes"] += 1
                     self._stats["write_s_last"] = dt
@@ -877,6 +925,9 @@ class CheckpointManager:
 
     def _restore_version(self, target: Any, version: int | None
                          ) -> tuple[Any, TrainStatus] | None:
+        # ended only where something was restored: a restore that finds
+        # nothing, or raises, leaves no span
+        sp = trace.start_span("ckpt.restore", attrs={"source": "disk"})
         t_start = time.perf_counter()
         if version is None:
             version = self.latest_version()
@@ -951,4 +1002,7 @@ class CheckpointManager:
         self.last_restore_s = time.perf_counter() - t_start
         log.info("restored checkpoint %s (epoch=%d step=%d) in %.3fs", path,
                  status.epoch, status.step, self.last_restore_s)
+        if sp is not None:
+            sp.end(version=version,
+                   bytes=_nbytes(jax.tree_util.tree_leaves(state)))
         return state, status

@@ -12,6 +12,7 @@ restart with a different mesh.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass
 from typing import Any, Callable, Iterable
@@ -157,6 +158,11 @@ class TrainLoop:
                      if self.config.ckpt_dir else None)
         self.last_metrics: dict = {}
         self._profiling = False
+        if self.config.profile_dir:
+            # a process that will be profiled records its spans from
+            # here on (entry points that parse --profile call this
+            # earlier, for their start-up spans); written at flush()
+            trace.collect(self.config.profile_dir)
         # Save-stall accounting (benchlog/timeline): step-loop-visible ms
         # spent in _save calls — full write under sync, snapshot copy
         # under async — plus the restore seconds of this run's resume.
@@ -240,8 +246,13 @@ class TrainLoop:
             from edl_tpu.collective.migration import PeerRestoreError
             t0 = time.perf_counter()
             try:
+                # ended on success only: no donor is the common case
+                sp = trace.start_span("ckpt.restore",
+                                      attrs={"source": "peers"})
                 state, status, stats = self._migration.restore_from_peers(
                     self.state, local_version=self.ckpt.latest_version())
+                if sp is not None:
+                    sp.end(bytes=int(stats["bytes_from_peers"]))
                 restored = (state, status)
                 self.restore_source = "peers"
                 self.bytes_from_peers = int(stats["bytes_from_peers"])
@@ -556,8 +567,7 @@ class TrainLoop:
                     # failure surfaces here, not epochs later.
                     self.ckpt.wait()
             if self._profiling:  # run shorter than the window: still flush
-                jax.profiler.stop_trace()
-                self._profiling = False
+                self._stop_profiler()
             if self.ckpt_saves:
                 log.info("ckpt plane: %s", self.ckpt_stats())
             return self.status
@@ -622,6 +632,10 @@ class TrainLoop:
                      cfg.profile_start_step + cfg.profile_steps,
                      cfg.profile_dir)
             jax.profiler.start_trace(cfg.profile_dir)
+            # from here every span of this process, on any thread, is
+            # also an event of the profiler's host plane: one file, one
+            # clock with the device's lines
+            trace.set_annotator(_annotation)
             self._profiling = True
         elif self._profiling and self.status.step >= \
                 cfg.profile_start_step + cfg.profile_steps:
@@ -629,9 +643,34 @@ class TrainLoop:
             # state is the live device data (last_metrics is already
             # host numpy by the time it's stored)
             jax.block_until_ready(self.state)
-            jax.profiler.stop_trace()
-            self._profiling = False
+            self._stop_profiler()
             log.info("profiler: trace written to %s", cfg.profile_dir)
+            _log_device_memory()
+
+    def _stop_profiler(self) -> None:
+        trace.set_annotator(None)
+        jax.profiler.stop_trace()
+        self._profiling = False
+        trace.flush()   # spans-<pid>.jsonl beside the profiler's file
+
+    def _step_annotation(self):
+        """The profiler's own step marker around one iteration, while
+        it runs (`Steps` in its viewers group by it)."""
+        if self._profiling:
+            return jax.profiler.StepTraceAnnotation(
+                "train", step_num=self.status.step + 1)
+        return contextlib.nullcontext()
+
+    def _batches(self, it):
+        """``it``'s items, the wait for each (the loader's next batch
+        and its placement on the device) inside a span."""
+        end = object()
+        while True:
+            with trace.span("train.loader_wait"):
+                item = next(it, end)
+            if item is end:
+                return
+            yield item
 
     def _epoch_iter(self, src, skip: int):
         """(index, device-placed batch) pairs starting at ``skip``.
@@ -675,7 +714,7 @@ class TrainLoop:
                      "batches of epoch %d", skip, epoch)
         src = data_fn(epoch)
         it = self._epoch_iter(src, skip)
-        for i, batch in it:
+        for i, batch in self._batches(it):
             if self._migration is not None:
                 if self._migration.stop_requested.is_set():
                     # Graceful stop: leave at the step boundary with the
@@ -691,108 +730,147 @@ class TrainLoop:
                     # the machine's clean stop-resume downgrade
                     return self._adopt(reform)
             self._profile_window()
-            t_dispatch = time.perf_counter()
-            self.state, metrics = self.step_fn(self.state, batch)
-            if self._reform_t0 is not None:
-                # first dispatch of the adopted generation: the call
-                # wall covers trace + (cache-missing) compile — the
-                # re-jit phase of the reform ladder
-                rejit_s = time.perf_counter() - t_dispatch
-            if not self._first_step_done:
-                # Downtime-accounting marker: the first step of THIS run
-                # (post-restore, post-compile) has really executed — the
-                # elastic kill->resume bench keys on this line, so force
-                # the dispatch before stamping it.
-                jax.block_until_ready(self.state)
-                self._first_step_done = True
-                log.info("first-step-complete global_step=%d restore_s=%s",
-                         self.status.step + 1,
-                         "%.3f" % self.restore_s
-                         if self.restore_s is not None else "none")
-                log.info("first-step wall (trace+compile+run) %.3fs, "
-                         "persistent compile cache %s",
-                         time.perf_counter() - t_dispatch,
-                         distributed.compilation_cache_counts())
-                if self._migration is not None:
-                    # restore ack: this pod is trained-and-running —
-                    # what lingering donors and the resize audit key on
+            with self._step_annotation():
+                t_dispatch = time.perf_counter()
+                with trace.span("train.dispatch", attrs={
+                        "step": self.status.step + 1}) as sp:
+                    self.state, metrics = self.step_fn(self.state, batch)
+                    if sp is not None and not self._first_step_done:
+                        # the first dispatch traces and compiles, or
+                        # loads from the persistent cache
+                        counts = distributed.compilation_cache_counts()
+                        sp.attrs.update(
+                            cache_hits=counts.get("hits", 0),
+                            cache_misses=counts.get("misses", 0))
+                if self._reform_t0 is not None:
+                    # first dispatch of the adopted generation: the call
+                    # wall covers trace + (cache-missing) compile — the
+                    # re-jit phase of the reform ladder
+                    rejit_s = time.perf_counter() - t_dispatch
+                if not self._first_step_done:
+                    # Downtime-accounting marker: the first step of THIS run
+                    # (post-restore, post-compile) has really executed — the
+                    # elastic kill->resume bench keys on this line, so force
+                    # the dispatch before stamping it.
+                    jax.block_until_ready(self.state)
+                    self._first_step_done = True
+                    log.info("first-step-complete global_step=%d restore_s=%s",
+                             self.status.step + 1,
+                             "%.3f" % self.restore_s
+                             if self.restore_s is not None else "none")
+                    log.info("first-step wall (trace+compile+run) %.3fs, "
+                             "persistent compile cache %s",
+                             time.perf_counter() - t_dispatch,
+                             distributed.compilation_cache_counts())
+                    _log_device_memory()
+                    if self._migration is not None:
+                        # restore ack: this pod is trained-and-running —
+                        # what lingering donors and the resize audit key on
+                        self._migration.ack(
+                            self.restore_source or "fresh",
+                            bytes_from_peers=self.bytes_from_peers,
+                            restore_s=self.restore_s)
+                        if self.restore_source == "peers" \
+                                and trace.enabled() \
+                                and self._util_publisher is not None:
+                            # a grown pod's first fresh util closes the
+                            # resize trace the same way an adoption's does
+                            from edl_tpu.collective.migration import \
+                                resize_trace_ctx
+                            self._util_publisher.resize_trace = \
+                                resize_trace_ctx(self._migration.store,
+                                                 self._migration.job_id)
+                if self._reform_t0 is not None:
+                    # First step of the adopted generation: force the
+                    # dispatch so the measured gap covers real training
+                    # resumption, not an async enqueue.
+                    t_block = time.perf_counter()
+                    jax.block_until_ready(self.state)
+                    now = time.perf_counter()
+                    gap = now - self._reform_t0
+                    self._reform_t0 = None
+                    self.last_reform_downtime_s = gap
+                    reform_doc = None
+                    if self._reform_machine is not None:
+                        # close the deferred ladder phases: the first
+                        # post-reform step IS re-jit (dispatch wall; a
+                        # compile-cache hit collapses it) + first-step
+                        machine = self._reform_machine
+                        self._reform_machine = None
+                        machine.note_deferred("re-jit", rejit_s)
+                        machine.note_deferred("first-step", now - t_block)
+                        reform_doc = self.last_reform = machine.finish()
+                    log.info("reform-step-complete generation=%d "
+                             "downtime_s=%.3f",
+                             self._migration.generation, gap)
+                    flight.record("resize_adopt",
+                                  pod=self._migration.pod_id,
+                                  generation=self._migration.generation,
+                                  downtime_s=round(gap, 4))
+                    if self._reform_span is not None:
+                        # the span covers reform -> first step of the new
+                        # generation: duration == the measured survivor gap
+                        self._reform_span.end(downtime_s=round(gap, 4))
+                        if self._util_publisher is not None:
+                            # first fresh util at the new world closes the
+                            # trace (the scaler's downtime probe signal)
+                            self._util_publisher.resize_trace = \
+                                self._reform_span.context
+                        self._reform_span = None
                     self._migration.ack(
-                        self.restore_source or "fresh",
-                        bytes_from_peers=self.bytes_from_peers,
-                        restore_s=self.restore_s)
-                    if self.restore_source == "peers" \
-                            and trace.enabled() \
-                            and self._util_publisher is not None:
-                        # a grown pod's first fresh util closes the
-                        # resize trace the same way an adoption's does
-                        from edl_tpu.collective.migration import \
-                            resize_trace_ctx
-                        self._util_publisher.resize_trace = \
-                            resize_trace_ctx(self._migration.store,
-                                             self._migration.job_id)
-            if self._reform_t0 is not None:
-                # First step of the adopted generation: force the
-                # dispatch so the measured gap covers real training
-                # resumption, not an async enqueue.
-                t_block = time.perf_counter()
-                jax.block_until_ready(self.state)
-                now = time.perf_counter()
-                gap = now - self._reform_t0
-                self._reform_t0 = None
-                self.last_reform_downtime_s = gap
-                reform_doc = None
-                if self._reform_machine is not None:
-                    # close the deferred ladder phases: the first
-                    # post-reform step IS re-jit (dispatch wall; a
-                    # compile-cache hit collapses it) + first-step
-                    machine = self._reform_machine
-                    self._reform_machine = None
-                    machine.note_deferred("re-jit", rejit_s)
-                    machine.note_deferred("first-step", now - t_block)
-                    reform_doc = self.last_reform = machine.finish()
-                log.info("reform-step-complete generation=%d "
-                         "downtime_s=%.3f",
-                         self._migration.generation, gap)
-                flight.record("resize_adopt",
-                              pod=self._migration.pod_id,
-                              generation=self._migration.generation,
-                              downtime_s=round(gap, 4))
-                if self._reform_span is not None:
-                    # the span covers reform -> first step of the new
-                    # generation: duration == the measured survivor gap
-                    self._reform_span.end(downtime_s=round(gap, 4))
-                    if self._util_publisher is not None:
-                        # first fresh util at the new world closes the
-                        # trace (the scaler's downtime probe signal)
-                        self._util_publisher.resize_trace = \
-                            self._reform_span.context
-                    self._reform_span = None
-                self._migration.ack(
-                    "adopted", downtime_s=round(gap, 4),
-                    bytes_from_peers=self.bytes_from_peers
-                    if reform_doc and reform_doc.get("restore") == "peers"
-                    else 0,
-                    reform=reform_doc)
-            self.status.step += 1
-            self.status.step_in_epoch = i + 1
-            n = (batch_size_fn(batch) if batch_size_fn
-                 else _default_batch_size(batch))
-            window_samples += n
-            self.status.samples_seen += n
-            if cfg.ckpt_every_steps and \
-                    self.status.step % cfg.ckpt_every_steps == 0:
-                self._save()  # epoch = last complete; step_in_epoch = cursor
-            if self.status.step % max(1, cfg.log_every_steps) == 0:
-                metrics = jax.device_get(metrics)
-                self.last_metrics = metrics
-                elapsed = time.perf_counter() - window_start
-                rate = window_samples / max(elapsed, 1e-9)
-                log.info("epoch %d step %d: %s %.1f samples/s",
-                         epoch, self.status.step, _fmt(metrics), rate)
-                for hook in self.hooks:
-                    hook(self, epoch, self.status.step, metrics)
-                window_start = time.perf_counter()
-                window_samples = 0
+                        "adopted", downtime_s=round(gap, 4),
+                        bytes_from_peers=self.bytes_from_peers
+                        if reform_doc and reform_doc.get("restore") == "peers"
+                        else 0,
+                        reform=reform_doc)
+                self.status.step += 1
+                self.status.step_in_epoch = i + 1
+                n = (batch_size_fn(batch) if batch_size_fn
+                     else _default_batch_size(batch))
+                window_samples += n
+                self.status.samples_seen += n
+                if cfg.ckpt_every_steps and \
+                        self.status.step % cfg.ckpt_every_steps == 0:
+                    # epoch = last complete; step_in_epoch = cursor
+                    self._save()
+                if self.status.step % max(1, cfg.log_every_steps) == 0:
+                    with trace.span("train.log_fetch"):
+                        metrics = jax.device_get(metrics)
+                    self.last_metrics = metrics
+                    elapsed = time.perf_counter() - window_start
+                    rate = window_samples / max(elapsed, 1e-9)
+                    log.info("epoch %d step %d: %s %.1f samples/s",
+                             epoch, self.status.step, _fmt(metrics), rate)
+                    for hook in self.hooks:
+                        hook(self, epoch, self.status.step, metrics)
+                    window_start = time.perf_counter()
+                    window_samples = 0
+
+
+def _annotation(name: str, attrs: dict):
+    """A span as an event of the running profiler's host plane."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
+def _log_device_memory() -> None:
+    """The fullest local chip's peak so far. This runtime books a
+    running program's temporaries as reserved, not in use, so the peak
+    counter alone misses them: log the larger of the two."""
+    stats = [s for s in (_memory_stats(d) for d in jax.local_devices()) if s]
+    if not stats:
+        return
+    peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
+    held = max(s.get("bytes_in_use", 0) + s.get("bytes_reserved", 0)
+               for s in stats)
+    log.info("device-memory peak_bytes=%d (peak_bytes_in_use=%d, "
+             "bytes_in_use+bytes_reserved=%d)", max(peak, held), peak, held)
+
+
+def _memory_stats(device) -> dict | None:
+    try:
+        return device.memory_stats()
+    except Exception:  # noqa: BLE001 — a backend without the counter
+        return None
 
 
 def _default_batch_size(batch) -> int:

@@ -61,30 +61,36 @@ def make_train_step(loss_fn: LossFn, donate: bool = True,
     if loss_scale:
         from edl_tpu.train import amp
 
-        def amp_step(state, batch, ls):
+        def train_step(state, batch, ls):
             def compute(params):
                 return loss_fn(state, params, batch)
 
             (loss, aux), grads = amp.scaled_value_and_grad(
                 compute, state.params, ls)
-            new_state = apply(state, grads, aux)
+            with jax.named_scope("opt_update"):
+                new_state = apply(state, grads, aux)
             ls, selected, finite = amp.update_scale_and_select(
                 ls, grads, new_state, state)
             return selected, {"loss": loss, "loss_scale": ls.scale,
                               "finite": finite, **aux}, ls
 
-        return jax.jit(amp_step, donate_argnums=(0,) if donate else ())
+        return jax.jit(train_step, donate_argnums=(0,) if donate else ())
 
-    def step(state, batch):
+    # The function's name is the program's in a device trace
+    # (`jit_train_step` on `XLA Modules`), whatever the mesh; the scope
+    # names the optimizer's share of it. Names are metadata: the
+    # compiled step is the same with and without them.
+    def train_step(state, batch):
         def compute(params):
             return loss_fn(state, params, batch)
 
         (loss, aux), grads = jax.value_and_grad(compute, has_aux=True)(
             state.params)
-        state = apply(state, grads, aux)
+        with jax.named_scope("opt_update"):
+            state = apply(state, grads, aux)
         return state, {"loss": loss, **aux}
 
-    return jax.jit(step, donate_argnums=(0,) if donate else ())
+    return jax.jit(train_step, donate_argnums=(0,) if donate else ())
 
 
 def make_eval_step(metric_fn: Callable[[Any, Any], dict]) -> Callable:

@@ -119,7 +119,8 @@ ENV_VARS: dict[str, str] = {
     "EDL_TPU_LOG_DIR": "launcher workerlog directory",
     "EDL_TPU_LOG_LEVEL": "python log level for edl_tpu loggers",
     "EDL_TPU_PROFILE": "timeline tracing on/off",
-    "EDL_TPU_PROFILE_DIR": "jax profiler trace output directory",
+    "EDL_TPU_PROFILE_DIR": "jax profiler trace output directory; also "
+                           "switches spans on, buffered, into it",
     "EDL_TPU_PROFILE_START": "profiler start step",
     "EDL_TPU_PROFILE_STEPS": "profiler step count",
     # -- control plane (watch streams, utilization) ------------------------

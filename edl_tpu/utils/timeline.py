@@ -4,13 +4,14 @@ plane since the observability PR.
 Capability of the reference's distill timeline (distill/timeline.py:20-43:
 ``DISTILL_READER_PROFILE=1`` swaps a nop for a real recorder emitting
 ``pid/op/ms`` lines to stderr, hooked at every pipeline stage). Ours is
-``EDL_TPU_PROFILE=1`` and also offers a jax-profiler trace context for
-device-side timelines.
+``EDL_TPU_PROFILE=1``; its one user is the distill reader
+(``distill/reader.py``). The trainer's spans (loop, checkpoints,
+start-up) go through ``obs.trace.span`` directly, and the device trace
+is ``TrainLoop``'s profile window.
 
     tl = timeline("distill.worker")      # nop unless profiling/tracing
     with tl.span("predict"):
         ...
-    tl.record("put_data", t0)            # explicit start time
 
 Sinks (the r19 hot-path fix — the old ``_RealTimeline.record`` did an
 UNBUFFERED per-event ``print`` to stderr, a measurable syscall tax on
@@ -126,16 +127,3 @@ def timeline(name: str):
     if profiling_enabled() or _trace.enabled():
         return _ObsTimeline(name)
     return _NopTimeline()
-
-
-@contextlib.contextmanager
-def device_trace(logdir: str):
-    """jax profiler trace (TensorBoard-viewable) around a code region —
-    the device-side analogue of the reference's --profile batches window
-    (train_with_fleet.py:521-530)."""
-    import jax
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

@@ -257,3 +257,65 @@ def test_replacement_starts_after_lingering_donor_exits(tmp_path):
     assert done == {"rc": 0}
     wait(lambda: len(seen("exit")) == 2)  # the released replacement, too
     joiner.release()
+
+
+def test_crash_respawn_logs_one_reform_line(tmp_path, caplog):
+    """A crashed trainer's way back, by phase: the launcher writes one
+    `reform:` line a respawn, where the next trainer is started, and a
+    first start writes none."""
+    import logging
+    import os
+    import re
+    import signal
+    import sys
+
+    from edl_tpu.collective.launch import launch
+
+    script, events = tmp_path / "trainer.py", tmp_path / "events"
+    script.write_text(_FAKE_TRAINER)
+    events.write_text("")
+    store = InMemStore()
+    job = JobEnv(job_id=JOB, pod_id="pod0", nodes_range="1:1",
+                 log_dir=str(tmp_path / "log"), lease_ttl=5.0,
+                 barrier_stable_secs=0.2, barrier_timeout=20.0,
+                 rejoin_delay_secs=0.3)
+    done = {}
+    runner = threading.Thread(
+        target=lambda: done.update(rc=launch(
+            job, [sys.executable, str(script), str(events)], store=store,
+            poll=0.1)), daemon=True)
+    # the framework's loggers do not propagate: listen on this one
+    launch_log = logging.getLogger("edl_tpu.collective.launch")
+    launch_log.addHandler(caplog.handler)
+    runner.start()
+
+    def starts():
+        return [int(ln.split()[1]) for ln in
+                events.read_text().splitlines() if ln.startswith("start")]
+
+    def reform_lines():
+        return [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("reform:")]
+
+    def wait(cond, timeout=30.0):
+        deadline = time.monotonic() + timeout
+        while not cond():
+            assert time.monotonic() < deadline, events.read_text()
+            time.sleep(0.05)
+
+    wait(lambda: len(starts()) == 1)
+    assert reform_lines() == []
+    for n in (2, 3):
+        os.kill(starts()[-1], signal.SIGKILL)
+        wait(lambda: len(starts()) == n)
+        wait(lambda: len(reform_lines()) == n - 1)
+    store.put(reg.complete_key(JOB), "1")
+    runner.join(20.0)
+    launch_log.removeHandler(caplog.handler)
+    assert done == {"rc": 0}
+    assert len(reform_lines()) == 2
+    m = re.match(r"reform: exit_seen\S+spawn ([\d.]+)s \(rejoin_wait "
+                 r"([\d.]+)s, barrier ([\d.]+)s, spawn ([\d.]+)s\)$",
+                 reform_lines()[-1])
+    total, *parts = map(float, m.groups())
+    assert parts[0] >= 0.3 and abs(total - sum(parts)) < 0.01
