@@ -218,7 +218,7 @@ class Job:
             sealed = self.sealed(pid)
             if len(steps) >= n and (sealed_from is None or (
                     sealed and sealed[-1] >= sealed_from
-                    and max(steps) >= sealed[-1] + 2)):
+                    and max(steps) >= sealed[-1] + 3)):
                 return steps
             if not alive(pid):
                 raise SmokeFailure(
@@ -303,12 +303,52 @@ def check_resume(job: Job, pid: int, want_step: int, what: str) -> None:
           f"first={first})")
 
 
+def check_snapshot_outlived_steps(job: Job, pid: int, sealed: int,
+                                  what: str, *, tpu: bool) -> None:
+    """The snapshot of step ``sealed`` waited for its writer while the
+    loop ran on: the steps logged before the seal each donated the
+    buffers it was fetched from. (The replay after the resume, which
+    `check_losses` holds to four decimals, is what shows it untorn.)"""
+    during = 0
+    for ln in job.lines(pid):
+        if re.search(rf"saved sharded checkpoint .*step={sealed}\)", ln):
+            break
+        m = re.search(r"epoch \d+ step (\d+): ", ln)
+        during += bool(m and int(m.group(1)) > sealed)
+    say(f"{what}: {during} donating steps between the snapshot of step "
+        f"{sealed} and its seal")
+    if tpu:  # a toy state is written before the next step is logged
+        check(during >= 3, f"{what}: at least three steps donated their "
+              "buffers while the snapshot waited for the writer")
+
+
+def check_copied_bytes(job: Job, pid: int, what: str, *, tpu: bool) -> None:
+    """The trainer's `ckpt plane:` line (written on every way out of the
+    loop): on a chip the fetched arrays are host memory of their own and
+    none is copied; on the cpu platform all of them are."""
+    m = re.search(r"ckpt plane: (\{.*\})", "\n".join(job.lines(pid)))
+    check(m is not None, f"{what}: trainer wrote its ckpt plane line")
+    stats = ast.literal_eval(m.group(1))
+    copied = stats["ckpt_copied_bytes_last"]
+    say(f"{what}: ckpt_copied_bytes_last={copied} after "
+        f"{stats['ckpt_saves_async']} async saves, snapshot "
+        f"{stats['ckpt_snapshot_ms_last']} ms, write "
+        f"{stats['ckpt_write_s_last']} s")
+    check(copied == 0 if tpu else copied > 0,
+          f"{what}: copied_bytes_last={copied} "
+          + ("(handed to the writer as fetched)" if tpu
+             else "(cpu platform: private copies)"))
+
+
 def check_losses(before: dict[int, float], after: dict[int, float],
-                 what: str, tol: float = 0.05) -> None:
+                 what: str, tol: float = 0.05, replayed: int = 0) -> None:
     check(all(math.isfinite(v) for v in [*before.values(),
                                          *after.values()]),
           f"{what}: all {len(before) + len(after)} losses finite")
     both = sorted(set(before) & set(after))
+    if replayed:
+        check(len(both) >= replayed,
+              f"{what}: at least {replayed} steps were replayed ({both})")
     if both:  # the replayed steps: same state, same batches
         worst = max(abs(before[s] - after[s]) for s in both)
         check(worst <= tol, f"{what}: steps {both[0]}..{both[-1]} replayed "
@@ -343,6 +383,7 @@ def phase_train(work: str, env: dict, *, tpu: bool, lm_args: list[str],
         sealed = job.sealed(g1)[-1]
         say(f"gen 1 reached step {max(steps1)}, newest sealed async "
             f"sharded checkpoint at step {sealed}")
+        check_snapshot_outlived_steps(job, g1, sealed, "gen 1", tpu=tpu)
         # generation 2: respawned by the launcher, restores, continues
         g2 = job.next_trainer()
         steps2 = job.wait_steps(g2, 4, 600)
@@ -350,7 +391,10 @@ def phase_train(work: str, env: dict, *, tpu: bool, lm_args: list[str],
                                 local_batch=local_batch)
         check_resume(job, g2, sealed, "gen 2")
         say(f"gen 2 losses {fmt_losses(steps2)}")
-        check_losses(steps1, steps2, "gen 1 -> gen 2")
+        # to the four decimals the trainer logs: a snapshot torn by a
+        # donating step would restore another state than the one saved
+        check_losses(steps1, steps2, "gen 1 -> gen 2", tol=1.5e-4,
+                     replayed=3)
         check(gen2["cache"]["hits"] >= max(1, gen2["cache"]["misses"]),
               "gen 2 compiled from the persistent cache "
               f"(hits {gen2['cache']['hits']}, misses "
@@ -386,6 +430,7 @@ def phase_train(work: str, env: dict, *, tpu: bool, lm_args: list[str],
               f"SIGTERM; at most {most} live trainer process(es) "
               "meanwhile")
         check(most <= 1, "never two live trainers on the chip")
+        check_copied_bytes(job, g2, "gen 2", tpu=tpu)
         g3 = job.next_trainer()
         steps3 = job.wait_steps(g3, 3, 600)
         check_generation(job, g3, "gen 3 (after SIGTERM)", tpu=tpu)

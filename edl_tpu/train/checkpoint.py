@@ -25,12 +25,16 @@ Two state-payload formats behind one manager:
   so an elastic restart can move between formats.
 
 Async snapshot-then-write (``save_async``, the CheckFreq/Check-N-Run
-recipe): the step loop blocks only for a device->host snapshot into a
-double-buffered staging arena; a single background writer thread then
-does serialization, chunk writes, the tmp->final seal, mirror upload and
-GC. The queue is bounded drop-to-latest — a NEW snapshot supersedes a
-queued unwritten one but never an in-flight write — so checkpoint
-frequency can rise without the writer ever falling unboundedly behind.
+recipe): the step loop blocks only for the device->host snapshot. The
+fetched arrays go to the writer's job as they are wherever they cannot
+alias a live device buffer (sharded_checkpoint.may_alias_device: on an
+accelerator the transfer allocates host memory of its own); the ones
+that can (the cpu platform, host memory kinds) get a private copy
+first. A single background writer thread then does serialization, chunk
+writes, the tmp->final seal, mirror upload and GC. The queue is bounded
+drop-to-latest — a NEW snapshot supersedes a queued unwritten one but
+never an in-flight write — so checkpoint frequency can rise without the
+writer ever falling unboundedly behind.
 ``wait()``/``close()`` are the epoch-end/shutdown barriers; a failed
 background write surfaces as ``CheckpointWriteError`` on the NEXT
 save/wait/close call. Sync and async saves produce bitwise-identical
@@ -72,8 +76,7 @@ _INDEX_FILE_RE = re.compile(r"^index\.(\d+)\.json$")
 
 
 def _nbytes(arrays) -> int:
-    """Bytes of the array leaves (a span attribute, so only computed
-    while spans are on)."""
+    """Bytes of the array leaves."""
     return int(sum(getattr(a, "nbytes", 0) for a in arrays))
 
 
@@ -123,20 +126,16 @@ class CheckpointManager:
         self._writer: threading.Thread | None = None  # guarded-by: _cond
         self._closed = False                # guarded-by: _cond
         self._write_error: BaseException | None = None  # guarded-by: _cond
-        # double-buffered host staging: retired snapshot arenas recycled
-        # by np.copyto instead of reallocating the full state per save
-        self._staging_free: list[list] = []   # guarded-by: _cond
-        self._staging_key: tuple | None = None  # guarded-by: _cond
         self._async_fallback_logged = False   # training-thread-only
         # -- sealed-snapshot retention (state-migration donor plane) -------
         # When retain_sealed is set (collective/migration.py), the newest
         # successfully sealed save's HOST-side payload is kept in memory
         # so surviving pods can serve it to peers during a resize without
-        # re-reading disk. Retained payloads are never recycled back into
-        # the staging pool — a fetch in flight may still be reading the
-        # previous snapshot when a newer one seals, and np.copyto-ing
-        # over it would serve torn bytes; the old payload is simply
-        # dropped and freed by GC once the last reader releases it.
+        # re-reading disk. A snapshot's arrays are written once (by the
+        # fetch or its private copy) and never again — no buffer is
+        # reused across saves — so a peer fetch still reading the
+        # previous payload when a newer one seals cannot be served torn
+        # bytes; the old payload goes when its last reader lets go.
         self.retain_sealed = False
         self._sealed: dict | None = None    # guarded-by: _cond
         # called (no args, outside the lock) after each retention update;
@@ -145,6 +144,7 @@ class CheckpointManager:
         self._stats = {  # guarded-by: _cond
             "saves_async": 0, "saves_sync": 0, "superseded": 0,
             "writes": 0, "errors": 0, "state_bytes_last": 0,
+            "copied_bytes_last": 0,
             "snapshot_ms_last": 0.0, "save_stall_ms_total": 0.0,
             "write_s_last": 0.0, "write_s_total": 0.0, "files_last": 0}
         # the stats() dict stays the benchlog API; the per-process obs
@@ -419,13 +419,13 @@ class CheckpointManager:
             shutil.rmtree(tmp, ignore_errors=True)
             raise
         if owns_snap and self.retain_sealed:
-            # Sync-path retention: snapshot_shards arrays MAY alias live
-            # device buffers (its documented contract), and a donated
-            # train step after this save would overwrite them under an
-            # in-flight peer fetch — copy before retaining. The async
-            # path retains its already-staged arena in the writer loop
-            # instead (no copy needed there).
-            kept = dict(snap, chunks=[(n, np.array(a))
+            # Sync-path retention: a donated train step after this save
+            # would overwrite the chunks that alias live buffers under
+            # an in-flight peer fetch — copy those before retaining.
+            # The async path retains its already-private snapshot in
+            # the writer loop instead.
+            aliased = snap.pop("may_alias")
+            kept = dict(snap, chunks=[(n, np.array(a) if n in aliased else a)
                                       for n, a in snap["chunks"]])
             self._retain("sharded", kept, version, status)
         if self.process_index != 0:
@@ -565,9 +565,8 @@ class CheckpointManager:
     def _retain(self, kind: str, payload: Any, version: int | None,
                 status: TrainStatus) -> None:
         """Keep the just-sealed save's host payload for peer serving.
-        No-op unless `retain_sealed`. Never recycles the PREVIOUS
-        retained payload into the staging pool (see __init__ note —
-        torn-serve hazard); it is dropped for GC instead."""
+        No-op unless `retain_sealed`. The PREVIOUS retained payload is
+        simply dropped (see __init__ note)."""
         cb = None
         with self._cond:
             if not self.retain_sealed:
@@ -609,7 +608,7 @@ class CheckpointManager:
 
     def save_async(self, state: Any, status: TrainStatus) -> None:
         """Queue a checkpoint: the caller blocks only for the
-        device->host snapshot copy; serialization, disk writes, the
+        device->host snapshot; serialization, disk writes, the
         tmp->final seal, mirror upload and GC all happen on the
         background writer thread. Raises ``CheckpointWriteError`` here
         if a PREVIOUS background write failed.
@@ -633,44 +632,39 @@ class CheckpointManager:
             return
         t0 = time.perf_counter()
         with trace.span("ckpt.snapshot", attrs={"step": status.step}) as sp:
-            # Supersede BEFORE staging so the dropped snapshot's arena is
-            # recycled into this copy (true double buffering: at most one
-            # in-flight + one pending arena live).
-            superseded = False
+            # Supersede BEFORE the fetch: the dropped snapshot's host
+            # memory is free again while this one's is allocated (at
+            # most one in-flight + one pending snapshot live).
             with self._cond:
-                if self._pending is not None:
-                    old = self._pending
+                superseded = self._pending is not None
+                if superseded:
                     self._pending = None
                     self._stats["superseded"] += 1
-                    self._recycle_arena(old)
-                    superseded = True
             status = TrainStatus.from_dict(status.to_dict())  # isolate the
             # snapshot from the loop's live, mutating status cursor
-            # The two halves of the stall get a span each: the device's
-            # copy to the host, and the host's copy into the arena.
             if self.sharded:
                 with trace.span("ckpt.d2h"):
                     snap = sc.snapshot_shards(state)
+                aliased = snap.pop("may_alias")
                 names = [n for n, _ in snap["chunks"]]
-                with trace.span("ckpt.stage"):
-                    staged, arena = self._stage(
-                        [a for _, a in snap["chunks"]])
-                snap["chunks"] = list(zip(names, staged))
+                arrays, copied = self._stage(
+                    [a for _, a in snap["chunks"]],
+                    [n in aliased for n in names])
+                snap["chunks"] = list(zip(names, arrays))
                 job = {"kind": "sharded", "snap": snap}
             else:
                 leaves, treedef = jax.tree_util.tree_flatten(state)
                 with trace.span("ckpt.d2h"):
                     fetched = jax.device_get(leaves)
-                with trace.span("ckpt.stage"):
-                    staged, arena = self._stage(fetched)
-                del fetched
+                arrays, copied = self._stage(
+                    fetched, [sc.may_alias_device(x) for x in leaves])
                 job = {"kind": "replicated",
-                       "tree": jax.tree_util.tree_unflatten(treedef, staged)}
-            job.update(status=status, arena=arena,
-                       arena_key=self._staging_key)
+                       "tree": jax.tree_util.tree_unflatten(treedef, arrays)}
+            job["status"] = status
             if sp is not None:
-                job["bytes"] = _nbytes(staged)
-                sp.attrs.update(bytes=job["bytes"], superseded=superseded)
+                job["bytes"] = _nbytes(arrays)
+                sp.attrs.update(bytes=job["bytes"], copied_bytes=copied,
+                                superseded=superseded)
         stall_ms = (time.perf_counter() - t0) * 1e3
         with self._cond:
             if self._closed:
@@ -678,6 +672,7 @@ class CheckpointManager:
             self._stats["saves_async"] += 1
             self._stats["snapshot_ms_last"] = stall_ms
             self._stats["save_stall_ms_total"] += stall_ms
+            self._stats["copied_bytes_last"] = copied
             self._pending = job
             if self._writer is None:
                 self._writer = threading.Thread(
@@ -686,39 +681,24 @@ class CheckpointManager:
                 self._writer.start()
             self._cond.notify_all()
 
-    def _stage(self, arrays: list) -> tuple[list, list]:
-        """Copy fetched host arrays into a recycled snapshot arena.
-        Copying is mandatory even though `jax.device_get` already ran:
-        on the CPU backend the fetched array can be a zero-copy VIEW of
-        the live device buffer, which a donating train step overwrites
-        before the background write runs. Returns (staged, arena)."""
-        key = tuple((tuple(getattr(a, "shape", ())),
-                     str(getattr(a, "dtype", type(a).__name__)))
-                    for a in arrays)
-        with self._cond:
-            if key != self._staging_key:
-                # state structure changed (resize/reshard) — old arenas
-                # no longer fit
-                self._staging_free.clear()
-                self._staging_key = key
-            arena = self._staging_free.pop() if self._staging_free else None
-        staged, new_arena = [], []
-        for i, a in enumerate(arrays):
-            if isinstance(a, np.ndarray):
-                dst = arena[i] if arena is not None else np.empty_like(a)
-                np.copyto(dst, a)
-                staged.append(dst)
-                new_arena.append(dst)
-            else:  # python scalar leaf — immutable, no copy needed
-                staged.append(a)
-                new_arena.append(None)
-        return staged, new_arena
-
-    def _recycle_arena(self, job: dict) -> None:  # holds-lock: _cond
-        if (job.get("arena") is not None
-                and job.get("arena_key") == self._staging_key
-                and len(self._staging_free) < 2):
-            self._staging_free.append(job["arena"])
+    @staticmethod
+    def _stage(fetched: list, aliased: list[bool]) -> tuple[list, int]:
+        """Make the fetched host arrays the snapshot's own: the ones
+        flagged in `aliased` (`sc.may_alias_device` of their source) can
+        be zero-copy VIEWS of live buffers, which a donating train step
+        overwrites before the background write runs, and get a private
+        copy; the others already are host memory nothing else writes to
+        and go to the writer as they are. Returns (arrays, bytes
+        copied); the `ckpt.stage` span exists only where a copy does."""
+        # (a python scalar leaf is immutable: nothing to copy)
+        copy = [m and isinstance(a, np.ndarray)
+                for a, m in zip(fetched, aliased)]
+        if not any(copy):
+            return fetched, 0
+        with trace.span("ckpt.stage"):
+            arrays = [np.array(a) if c else a
+                      for a, c in zip(fetched, copy)]
+        return arrays, _nbytes(a for a, c in zip(fetched, copy) if c)
 
     def _writer_loop(self) -> None:
         while True:
@@ -764,14 +744,11 @@ class CheckpointManager:
                     self._write_error = exc
                     self._stats["errors"] += 1
             finally:
+                # let go of the snapshot now, not when the next job
+                # arrives: unless _retain kept it, its host memory is
+                # free again for the next fetch
+                del job
                 with self._cond:
-                    payload = (job.get("snap") if job["kind"] == "sharded"
-                               else job.get("tree"))
-                    if self._sealed is None \
-                            or self._sealed.get("payload") is not payload:
-                        # not retained (or retention replaced it):
-                        # arena returns to the staging pool as before
-                        self._recycle_arena(job)
                     self._inflight = False
                     self._cond.notify_all()
 

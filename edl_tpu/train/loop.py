@@ -568,8 +568,6 @@ class TrainLoop:
                     self.ckpt.wait()
             if self._profiling:  # run shorter than the window: still flush
                 self._stop_profiler()
-            if self.ckpt_saves:
-                log.info("ckpt plane: %s", self.ckpt_stats())
             return self.status
         finally:
             if self.ckpt is not None:
@@ -578,6 +576,9 @@ class TrainLoop:
                 # in-flight exception; clean-path write errors already
                 # surfaced at the epoch-end wait() above.
                 self.ckpt.close(raise_errors=False)
+            if self.ckpt_saves:  # on every way out, the graceful stop's
+                # SystemExit too, and after the drain: the last write counts
+                log.info("ckpt plane: %s", self.ckpt_stats())
             if self._migration is not None:
                 # After ckpt.close() so the drained final snapshot is
                 # retained and served: on a graceful stop this lingers

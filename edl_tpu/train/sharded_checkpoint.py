@@ -84,20 +84,43 @@ def _leaf_key(path) -> str:
     return jax.tree_util.keystr(path)
 
 
+def may_alias_device(x) -> bool:
+    """Can the host array that ``np.asarray(x)`` / ``jax.device_get(x)``
+    returns be a VIEW of memory a later (donating) step overwrites?
+
+    The checkpoint plane's one aliasing predicate: a caller that defers
+    its write past the next train step copies exactly the arrays this
+    says True for and hands the others on as fetched. A ``jax.Array`` in
+    device memory of a non-``cpu`` platform is fetched into a host
+    buffer the transfer itself allocated, which no step can touch. On
+    the ``cpu`` platform the fetch of an aligned buffer is zero-copy,
+    and a host memory kind (``pinned_host``, ``unpinned_host``) is
+    host-addressable on any platform, so both count as aliasing; so does
+    a numpy leaf, which ``np.asarray`` returns as the very object its
+    owner may still write to.
+    """
+    if not isinstance(x, jax.Array):
+        return True
+    kind = x.sharding.memory_kind
+    return (kind is not None and "host" in kind) or any(
+        d.platform == "cpu" for d in x.devices())
+
+
 def snapshot_shards(state: Any) -> dict:
     """Host snapshot of this process's unique shards of ``state``.
 
     The device->host pull half of ``save_sharded`` — the only part that
     must run on the training thread (and the only part whose duration
     the step loop pays under async saves). Returns ``{"leaves": table,
-    "chunks": [(fname, array), ...]}`` where the arrays MAY alias device
-    buffers on the CPU backend (np.asarray of an aligned shard is
-    zero-copy) — a caller that defers the write past the next train step
-    must copy them first (checkpoint.py stages them into its snapshot
-    arena).
+    "chunks": [(fname, array), ...], "may_alias": {fname, ...}}``. The
+    chunks named in ``may_alias`` (`may_alias_device` of their source)
+    can be views of live buffers — a caller that defers the write past
+    the next train step must copy those first; the others are host
+    memory of their own.
     """
     leaves = jax.tree_util.tree_flatten_with_path(state)[0]
     chunks_out: list[tuple[str, np.ndarray]] = []
+    may_alias: set[str] = set()
     table = []
     for i, (path, leaf) in enumerate(leaves):
         key = _leaf_key(path)
@@ -110,7 +133,13 @@ def snapshot_shards(state: Any) -> dict:
                     continue
                 offset, size = _slices_to_offset_shape(shard.index, shape)
                 fname = _chunk_name(i, offset)
+                # One blocking fetch at a time: starting every shard's
+                # transfer first (copy_to_host_async) made the fetch of
+                # a 7.35 GB state 1.3-2.3 s LONGER on a v5e (PERF.md,
+                # PR 25).
                 chunks_out.append((fname, np.asarray(shard.data)))
+                if may_alias_device(shard.data):
+                    may_alias.add(fname)
                 chunks.append({"offset": list(offset), "shape": list(size),
                                "file": fname})
         else:  # host scalar / numpy leaf — process 0 owns it whole
@@ -121,11 +150,13 @@ def snapshot_shards(state: Any) -> dict:
                 offset = tuple(0 for _ in shape)
                 fname = _chunk_name(i, offset)
                 chunks_out.append((fname, arr))
+                if may_alias_device(leaf):
+                    may_alias.add(fname)
                 chunks.append({"offset": list(offset),
                                "shape": list(arr.shape), "file": fname})
         table.append({"key": key, "shape": list(shape), "dtype": dtype,
                       "chunks": chunks})
-    return {"leaves": table, "chunks": chunks_out,
+    return {"leaves": table, "chunks": chunks_out, "may_alias": may_alias,
             "process_index": jax.process_index()}
 
 

@@ -11,14 +11,20 @@ train/checkpoint.py `save_async`):
   surfaces on the NEXT save/wait call, and the manager recovers;
 - sync and async saves produce bitwise-identical checkpoint bytes
   (replicated msgpack AND sharded chunk files);
+- copy or hand over: a fetched array that may alias a live buffer
+  (sharded_checkpoint.may_alias_device — always, on this CPU backend)
+  reaches the writer as a private copy, every other one as the very
+  object the fetch returned; no buffer is reused across saves;
 - crash-mid-save atomicity: a writer killed between chunk writes and the
   seal leaves a torn .tmp dir that restore never sees and startup GC
   removes.
 """
 
+import gc
 import json
 import os
 import threading
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -26,11 +32,20 @@ import numpy as np
 import optax
 import pytest
 
+from edl_tpu.obs import trace
 from edl_tpu.parallel import mesh as mesh_lib, sharding as shd
 from edl_tpu.train import sharded_checkpoint as sc
 from edl_tpu.train.checkpoint import (CheckpointManager,
                                       CheckpointWriteError)
 from edl_tpu.train.state import TrainState, TrainStatus
+
+# tier-1 has no accelerator, so the aliasing predicate is forced both
+# ways: "copy" is what it says of every array here, "hand-over" what it
+# says of device memory on a chip
+COPY_OR_HAND_OVER = pytest.mark.parametrize(
+    "may_alias", [True, False], ids=["copy", "hand-over"])
+PAYLOAD_FORMATS = pytest.mark.parametrize(
+    "sharded", [False, True], ids=["replicated", "sharded"])
 
 
 def _state(value: float) -> TrainState:
@@ -158,7 +173,10 @@ def test_nonzero_rank_save_async_is_noop(tmp_path):
 # -- bitwise identity --------------------------------------------------------
 
 
-def test_sync_async_bitwise_identical_replicated(tmp_path):
+@COPY_OR_HAND_OVER
+def test_sync_async_bitwise_identical_replicated(tmp_path, monkeypatch,
+                                                 may_alias):
+    monkeypatch.setattr(sc, "may_alias_device", lambda x: may_alias)
     state, status = _state(4.25), TrainStatus(epoch=2, step=20, world_size=8)
     sync_mgr = CheckpointManager(str(tmp_path / "sync"), process_index=0)
     sync_mgr.save(state, status)
@@ -185,7 +203,10 @@ def _sharded_state(mesh):
                              tx=optax.adamw(1e-3))
 
 
-def test_sync_async_bitwise_identical_sharded(tmp_path):
+@COPY_OR_HAND_OVER
+def test_sync_async_bitwise_identical_sharded(tmp_path, monkeypatch,
+                                              may_alias):
+    monkeypatch.setattr(sc, "may_alias_device", lambda x: may_alias)
     mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec({"fsdp": 2, "tp": 2}),
                               n_devices=4)
     state = _sharded_state(mesh)
@@ -218,6 +239,164 @@ def test_async_sharded_roundtrip_onto_other_mesh(tmp_path):
     for a, b in zip(jax.tree.leaves(jax.device_get(state)),
                     jax.tree.leaves(jax.device_get(restored))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    mgr.close()
+
+
+# -- copy or hand over -------------------------------------------------------
+
+
+def _small_state(value: float, sharded: bool):
+    """A device leaf split over two devices (sharded format) or on one,
+    a replicated device leaf and a numpy leaf."""
+    w = np.full((8, 4), value, np.float32)
+    if sharded:
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+        w = jax.device_put(w, NamedSharding(mesh, P("dp")))
+    return {"w": jnp.asarray(w), "b": jnp.arange(6.0),
+            "n": np.arange(3, dtype=np.int32)}
+
+
+def _spy(mgr, monkeypatch):
+    """Record the arrays each fetch returned and the arrays each write
+    was handed (a list per save, in call order)."""
+    fetched, handed = [], []
+    real_get, real_snap = jax.device_get, sc.snapshot_shards
+
+    def device_get(x):
+        out = real_get(x)
+        fetched.append(jax.tree_util.tree_leaves(out))
+        return out
+
+    def snapshot_shards(state):
+        snap = real_snap(state)
+        fetched.append([a for _, a in snap["chunks"]])
+        return snap
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(sc, "snapshot_shards", snapshot_shards)
+    real_rep, real_sh = mgr._write_replicated, mgr._save_sharded
+
+    def write_replicated(host_state, status):
+        handed.append(jax.tree_util.tree_leaves(host_state))
+        return real_rep(host_state, status)
+
+    def save_sharded(state, status, snap=None):
+        handed.append([a for _, a in snap["chunks"]])
+        return real_sh(state, status, snap=snap)
+
+    mgr._write_replicated, mgr._save_sharded = write_replicated, save_sharded
+    return fetched, handed
+
+
+@PAYLOAD_FORMATS
+@COPY_OR_HAND_OVER
+def test_writer_gets_private_copies_or_the_fetched_arrays(
+        tmp_path, monkeypatch, may_alias, sharded):
+    monkeypatch.setattr(sc, "may_alias_device", lambda x: may_alias)
+    mgr = CheckpointManager(str(tmp_path), process_index=0, sharded=sharded)
+    fetched, handed = _spy(mgr, monkeypatch)
+    state = _small_state(2.5, sharded)
+    trace.collect(str(tmp_path / "prof"))
+    try:
+        mgr.save_async(state, TrainStatus(step=7))
+        mgr.wait()
+        (snap,) = trace.finished("ckpt.snapshot")
+        stages = trace.finished("ckpt.stage")
+    finally:
+        trace.reconfigure()
+    (got,), gave = handed, fetched[0]
+    nbytes = sum(a.nbytes for a in gave)
+    assert len(got) == len(gave) == (4 if sharded else 3)
+    assert snap["attrs"]["bytes"] == nbytes > 0
+    if may_alias:
+        assert all(not np.shares_memory(g, f) for g in got for f in gave)
+        assert snap["attrs"]["copied_bytes"] == nbytes
+        assert mgr.stats()["copied_bytes_last"] == nbytes
+        assert [s["parent"] for s in stages] == [snap["sid"]]
+    else:
+        assert all(g is f for g, f in zip(got, gave))
+        assert snap["attrs"]["copied_bytes"] == 0
+        assert mgr.stats()["copied_bytes_last"] == 0
+        assert stages == []  # the span exists only where a copy does
+    restored, status = mgr.restore(_small_state(0.0, sharded))
+    assert status.step == 7
+    for k in state:
+        np.testing.assert_array_equal(np.asarray(restored[k]),
+                                      np.asarray(state[k]))
+    mgr.close()
+
+
+@PAYLOAD_FORMATS
+def test_the_predicate_says_copy_for_everything_on_the_cpu(tmp_path,
+                                                           sharded):
+    """Unforced: every array of a CPU state may be a view of a live
+    buffer (a device array's fetch is zero-copy here, a numpy leaf is
+    its owner's own object), so all of it is copied, as before."""
+    state = _small_state(1.0, sharded)
+    assert all(sc.may_alias_device(x) for x in state.values())
+    assert all(sc.may_alias_device(s.data)
+               for s in state["w"].addressable_shards)
+    mgr = CheckpointManager(str(tmp_path), process_index=0, sharded=sharded)
+    mgr.save_async(state, TrainStatus(step=1))
+    mgr.close()
+    assert mgr.stats()["copied_bytes_last"] == sum(
+        np.asarray(x).nbytes for x in state.values())
+
+
+@PAYLOAD_FORMATS
+@COPY_OR_HAND_OVER
+def test_superseded_dropped_and_retained_kept_without_a_pool(
+        tmp_path, monkeypatch, may_alias, sharded):
+    """No buffer is reused across saves: a superseded snapshot is freed,
+    and a retained one that a peer still reads keeps its bytes when
+    newer saves seal."""
+    monkeypatch.setattr(sc, "may_alias_device", lambda x: may_alias)
+    mgr = CheckpointManager(str(tmp_path), process_index=0, sharded=sharded)
+    mgr.retain_sealed = True
+    fetched, handed = _spy(mgr, monkeypatch)
+    started, gate = threading.Event(), threading.Event()
+    write = "_save_sharded" if sharded else "_write_replicated"
+    spied = getattr(mgr, write)
+
+    def gated(*a, **kw):
+        started.set()
+        assert gate.wait(10.0)
+        return spied(*a, **kw)
+
+    setattr(mgr, write, gated)
+    mgr.save_async(_small_state(1.0, sharded), TrainStatus(step=1))
+    assert started.wait(10.0)                      # 1 is in flight ...
+    mgr.save_async(_small_state(2.0, sharded), TrainStatus(step=2))
+    with mgr._cond:                                # ... 2 is queued ...
+        job = mgr._pending
+        queued = (job["snap"]["chunks"] if sharded
+                  else list(job["tree"].items()))
+        doomed = [weakref.ref(a) for _, a in queued
+                  if isinstance(a, np.ndarray)]
+        del job, queued
+    assert doomed and all(r() is not None for r in doomed)
+    mgr.save_async(_small_state(3.0, sharded), TrainStatus(step=3))
+    del fetched[:]                                 # ... and superseded
+    gc.collect()
+    if may_alias:  # private copies: the job was their only owner
+        assert all(r() is None for r in doomed)
+    gate.set()
+    mgr.wait()
+    assert mgr.versions() == [0, 1] and mgr.stats()["superseded"] == 1
+    first = mgr.sealed_snapshot()
+    assert first["version"] == 1 and first["status"]["step"] == 3
+    # the retained payload is what the writer was handed (no re-copy)
+    assert all(any(a is h for h in handed[-1])
+               for a in first["chunks"].values())
+    held = {k: np.array(a) for k, a in first["chunks"].items()}
+    for step in (4, 5, 6):                         # three more seals
+        mgr.save_async(_small_state(float(step), sharded),
+                       TrainStatus(step=step))
+        mgr.wait()
+    assert mgr.sealed_snapshot()["status"]["step"] == 6
+    for k, a in first["chunks"].items():
+        np.testing.assert_array_equal(a, held[k])
     mgr.close()
 
 
