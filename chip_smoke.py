@@ -47,6 +47,17 @@ LM_LARGE = ["--vocab", "32768", "--d-model", "2048", "--n-heads", "16",
             "--bf16", *LM_RUN]
 LM_TINY = ["--vocab", "512", "--d-model", "128", "--n-heads", "4",
            "--n-layers", "2", "--d-ff", "256", "--seq-len", "128", *LM_RUN]
+# OLMoE-1B-7B at its published widths, one layer (benchmark/configs/
+# olmoe-1b-7b-d1.json): 4 x 4096 tokens a step, 64 experts, 8 a token
+LM_OLMOE = ["--vocab", "50304", "--d-model", "2048", "--n-heads", "16",
+            "--n-layers", "1", "--d-ff", "1024", "--seq-len", "4096",
+            "--bf16", "--arch", "olmoe", "--batch-size", "4",
+            "--fused-loss", "--make-synthetic", "1", "--rows-per-file",
+            "256", "--epochs", "100", "--warmup-steps", "10", "--seed", "0"]
+LM_OLMOE_TINY = ["--vocab", "512", "--d-model", "64", "--n-heads", "2",
+                 "--n-layers", "2", "--d-ff", "32", "--seq-len", "128",
+                 "--arch", "olmoe", "--n-experts", "8", "--moe-top-k", "2",
+                 *LM_RUN]
 CKPT_STEPS = 5
 LIMIT_S = 1140      # the whole run: the driver allows 1200 s
 _procs: list[subprocess.Popen] = []
@@ -738,6 +749,10 @@ def main() -> int:
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the fsdp world on four chips and its "
                          "one-chip comparison")
+    ap.add_argument("--arch", choices=("gpt2", "olmoe"), default="gpt2",
+                    help="olmoe: only the train phase (save, SIGKILL, "
+                         "resume, SIGTERM) with lm_train's --arch olmoe at "
+                         "the published widths, one layer")
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="tiny shapes on the CPU: checks this script's "
                          "control flow, never prints a verdict")
@@ -753,6 +768,8 @@ def main() -> int:
         env.update(JAX_PLATFORMS="cpu",
                    JAX_NUM_CPU_DEVICES="4" if args.four_chips else "1")
     lm_args = LM_LARGE if tpu else LM_TINY
+    if args.arch == "olmoe":
+        lm_args = LM_OLMOE if tpu else LM_OLMOE_TINY
     work = tempfile.mkdtemp(prefix="chip-smoke-")
     say(f"work dir {work} ({shutil.disk_usage(work).free >> 30} GiB free); "
         "compile cache: " + (env.get("JAX_COMPILATION_CACHE_DIR")
@@ -761,6 +778,10 @@ def main() -> int:
         if args.four_chips:
             device = four_chips(work, env, tpu=tpu,
                                 lm_args=lm_args)["device"]
+        elif args.arch == "olmoe":
+            say("phase train")
+            device = phase_train(work, env, tpu=tpu,
+                                 lm_args=lm_args)["device"]
         else:
             if tpu:
                 say("phase kernels")

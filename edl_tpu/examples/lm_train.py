@@ -30,7 +30,7 @@ import optax
 from edl_tpu.data.pipeline import DataLoader, FileSource
 from edl_tpu.models.transformer import (Transformer, TransformerConfig,
                                         lm_loss_fn, lm_loss_fused,
-                                        lm_loss_moe)
+                                        lm_loss_moe, olmoe_config)
 from edl_tpu.obs import trace
 from edl_tpu.parallel import distributed, mesh as mesh_lib, sharding as shd
 from edl_tpu.train import lr as lr_lib
@@ -116,17 +116,28 @@ def main(argv=None) -> int:
                              "overlap earlier buckets' communication "
                              "(default $EDL_TPU_COMM_BUCKET_MB, else 0 "
                              "= XLA's single fused reduction)")
+    parser.add_argument("--arch", choices=("gpt2", "olmoe"),
+                        default="gpt2",
+                        help="the block: gpt2 = LayerNorm, learned "
+                             "positions, gelu; olmoe = models.transformer."
+                             "olmoe_config (RMSNorm, RoPE, qk-norm, SwiGLU "
+                             "experts, top-k gates as they are; implies "
+                             "--moe, 64 experts, 8 a token unless given)")
     parser.add_argument("--moe", action="store_true",
-                        help="mixture-of-experts FFNs: top-k capacity-"
-                             "factor router, expert tables sharded over "
-                             "an ep mesh, hierarchical all-to-all "
-                             "dispatch (train/comm.py; "
-                             "doc/design_comm.md)")
+                        help="mixture-of-experts FFNs. One device: "
+                             "dropless sort-and-gather dispatch into "
+                             "grouped matmuls, inside the jit step. "
+                             "Several: top-k capacity-factor router, "
+                             "expert tables sharded over an ep mesh, "
+                             "hierarchical all-to-all dispatch "
+                             "(train/comm.py; doc/design_comm.md)")
     parser.add_argument("--n-experts", type=int, default=0,
-                        help="expert count (default 2x device count; "
-                             "must divide evenly over the devices)")
-    parser.add_argument("--moe-top-k", type=int, default=2,
-                        help="experts per token")
+                        help="expert count (default 2x device count, 64 "
+                             "under --arch olmoe; must divide evenly over "
+                             "the devices)")
+    parser.add_argument("--moe-top-k", type=int, default=0,
+                        help="experts per token (default 2, 8 under "
+                             "--arch olmoe)")
     parser.add_argument("--moe-dispatch", choices=("flat", "hier"),
                         default=None,
                         help="MoE all-to-all decomposition (default "
@@ -179,6 +190,7 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     if args.fp16 and args.bf16:
         parser.error("--fp16 and --bf16 are mutually exclusive")
+    args.moe = args.moe or args.arch == "olmoe"
     if args.profile:
         trace.collect(args.profile)  # spans from here on, start-up's too
 
@@ -253,9 +265,6 @@ def main(argv=None) -> int:
             raise SystemExit("--moe is not supported with --fp16 (the "
                              "MoE comm step owns the backward; no "
                              "loss-scale hook)")
-        if args.fused_loss:
-            raise SystemExit("--fused-loss has no MoE variant (the MoE "
-                             "loss collects router aux terms)")
         if args.batch_size % jax.device_count():
             raise SystemExit(f"--moe routes per chip: --batch-size "
                              f"{args.batch_size} must divide over "
@@ -316,12 +325,16 @@ def main(argv=None) -> int:
             "quantized moments would still carry the overflowed "
             "requantization residuals. Use --fused-opt fp32 (bitwise, "
             "rollback-safe) or bf16/fp32 activations.")
-    moe_kw = {}
-    if args.moe:
+    make_cfg, moe_kw = TransformerConfig, {}
+    if args.arch == "olmoe":
+        make_cfg = olmoe_config
+        moe_kw = {k: v for k, v in (("n_experts", args.n_experts),
+                                    ("moe_top_k", args.moe_top_k)) if v}
+    elif args.moe:
         moe_kw = dict(moe=True,
                       n_experts=args.n_experts or 2 * jax.device_count(),
-                      moe_top_k=args.moe_top_k)
-    cfg = TransformerConfig(
+                      moe_top_k=args.moe_top_k or 2)
+    cfg = make_cfg(
         vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
         n_layers=args.n_layers, d_ff=args.d_ff, max_len=args.seq_len,
         dtype=(jnp.float16 if args.fp16
@@ -369,6 +382,13 @@ def main(argv=None) -> int:
     jax.block_until_ready(state)
     startup.done("state_init")
     loss = lm_loss_fused if args.fused_loss else lm_loss_fn
+    if args.moe:  # either loss with the routers' auxiliary terms
+        loss = functools.partial(
+            lm_loss_fused if args.fused_loss else lm_loss_moe,
+            aux_weight=cfg.moe_aux_weight, z_weight=cfg.moe_z_weight)
+    # several chips: experts over the ep axis, through the manual region
+    # and its capacity router; one chip: the jit step like a dense model
+    manual_ep = args.moe and jax.device_count() > 1
     if args.fp16:
         # TrainLoop's contract is step(state, batch); the loss-scale
         # state rides a closure cell. It is NOT checkpointed — after an
@@ -382,15 +402,13 @@ def main(argv=None) -> int:
         def step(state, batch):
             state, metrics, ls_box[0] = raw_step(state, batch, ls_box[0])
             return state, metrics
-    elif args.moe:
+    elif manual_ep:
         from edl_tpu.train.comm import (MoEDispatchConfig,
                                         make_moe_comm_step)
 
         def moe_loss_factory(wire):
             wired = Transformer(dataclasses.replace(cfg, moe_wire=wire))
-            return functools.partial(lm_loss_moe,
-                                     aux_weight=cfg.moe_aux_weight,
-                                     apply_fn=wired.apply)
+            return functools.partial(loss, apply_fn=wired.apply)
 
         step = make_moe_comm_step(
             moe_loss_factory, mesh=mesh,
@@ -409,6 +427,9 @@ def main(argv=None) -> int:
                  comm_cfg.bucket_mb, comm_cfg.compress)
     else:
         step = make_train_step(loss, donate=True)
+        if args.moe:
+            log.info("moe path: E=%d top_k=%d dropless, in the jit step",
+                     cfg.n_experts, cfg.moe_top_k)
     log.info("world=%d rank=%d devices=%d params=%s steps/epoch=%d",
              world, rank, jax.device_count(),
              sum(p.size for p in jax.tree.leaves(state.params)),
@@ -428,12 +449,8 @@ def main(argv=None) -> int:
 
     # eval must honor the fused path too — the dense loss would
     # materialize exactly the logits tensor --fused-loss exists to avoid
-    # (MoE eval rides the jit-dense router: global-batch capacity)
-    eval_loss_fn = (functools.partial(lm_loss_moe,
-                                      aux_weight=cfg.moe_aux_weight)
-                    if args.moe
-                    else lm_loss_fused if args.fused_loss else lm_loss_fn)
-    eval_step = jax.jit(lambda s, b: eval_loss_fn(s, s.params, b)[0])
+    # (MoE eval rides the dropless dispatch, whatever the training step)
+    eval_step = jax.jit(lambda s, b: loss(s, s.params, b)[0])
     blog = BenchmarkLog(f"transformer_lm_{args.d_model}d{args.n_layers}L",
                         batch_size=args.batch_size, world_size=world)
     epoch_t0 = [time.perf_counter()]
@@ -476,7 +493,7 @@ def main(argv=None) -> int:
     log.info("startup: process_start\u2192run %.3fs (%s)", *startup.emit())
     status = loop.run(data_fn)
     blog.extra(**loop.ckpt_stats())  # save-stall / restore accounting
-    if comm_cfg is not None or args.moe:
+    if comm_cfg is not None or manual_ep:
         blog.extra(**step.stats())  # bucket plan + DCN wire accounting
     if rank == 0 and args.benchmark_log:
         blog.write(args.benchmark_log, rank)

@@ -47,24 +47,41 @@ class TransformerConfig:
     rules: tuple = shd.DEFAULT_RULES
     # -- mixture of experts (dense fallback: moe=False leaves every
     # existing config byte-identical — blocks keep the plain MLP).
-    # moe=True swaps each block's MLP for MoEMLP: a top-k
-    # capacity-factor router over n_experts expert FFNs whose tables
-    # carry the ("expert", ...) logical axis — sharded over ep by
+    # moe=True swaps each block's MLP for MoEMLP: a softmax top-k
+    # router over n_experts expert FFNs whose tables carry the
+    # ("expert", ...) logical axis — sharded over ep by
     # sharding.DEFAULT_RULES, so they enter the checkpoint index as
     # ep-sharded leaves and re-shard on resize like any sharded state.
     moe: bool = False
     n_experts: int = 8
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float = 1.25  # the wire's buffers only
     moe_aux_weight: float = 0.01
-    # moe_wire: transport for expert dispatch/combine. None = dense
-    # einsum dispatch (single device, or XLA-partitioned over an ep
-    # mesh). Inside a manual shard_map region, train/comm injects its
-    # hierarchical all-to-all wire here (an object with
-    # dispatch/combine/local_slice — see comm.MoEWire).
+    # -- the block's kind. The defaults are the GPT-2-shaped block this
+    # file always built (LayerNorm, a learned position table, gelu
+    # experts, renormalised gates) and leave its parameter tree, names
+    # and arithmetic as they were; `olmoe_config` below sets them all.
+    norm: str = "layernorm"        # | "rmsnorm" (scale only, float32)
+    norm_eps: float = 1e-6
+    pos: str = "learned"           # | "rope" (rotate-half, whole head)
+    rope_theta: float = 10000.0
+    qk_norm: bool = False          # RMSNorm on q and k over all heads
+    moe_gated: bool = False        # SwiGLU experts: w_gate, w_up, w_down
+    moe_renorm: bool = True        # kept top-k gates renormalised to 1
+    moe_z_weight: float = 0.0      # router z-loss, mean logsumexp^2
+    # moe_wire: transport for expert dispatch/combine. None = the
+    # dropless sort-and-gather dispatch inside the jit step. Inside a
+    # manual shard_map region, train/comm injects its hierarchical
+    # all-to-all wire here (an object with dispatch/combine/local_slice
+    # — see comm.MoEWire), and the capacity router fills its buffers.
     moe_wire: Any = dfield(default=None, hash=False, compare=False)
 
     def __post_init__(self):
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"unknown norm={self.norm!r} "
+                             "(layernorm|rmsnorm)")
+        if self.pos not in ("learned", "rope"):
+            raise ValueError(f"unknown pos={self.pos!r} (learned|rope)")
         if self.moe:
             if self.n_experts < 2:
                 raise ValueError(
@@ -136,6 +153,40 @@ def _dense(features, names, cfg, name=None):
             names))
 
 
+class RMSNorm(nn.Module):
+    """x * rsqrt(mean(x^2) + eps) * scale, in float32, cast back."""
+
+    eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + self.eps)
+        return (x * scale).astype(self.dtype)
+
+
+def _norm(cfg: TransformerConfig, name: str) -> nn.Module:
+    if cfg.norm == "rmsnorm":
+        return RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
+    return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
+
+
+def rope(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions on (B, S, H, D), over the whole head, in the
+    rotate-half convention: x*cos + cat(-x[D/2:], x[:D/2])*sin with
+    angles pos * theta^(-2i/D), i < D/2, repeated twice. Float32
+    inside, cast back."""
+    s, d = x.shape[1], x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None]
+    angles = jnp.concatenate([angles, angles], -1)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    half = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
+    return (x32 * jnp.cos(angles) + half * jnp.sin(angles)).astype(x.dtype)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -154,6 +205,14 @@ class Attention(nn.Module):
                  name="key")(x)
         v = proj((cfg.n_heads, cfg.head_dim), kernel_init=qkv_init,
                  name="value")(x)
+        if cfg.qk_norm:  # over all heads' features, before the split
+            q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(
+                q.reshape(b, s, -1)).reshape(q.shape)
+            k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(
+                k.reshape(b, s, -1)).reshape(k.shape)
+        if cfg.pos == "rope":
+            with jax.named_scope("rope"):
+                q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
         q = cfg.constrain(q, ("batch", "seq", "heads", "kv"))
         k = cfg.constrain(k, ("batch", "seq", "heads", "kv"))
         v = cfg.constrain(v, ("batch", "seq", "heads", "kv"))
@@ -232,21 +291,74 @@ def router_topk(logits: jax.Array, top_k: int, capacity: int
     return combine, dispatch, aux
 
 
-class MoEMLP(nn.Module):
-    """Expert-parallel MLP: top-k capacity-factor router + n_experts
-    gelu FFNs whose (E, ...) tables carry the "expert" logical axis
-    (sharded over ep by sharding.DEFAULT_RULES — the leaves the
-    checkpoint index stores ep-sharded and re-shards on resize).
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(x, order, inv, k: int):
+    """Rows of x (T, d) in expert order: x[order // k], where ``order``
+    sorts the T*k token-major assignments by expert and ``inv`` is its
+    inverse. The backward is a gather as well (the assignments' rows
+    back in token order, summed over the k slots), so no scatter-add
+    over repeated rows appears in either direction."""
+    return x[order // k]
 
-    Two transports, one set of router/expert math:
-    - cfg.moe_wire=None (default): dense einsum dispatch. On a single
-      device this is the whole layer; on an ep mesh XLA's partitioner
-      turns the (E, cap, d) einsums into its own all-to-all.
+
+def _dispatch_rows_fwd(x, order, inv, k):
+    return x[order // k], inv
+
+
+def _dispatch_rows_bwd(k, inv, g):
+    g = g[inv].reshape(-1, k, g.shape[-1])
+    return jnp.sum(g.astype(jnp.float32), 1).astype(g.dtype), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inv):
+    """x[perm] for a permutation whose inverse is ``inv``: the backward
+    is g[inv], a gather over unique indices."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inv):
+    return x[perm], inv
+
+
+def _permute_rows_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _expert_ffn(x, tables, matmul, dtype):
+    """One expert FFN over rows grouped by expert; ``matmul(x, table)``
+    is the grouped product of the caller's layout. Two tables: gelu
+    (w_in, w_out); three: SwiGLU (w_gate, w_up, w_down)."""
+    *w_in, w_out = (t.astype(dtype) for t in tables)
+    h = matmul(x, w_in[0])
+    h = nn.silu(h) * matmul(x, w_in[1]) if len(w_in) == 2 else nn.gelu(h)
+    return matmul(h, w_out)
+
+
+class MoEMLP(nn.Module):
+    """Mixture-of-experts MLP: a softmax top-k router over n_experts
+    FFNs (gelu, or SwiGLU under cfg.moe_gated) whose (E, ...) tables
+    carry the "expert" logical axis (sharded over ep by
+    sharding.DEFAULT_RULES — the leaves the checkpoint index stores
+    ep-sharded and re-shards on resize).
+
+    Two dispatches, one set of tables:
+    - cfg.moe_wire=None (default): dropless. The T*k assignments are
+      stable-sorted by expert, their rows gathered, the FFN run as
+      grouped matmuls over the ragged groups (`jax.lax.ragged_dot`; a
+      Mosaic kernel of XLA's on a TPU), and the results un-permuted
+      and weighted. No capacity, no (T, E, C) array, nothing dropped.
     - cfg.moe_wire set (inside train/comm's manual shard_map region):
-      the wire object transports the per-chip dispatch buffer to the
-      experts' owner chips (hierarchical ICI/DCN all-to-all, optionally
-      int8 on the DCN leg) and back; each chip computes only its
-      local expert slice.
+      the capacity router `router_topk`, whose fixed-shape (E, C, d)
+      buffer the wire object carries to the experts' owner chips
+      (hierarchical ICI/DCN all-to-all, optionally int8 on the DCN
+      leg) and back; each chip computes only its local expert slice.
     """
 
     cfg: TransformerConfig
@@ -257,7 +369,6 @@ class MoEMLP(nn.Module):
         b, s, d = x.shape
         e, k = cfg.n_experts, cfg.moe_top_k
         t = b * s
-        cap = moe_capacity(t, e, k, cfg.moe_capacity_factor)
         router = self.param(
             "router",
             nn.with_logical_partitioning(nn.initializers.normal(0.02),
@@ -266,40 +377,66 @@ class MoEMLP(nn.Module):
         table_init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
             batch_axis=(0,))
-        w_in = self.param(
-            "w_in", nn.with_logical_partitioning(
-                table_init, ("expert", "embed", "mlp")),
-            (e, cfg.d_model, cfg.d_ff))
-        w_out = self.param(
-            "w_out", nn.with_logical_partitioning(
-                table_init, ("expert", "mlp", "embed")),
-            (e, cfg.d_ff, cfg.d_model))
+
+        def table(name, d_in, d_out, axes):
+            return self.param(name, nn.with_logical_partitioning(
+                table_init, ("expert", *axes)), (e, d_in, d_out))
+        names = ("w_gate", "w_up") if cfg.moe_gated else ("w_in",)
+        tables = [table(n, cfg.d_model, cfg.d_ff, ("embed", "mlp"))
+                  for n in names]
+        tables.append(table("w_down" if cfg.moe_gated else "w_out",
+                            cfg.d_ff, cfg.d_model, ("mlp", "embed")))
 
         xf = x.reshape(t, d)
-        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
-                            router.astype(jnp.float32))
-        combine, dispatch, aux = router_topk(logits, k, cap)
-        self.sow("intermediates", "moe_aux", aux["load_balance"])
-        self.sow("intermediates", "moe_dropped", aux["dropped_frac"])
-
-        buf = jnp.einsum("tec,td->ecd", dispatch.astype(cfg.dtype), xf)
+        with jax.named_scope("moe_router"):
+            logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                                router.astype(jnp.float32))
         wire = cfg.moe_wire
-        if wire is None:
-            h = jnp.einsum("ecd,edf->ecf", buf,
-                           w_in.astype(cfg.dtype))
-            h = nn.gelu(h)
-            out = jnp.einsum("ecf,efd->ecd", h,
-                             w_out.astype(cfg.dtype))
-        else:
-            recv = wire.dispatch(buf)           # (E/W, W*cap, d)
-            h = jnp.einsum("ecd,edf->ecf", recv,
-                           wire.local_slice(w_in).astype(cfg.dtype))
-            h = nn.gelu(h)
-            out = jnp.einsum("ecf,efd->ecd", h,
-                             wire.local_slice(w_out).astype(cfg.dtype))
+        if wire is not None:
+            cap = moe_capacity(t, e, k, cfg.moe_capacity_factor)
+            combine, dispatch, aux = router_topk(logits, k, cap)
+            self.sow("intermediates", "moe_aux", aux["load_balance"])
+            self.sow("intermediates", "moe_dropped", aux["dropped_frac"])
+            buf = jnp.einsum("tec,td->ecd", dispatch.astype(cfg.dtype), xf)
+            out = _expert_ffn(
+                wire.dispatch(buf),             # (E/W, W*cap, d)
+                [wire.local_slice(w) for w in tables],
+                partial(jnp.einsum, "ecd,edf->ecf"), cfg.dtype)
             out = wire.combine(out)             # back to (E, cap, d)
-        y = jnp.einsum("tec,ecd->td", combine.astype(cfg.dtype), out)
-        return y.reshape(b, s, d)
+            y = jnp.einsum("tec,ecd->td", combine.astype(cfg.dtype), out)
+            return y.reshape(b, s, d)
+
+        with jax.named_scope("moe_router"):
+            probs = jax.nn.softmax(logits, axis=-1)
+            gate, idx = jax.lax.top_k(probs, k)             # (T, k)
+            if cfg.moe_renorm:
+                gate = gate / jnp.maximum(
+                    jnp.sum(gate, -1, keepdims=True), 1e-9)
+            counts = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.int32),
+                             axis=(0, 1))                   # (E,)
+            # what the loss pools over the layers (`_moe_terms`)
+            self.sow("intermediates", "moe_frac", counts / (t * k))
+            self.sow("intermediates", "moe_probs", jnp.mean(probs, 0))
+            self.sow("intermediates", "moe_z", jnp.mean(jnp.square(
+                jax.nn.logsumexp(logits, axis=-1))))
+            self.sow("intermediates", "moe_dropped",
+                     jnp.zeros((), jnp.float32))
+        with jax.named_scope("moe_dispatch"):
+            # assignment a = token * k + slot; a stable sort by expert
+            # keeps the tokens of one expert in token order
+            order = jnp.argsort(idx.reshape(t * k), stable=True)
+            inv = jnp.zeros_like(order).at[order].set(
+                jnp.arange(t * k, dtype=order.dtype), unique_indices=True)
+            rows = _dispatch_rows(xf, order, inv, k)        # (T*k, d)
+        with jax.named_scope("moe_experts"):
+            out = _expert_ffn(
+                rows, tables,
+                lambda a, w: jax.lax.ragged_dot(a, w, counts), cfg.dtype)
+        with jax.named_scope("moe_combine"):
+            out = _permute_rows(out, inv, order).reshape(t, k, d)
+            y = jnp.einsum("tk,tkd->td", gate.astype(cfg.dtype), out,
+                           preferred_element_type=jnp.float32)
+        return y.astype(cfg.dtype).reshape(b, s, d)
 
 
 class Block(nn.Module):
@@ -312,15 +449,16 @@ class Block(nn.Module):
         # (`block<i>/attn/...`); `ln` and `mlp` group what has no module
         # of its own. Scopes are metadata: no parameter path changes.
         with jax.named_scope("ln"):
-            h = nn.LayerNorm(dtype=cfg.dtype, name="ln_attn")(x)
+            h = _norm(cfg, "ln_attn")(x)
         h = Attention(cfg, name="attn")(h, train)
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout, deterministic=not train)(h)
         x = x + h
         with jax.named_scope("ln"):
-            h = nn.LayerNorm(dtype=cfg.dtype, name="ln_mlp")(x)
+            h = _norm(cfg, "ln_mlp")(x)
         if cfg.moe:
-            h = MoEMLP(cfg, name="moe_mlp")(h)
+            with jax.named_scope("mlp"):
+                h = MoEMLP(cfg, name="moe_mlp")(h)
         else:
             with jax.named_scope("mlp"):
                 h = _dense(cfg.d_ff, ("embed", "mlp"), cfg,
@@ -361,10 +499,11 @@ class Transformer(nn.Module):
             "pos_embed",
             nn.with_logical_partitioning(nn.initializers.normal(0.02),
                                          ("seq", "embed")),
-            (cfg.max_len, cfg.d_model))
+            (cfg.max_len, cfg.d_model)) if cfg.pos == "learned" else None
         with jax.named_scope("embed"):
             x = embed(tokens)
-            x = x + pos_embed[None, :tokens.shape[1]].astype(cfg.dtype)
+            if pos_embed is not None:
+                x = x + pos_embed[None, :tokens.shape[1]].astype(cfg.dtype)
         x = cfg.constrain(x, ("batch", "seq", "embed"))
         block = Block
         if cfg.remat:
@@ -372,7 +511,7 @@ class Transformer(nn.Module):
         for i in range(cfg.n_layers):
             x = block(cfg, name=f"block{i}")(x, train)
         with jax.named_scope("ln"):
-            x = nn.LayerNorm(dtype=cfg.dtype, name="ln_final")(x)
+            x = _norm(cfg, "ln_final")(x)
         if return_hidden:
             return x
         # Tied-untied head: separate projection, fp32 logits for stable CE.
@@ -396,11 +535,16 @@ def lm_loss_fn(state, params, batch):
     return loss, {"ppl": jnp.exp(loss)}
 
 
-def lm_loss_fused(state, params, batch, *, chunk: int = 8192):
+def lm_loss_fused(state, params, batch, *, chunk: int = 8192,
+                  aux_weight: float | None = None, z_weight: float = 0.0,
+                  apply_fn=None):
     """lm_loss_fn without the (B,S,V) logits tensor: hidden states feed
     the streamed-vocab CE (ops/fused_xent.py), which reads the lm_head
     kernel from the param tree. Numerically equivalent to lm_loss_fn;
-    use for large-vocab models where the logits dominate memory.
+    use for large-vocab models where the logits dominate memory. With
+    ``aux_weight`` given (a moe=True model) the routers' auxiliary
+    terms are collected and added as in `lm_loss_moe`, whose
+    ``apply_fn`` it takes too.
 
     Mesh note: intended for dp/fsdp worlds (kernel replicated or sharded
     on the embed dim — the contraction reduces it with a psum). Under
@@ -409,14 +553,21 @@ def lm_loss_fused(state, params, batch, *, chunk: int = 8192):
     lm_loss_fn there (its vocab-parallel softmax partitions cleanly)."""
     from edl_tpu.ops.fused_xent import streamed_lm_xent
 
-    hidden = state.apply_fn({"params": params}, batch["tokens"],
-                            train=True, return_hidden=True)
+    moe = aux_weight is not None
+    hidden = (apply_fn or state.apply_fn)(
+        {"params": params}, batch["tokens"], train=True, return_hidden=True,
+        **({"mutable": ["intermediates"]} if moe else {}))
+    if moe:
+        hidden, mutated = hidden
     b, s, d = hidden.shape
     hidden = hidden[:, :-1].reshape(b * (s - 1), d)
     targets = batch["tokens"][:, 1:].reshape(-1)
     kernel = params["lm_head"]["kernel"]
     loss = streamed_lm_xent(hidden, kernel, targets, chunk)
-    return loss, {"ppl": jnp.exp(loss)}
+    if not moe:
+        return loss, {"ppl": jnp.exp(loss)}
+    extra, metrics = _moe_terms(mutated, aux_weight, z_weight)
+    return loss + extra.astype(loss.dtype), {"ppl": jnp.exp(loss), **metrics}
 
 
 def _sown(intermediates, name: str) -> list:
@@ -429,14 +580,50 @@ def _sown(intermediates, name: str) -> list:
             if any(getattr(kk, "key", None) == name for kk in path)]
 
 
+def _moe_terms(mutated, aux_weight: float, z_weight: float
+               ) -> tuple[jax.Array, dict]:
+    """(aux_weight * balance + z_weight * z, the step line's counters)
+    from what the MoE blocks sowed.
+
+    ``balance`` is 1.0 at perfect balance: E * sum_e f_e * p_e with f
+    the share of the T*k assignments an expert got and p its mean
+    router probability. The dropless layers sow f and p as vectors, and
+    they are pooled over the layers before the product, as the
+    published `load_balancing_loss_func` pools the tokens of all layers
+    (which is top_k times this: `olmoe_config` folds that into the
+    weight). The capacity router sows one scalar a layer, averaged."""
+    inter = mutated.get("intermediates", {})
+    zero = jnp.zeros((), jnp.float32)
+
+    def mean(name):
+        got = _sown(inter, name)
+        return jnp.mean(jnp.stack(got), 0) if got else zero
+    metrics = {"moe_dropped": mean("moe_dropped")}
+    frac = _sown(inter, "moe_frac")
+    if frac:
+        frac, p = jnp.stack(frac), mean("moe_probs")     # (layers, E), (E,)
+        e = p.shape[0]
+        metrics.update(
+            moe_balance=e * jnp.sum(jnp.mean(frac, 0) * p),
+            moe_z=mean("moe_z"),
+            # the fullest expert's tokens over the mean, worst layer
+            moe_max_load=e * jnp.max(frac))
+        extra = aux_weight * metrics["moe_balance"] \
+            + z_weight * metrics["moe_z"]
+    else:
+        metrics["moe_balance"] = mean("moe_aux")
+        extra = aux_weight * metrics["moe_balance"]
+    return extra, metrics
+
+
 def lm_loss_moe(state, params, batch, *, aux_weight: float = 0.01,
-                apply_fn=None):
+                z_weight: float = 0.0, apply_fn=None):
     """lm_loss_fn for moe=True configs: next-token CE plus the routers'
-    load-balance auxiliary (aux_weight * mean over MoE blocks), with
-    the capacity-drop fraction reported in the metrics. ``apply_fn``
-    overrides state.apply_fn when the loss must run a DIFFERENT model
-    binding than the state was built with (the manual-dispatch path
-    rebinds cfg.moe_wire without touching the params)."""
+    auxiliary terms (`_moe_terms`), with the capacity-drop fraction
+    reported in the metrics. ``apply_fn`` overrides state.apply_fn when
+    the loss must run a DIFFERENT model binding than the state was
+    built with (the manual-dispatch path rebinds cfg.moe_wire without
+    touching the params)."""
     fn = apply_fn or state.apply_fn
     logits, mutated = fn({"params": params}, batch["tokens"],
                          train=True, mutable=["intermediates"])
@@ -444,17 +631,28 @@ def lm_loss_moe(state, params, batch, *, aux_weight: float = 0.01,
     logp = jax.nn.log_softmax(logits[:, :-1])
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     ce = -jnp.mean(ll)
-    inter = mutated.get("intermediates", {})
-    aux = _sown(inter, "moe_aux")
-    dropped = _sown(inter, "moe_dropped")
-    balance = (jnp.mean(jnp.stack(aux)) if aux
-               else jnp.zeros((), jnp.float32))
-    loss = ce + jnp.asarray(aux_weight, ce.dtype) * balance.astype(
-        ce.dtype)
-    return loss, {"ppl": jnp.exp(ce),
-                  "moe_balance": balance,
-                  "moe_dropped": (jnp.mean(jnp.stack(dropped)) if dropped
-                                  else jnp.zeros((), jnp.float32))}
+    extra, metrics = _moe_terms(mutated, aux_weight, z_weight)
+    return ce + extra.astype(ce.dtype), {"ppl": jnp.exp(ce), **metrics}
+
+
+def olmoe_config(*, vocab_size: int = 50304, d_model: int = 2048,
+                 n_heads: int = 16, n_layers: int = 16, d_ff: int = 1024,
+                 max_len: int = 4096, n_experts: int = 64,
+                 moe_top_k: int = 8, **kw) -> TransformerConfig:
+    """OLMoE-1B-7B (arXiv:2409.02060; `model_type: olmoe`): RMSNorm
+    pre-norm (eps 1e-5), RoPE (theta 10000), RMSNorm on q and k, 64
+    SwiGLU experts of width ``d_ff``, 8 a token, gates not renormalised,
+    nothing dropped. The sizes default to the published ones. The
+    source's load-balance term is top_k at perfect balance where
+    `_moe_terms`'s is 1, so its coefficient 0.01 is held as
+    0.01 * top_k; 0.001 is the paper's z-loss weight."""
+    return TransformerConfig(
+        vocab_size=vocab_size, d_model=d_model, n_heads=n_heads,
+        n_layers=n_layers, d_ff=d_ff, max_len=max_len, norm="rmsnorm",
+        norm_eps=1e-5, pos="rope", rope_theta=10000.0, qk_norm=True,
+        moe=True, moe_gated=True, moe_renorm=False, n_experts=n_experts,
+        moe_top_k=moe_top_k, moe_aux_weight=0.01 * moe_top_k,
+        moe_z_weight=0.001, **kw)
 
 
 def choose_remat(cfg: TransformerConfig, batch_size: int,
@@ -468,7 +666,8 @@ def choose_remat(cfg: TransformerConfig, batch_size: int,
     no-remat backward keeps every block's saved activations live at
     once — roughly 12 d_model-wide tensors per block (embeddings, qkv,
     attn out, both mlp halves), plus the (heads, S, S) score matrix
-    when attention is dense — while remat keeps ONE block's worth and
+    when attention is dense, plus under moe the k-fold expert rows —
+    while remat keeps ONE block's worth and
     recomputes the rest. If the no-remat estimate exceeds
     ``budget_frac`` of what is left after params + fp32 moments, remat
     pays its ~30% recompute FLOPs. ``hbm_bytes`` defaults to the
@@ -479,15 +678,23 @@ def choose_remat(cfg: TransformerConfig, batch_size: int,
     seq = seq_len or cfg.max_len
     itemsize = jnp.dtype(cfg.dtype).itemsize
     per_block = 12 * batch_size * seq * cfg.d_model * itemsize
+    ffn = 2 * cfg.d_model * cfg.d_ff
+    if cfg.moe:
+        # every token's row is held once per chosen expert: gathered
+        # input and output (d_model wide), the hidden products (d_ff)
+        tables = 3 if cfg.moe_gated else 2
+        per_block += batch_size * seq * cfg.moe_top_k * itemsize * (
+            2 * cfg.d_model + tables * cfg.d_ff)
+        ffn = cfg.n_experts * tables * cfg.d_model * cfg.d_ff \
+            + cfg.d_model * cfg.n_experts
     if cfg.attention == "dense" or (
             cfg.attention == "auto" and not cfg.use_ring
             and jax.default_backend() != "tpu"):
         per_block += batch_size * cfg.n_heads * seq * seq * itemsize
     activations = cfg.n_layers * per_block
     n_params = (cfg.vocab_size * cfg.d_model * 2          # embed + head
-                + cfg.max_len * cfg.d_model
-                + cfg.n_layers * (4 * cfg.d_model ** 2
-                                  + 2 * cfg.d_model * cfg.d_ff))
+                + cfg.max_len * cfg.d_model * (cfg.pos == "learned")
+                + cfg.n_layers * (4 * cfg.d_model ** 2 + ffn))
     resident = n_params * (4 + 8)                          # fp32 + adam
     if hbm_bytes is None:
         dev = jax.devices()[0]

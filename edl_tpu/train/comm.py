@@ -1097,9 +1097,9 @@ def moe_parity_gate(loss_factory: Callable, state, batch, *, mesh,
        is the binding check on whether that noise costs learning.
 
     Both arms are MoECommStep instances — jit-vs-manual is NOT gated
-    here: the manual region routes per CHIP (local capacity) while the
-    jit dense path routes per GLOBAL batch, a documented semantic
-    delta covered by the convergence smoke's relative envelope.
+    here: the manual region routes per CHIP under a local capacity
+    while the jit path is dropless, a documented semantic delta
+    covered by the convergence smoke's relative envelope.
     Callers hand in a throwaway state (every arm trains from it).
     """
     moe_config = moe_config or MoEDispatchConfig()
@@ -1139,8 +1139,8 @@ def moe_parity_gate(loss_factory: Callable, state, batch, *, mesh,
 def _smoke_moe(world: int):
     """Tiny MoE markov-LM: returns ``(loss_factory, jit_loss_fn,
     state, batch)``. The factory closes over the wire for the manual
-    step; the jit loss runs the dense-einsum dispatch (wire=None) on
-    the same params."""
+    step; the jit loss runs the dropless dispatch (wire=None) on the
+    same params."""
     import functools
 
     import optax
@@ -1196,7 +1196,7 @@ def moe_convergence_smoke(compress: str = "int8", steps: int = 40,
     baseline — penalizing |delta| would fail runs that beat dense).
     The flat/off MoECommStep is the dense reference so the
     envelope isolates the wire (per-chip routing is identical in both
-    arms; the jit path's global-capacity routing delta is reported as
+    arms; the jit path's dropless routing delta is reported as
     ``jit_loss_final`` for the learned check, not gated). Runs the
     bitwise flat-vs-hier parity gate first; a red gate fails the
     smoke regardless of convergence."""

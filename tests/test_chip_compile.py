@@ -184,3 +184,44 @@ def test_lm_large_train_step_compiles(one_chip, no_persistent_cache,
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert held < 16e9, (mem.argument_size_in_bytes,
                          mem.temp_size_in_bytes)
+
+
+def test_olmoe_d1_train_step_compiles(one_chip, no_persistent_cache,
+                                      monkeypatch):
+    """The step `lm_train --arch olmoe --bf16 --fused-loss` builds for
+    benchmark/configs/olmoe-1b-7b-d1.json (published widths, one layer,
+    4 x 4096 tokens), for one v5e chip: the three flash kernels, the
+    nine grouped expert matmuls as XLA's own Mosaic kernel (three
+    tables x forward, d-lhs, d-rhs; a dense fallback would be 64 times
+    the FLOPs), and it fits 16 GB beside 7.5 GB of state."""
+    from edl_tpu.models.transformer import olmoe_config
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = olmoe_config(n_layers=1, dtype=jnp.bfloat16)
+    model = Transformer(cfg)
+
+    def create():
+        from flax.core import meta
+        variables = meta.unbox(model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 4096), jnp.int32),
+            train=False))
+        return TrainState.create(apply_fn=model.apply,
+                                 params=variables["params"],
+                                 tx=optax.adamw(4e-4, weight_decay=0.01))
+
+    state = jax.eval_shape(create)
+    batch = {"tokens": sds((4, 4096), jnp.int32)}
+    step = make_train_step(functools.partial(
+        lm_loss_fused, aux_weight=cfg.moe_aux_weight,
+        z_weight=cfg.moe_z_weight), donate=True)
+    compiled = step.lower(placed(one_chip, state),
+                          placed(one_chip, batch)).compile()
+    text = compiled.as_text()
+    grouped = sum("%ragged-dot-none" in ln.split(" = ")[0]
+                  for ln in text.splitlines())
+    assert grouped == 9, grouped
+    assert sum(f"%{name}" in text for name in
+               ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")) == 3
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 12e9 < held < 14.5e9, (mem.argument_size_in_bytes,
+                                  mem.temp_size_in_bytes)
