@@ -440,6 +440,14 @@ def main(argv=None) -> int:
              "ring" if cfg.use_ring else
              "flash" if cfg.use_flash(args.seq_len) else "dense")
     log.info("state bytes per device: %s", shd.bytes_per_device(state))
+    if args.fused_loss:
+        from edl_tpu.ops import fused_xent
+        # the rows one chip sweeps: the manual regions (comm, ep) split
+        # the batch over every chip too, without a mesh in the config
+        shards = (cfg.xent_shards() if cfg.mesh is not None
+                  else jax.device_count())
+        log.info("fused-loss: %s", fused_xent.describe(
+            args.batch_size * args.seq_len // shards, cfg.vocab_size))
 
     eval_toks = None
     val_path = os.path.join(args.data_dir, "val.npz")

@@ -13,6 +13,7 @@ Everything is static-shaped and jit-traceable; remat is applied per block
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field as dfield
 from functools import partial
 from typing import Any, Callable
@@ -143,6 +144,49 @@ class TransformerConfig:
         return shard_map(fn, mesh=self.mesh,
                          in_specs=(spec, spec, spec), out_specs=spec,
                          check_vma=False)(q, k, v)
+
+    def xent_shards(self) -> int:
+        """Chips that each sweep their own share of the batch in `xent`:
+        the mesh's batch axes where nothing else is sharded (tp shards
+        the head kernel's vocabulary, which is not the streamed CE's
+        case), else 1."""
+        shape = self.mesh.shape if self.mesh is not None else {}
+        sharded = {a: n for a, n in shape.items() if n > 1}
+        if not set(sharded) <= {"dp", "fsdp"}:
+            return 1
+        return math.prod(sharded.values())
+
+    def xent(self, hidden, kernel, targets, block_rows=None):
+        """The streamed CE (ops/fused_xent.py), shard_mapped over the
+        mesh's batch axes as `flash` is. Each chip sweeps its own
+        sequences against the whole head kernel, so the op sizes its
+        blocks from the rows a chip holds, the kernel is gathered once
+        a step where fsdp shards it, and d_kernel is summed over the
+        chips once after the sweep: left to the partitioner, the loop
+        would reduce it once a block."""
+        from edl_tpu.ops.fused_xent import streamed_lm_xent
+        if self.xent_shards() == 1:
+            return streamed_lm_xent(hidden, kernel, targets, block_rows)
+        from jax.sharding import PartitionSpec as P
+        from edl_tpu.parallel.compat import shard_map
+        batch = tuple(a for a in ("dp", "fsdp")
+                      if self.mesh.shape.get(a, 1) > 1)
+        kspec = shd.logical_to_spec(("embed", "vocab"), self.rules, self.mesh)
+        embed_axes = kspec[0] if len(kspec) else None
+
+        def local(h, k, t, n):
+            if embed_axes is not None:
+                k = jax.lax.all_gather(k, embed_axes, axis=0, tiled=True)
+            return streamed_lm_xent(h, k, t, block_rows, n)[None]
+
+        # the scope puts the gather and the reduce-scatter beside the
+        # sweep they serve in a device trace
+        with jax.named_scope("xent"):
+            parts = shard_map(local, mesh=self.mesh,
+                              in_specs=(P(batch), kspec, P(batch), P()),
+                              out_specs=P(batch))(
+                hidden, kernel, targets, jnp.sum(targets >= 0))
+            return jnp.sum(parts)
 
 
 def _dense(features, names, cfg, name=None):
@@ -535,35 +579,44 @@ def lm_loss_fn(state, params, batch):
     return loss, {"ppl": jnp.exp(loss)}
 
 
-def lm_loss_fused(state, params, batch, *, chunk: int = 8192,
+def lm_loss_fused(state, params, batch, *, block_rows: int | None = None,
                   aux_weight: float | None = None, z_weight: float = 0.0,
                   apply_fn=None):
     """lm_loss_fn without the (B,S,V) logits tensor: hidden states feed
-    the streamed-vocab CE (ops/fused_xent.py), which reads the lm_head
-    kernel from the param tree. Numerically equivalent to lm_loss_fn;
-    use for large-vocab models where the logits dominate memory. With
+    the streamed CE (ops/fused_xent.py), which reads the lm_head
+    kernel from the param tree and makes the loss and its gradient in
+    one sweep. Numerically equivalent to lm_loss_fn; use for
+    large-vocab models where the logits dominate memory. With
     ``aux_weight`` given (a moe=True model) the routers' auxiliary
     terms are collected and added as in `lm_loss_moe`, whose
-    ``apply_fn`` it takes too.
+    ``apply_fn`` it takes too. ``block_rows`` (tests) sets the rows of
+    a block, which else follow from the shapes.
 
     Mesh note: intended for dp/fsdp worlds (kernel replicated or sharded
-    on the embed dim — the contraction reduces it with a psum). Under
-    tp the head kernel is sharded on the VOCAB dim, and the chunked
-    dynamic_slice would make XLA gather the full table — use the dense
-    lm_loss_fn there (its vocab-parallel softmax partitions cleanly)."""
+    on the embed dim): the model's config, found on the bound
+    ``apply_fn``, runs the op on each chip's share of the batch
+    (`TransformerConfig.xent`). Under tp the head kernel is sharded on
+    the VOCAB dim, and a block's whole-vocabulary matmul would make XLA
+    gather the full table — use the dense lm_loss_fn there (its
+    vocab-parallel softmax partitions cleanly)."""
     from edl_tpu.ops.fused_xent import streamed_lm_xent
 
     moe = aux_weight is not None
-    hidden = (apply_fn or state.apply_fn)(
+    apply_fn = apply_fn or state.apply_fn
+    hidden = apply_fn(
         {"params": params}, batch["tokens"], train=True, return_hidden=True,
         **({"mutable": ["intermediates"]} if moe else {}))
     if moe:
         hidden, mutated = hidden
-    b, s, d = hidden.shape
-    hidden = hidden[:, :-1].reshape(b * (s - 1), d)
-    targets = batch["tokens"][:, 1:].reshape(-1)
+    tokens = batch["tokens"]
+    # a sequence's last position has no next token: a row of weight 0,
+    # so the (B, S, d) hidden states go in as they are
+    targets = jnp.concatenate(
+        [tokens[:, 1:], jnp.full_like(tokens[:, :1], -1)], axis=1)
     kernel = params["lm_head"]["kernel"]
-    loss = streamed_lm_xent(hidden, kernel, targets, chunk)
+    cfg = getattr(getattr(apply_fn, "__self__", None), "cfg", None)
+    xent = cfg.xent if cfg is not None else streamed_lm_xent
+    loss = xent(hidden, kernel, targets, block_rows)
     if not moe:
         return loss, {"ppl": jnp.exp(loss)}
     extra, metrics = _moe_terms(mutated, aux_weight, z_weight)

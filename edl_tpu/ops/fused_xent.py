@@ -1,21 +1,28 @@
-"""Streamed-vocab softmax cross-entropy for LM heads.
+"""Softmax cross-entropy for LM heads: the loss and its gradient in one
+sweep over row blocks that see the whole vocabulary.
 
-The last big activation in the LM step is the logits tensor: at
-B=16, S=1024, V=32768 it is 2 GB of fp32 that exists only to be
-log-softmaxed and gathered. This op never materializes it — the head
-matmul and the CE fuse into one pass that streams VOCAB CHUNKS, keeping
-a running (max, sum-exp) and the target's logit per row, exactly the
-flash-attention trick applied to the classifier axis. The backward
-replays the chunks from the saved log-sum-exp: d_logits for a chunk is
-(softmax - onehot) — formed chunk-at-a-time and immediately contracted
-into d_hidden and that chunk's d_kernel, so the full logits gradient
-never exists either. Peak transient memory drops from O(N*V) to
-O(N*chunk), which is what lets the LM batch grow past the logits wall.
+The logits of an LM step are the largest tensor it could make: at 6
+sequences of 2,048 positions and 50,257 words, 2.5 GB of float32 that
+exist only to be log-softmaxed and gathered. This op never holds them.
+It walks the rows in blocks (a few thousand rows, all V columns): one
+matmul makes the block's logits, the row's log-sum-exp and the target's
+logit come from them, and at once ``dlogits = (softmax - onehot) / n``
+goes into ``d_hidden`` of the block and is added into ``d_kernel``. A
+block's logits exist once and are never replayed: three N x d x V
+matmuls a step, which is what the result needs, and a transient of
+O(block x V), not O(N x V).
 
-Plain XLA inside (`lax.fori_loop`/`dynamic_slice` + MXU matmuls with
-fp32 accumulation) under a `jax.custom_vjp` — the compiler tiles these
-matmuls well; the win here is the memory schedule, not hand-written
-vector code.
+The `custom_vjp`'s forward rule keeps ``(d_hidden, d_kernel)`` as its
+residuals and the backward rule only multiplies them by the incoming
+cotangent (1 under `jax.value_and_grad`, the loss scale under
+`amp.scaled_value_and_grad`). Without a gradient asked (evaluation)
+the sweep makes the loss alone, one matmul.
+
+Plain XLA inside (`lax.fori_loop` + MXU matmuls with float32
+accumulation): the compiler tiles these matmuls well, and a whole
+vocabulary a block leaves it nothing to clamp or mask. The loop walks
+the rows it is given: where the batch is sharded over chips,
+`models.transformer.lm_loss_fused` runs the op on each chip's own rows.
 
 No reference counterpart (its models are CNNs); net-new tpu-first
 capability like ops/flash_attention.py.
@@ -29,123 +36,123 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-
-def _chunks(v: int, want: int) -> int:
-    """Chunk width: v if it fits, else `want` (the loop handles a ragged
-    tail by clamped slices + masking — any vocab keeps the O(N*chunk)
-    bound, including primes like GPT-2's 50257)."""
-    return v if v <= want else want
-
-
-def _chunk_cols(ci, chunk, v):
-    """(start, global col index grid (1, chunk)) for clamped chunk ci.
-
-    dynamic_slice clamps an out-of-bounds start, so the final ragged
-    chunk re-reads some columns of the previous one; the caller masks by
-    comparing the global index against the chunk's true [c0, c0+chunk)
-    window, which zeroes the overlap exactly once."""
-    c0 = ci * chunk
-    start = jnp.minimum(c0, v - chunk)
-    cols = start + lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
-    return c0, start, cols
+# Rows x columns of one block's logits: 0.34 GB in float32. On a v5e at
+# d=2048, V=50,257 the sweep is fastest at 1,536 rows a block (3.9 us a
+# row; 1,024: 4.2, 2,048: 4.6, 512: 5.0, 256: 7.4; the op alone, PR 29):
+# below that the read-add-write of d_kernel (8 x d x V bytes a block)
+# shows beside the matmul that makes it (rows / 4 FLOP a byte against
+# the chip's ridge of 240), above it nothing is gained for the memory.
+_BLOCK_ELEMS = 80 << 20
 
 
-# The scope names the two `while` loops (the forward's here, the
-# backward's below) in a device trace, which otherwise shows them as
-# anonymous fusions. Names are metadata: the compiled loops are the same.
+def blocking(rows: int, vocab: int,
+             block_rows: int | None = None) -> tuple[int, int]:
+    """(blocks, rows of a block), from the shapes alone: the most rows,
+    in steps of 256, whose block of logits stays under `_BLOCK_ELEMS`.
+    ``block_rows`` (tests) is taken as it is. The last block may reach
+    past ``rows``: the sweep pads with rows of weight 0."""
+    if block_rows is None:
+        block_rows = max(256, _BLOCK_ELEMS // vocab // 256 * 256)
+    block_rows = min(block_rows, rows)
+    return -(-rows // block_rows), block_rows
+
+
+def describe(rows: int, vocab: int) -> str:
+    """What a run logs of the blocking it ran (`lm_train --fused-loss`)."""
+    blocks, block_rows = blocking(rows, vocab)
+    return (f"one sweep, {blocks} blocks of {block_rows} rows x {vocab}, "
+            "3 matmuls")
+
+
+# The scope names the sweep in a device trace, which otherwise shows
+# anonymous fusions; the narrower ones name its three matmuls. Names are
+# metadata: the compiled loop is the same with and without them.
 @jax.named_scope("xent")
-def _fwd_pass(hidden, kernel, targets, chunk):
-    """Returns (lse (N,), target_logit (N,)) streaming vocab chunks."""
+def _sweep(hidden, kernel, targets, n_rows, block_rows, with_grad):
+    """Sum over the rows with a target >= 0 of (lse - target's logit) / n_rows,
+    and with ``with_grad`` its gradient for hidden and kernel."""
     n, d = hidden.shape
     v = kernel.shape[1]
-    h32 = hidden.astype(jnp.float32)
+    blocks, r = blocking(n, v, block_rows)
+    if blocks * r > n:
+        hidden = jnp.pad(hidden, ((0, blocks * r - n), (0, 0)))
+        targets = jnp.pad(targets, (0, blocks * r - n), constant_values=-1)
     k32 = kernel.astype(jnp.float32)
-    n_chunks = -(-v // chunk)
+    scale = 1.0 / n_rows.astype(jnp.float32)
 
-    def body(ci, carry):
-        m, l, tgt = carry
-        c0, start, cols = _chunk_cols(ci, chunk, v)
-        k_blk = lax.dynamic_slice(k32, (0, start), (d, chunk))
-        logits = jnp.dot(h32, k_blk,
-                         preferred_element_type=jnp.float32)  # (N, C)
-        valid = (cols >= c0) & (cols < v)
-        logits = jnp.where(valid, logits, -jnp.inf)
-        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
-        l = l * jnp.exp(m - m_new) + jnp.sum(
-            jnp.where(valid, jnp.exp(logits - m_new[:, None]), 0.0),
-            axis=-1)
-        local = targets - start
-        in_chunk = (targets >= c0) & (targets < jnp.minimum(c0 + chunk, v))
+    # A block is held vocabulary-major, (V, R), and d_kernel as (V, d):
+    # the layouts the compiler picks by itself for a vocabulary that is
+    # no multiple of 128 and does not for one that is, where the
+    # d_kernel product then runs at half its rate (35 against 25 ms a
+    # step in olmoe_d1.steady; my chip runs, PR 29).
+    def body(i, carry):
+        h32 = lax.dynamic_slice(hidden, (i * r, 0), (r, d)
+                                ).astype(jnp.float32)
+        t = lax.dynamic_slice(targets, (i * r,), (r,))
+        with jax.named_scope("xent_logits"):
+            logits = lax.dot_general(k32, h32, (((0,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        m = jnp.max(logits, axis=0)
+        lse = m + jnp.log(jnp.sum(jnp.exp(logits - m), axis=0))
         picked = jnp.take_along_axis(
-            logits, jnp.clip(local, 0, chunk - 1)[:, None], axis=-1)[:, 0]
-        tgt = jnp.where(in_chunk, picked, tgt)
-        return m_new, l, tgt
+            logits, jnp.maximum(t, 0)[None, :], axis=0)[0]
+        w = jnp.where(t >= 0, scale, 0.0)
+        loss = carry[0] + jnp.sum(w * (lse - picked))
+        if not with_grad:
+            return (loss,)
+        _, dh, dk = carry
+        onehot = lax.broadcasted_iota(jnp.int32, (v, 1), 0) == t
+        dlogits = (jnp.exp(logits - lse) - onehot.astype(jnp.float32)) * w
+        with jax.named_scope("xent_dh"):
+            dh_blk = lax.dot_general(dlogits, k32, (((0,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        with jax.named_scope("xent_dk"):
+            dk = dk + jnp.dot(dlogits, h32,
+                              preferred_element_type=jnp.float32)
+        dh = lax.dynamic_update_slice(dh, dh_blk.astype(dh.dtype), (i * r, 0))
+        return loss, dh, dk
 
-    m0 = jnp.full((n,), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((n,), jnp.float32)
-    t0 = jnp.zeros((n,), jnp.float32)
-    m, l, tgt = lax.fori_loop(0, n_chunks, body, (m0, l0, t0))
-    return m + jnp.log(l), tgt
+    init = (jnp.zeros((), jnp.float32),)
+    if with_grad:
+        init += (jnp.zeros_like(hidden), jnp.zeros((v, d), jnp.float32))
+    out = lax.fori_loop(0, blocks, body, init)
+    if not with_grad:
+        return out[0]
+    return out[0], out[1][:n], out[2].T.astype(kernel.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def streamed_lm_xent(hidden, kernel, targets, chunk: int = 8192):
-    """Mean CE of softmax(hidden @ kernel) vs integer targets.
-
-    hidden: (N, d); kernel: (d, V); targets: (N,) int32 in [0, V).
-    Equivalent to
-    ``-mean(log_softmax(hidden @ kernel)[arange(N), targets])`` without
-    ever materializing the (N, V) logits.
-    """
-    chunk = _chunks(kernel.shape[1], chunk)
-    lse, tgt = _fwd_pass(hidden, kernel, targets, chunk)
-    return jnp.mean(lse - tgt)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _xent(hidden, kernel, targets, n_rows, block_rows):
+    return _sweep(hidden, kernel, targets, n_rows, block_rows, False)
 
 
-def _xent_fwd(hidden, kernel, targets, chunk):
-    chunk = _chunks(kernel.shape[1], chunk)
-    lse, tgt = _fwd_pass(hidden, kernel, targets, chunk)
-    return jnp.mean(lse - tgt), (hidden, kernel, targets, lse)
+def _xent_fwd(hidden, kernel, targets, n_rows, block_rows):
+    loss, dh, dk = _sweep(hidden, kernel, targets, n_rows, block_rows, True)
+    return loss, (dh, dk)
 
 
 @jax.named_scope("xent")
-def _xent_bwd(chunk, res, g):
-    hidden, kernel, targets, lse = res
-    n, d = hidden.shape
-    v = kernel.shape[1]
-    chunk = _chunks(v, chunk)
-    h32 = hidden.astype(jnp.float32)
-    k32 = kernel.astype(jnp.float32)
-    scale = g / n  # d(mean)/d(row)
-    n_chunks = -(-v // chunk)
-
-    def body(ci, carry):
-        dh, dk = carry
-        c0, start, cols = _chunk_cols(ci, chunk, v)
-        k_blk = lax.dynamic_slice(k32, (0, start), (d, chunk))
-        logits = jnp.dot(h32, k_blk, preferred_element_type=jnp.float32)
-        valid = (cols >= c0) & (cols < v)
-        p = jnp.where(valid, jnp.exp(logits - lse[:, None]), 0.0)
-        local = targets - start
-        in_chunk = (targets >= c0) & (targets < jnp.minimum(c0 + chunk, v))
-        onehot = (lax.broadcasted_iota(jnp.int32, (1, chunk), 1) ==
-                  jnp.clip(local, 0, chunk - 1)[:, None]) & in_chunk[:, None]
-        dlogits = (p - onehot.astype(jnp.float32)) * scale
-        dh = dh + jnp.dot(dlogits, k_blk.T,
-                          preferred_element_type=jnp.float32)
-        dk_blk = jnp.dot(h32.T, dlogits,
-                         preferred_element_type=jnp.float32)
-        # accumulate into the preallocated (d, V) gradient in place —
-        # read-add-write is overlap-safe because masked columns
-        # contribute exactly 0 from the ragged chunk
-        cur = lax.dynamic_slice(dk, (0, start), (d, chunk))
-        dk = lax.dynamic_update_slice(dk, cur + dk_blk, (0, start))
-        return dh, dk
-
-    dh, dk = lax.fori_loop(
-        0, n_chunks, body,
-        (jnp.zeros((n, d), jnp.float32), jnp.zeros((d, v), jnp.float32)))
-    return (dh.astype(hidden.dtype), dk.astype(kernel.dtype), None)
+def _xent_bwd(block_rows, res, g):
+    dh, dk = res
+    return (g * dh).astype(dh.dtype), (g * dk).astype(dk.dtype), None, None
 
 
-streamed_lm_xent.defvjp(_xent_fwd, _xent_bwd)
+_xent.defvjp(_xent_fwd, _xent_bwd)
+
+
+def streamed_lm_xent(hidden, kernel, targets, block_rows: int | None = None,
+                     n_rows=None):
+    """Mean CE of softmax(hidden @ kernel) against integer targets.
+
+    hidden: (..., d); kernel: (d, V); targets: (...) int32 in [0, V), or
+    negative for a row that does not count (an LM batch's last
+    positions). Equivalent to the mean of
+    ``-log_softmax(hidden @ kernel)[..., targets]`` over the rows that
+    count, without ever holding more than a block of the logits.
+    ``n_rows`` is what the sum is divided by where that is not this
+    call's own count of rows (a caller that holds a share of the batch
+    gives the whole batch's)."""
+    if n_rows is None:
+        n_rows = jnp.sum(targets >= 0)
+    return _xent(hidden.reshape(-1, hidden.shape[-1]), kernel,
+                 targets.reshape(-1), n_rows, block_rows)

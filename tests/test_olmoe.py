@@ -249,14 +249,14 @@ def test_fused_loss_with_moe_is_the_dense_logits_loss(tree, tokens):
     state = state_of(tree)
     dense, dm = tfm.lm_loss_moe(state, tree, {"tokens": tokens}, **kw)
     fused, fm = tfm.lm_loss_fused(state, tree, {"tokens": tokens},
-                                  chunk=32, **kw)
+                                  block_rows=32, **kw)
     assert abs(float(dense) - float(fused)) < 1e-5
     assert set(dm) == set(fm) == {"ppl", "moe_balance", "moe_dropped",
                                   "moe_z", "moe_max_load"}
     gd = jax.grad(lambda p: tfm.lm_loss_moe(
         state, p, {"tokens": tokens}, **kw)[0])(tree)
     gf = jax.grad(lambda p: tfm.lm_loss_fused(
-        state, p, {"tokens": tokens}, chunk=32, **kw)[0])(tree)
+        state, p, {"tokens": tokens}, block_rows=32, **kw)[0])(tree)
     for a, b in zip(jax.tree.leaves(gd), jax.tree.leaves(gf)):
         assert float(jnp.abs(a - b).max()) < 1e-5
 

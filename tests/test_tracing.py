@@ -450,11 +450,16 @@ def test_step_hlo_carries_the_scope(step_hlo, scope):
     assert any(f"/{scope}/" in n or f"({scope})/" in n for n in names), scope
 
 
-def test_step_is_named_train_step_and_xent_is_scoped_both_ways(step_hlo):
+def test_step_is_named_train_step_and_xent_names_its_three_matmuls(step_hlo):
     import re
     assert "HloModule jit_train_step" in step_hlo
     names = re.findall(r'op_name="([^"]+)"', step_hlo)
-    xent = [n for n in names if "xent" in n]
-    # forward and backward, both inside the streamed loops
-    assert any("transpose" not in n and "while" in n for n in xent)
-    assert any("transpose" in n and "while" in n for n in xent)
+    xent = [n for n in names if "(xent)/" in n]
+    # one sweep makes the loss and its gradient: the three products are
+    # named inside the forward rule's loop, and the backward pass
+    # replays nothing of it
+    for inner in ("xent_logits", "xent_dh", "xent_dk"):
+        assert any(n.endswith(f"/while/body/closed_call/{inner}/dot_general")
+                   for n in xent), inner
+    assert sum(n.endswith("dot_general") for n in set(xent)) == 3
+    assert not any("transpose" in n for n in xent)
