@@ -28,8 +28,7 @@ Contract preserved end to end (the part that makes a relay safe):
   reconnects with jittered backoff and resumes by revision; a restarted
   relay re-subscribes upstream from that revision and the store's event
   history replays the gap. Zero lost, zero duplicated — verified by
-  ``selftest`` here and at 100k-pod scale by ``tools/store_bench.py
-  --fleet``.
+  ``selftest`` here and by tests/test_relay.py.
 
 Layering: stdlib-only (layers.toml pins coord jax/numpy-free) — the
 relay tier runs on scheduler nodes with no accelerator stack.

@@ -41,8 +41,8 @@ File layout (all little-endian, offsets 64-aligned):
     [4096:...) field tables, each a contiguous (n, *shape) array
 
 The trade is explicit: pre-decoded uint8 pixels are larger on disk than
-JPEG (`bench.py` reports `loader_pack_ratio_bytes`), but disk bandwidth
-is the cheap resource and host CPU the scarce one on a TPU VM.
+JPEG, but disk bandwidth is the cheap resource and host CPU the scarce
+one on a TPU VM.
 
 CLI:
 

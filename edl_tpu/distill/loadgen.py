@@ -1,7 +1,6 @@
 """Open-loop load generation for the teacher serving plane.
 
-The serving benches (tools/serve_load_bench.py, ``elastic_demo
---serve-load``, bench.py ``serving_throughput``) need an OPEN-loop
+A serving bench (``elastic_demo --serve-load``) needs an OPEN-loop
 generator: arrival times come from a schedule alone, never from
 completions. `TeacherClient` is the wrong tool for that twice over —
 it is not thread-safe, and its ``max_inflight`` gate blocks the

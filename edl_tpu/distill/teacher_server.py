@@ -779,8 +779,8 @@ class TeacherServer:
         # Hard-close live connections: clients see ECONNRESET now and
         # requeue their in-flight work to surviving teachers at once,
         # exactly as if the process had been killed — without this they
-        # stall head-of-line until rpc_timeout (measured as a 60s e2e
-        # dip in bench_distill_churn before the fix).
+        # stall head-of-line until rpc_timeout (a 60s end-to-end dip
+        # under teacher churn before the fix).
         with self._server.conns_lock:  # type: ignore[attr-defined]
             conns = list(self._server.active_conns)  # type: ignore[attr-defined]
         for sock in conns:

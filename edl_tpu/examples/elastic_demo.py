@@ -191,7 +191,7 @@ def run_scaler_demo(args) -> int:
     if silent:
         log.error("scaler never observed fresh utilization (nodes %d:%d"
                   ") — the closed loop is not closing", lo, hi)
-    # machine-readable (mirrors the ckpt_stats= convention bench.py reads)
+    # machine-readable, as the ckpt_stats= line is
     print("scaler_summary=" + json.dumps(summary), flush=True)
     if args.journal is None:
         shutil.rmtree(tmp, ignore_errors=True)
@@ -218,7 +218,7 @@ def run_serve_scaler_demo(args) -> int:
         -> in-flight work done -> stop), with zero hard kills,
       - the pool's latency SLO was met again by the end of the run.
 
-    Prints a machine-readable ``serve_summary=`` line (bench.py-style).
+    Prints a machine-readable ``serve_summary=`` line.
     """
     import os
     import shutil
@@ -565,9 +565,9 @@ def run_p2p_demo(args) -> int:
       - /resize published a migration epoch per applied resize,
 
     and exits 1 when any of it silently degraded to the disk recipe.
-    Prints a machine-readable ``p2p_summary=`` line (bench.py reads
-    ``elastic_downtime_p2p_s`` — the worst surviving-pod training gap —
-    and ``resize_bytes_from_peers`` from it)."""
+    Prints a machine-readable ``p2p_summary=`` line
+    (``elastic_downtime_p2p_s`` is the worst surviving-pod training gap,
+    beside ``resize_bytes_from_peers``)."""
     import os
     import shutil
     import tempfile
@@ -760,8 +760,7 @@ def run_reform_demo(args) -> int:
     Prints ``reform_summary=``: `elastic_downtime_multihost_s` is the
     best (compile-cache-warm) survivor gap — the steady-state cost of a
     device-world change; `_cold_s` is the worst (first sight of a new
-    shape pays exactly one compile). bench.py and the resize_bench
-    world axis read both.
+    shape pays exactly one compile).
     """
     import os
     import shutil
@@ -911,7 +910,7 @@ def run_reform_demo(args) -> int:
     gaps = sorted(d["downtime_s"] for d in reforms
                   if d.get("downtime_s") is not None)
     # respawned-pod gap: the stop-resume price a NON-surviving process
-    # pays on the same resize (resize_bench's world-axis column)
+    # pays on the same resize
     respawn_gaps = sorted(d["ts"] - t_grow for d in respawn_restores
                           if t_grow is not None and d["ts"] > t_grow)
     ok = (phases_ok and complete and len(reforms) >= 2
@@ -1319,8 +1318,7 @@ def main(argv=None) -> int:
     b = float(np.asarray(loop.state.params["Dense_0"]["bias"])[0])
     log.info("done: epoch=%d step=%d w=%.3f b=%.3f", status.epoch,
              status.step, w, b)
-    # machine-readable for the elastic-downtime bench (bench.py). A
-    # graceful SIGTERM stop never reaches here: loop.run raises
+    # machine-readable. A graceful SIGTERM stop never reaches here: loop.run raises
     # SystemExit(143) after its donor linger (the launcher must not
     # read a stopped trainer as "training complete").
     print("ckpt_stats=" + json.dumps(loop.ckpt_stats()), flush=True)

@@ -51,7 +51,7 @@ from edl_tpu.train.classification import (create_state,
                                           make_classification_step,
                                           make_eval_step)
 from edl_tpu.train.loop import LoopConfig, TrainLoop
-from edl_tpu.utils.config import from_env
+from edl_tpu.utils.config import from_env, given
 from edl_tpu.utils.logging import get_logger
 
 log = get_logger("edl_tpu.examples.imagenet_train")
@@ -346,56 +346,31 @@ def main(argv=None) -> int:
                          f"world {world}")
     local_bs = args.batch_size // world
 
-    ckpt_kw = {}
-    if args.ckpt_steps is not None:
-        ckpt_kw["ckpt_every_steps"] = args.ckpt_steps
-    if args.ckpt_sync:
-        ckpt_kw["ckpt_async"] = False
+    # every option below: the flag where given, else its environment
+    # name (bound on the dataclass of the module that consumes it)
     loop_cfg = from_env(LoopConfig, num_epochs=args.epochs,
                         ckpt_dir=args.ckpt_dir or env.checkpoint_path
                         or None,
-                        profile_dir=args.profile or None, **ckpt_kw)
-    # --loader-workers wins when given; otherwise the LoopConfig (its
-    # EDL_TPU_LOADER_WORKERS binding) sets the mp pool width, so the
-    # loop config actually drives the input plane it runs on.
-    loader_workers = (args.loader_workers
-                      if args.loader_workers is not None
-                      else loop_cfg.loader_workers)
+                        profile_dir=args.profile or None,
+                        **given(ckpt_every_steps=args.ckpt_steps,
+                                ckpt_async=False if args.ckpt_sync
+                                else None))
 
     # hybrid ICI x DCN when the job is (or declares itself) multi-slice:
     # dp's major dimension crosses DCN, flat dp otherwise
     mesh = distributed.make_mesh_from_env(mesh_lib.MeshSpec({"dp": -1}),
                                           env)
-    # DCN-aware gradient path: CLI > env (LoopConfig binding) > off.
-    # A compressed wire implies bucketing (default 4 MiB target).
-    dcn_compress = (args.dcn_compress if args.dcn_compress is not None
-                    else loop_cfg.dcn_compress)
-    comm_bucket_mb = (args.comm_bucket_mb
-                      if args.comm_bucket_mb is not None
-                      else loop_cfg.comm_bucket_mb)
-    comm_cfg = None
-    if dcn_compress != "off" or comm_bucket_mb > 0:
-        if args.teachers:
-            raise SystemExit(
-                "--dcn-compress/--comm-bucket-mb are not supported "
-                "with --teachers (the distill steps carry their own "
-                "jit; the dp gradient wire is the student-only path)")
-        from edl_tpu.train.comm import CommConfig
-        comm_cfg = CommConfig(bucket_mb=comm_bucket_mb or 4.0,
-                              compress=dcn_compress)
-    # Fused optimizer path: CLI > env (LoopConfig binding) > off;
-    # EDL_TPU_OPT_QUANT overrides just the resident-moment codec.
-    fused_opt = (args.fused_opt if args.fused_opt is not None
-                 else loop_cfg.fused_opt)
-    if loop_cfg.opt_quant and fused_opt != "off":
-        if loop_cfg.opt_quant not in ("off", "int8", "fp8"):
-            raise SystemExit(f"EDL_TPU_OPT_QUANT must be off|int8|fp8, "
-                             f"got {loop_cfg.opt_quant!r}")
-        fused_opt = ("fp32" if loop_cfg.opt_quant == "off"
-                     else loop_cfg.opt_quant)
-    if fused_opt not in ("off", "fp32", "int8", "fp8"):
-        raise SystemExit(f"EDL_TPU_FUSED_OPT must be off|fp32|int8|fp8, "
-                         f"got {fused_opt!r}")
+    # the manual dp gradient path, where a flag or the environment asks
+    from edl_tpu.train.comm import CommConfig
+    comm_cfg = CommConfig.from_flags(
+        bucket_mb=args.comm_bucket_mb, compress=args.dcn_compress)
+    if comm_cfg is not None and args.teachers:
+        raise SystemExit(
+            "--dcn-compress/--comm-bucket-mb are not supported "
+            "with --teachers (the distill steps carry their own "
+            "jit; the dp gradient wire is the student-only path)")
+    from edl_tpu.train.fused_opt import fused_mode, make_fused_tx
+    fused_opt = fused_mode(args.fused_opt)
     if fused_opt != "off" and args.dgc_sparsity > 0:
         raise SystemExit(
             "--fused-opt and --dgc-sparsity are mutually exclusive: "
@@ -424,7 +399,7 @@ def main(argv=None) -> int:
         loader = DataLoader(source, local_bs, rank=rank, world=world,
                             seed=args.seed, sample_transforms=(sample_t,),
                             decode_threads=args.decode_threads,
-                            num_workers=loader_workers)
+                            num_workers=args.loader_workers)
         normalize = "imagenet"  # uint8 off the wire; normalize on chip
         n_files = len(source)
     else:
@@ -457,7 +432,7 @@ def main(argv=None) -> int:
             else (random_flip_lr, random_crop)
         loader = DataLoader(source, local_bs, rank=rank, world=world,
                             seed=args.seed, transforms=transforms,
-                            num_workers=loader_workers,
+                            num_workers=args.loader_workers,
                             emit_batch_seed=augment_device)
     steps_per_epoch = loader.steps_per_epoch()
     log.info("world=%d rank=%d devices=%d format=%s shards=%d samples=%d "
@@ -480,7 +455,6 @@ def main(argv=None) -> int:
             optax.add_decayed_weights(args.weight_decay),
             optax.sgd(schedule))
     elif fused_opt != "off":
-        from edl_tpu.train.fused_opt import make_fused_tx
         # same math as the optax chain below (fp32 mode is bitwise):
         # decayed weights fold into the momentum update in-kernel
         tx = make_fused_tx("sgdm", schedule, fused_opt,
@@ -538,7 +512,7 @@ def main(argv=None) -> int:
             topology=distributed.slice_topology(env))
         if comm_cfg is not None:
             log.info("dcn-aware gradient path: bucket=%.1fMiB "
-                     "compress=%s", comm_cfg.bucket_mb,
+                     "compress=%s", comm_cfg.target_mb,
                      comm_cfg.compress)
     eval_step = make_eval_step(normalize=normalize)
     augment = None

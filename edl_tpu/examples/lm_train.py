@@ -30,7 +30,7 @@ import optax
 from edl_tpu.data.pipeline import DataLoader, FileSource
 from edl_tpu.models.transformer import (Transformer, TransformerConfig,
                                         lm_loss_fn, lm_loss_fused,
-                                        lm_loss_moe, olmoe_config)
+                                        olmoe_config)
 from edl_tpu.obs import trace
 from edl_tpu.parallel import distributed, mesh as mesh_lib, sharding as shd
 from edl_tpu.train import lr as lr_lib
@@ -38,7 +38,7 @@ from edl_tpu.train.benchlog import BenchmarkLog
 from edl_tpu.train.loop import LoopConfig, TrainLoop
 from edl_tpu.train.state import TrainState
 from edl_tpu.train.step import make_train_step
-from edl_tpu.utils.config import from_env
+from edl_tpu.utils.config import from_env, given
 from edl_tpu.utils.logging import get_logger
 
 log = get_logger("edl_tpu.examples.lm_train")
@@ -92,12 +92,6 @@ def main(argv=None) -> int:
     parser.add_argument("--lr", type=float, default=3e-4)
     parser.add_argument("--warmup-steps", type=int, default=100)
     parser.add_argument("--bf16", action="store_true")
-    parser.add_argument("--fp16", action="store_true",
-                        help="float16 activations + dynamic loss scaling "
-                             "(train/amp.py; the reference's --fp16/"
-                             "--scale_loss). bf16 is the TPU-native "
-                             "choice — this exists for parity and "
-                             "fp16 experiments")
     parser.add_argument("--fused-loss", action="store_true",
                         help="streamed-vocab CE: never materializes the "
                              "(B,S,V) logits (ops/fused_xent.py) — use "
@@ -172,8 +166,6 @@ def main(argv=None) -> int:
                         help="dp: data parallel; fsdp: params sharded; "
                              "sp: sequence parallel — ring attention over "
                              "the sequence axis (long-context mode)")
-    parser.add_argument("--fsdp", action="store_true",
-                        help=argparse.SUPPRESS)  # legacy alias of --mesh fsdp
     parser.add_argument("--ckpt-dir", default="")
     parser.add_argument("--ckpt-sharded", action="store_true")
     parser.add_argument("--ckpt-steps", type=int, default=None,
@@ -188,8 +180,6 @@ def main(argv=None) -> int:
                         help="jax profiler trace dir (steps 10-15, rank 0)")
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(argv)
-    if args.fp16 and args.bf16:
-        parser.error("--fp16 and --bf16 are mutually exclusive")
     args.moe = args.moe or args.arch == "olmoe"
     if args.profile:
         trace.collect(args.profile)  # spans from here on, start-up's too
@@ -224,26 +214,17 @@ def main(argv=None) -> int:
         raise SystemExit("global batch not divisible by world")
     local_bs = args.batch_size // world
 
-    ckpt_kw = {}
-    if args.ckpt_steps is not None:
-        ckpt_kw["ckpt_every_steps"] = args.ckpt_steps
-    if args.ckpt_sync:
-        ckpt_kw["ckpt_async"] = False
+    # every option below: the flag where given, else its environment
+    # name (bound on the dataclass of the module that consumes it)
     loop_cfg = from_env(LoopConfig, num_epochs=args.epochs,
                         ckpt_dir=args.ckpt_dir or env.checkpoint_path
                         or None, ckpt_sharded=args.ckpt_sharded,
-                        profile_dir=args.profile or None, **ckpt_kw)
-    # --loader-workers wins when given; otherwise the LoopConfig (its
-    # EDL_TPU_LOADER_WORKERS binding) sets the mp pool width.
-    loader_workers = (args.loader_workers
-                      if args.loader_workers is not None
-                      else loop_cfg.loader_workers)
+                        profile_dir=args.profile or None,
+                        **given(ckpt_every_steps=args.ckpt_steps,
+                                ckpt_async=False if args.ckpt_sync
+                                else None))
 
-    if args.fsdp and args.mesh != "dp":
-        raise SystemExit("--fsdp is a legacy alias of --mesh fsdp; "
-                         f"it conflicts with --mesh {args.mesh}")
-    kind = "fsdp" if args.fsdp else args.mesh
-    if kind == "sp":
+    if args.mesh == "sp":
         if world > 1:
             # rank-sharded loading + replicate_host_tree assume a data
             # axis; an sp-only mesh would feed divergent "replicated"
@@ -257,14 +238,10 @@ def main(argv=None) -> int:
                              f"{n_dev} devices; --seq-len {args.seq_len} "
                              f"is not divisible by {n_dev}")
     if args.moe:
-        if kind != "dp":
+        if args.mesh != "dp":
             raise SystemExit(f"--moe owns the ep mesh (expert tables "
-                             f"sharded over every chip); --mesh {kind} "
-                             "conflicts")
-        if args.fp16:
-            raise SystemExit("--moe is not supported with --fp16 (the "
-                             "MoE comm step owns the backward; no "
-                             "loss-scale hook)")
+                             f"sharded over every chip); --mesh "
+                             f"{args.mesh} conflicts")
         if args.batch_size % jax.device_count():
             raise SystemExit(f"--moe routes per chip: --batch-size "
                              f"{args.batch_size} must divide over "
@@ -273,58 +250,21 @@ def main(argv=None) -> int:
     # a dp axis — or ep under --moe — to carry DCN; other --mesh kinds
     # fail fast there); single-slice worlds get the flat mesh as before
     mesh = distributed.make_mesh_from_env(
-        mesh_lib.MeshSpec({"ep" if args.moe else kind: -1}), env)
-    # DCN-aware gradient path: CLI > env (LoopConfig binding) > off.
-    # A compressed wire implies bucketing (default 4 MiB target).
-    dcn_compress = (args.dcn_compress if args.dcn_compress is not None
-                    else loop_cfg.dcn_compress)
-    comm_bucket_mb = (args.comm_bucket_mb
-                      if args.comm_bucket_mb is not None
-                      else loop_cfg.comm_bucket_mb)
-    comm_cfg = None
-    if dcn_compress != "off" or comm_bucket_mb > 0:
-        if kind != "dp":
-            raise SystemExit(
-                f"--dcn-compress/--comm-bucket-mb own the dp gradient "
-                f"reduction; --mesh {kind} keeps the XLA-partitioned "
-                "step (fsdp/tp collectives are slice-local already)")
-        if args.fp16:
-            raise SystemExit("--dcn-compress/--comm-bucket-mb are not "
-                             "supported with --fp16 (the manual path "
-                             "owns the backward's reduction)")
-        from edl_tpu.train.comm import CommConfig
-        comm_cfg = CommConfig(bucket_mb=comm_bucket_mb or 4.0,
-                              compress=dcn_compress)
-    # MoE dispatch knobs: CLI > env (LoopConfig binding) > hier/off.
-    moe_dispatch = (args.moe_dispatch if args.moe_dispatch is not None
-                    else loop_cfg.moe_dispatch)
-    moe_compress = (args.moe_compress if args.moe_compress is not None
-                    else loop_cfg.moe_compress)
-    if args.moe and dcn_compress != "off":
+        mesh_lib.MeshSpec({"ep" if args.moe else args.mesh: -1}), env)
+    # the manual dp gradient path, where a flag or the environment asks
+    from edl_tpu.train import comm
+    comm_cfg = comm.CommConfig.from_flags(
+        bucket_mb=args.comm_bucket_mb, compress=args.dcn_compress)
+    if comm_cfg is not None and args.mesh != "dp":
+        raise SystemExit(
+            f"--dcn-compress/--comm-bucket-mb own the dp gradient "
+            f"reduction; --mesh {args.mesh} keeps the XLA-partitioned "
+            "step (fsdp/tp collectives are slice-local already)")
+    if comm_cfg is not None and args.moe and comm_cfg.compress != "off":
         raise SystemExit("--dcn-compress compresses the dp gradient "
                          "wire; under --moe the wire knob is "
                          "--moe-compress (gradient compression over "
                          "the ep axis is not parity-gated yet)")
-    # Fused optimizer path: CLI > env (LoopConfig binding) > off;
-    # EDL_TPU_OPT_QUANT overrides just the resident-moment codec.
-    fused_opt = (args.fused_opt if args.fused_opt is not None
-                 else loop_cfg.fused_opt)
-    if loop_cfg.opt_quant and fused_opt != "off":
-        if loop_cfg.opt_quant not in ("off", "int8", "fp8"):
-            raise SystemExit(f"EDL_TPU_OPT_QUANT must be off|int8|fp8, "
-                             f"got {loop_cfg.opt_quant!r}")
-        fused_opt = ("fp32" if loop_cfg.opt_quant == "off"
-                     else loop_cfg.opt_quant)
-    if fused_opt not in ("off", "fp32", "int8", "fp8"):
-        raise SystemExit(f"EDL_TPU_FUSED_OPT must be off|fp32|int8|fp8, "
-                         f"got {fused_opt!r}")
-    if args.fp16 and fused_opt in ("int8", "fp8"):
-        raise SystemExit(
-            "--fused-opt int8/fp8 is not supported with --fp16: on a "
-            "non-finite step the loss-scaler rolls the state back, but "
-            "quantized moments would still carry the overflowed "
-            "requantization residuals. Use --fused-opt fp32 (bitwise, "
-            "rollback-safe) or bf16/fp32 activations.")
     make_cfg, moe_kw = TransformerConfig, {}
     if args.arch == "olmoe":
         make_cfg = olmoe_config
@@ -337,8 +277,7 @@ def main(argv=None) -> int:
     cfg = make_cfg(
         vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
         n_layers=args.n_layers, d_ff=args.d_ff, max_len=args.seq_len,
-        dtype=(jnp.float16 if args.fp16
-               else jnp.bfloat16 if args.bf16 else jnp.float32),
+        dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
         # the comm/moe step's manual region is mesh-free: sharding
         # constraints / nested shard_maps would clash with the manual
         # dp/ep axis — each shard computes exactly one chip's backward
@@ -354,7 +293,7 @@ def main(argv=None) -> int:
 
     source = FileSource(files)
     loader = DataLoader(source, local_bs, rank=rank, world=world,
-                        seed=args.seed, num_workers=loader_workers)
+                        seed=args.seed, num_workers=args.loader_workers)
     steps_per_epoch = loader.steps_per_epoch()
     total_steps = steps_per_epoch * (args.schedule_epochs or args.epochs)
     # --batch-size is GLOBAL: LR stays batch-tied across elastic resizes
@@ -362,13 +301,12 @@ def main(argv=None) -> int:
     schedule = lr_lib.cosine_with_warmup(
         args.lr, total_steps,
         min(args.warmup_steps, max(1, total_steps // 10)))
-    if fused_opt != "off":
-        from edl_tpu.train.fused_opt import make_fused_tx
-        tx = make_fused_tx("adam", schedule, fused_opt,
-                           weight_decay=0.01)
-        log.info("fused optimizer path: adam %s", fused_opt)
-    else:
+    from edl_tpu.train.fused_opt import make_fused_tx
+    tx = make_fused_tx("adam", schedule, args.fused_opt, weight_decay=0.01)
+    if tx is None:
         tx = optax.adamw(schedule, weight_decay=0.01)
+    else:
+        log.info("fused optimizer path: adam, moments %s", tx.quant)
 
     # one row per batch shard: the flash kernel runs under a shard_map
     # over the batch axes, which a single row cannot be split over
@@ -381,50 +319,32 @@ def main(argv=None) -> int:
                               params=variables["params"], tx=tx)
     jax.block_until_ready(state)
     startup.done("state_init")
+    # a moe model's loss takes its routers' terms, weighted by its config
     loss = lm_loss_fused if args.fused_loss else lm_loss_fn
-    if args.moe:  # either loss with the routers' auxiliary terms
-        loss = functools.partial(
-            lm_loss_fused if args.fused_loss else lm_loss_moe,
-            aux_weight=cfg.moe_aux_weight, z_weight=cfg.moe_z_weight)
     # several chips: experts over the ep axis, through the manual region
     # and its capacity router; one chip: the jit step like a dense model
     manual_ep = args.moe and jax.device_count() > 1
-    if args.fp16:
-        # TrainLoop's contract is step(state, batch); the loss-scale
-        # state rides a closure cell. It is NOT checkpointed — after an
-        # elastic restart the scale re-warms from init, costing at most
-        # a few skipped steps (the reference's decorate() state is
-        # likewise process-local).
-        from edl_tpu.train.amp import DynamicLossScale
-        raw_step = make_train_step(loss, donate=True, loss_scale=True)
-        ls_box = [DynamicLossScale.create()]
-
-        def step(state, batch):
-            state, metrics, ls_box[0] = raw_step(state, batch, ls_box[0])
-            return state, metrics
-    elif manual_ep:
-        from edl_tpu.train.comm import (MoEDispatchConfig,
-                                        make_moe_comm_step)
+    if manual_ep:
+        moe_cfg = from_env(comm.MoEDispatchConfig, **given(
+            mode=args.moe_dispatch, compress=args.moe_compress))
 
         def moe_loss_factory(wire):
             wired = Transformer(dataclasses.replace(cfg, moe_wire=wire))
             return functools.partial(loss, apply_fn=wired.apply)
 
-        step = make_moe_comm_step(
+        step = comm.make_moe_comm_step(
             moe_loss_factory, mesh=mesh,
             topology=distributed.slice_topology(env),
-            config=comm_cfg, donate=True,
-            moe_config=MoEDispatchConfig(mode=moe_dispatch,
-                                         compress=moe_compress))
+            config=comm_cfg, donate=True, moe_config=moe_cfg)
         log.info("moe path: E=%d top_k=%d dispatch=%s compress=%s",
-                 cfg.n_experts, cfg.moe_top_k, moe_dispatch,
-                 moe_compress)
+                 cfg.n_experts, cfg.moe_top_k, moe_cfg.mode,
+                 moe_cfg.compress)
     elif comm_cfg is not None:
-        step = make_train_step(loss, donate=True, comm=comm_cfg,
-                               mesh=mesh,
-                               topology=distributed.slice_topology(env))
+        step = comm.make_comm_train_step(
+            loss, mesh=mesh, config=comm_cfg,
+            topology=distributed.slice_topology(env), donate=True)
         log.info("dcn-aware gradient path: bucket=%.1fMiB compress=%s",
-                 comm_cfg.bucket_mb, comm_cfg.compress)
+                 comm_cfg.target_mb, comm_cfg.compress)
     else:
         step = make_train_step(loss, donate=True)
         if args.moe:

@@ -568,29 +568,62 @@ class Transformer(nn.Module):
         return logits
 
 
-def lm_loss_fn(state, params, batch):
-    """Causal LM loss for {'tokens': (B,S)} batches (next-token CE)."""
-    logits = state.apply_fn({"params": params}, batch["tokens"], train=True)
+# what the routers sow is kept only where a loss takes their terms
+_SOW = {"mutable": ["intermediates"]}
+
+
+def _model_of(apply_fn, aux_weight, z_weight) -> tuple:
+    """(the config of the `Transformer` whose ``apply`` this is, the
+    (aux, z) weights of the routers' terms). The config is None for any
+    other callable; the weights are a keyword given, else a moe model's
+    own, else None: the loss takes no such terms."""
+    cfg = getattr(getattr(apply_fn, "__self__", None), "cfg", None)
+    if aux_weight is None and cfg is not None and cfg.moe:
+        aux_weight = cfg.moe_aux_weight
+        z_weight = cfg.moe_z_weight if z_weight is None else z_weight
+    return cfg, None if aux_weight is None else (aux_weight, z_weight or 0.0)
+
+
+def _with_router_terms(ce, mutated, weights) -> tuple[jax.Array, dict]:
+    """(loss, metrics) of either LM loss from its cross-entropy."""
+    if weights is None:
+        return ce, {"ppl": jnp.exp(ce)}
+    extra, metrics = _moe_terms(mutated, *weights)
+    return ce + extra.astype(ce.dtype), {"ppl": jnp.exp(ce), **metrics}
+
+
+def lm_loss_fn(state, params, batch, *, aux_weight: float | None = None,
+               z_weight: float | None = None, apply_fn=None):
+    """Causal LM loss for {'tokens': (B,S)} batches (next-token CE). On
+    a moe=True model (found on the bound ``apply_fn``) the routers'
+    auxiliary terms (`_moe_terms`) are added with the weights of its
+    config, and the metrics carry the step line's `moe_*` counters;
+    ``aux_weight`` / ``z_weight`` override the weights. ``apply_fn``
+    overrides state.apply_fn when the loss must run a DIFFERENT model
+    binding than the state was built with (the manual-dispatch path
+    rebinds cfg.moe_wire without touching the params)."""
+    apply_fn = apply_fn or state.apply_fn
+    _, moe = _model_of(apply_fn, aux_weight, z_weight)
+    logits = apply_fn({"params": params}, batch["tokens"], train=True,
+                      **_SOW if moe else {})
+    logits, mutated = logits if moe else (logits, None)
     targets = batch["tokens"][:, 1:]
     logits = logits[:, :-1]
     logp = jax.nn.log_softmax(logits)
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    loss = -jnp.mean(ll)
-    return loss, {"ppl": jnp.exp(loss)}
+    return _with_router_terms(-jnp.mean(ll), mutated, moe)
 
 
 def lm_loss_fused(state, params, batch, *, block_rows: int | None = None,
-                  aux_weight: float | None = None, z_weight: float = 0.0,
-                  apply_fn=None):
+                  aux_weight: float | None = None,
+                  z_weight: float | None = None, apply_fn=None):
     """lm_loss_fn without the (B,S,V) logits tensor: hidden states feed
     the streamed CE (ops/fused_xent.py), which reads the lm_head
     kernel from the param tree and makes the loss and its gradient in
-    one sweep. Numerically equivalent to lm_loss_fn; use for
-    large-vocab models where the logits dominate memory. With
-    ``aux_weight`` given (a moe=True model) the routers' auxiliary
-    terms are collected and added as in `lm_loss_moe`, whose
-    ``apply_fn`` it takes too. ``block_rows`` (tests) sets the rows of
-    a block, which else follow from the shapes.
+    one sweep. Numerically equivalent to lm_loss_fn, the routers' terms
+    of a moe=True model and the keywords included; use for large-vocab
+    models where the logits dominate memory. ``block_rows`` (tests)
+    sets the rows of a block, which else follow from the shapes.
 
     Mesh note: intended for dp/fsdp worlds (kernel replicated or sharded
     on the embed dim): the model's config, found on the bound
@@ -601,26 +634,20 @@ def lm_loss_fused(state, params, batch, *, block_rows: int | None = None,
     vocab-parallel softmax partitions cleanly)."""
     from edl_tpu.ops.fused_xent import streamed_lm_xent
 
-    moe = aux_weight is not None
     apply_fn = apply_fn or state.apply_fn
-    hidden = apply_fn(
-        {"params": params}, batch["tokens"], train=True, return_hidden=True,
-        **({"mutable": ["intermediates"]} if moe else {}))
-    if moe:
-        hidden, mutated = hidden
+    cfg, moe = _model_of(apply_fn, aux_weight, z_weight)
+    hidden = apply_fn({"params": params}, batch["tokens"], train=True,
+                      return_hidden=True, **_SOW if moe else {})
+    hidden, mutated = hidden if moe else (hidden, None)
     tokens = batch["tokens"]
     # a sequence's last position has no next token: a row of weight 0,
     # so the (B, S, d) hidden states go in as they are
     targets = jnp.concatenate(
         [tokens[:, 1:], jnp.full_like(tokens[:, :1], -1)], axis=1)
     kernel = params["lm_head"]["kernel"]
-    cfg = getattr(getattr(apply_fn, "__self__", None), "cfg", None)
     xent = cfg.xent if cfg is not None else streamed_lm_xent
-    loss = xent(hidden, kernel, targets, block_rows)
-    if not moe:
-        return loss, {"ppl": jnp.exp(loss)}
-    extra, metrics = _moe_terms(mutated, aux_weight, z_weight)
-    return loss + extra.astype(loss.dtype), {"ppl": jnp.exp(loss), **metrics}
+    return _with_router_terms(xent(hidden, kernel, targets, block_rows),
+                              mutated, moe)
 
 
 def _sown(intermediates, name: str) -> list:
@@ -667,25 +694,6 @@ def _moe_terms(mutated, aux_weight: float, z_weight: float
         metrics["moe_balance"] = mean("moe_aux")
         extra = aux_weight * metrics["moe_balance"]
     return extra, metrics
-
-
-def lm_loss_moe(state, params, batch, *, aux_weight: float = 0.01,
-                z_weight: float = 0.0, apply_fn=None):
-    """lm_loss_fn for moe=True configs: next-token CE plus the routers'
-    auxiliary terms (`_moe_terms`), with the capacity-drop fraction
-    reported in the metrics. ``apply_fn`` overrides state.apply_fn when
-    the loss must run a DIFFERENT model binding than the state was
-    built with (the manual-dispatch path rebinds cfg.moe_wire without
-    touching the params)."""
-    fn = apply_fn or state.apply_fn
-    logits, mutated = fn({"params": params}, batch["tokens"],
-                         train=True, mutable=["intermediates"])
-    targets = batch["tokens"][:, 1:]
-    logp = jax.nn.log_softmax(logits[:, :-1])
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    ce = -jnp.mean(ll)
-    extra, metrics = _moe_terms(mutated, aux_weight, z_weight)
-    return ce + extra.astype(ce.dtype), {"ppl": jnp.exp(ce), **metrics}
 
 
 def olmoe_config(*, vocab_size: int = 50304, d_model: int = 2048,

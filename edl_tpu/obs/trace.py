@@ -41,7 +41,7 @@ file, on the clock its device lines use. ``obs/`` itself never imports
 JAX: the annotator is a callable ``(name, attrs) -> context manager``.
 
 A bounded in-process ring keeps the most recent spans readable without
-file I/O (tests, resize_bench's phase column). ``python -m edl_tpu.obs
+file I/O (tests). ``python -m edl_tpu.obs
 trace <dir>`` merges the files into per-trace trees and exports
 Chrome-trace/Perfetto JSON.
 
@@ -455,8 +455,8 @@ def clear_ring() -> None:
     _ring.clear()
 
 
-# -- merged-trace analysis (CLI `python -m edl_tpu.obs trace`, the
-#    resize_bench phase column, and bench_obs all read through these) --
+# -- merged-trace analysis (CLI `python -m edl_tpu.obs trace` reads
+#    through these) --
 
 def load_spans(directory: str) -> list[dict]:
     """Every span from every ``spans-*.jsonl`` in ``directory``

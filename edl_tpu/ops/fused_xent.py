@@ -14,9 +14,8 @@ O(block x V), not O(N x V).
 
 The `custom_vjp`'s forward rule keeps ``(d_hidden, d_kernel)`` as its
 residuals and the backward rule only multiplies them by the incoming
-cotangent (1 under `jax.value_and_grad`, the loss scale under
-`amp.scaled_value_and_grad`). Without a gradient asked (evaluation)
-the sweep makes the loss alone, one matmul.
+cotangent (1 under `jax.value_and_grad`). Without a gradient asked
+(evaluation) the sweep makes the loss alone, one matmul.
 
 Plain XLA inside (`lax.fori_loop` + MXU matmuls with float32
 accumulation): the compiler tiles these matmuls well, and a whole

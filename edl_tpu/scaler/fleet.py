@@ -26,8 +26,7 @@ reference's cluster-level TrainingJob controller actually schedules:
 
 Everything is virtual-clock + `random.Random(seed)` — no wall clock,
 no global RNG (the ``sim-determinism`` edl-lint row covers this file) —
-so a 200-job tournament is exactly reproducible and sha256-pinnable
-(`tools/fleet_bench.py`, `bench.py::bench_fleet`).
+so a 200-job tournament is exactly reproducible and sha256-pinnable.
 
 Pure stdlib, jax/numpy-free (scaler layer row in layers.toml; the CI
 selftest runs before any dependency install and asserts it).
@@ -50,9 +49,10 @@ from edl_tpu.utils.config import env_float, env_int
 TIERS = ("prod", "batch", "best-effort")
 TIER_RANK = {t: i for i, t in enumerate(TIERS)}
 
-# Measured resize-ladder numbers (bench.py artifacts: r12 p2p adoption,
-# r20 in-place reform, r9 disk stop-resume). The defaults double as the
-# documented fallback when no artifact is supplied.
+# Resize-ladder seconds of the simulation's downtime model (p2p
+# adoption, in-place reform, disk stop-resume), taken on the pre-chip
+# harness and not measured on the chip since: the fallback when no
+# artifact is supplied.
 MEASURED_ADOPT_S = 0.061
 MEASURED_REFORM_S = 0.138
 MEASURED_STOP_RESUME_S = 1.2

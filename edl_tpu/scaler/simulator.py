@@ -7,8 +7,7 @@ with seeded multiplicative noise and a modeled resize downtime during
 which the job produces nothing (the measured `elastic_downtime_s`
 price). Time is virtual: `tick()` advances it by `tick_s`; nothing
 reads the wall clock, so every run is exactly reproducible and a
-thousand-tick sweep costs milliseconds (`tools/scaler_bench.py`,
-`bench.py::bench_scaler`).
+thousand-tick sweep costs milliseconds.
 
 `run_policy` is the harness: drive a policy over N ticks, actuate its
 proposals on the SimCluster, and report convergence (last-resize tick,

@@ -9,7 +9,6 @@ _LAZY = {
     "CheckpointManager": ("edl_tpu.train.checkpoint", "CheckpointManager"),
     "CheckpointWriteError": ("edl_tpu.train.checkpoint",
                              "CheckpointWriteError"),
-    "DynamicLossScale": ("edl_tpu.train.amp", "DynamicLossScale"),
     "lr": ("edl_tpu.train.lr", None),
 }
 

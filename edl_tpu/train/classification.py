@@ -87,8 +87,9 @@ def make_classification_step(num_classes: int, *, smoothing: float = 0.0,
     added here (reference uses optimizer regularizer; prefer optax wd).
     `normalize` runs on-device pixel normalization (see `normalize_image`)
     so uint8 batches off the JPEG plane train directly.
-    `comm`/`mesh`/`topology` route the gradient reduction through the
-    manual DCN-aware bucketed path (train/comm.py) — see make_train_step.
+    `comm` (a train/comm.CommConfig, with the `mesh` the step trains on
+    and optionally the slice `topology`) routes the gradient reduction
+    through the manual DCN-aware bucketed path: `make_comm_train_step`.
     """
 
     def loss_fn(state: TrainState, params: Any, batch: dict):
@@ -116,8 +117,11 @@ def make_classification_step(num_classes: int, *, smoothing: float = 0.0,
             aux["batch_stats"] = new_stats
         return loss, aux
 
-    return make_train_step(loss_fn, donate=donate, comm=comm, mesh=mesh,
-                           topology=topology)
+    if comm is not None:
+        from edl_tpu.train.comm import make_comm_train_step
+        return make_comm_train_step(loss_fn, mesh=mesh, config=comm,
+                                    topology=topology, donate=donate)
+    return make_train_step(loss_fn, donate=donate)
 
 
 def _make_kd_step(kd_loss: Callable, num_classes: int, *,

@@ -59,29 +59,35 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from edl_tpu.parallel import mesh as mesh_lib
 from edl_tpu.parallel.compat import shard_map
+from edl_tpu.utils.config import field, from_env, given
 from edl_tpu.utils.logging import get_logger
 
 log = get_logger("edl_tpu.train.comm")
 
 COMPRESS_MODES = ("off", "topk", "int8")
+DEFAULT_BUCKET_MB = 4.0
 
 
 @dataclass(frozen=True)
 class CommConfig:
-    """Knobs of the manual gradient path.
+    """Knobs of the manual gradient path; an entry point builds it with
+    `from_flags`.
 
-    bucket_mb: target bucket payload in MiB (EDL_TPU_COMM_BUCKET_MB).
-      A leaf larger than the target gets its own bucket.
-    compress: DCN-leg wire format (EDL_TPU_DCN_COMPRESS) —
-      'off' (dense), 'topk' (values+indices, error feedback), 'int8'
-      (per-chip scale, error feedback).
+    bucket_mb: target bucket payload in MiB; a leaf larger than the
+      target gets its own bucket. 0 = no bucketing asked for: a
+      compressed wire alone then buckets at DEFAULT_BUCKET_MB
+      (`target_mb`), and with the wire 'off' too nothing asks for the
+      manual step (`asked`) — the XLA-partitioned step stays.
+    compress: DCN-leg wire format — 'off' (dense), 'topk'
+      (values+indices, error feedback), 'int8' (per-chip scale, error
+      feedback).
     topk_frac: fraction of each chip's DCN shard shipped under 'topk'.
     min_compress_elems: shards smaller than this stay dense (index/scale
       overhead would exceed the payload).
     """
 
-    bucket_mb: float = 4.0
-    compress: str = "off"
+    bucket_mb: float = field(0.0, env="EDL_TPU_COMM_BUCKET_MB")
+    compress: str = field("off", env="EDL_TPU_DCN_COMPRESS")
     topk_frac: float = 0.01
     min_compress_elems: int = 1024
 
@@ -90,11 +96,27 @@ class CommConfig:
             raise ValueError(
                 f"compress must be one of {COMPRESS_MODES}, "
                 f"got {self.compress!r}")
-        if self.bucket_mb <= 0:
-            raise ValueError(f"bucket_mb must be > 0, got {self.bucket_mb}")
+        if self.bucket_mb < 0:
+            raise ValueError(f"bucket_mb must be >= 0, got {self.bucket_mb}")
         if not 0.0 < self.topk_frac <= 1.0:
             raise ValueError(
                 f"topk_frac must be in (0, 1], got {self.topk_frac}")
+
+    @classmethod
+    def from_flags(cls, **flags) -> "CommConfig | None":
+        """The manual step that the flags (a field each; None = not
+        given) or, behind them, the environment names the fields are
+        bound to ask for; None where nothing does."""
+        cfg = from_env(cls, **given(**flags))
+        return cfg if cfg.asked else None
+
+    @property
+    def asked(self) -> bool:
+        return self.compress != "off" or self.bucket_mb > 0
+
+    @property
+    def target_mb(self) -> float:
+        return self.bucket_mb or DEFAULT_BUCKET_MB
 
 
 # -- bucket planning (host-side, static) ------------------------------------
@@ -124,8 +146,7 @@ class BucketPlan:
 
     Deterministic in (tree structure, leaf shapes/dtypes, bucket_mb,
     align): the same params always produce the same wire layout — the
-    seeded-exact contract tools/comm_bench.py and the parity tests
-    rely on.
+    seeded-exact contract the parity tests rely on.
     """
 
     buckets: tuple[_Bucket, ...]
@@ -366,9 +387,9 @@ def _validate_ep_mesh(mesh) -> str:
 class CommTrainStep:
     """``(state, batch) -> (state, metrics)`` with the manual bucketed
     gradient path. Drop-in for TrainLoop; the error-feedback residuals
-    ride a closure cell exactly like the amp path's loss-scale state
-    (they are transient comm state, deliberately not checkpointed — a
-    restart re-contributes at most one step's dropped mass late).
+    ride a closure cell (they are transient comm state, deliberately
+    not checkpointed — a restart re-contributes at most one step's
+    dropped mass late).
 
     Built lazily: the bucket plan needs real leaf shapes, so the first
     call plans, initializes residuals and jits; later calls dispatch.
@@ -457,7 +478,7 @@ class CommTrainStep:
 
     def stats(self) -> dict:
         return {"comm_buckets": self.plan.n_buckets if self.plan else 0,
-                "comm_bucket_mb": self.config.bucket_mb,
+                "comm_bucket_mb": self.config.target_mb,
                 "dcn_compress": self.config.compress,
                 "dcn_bytes_per_step": self.dcn_bytes_per_step(),
                 "dcn_overlap_pct": self.dcn_overlap_pct(),
@@ -477,7 +498,7 @@ class CommTrainStep:
         return tuple(jax.device_put(r, sharding) for r in res)
 
     def _build(self, state, batch):
-        self.plan = plan_buckets(state.params, self.config.bucket_mb,
+        self.plan = plan_buckets(state.params, self.config.target_mb,
                                  align=self.world)
         plan, axis, world = self.plan, self.axis, self.world
         n_slices, chips, config = self.n_slices, self.chips, self.config
@@ -539,7 +560,7 @@ class CommTrainStep:
             "comm step: %d buckets (%.1f MiB target, align %d), "
             "%dx%d topology, compress=%s, dcn_bytes/step=%d, "
             "schedulable overlap %.1f%%", plan.n_buckets,
-            config.bucket_mb, world, self.n_slices, self.chips,
+            config.target_mb, world, self.n_slices, self.chips,
             config.compress, self.dcn_bytes_per_step(),
             self.dcn_overlap_pct())
 
@@ -600,19 +621,19 @@ MOE_COMPRESS_MODES = ("off", "int8")
 
 @dataclass(frozen=True)
 class MoEDispatchConfig:
-    """Knobs of the manual MoE dispatch path.
+    """Knobs of the manual MoE dispatch path, bound to their
+    environment names as `CommConfig`'s are.
 
     mode: 'flat' (one all-to-all over the whole ep axis — the single-
-      collective baseline) or 'hier' (ICI leg + DCN overflow leg;
-      EDL_TPU_MOE_DISPATCH).
-    compress: DCN-leg wire format (EDL_TPU_MOE_COMPRESS) — 'off'
-      (dense, bitwise with flat) or 'int8' (per-destination-block
-      symmetric scale). int8 requires mode='hier': only the
-      decomposed path has a separate DCN leg to compress.
+      collective baseline) or 'hier' (ICI leg + DCN overflow leg).
+    compress: DCN-leg wire format — 'off' (dense, bitwise with flat)
+      or 'int8' (per-destination-block symmetric scale). int8 requires
+      mode='hier': only the decomposed path has a separate DCN leg to
+      compress.
     """
 
-    mode: str = "hier"
-    compress: str = "off"
+    mode: str = field("hier", env="EDL_TPU_MOE_DISPATCH")
+    compress: str = field("off", env="EDL_TPU_MOE_COMPRESS")
 
     def __post_init__(self):
         if self.mode not in MOE_DISPATCH_MODES:
@@ -1147,8 +1168,7 @@ def _smoke_moe(world: int):
     from flax.core import meta
 
     from edl_tpu.models.transformer import (Transformer,
-                                            TransformerConfig,
-                                            lm_loss_moe)
+                                            TransformerConfig, lm_loss_fn)
     from edl_tpu.train.state import TrainState
 
     vocab, seq = 32, 16
@@ -1172,13 +1192,9 @@ def _smoke_moe(world: int):
 
     def loss_factory(wire):
         wired = Transformer(dataclasses.replace(cfg, moe_wire=wire))
-        return functools.partial(lm_loss_moe,
-                                 aux_weight=cfg.moe_aux_weight,
-                                 apply_fn=wired.apply)
+        return functools.partial(lm_loss_fn, apply_fn=wired.apply)
 
-    jit_loss = functools.partial(lm_loss_moe,
-                                 aux_weight=cfg.moe_aux_weight)
-    return loss_factory, jit_loss, state, {"tokens": toks}
+    return loss_factory, lm_loss_fn, state, {"tokens": toks}
 
 
 def moe_convergence_smoke(compress: str = "int8", steps: int = 40,

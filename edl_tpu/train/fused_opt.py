@@ -31,8 +31,8 @@ Resident moment formats (``quant``):
 Integration: ``FusedOptimizer`` is duck-typed where optax's
 GradientTransformation sits (``TrainState.create(tx=fused_sgd(...))``);
 ``TrainState.apply_gradients`` routes through ``fused_apply`` whenever
-the tx provides it, so the plain jit step, the amp step and the
-comm-path step all pick it up without changes.
+the tx provides it, so the plain jit step and the comm-path step both
+pick it up without changes.
 """
 
 from __future__ import annotations
@@ -45,6 +45,7 @@ import numpy as np
 
 from edl_tpu.ops import opt_kernels as ok
 from edl_tpu.train import comm as comm_lib
+from edl_tpu.utils.config import env_str
 
 OPTIMIZERS = ok.OPTIMIZERS
 QUANT_MODES = ok.QUANT_MODES
@@ -194,16 +195,24 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
                           bucket_mb=bucket_mb)
 
 
+def fused_mode(flag: str | None = None) -> str:
+    """The --fused-opt knob: the flag where given (not None), else
+    EDL_TPU_FUSED_OPT, else off."""
+    mode = flag if flag is not None else env_str("EDL_TPU_FUSED_OPT", "off")
+    if mode not in FUSED_MODES:
+        raise ValueError(f"--fused-opt / EDL_TPU_FUSED_OPT must be one of "
+                         f"{FUSED_MODES}, got {mode!r}")
+    return mode
+
+
 def make_fused_tx(optimizer: str, learning_rate: ScheduleOrFloat,
-                  fused_mode: str, **kw):
-    """The --fused-opt knob -> tx. fused_mode: off|fp32|int8|fp8
-    ('off' returns None — caller keeps its optax chain)."""
-    if fused_mode not in FUSED_MODES:
-        raise ValueError(f"fused mode must be one of {FUSED_MODES}, "
-                         f"got {fused_mode!r}")
-    if fused_mode == "off":
+                  flag: str | None = None, **kw):
+    """`fused_mode(flag)` -> tx ('off' returns None — caller keeps its
+    optax chain)."""
+    mode = fused_mode(flag)
+    if mode == "off":
         return None
-    quant = "off" if fused_mode == "fp32" else fused_mode
+    quant = "off" if mode == "fp32" else mode
     factory = fused_sgd if optimizer == "sgdm" else fused_adam
     return factory(learning_rate, quant=quant, **kw)
 

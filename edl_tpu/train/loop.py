@@ -66,47 +66,6 @@ class LoopConfig:
     # i+1 hides under step i (H2D overlap — the distill serving path's
     # student-side half). 0 = place inline on the training thread.
     prefetch_batches: int = field(0, env="EDL_TPU_PREFETCH_BATCHES")
-    # Input-plane worker processes (DataLoader num_workers): the
-    # shared-memory mp loader that scales host decode/augment past the
-    # GIL (data/mp_loader.py). 0 = inline/threaded path. The imagenet/lm
-    # entrypoints read this as the DataLoader's num_workers whenever the
-    # --loader-workers CLI flag is not given; DataLoader itself also
-    # honors the same env var when num_workers is left unset.
-    loader_workers: int = field(0, env="EDL_TPU_LOADER_WORKERS")
-    # Device-side augmentation (ops/augment.py): the loader ships raw
-    # packed/npz bytes + the parent-drawn per-step seed and jitted
-    # crop/flip/normalize runs on the accelerator, overlapping the step
-    # instead of burning host cores. Entrypoints read this to build the
-    # loader with emit_batch_seed=True and hand TrainLoop an augment_fn
-    # (imagenet_train --augment-device); 0 = host transforms, the
-    # unchanged fallback path.
-    augment_device: bool = field(False, env="EDL_TPU_AUGMENT_DEVICE")
-    # DCN-aware gradient path (train/comm.py): bucket the gradient
-    # tree into comm_bucket_mb-MiB reduction groups (0 = keep the
-    # XLA-partitioned single-graph reduction) and optionally compress
-    # the cross-slice DCN leg (off|topk|int8, error-feedback residuals,
-    # loss-parity gated). Entrypoints read these to build the manual
-    # step (--dcn-compress / --comm-bucket-mb override).
-    comm_bucket_mb: float = field(0.0, env="EDL_TPU_COMM_BUCKET_MB")
-    dcn_compress: str = field("off", env="EDL_TPU_DCN_COMPRESS")
-    # Expert-parallel dispatch (train/comm.py MoE section): how the
-    # token all-to-all decomposes (flat single collective | hier =
-    # ICI leg + cross-slice DCN leg) and the DCN leg's wire format
-    # (off | int8, one scale per destination slice, parity-gated).
-    # Entrypoints read these for --moe runs (--moe-dispatch /
-    # --moe-compress override).
-    moe_dispatch: str = field("hier", env="EDL_TPU_MOE_DISPATCH")
-    moe_compress: str = field("off", env="EDL_TPU_MOE_COMPRESS")
-    # Fused optimizer path (train/fused_opt.py): the whole momentum-SGD
-    # / Adam update as one Pallas VMEM pass per parameter bucket.
-    # off = the optax chain; fp32 = fused, bitwise vs optax; int8/fp8 =
-    # fused + quantized resident moments with error-feedback residuals
-    # (opt state, checkpoint and migration bytes halve; convergence-
-    # parity gated). Entrypoints read these (--fused-opt overrides).
-    fused_opt: str = field("off", env="EDL_TPU_FUSED_OPT")
-    # Resident-moment codec override: off | int8 | fp8. Empty = derive
-    # from fused_opt (fp32 -> off, int8 -> int8, fp8 -> fp8).
-    opt_quant: str = field("", env="EDL_TPU_OPT_QUANT")
 
 
 class TrainLoop:
@@ -470,7 +429,7 @@ class TrainLoop:
                "ckpt_async": bool(self.config.ckpt_async)}
         if self.restore_s is not None:
             out["ckpt_restore_s"] = round(self.restore_s, 3)
-        # state-migration plane accounting (resize_bench/demo audits)
+        # state-migration plane accounting (the demo's audits)
         out["restore_source"] = self.restore_source
         out["bytes_from_peers"] = self.bytes_from_peers
         out["reforms"] = self.reforms
@@ -479,8 +438,8 @@ class TrainLoop:
                 self.last_reform_downtime_s, 4)
         if self.last_reform is not None:
             # the state machine's outcome (result / restore source /
-            # per-phase seconds) — what resize_bench's world axis and
-            # the --resize-reform demo audit read
+            # per-phase seconds) — what the --resize-reform demo audit
+            # reads
             out["reform"] = self.last_reform
         if self.ckpt is not None:
             out.update({f"ckpt_{k}": (round(v, 3)

@@ -50,8 +50,8 @@ class TrainState:
         if hasattr(self.tx, "fused_apply"):
             # Fused bucket path (train/fused_opt.py): no "updates tree"
             # intermediate — params and moments are rewritten in one
-            # kernel pass. Duck-typed so the plain jit step, the amp
-            # step and the comm step all pick it up through this seam.
+            # kernel pass. Duck-typed so the plain jit step and the comm
+            # step both pick it up through this seam.
             new_params, new_opt_state = self.tx.fused_apply(
                 grads, self.opt_state, self.params)
         else:

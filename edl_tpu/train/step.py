@@ -16,66 +16,15 @@ import jax
 LossFn = Callable[..., tuple[jax.Array, dict]]
 
 
-def make_train_step(loss_fn: LossFn, donate: bool = True,
-                    loss_scale: bool = False, comm=None, mesh=None,
-                    topology=None) -> Callable:
+def make_train_step(loss_fn: LossFn, donate: bool = True) -> Callable:
     """Build a jitted step from loss_fn(state, params, batch)->(loss, aux).
 
     If the model has batch_stats (BN), loss_fn should return aux containing
     'batch_stats' with the new stats; they are folded into the state.
 
-    `loss_scale=True` wraps the backward in dynamic loss scaling
-    (train/amp.py — the reference's fp16 `--scale_loss` capability,
-    train_with_fleet.py:68-72,318-321): the step signature becomes
-    `step(state, batch, ls) -> (state, metrics, ls)` and metrics gain
-    'loss_scale'/'finite'. Unneeded for bf16 (the TPU default).
-
-    `comm` (a train/comm.CommConfig, with `mesh` and optionally the
-    slice `topology`) swaps the XLA-partitioned gradient reduction for
-    the manual DCN-aware path: size-bucketed, hierarchically decomposed
-    (ICI reduce-scatter -> cross-slice leg -> ICI all-gather) and
-    optionally compressed dp reductions with a loss-parity gate
-    (doc/design_comm.md). dp-only meshes; bucketed-dense is bitwise
-    with the plain jit path on flat worlds.
+    The manual DCN-aware gradient path is a builder of its own:
+    `train/comm.make_comm_train_step`.
     """
-    if comm is not None:
-        if loss_scale:
-            raise ValueError(
-                "comm= and loss_scale= are mutually exclusive (the "
-                "manual gradient path owns the backward's reduction; "
-                "fp16 scaling is an amp-path feature)")
-        if mesh is None:
-            raise ValueError("comm= needs the mesh the step trains on")
-        from edl_tpu.train.comm import make_comm_train_step
-        return make_comm_train_step(loss_fn, mesh=mesh, config=comm,
-                                    topology=topology, donate=donate)
-    def apply(state, grads, aux):
-        """Fold optional BN stats + apply the update (shared by both
-        branches so the batch_stats contract lives in one place)."""
-        new_stats = aux.pop("batch_stats", None)
-        if new_stats is not None:
-            return state.apply_gradients(grads=grads,
-                                         batch_stats=new_stats)
-        return state.apply_gradients(grads=grads)
-
-    if loss_scale:
-        from edl_tpu.train import amp
-
-        def train_step(state, batch, ls):
-            def compute(params):
-                return loss_fn(state, params, batch)
-
-            (loss, aux), grads = amp.scaled_value_and_grad(
-                compute, state.params, ls)
-            with jax.named_scope("opt_update"):
-                new_state = apply(state, grads, aux)
-            ls, selected, finite = amp.update_scale_and_select(
-                ls, grads, new_state, state)
-            return selected, {"loss": loss, "loss_scale": ls.scale,
-                              "finite": finite, **aux}, ls
-
-        return jax.jit(train_step, donate_argnums=(0,) if donate else ())
-
     # The function's name is the program's in a device trace
     # (`jit_train_step` on `XLA Modules`), whatever the mesh; the scope
     # names the optimizer's share of it. Names are metadata: the
@@ -86,8 +35,13 @@ def make_train_step(loss_fn: LossFn, donate: bool = True,
 
         (loss, aux), grads = jax.value_and_grad(compute, has_aux=True)(
             state.params)
+        new_stats = aux.pop("batch_stats", None)
         with jax.named_scope("opt_update"):
-            state = apply(state, grads, aux)
+            if new_stats is not None:
+                state = state.apply_gradients(grads=grads,
+                                              batch_stats=new_stats)
+            else:
+                state = state.apply_gradients(grads=grads)
         return state, {"loss": loss, **aux}
 
     return jax.jit(train_step, donate_argnums=(0,) if donate else ())

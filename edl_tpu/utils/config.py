@@ -111,9 +111,6 @@ ENV_VARS: dict[str, str] = {
     "EDL_TPU_FUSED_OPT": "fused optimizer path: off | fp32 | int8 | fp8 "
                          "(train/fused_opt.py; fp32 is bitwise vs optax, "
                          "int8/fp8 quantize resident moments)",
-    "EDL_TPU_OPT_QUANT": "override the resident-moment codec of the "
-                         "fused optimizer: off | int8 | fp8 (defaults "
-                         "to what EDL_TPU_FUSED_OPT implies)",
     "EDL_TPU_DISTILL_NOP": "distill reader no-op mode (wire debugging)",
     # -- logging / profiling ------------------------------------------------
     "EDL_TPU_LOG_DIR": "launcher workerlog directory",
@@ -299,6 +296,13 @@ def from_env(cls: type[T], **overrides: Any) -> T:
                 break
     kwargs.update(overrides)
     return cls(**kwargs)
+
+
+def given(**flags: Any) -> dict[str, Any]:
+    """The flags that were given, as `from_env`'s overrides: an option
+    whose argparse default is None and that still holds it was not, and
+    leaves its field to the environment."""
+    return {k: v for k, v in flags.items() if v is not None}
 
 
 def describe(cfg: Any) -> str:

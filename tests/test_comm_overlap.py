@@ -307,35 +307,52 @@ def test_int8_step_tracks_dense_within_envelope():
 # -- knobs / validation / wiring -------------------------------------------
 
 
-def test_loop_config_env_knobs(monkeypatch):
-    from edl_tpu.train.loop import LoopConfig
-    from edl_tpu.utils.config import from_env
+def test_comm_config_env_knobs(monkeypatch):
+    from edl_tpu.utils.config import from_env, given
+    for name in ("EDL_TPU_DCN_COMPRESS", "EDL_TPU_COMM_BUCKET_MB",
+                 "EDL_TPU_MOE_DISPATCH", "EDL_TPU_MOE_COMPRESS"):
+        monkeypatch.delenv(name, raising=False)
+    # neither a flag nor the environment asks: no manual step
+    assert comm.CommConfig.from_flags(bucket_mb=None, compress=None) is None
+    assert comm.CommConfig().target_mb == comm.DEFAULT_BUCKET_MB
+    # a compressed wire alone buckets at the default target
+    cfg = comm.CommConfig.from_flags(compress="int8")
+    assert cfg.asked and cfg.target_mb == comm.DEFAULT_BUCKET_MB
     monkeypatch.setenv("EDL_TPU_DCN_COMPRESS", "topk")
     monkeypatch.setenv("EDL_TPU_COMM_BUCKET_MB", "2.5")
-    cfg = from_env(LoopConfig)
-    assert cfg.dcn_compress == "topk"
-    assert cfg.comm_bucket_mb == 2.5
+    cfg = from_env(comm.CommConfig)
+    assert cfg.asked and cfg.compress == "topk" and cfg.target_mb == 2.5
+    assert comm.CommConfig.from_flags() == cfg
+    # a flag given wins over the environment, 0 MiB and 'off' included
+    assert comm.CommConfig.from_flags(bucket_mb=0.0, compress="off") is None
+    monkeypatch.setenv("EDL_TPU_MOE_DISPATCH", "flat")
+    assert from_env(comm.MoEDispatchConfig).mode == "flat"
+    moe = from_env(comm.MoEDispatchConfig,
+                   **given(mode="hier", compress="int8"))
+    assert (moe.mode, moe.compress) == ("hier", "int8")
 
 
 def test_comm_config_validation():
     with pytest.raises(ValueError):
         comm.CommConfig(compress="gzip")
     with pytest.raises(ValueError):
-        comm.CommConfig(bucket_mb=0)
+        comm.CommConfig(bucket_mb=-1)
     with pytest.raises(ValueError):
         comm.CommConfig(topk_frac=0.0)
 
 
-def test_make_train_step_routing_and_conflicts():
+def test_comm_step_builder_needs_a_dp_mesh():
     loss_fn, state, batch = _mlp_problem()
     mesh = mesh_lib.make_mesh(mesh_lib.MeshSpec({"dp": -1}))
     cfg = comm.CommConfig(bucket_mb=1.0)
-    step = make_train_step(loss_fn, comm=cfg, mesh=mesh)
+    step = comm.make_comm_train_step(loss_fn, mesh=mesh, config=cfg)
     assert isinstance(step, comm.CommTrainStep)
-    with pytest.raises(ValueError):
-        make_train_step(loss_fn, comm=cfg)  # no mesh
-    with pytest.raises(ValueError):
-        make_train_step(loss_fn, comm=cfg, mesh=mesh, loss_scale=True)
+    with pytest.raises(TypeError):
+        comm.make_comm_train_step(loss_fn, config=cfg)  # no mesh
+    with pytest.raises(ValueError, match="dp axis"):
+        comm.make_comm_train_step(
+            loss_fn, config=cfg,
+            mesh=mesh_lib.make_mesh(mesh_lib.MeshSpec({"fsdp": -1})))
 
 
 def test_non_dp_mesh_rejected():

@@ -66,8 +66,7 @@ def test_soft_labels_beat_hard_labels_on_same_budget():
     the teacher's soft labels must beat the SAME student trained on hard
     labels with an IDENTICAL budget — same subset, same epochs/LR/batch,
     same init seed; only the loss target differs. The teacher knows the
-    full training set; the students see a 1/16 subset. Flagship-scale
-    analogue: tools/distill_quality_tpu.py -> DISTILL_QUALITY_r5.json."""
+    full training set; the students see a 1/16 subset."""
     from edl_tpu.train.classification import (make_classification_step,
                                               make_eval_step)
 

@@ -304,7 +304,7 @@ class TestKnobs:
         assert tx.quant == "off" and tx.momentum == 0.8
         tx = fo.make_fused_tx("adam", 0.1, "int8")
         assert tx.optimizer == "adam" and tx.quant == "int8"
-        with pytest.raises(ValueError, match="fused mode"):
+        with pytest.raises(ValueError, match="EDL_TPU_FUSED_OPT"):
             fo.make_fused_tx("sgdm", 0.1, "int4")
 
     def test_validation(self):
@@ -319,15 +319,18 @@ class TestKnobs:
         with pytest.raises(ValueError, match="float params only"):
             fo.fused_sgd(0.1).init({"ids": jnp.zeros((8,), jnp.int32)})
 
-    def test_loop_config_env_knobs(self, monkeypatch):
-        from edl_tpu.train.loop import LoopConfig
-        from edl_tpu.utils.config import from_env
-
+    def test_fused_mode_env_knob(self, monkeypatch):
+        monkeypatch.delenv("EDL_TPU_FUSED_OPT", raising=False)
+        assert fo.fused_mode() == "off"
+        assert fo.make_fused_tx("adam", 0.1) is None
         monkeypatch.setenv("EDL_TPU_FUSED_OPT", "int8")
-        monkeypatch.setenv("EDL_TPU_OPT_QUANT", "fp8")
-        cfg = from_env(LoopConfig)
-        assert cfg.fused_opt == "int8"
-        assert cfg.opt_quant == "fp8"
+        assert fo.fused_mode() == "int8"
+        assert fo.make_fused_tx("adam", 0.1).quant == "int8"
+        assert fo.fused_mode("fp32") == "fp32"   # a flag given wins
+        assert fo.make_fused_tx("adam", 0.1, "off") is None
+        monkeypatch.setenv("EDL_TPU_FUSED_OPT", "int4")
+        with pytest.raises(ValueError, match="EDL_TPU_FUSED_OPT"):
+            fo.fused_mode()
 
     def test_opt_state_bytes_cut(self):
         params, _ = fo._gate_world(0)

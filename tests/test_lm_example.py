@@ -1,14 +1,12 @@
 """lm_train example: transformer pretraining over file-backed shards."""
 
+import json
+import types
+
 import pytest
 
-pytestmark = pytest.mark.slow  # LM trainer end-to-end epochs
 
-import json
-
-import numpy as np
-
-
+@pytest.mark.slow  # LM trainer end-to-end epochs
 class TestLmTrain:
     def test_end_to_end_learns_and_logs(self, tmp_path):
         from edl_tpu.examples.lm_train import main
@@ -50,3 +48,60 @@ class TestLmTrain:
         assert main(["--make-synthetic", "1", "--epochs", "1"]
                     + common) == 0
         assert main(["--epochs", "2"] + common) == 0  # resumes epoch 1
+
+
+# -- what the entry point refuses, each by the flag at fault ----------------
+
+REFUSALS = [
+    (["--schedule-epochs", "1", "--epochs", "2"], {}, "--schedule-epochs"),
+    (["--moe", "--mesh", "fsdp"], {}, "--moe owns the ep mesh"),
+    (["--moe", "--batch-size", "12"], {}, "--batch-size 12"),
+    (["--mesh", "sp", "--seq-len", "12"], {}, "--seq-len 12"),
+    (["--mesh", "sp"], {"world": 2}, "--mesh sp is single-process"),
+    (["--dcn-compress", "int8", "--mesh", "fsdp"], {},
+     "--dcn-compress/--comm-bucket-mb own the dp gradient"),
+    (["--comm-bucket-mb", "1", "--mesh", "fsdp"], {},
+     "--dcn-compress/--comm-bucket-mb own the dp gradient"),
+    # the environment asks as the flag does, and a flag given beats it
+    (["--mesh", "fsdp"], {"EDL_TPU_DCN_COMPRESS": "topk"},
+     "--dcn-compress/--comm-bucket-mb own the dp gradient"),
+    (["--moe", "--dcn-compress", "int8"], {}, "--moe-compress"),
+    (["--moe", "--dcn-compress", "off", "--mesh", "fsdp"],
+     {"EDL_TPU_DCN_COMPRESS": "topk"}, "--moe owns the ep mesh"),
+    (["--data-dir", "nowhere"], {}, "no train-*.npz"),
+]
+
+
+@pytest.fixture(scope="module")
+def shard_dir(tmp_path_factory):
+    from edl_tpu.examples.lm_train import make_synthetic_shards
+
+    path = tmp_path_factory.mktemp("lm_refusals")
+    make_synthetic_shards(str(path), 1, rows=16, seq_len=16, vocab=32)
+    return path
+
+
+@pytest.mark.parametrize("argv, setting, names", REFUSALS,
+                         ids=[" ".join(r[0]) + "".join(r[1]) for r in REFUSALS])
+def test_refusal_names_its_flag(shard_dir, tmp_path, monkeypatch, argv,
+                                setting, names):
+    from edl_tpu.examples import lm_train
+
+    setting = dict(setting)
+    world = setting.pop("world", 1)
+    if world > 1:  # a process of a larger world, without joining one
+        monkeypatch.setattr(
+            lm_train.distributed, "init_from_env",
+            lambda: types.SimpleNamespace(world_size=world, rank=0,
+                                          checkpoint_path=None))
+    for name, value in setting.items():
+        monkeypatch.setenv(name, value)
+    if "nowhere" in argv:
+        (tmp_path / "nowhere").mkdir()
+        argv = ["--data-dir", str(tmp_path / "nowhere")]
+    else:
+        argv = ["--data-dir", str(shard_dir)] + argv
+    with pytest.raises(SystemExit) as refused:
+        lm_train.main(["--seq-len", "16", "--vocab", "32", "--batch-size",
+                       "16"] + argv)
+    assert names in str(refused.value)

@@ -110,7 +110,7 @@ def test_transformer_config_moe_validation():
     tfm.TransformerConfig(**common, n_experts=1)
 
 
-def test_lm_loss_moe_collects_router_aux():
+def test_lm_loss_fn_collects_router_aux():
     cfg = tfm.TransformerConfig(vocab_size=16, d_model=16, n_heads=2,
                                 n_layers=2, d_ff=32, max_len=8,
                                 dtype=jnp.float32, moe=True, n_experts=4)
@@ -124,7 +124,7 @@ def test_lm_loss_moe_collects_router_aux():
     state = TrainState.create(apply_fn=model.apply,
                               params=variables["params"],
                               tx=optax.sgd(0.1))
-    loss, metrics = tfm.lm_loss_moe(state, state.params,
+    loss, metrics = tfm.lm_loss_fn(state, state.params,
                                     {"tokens": toks})
     assert float(loss) > 0
     assert {"ppl", "moe_balance", "moe_dropped"} <= set(metrics)
@@ -343,7 +343,7 @@ def _tiny_moe(world: int, n_layers: int = 1):
 
     def loss_factory(wire):
         wired = tfm.Transformer(dataclasses.replace(cfg, moe_wire=wire))
-        return functools.partial(tfm.lm_loss_moe,
+        return functools.partial(tfm.lm_loss_fn,
                                  aux_weight=cfg.moe_aux_weight,
                                  apply_fn=wired.apply)
 

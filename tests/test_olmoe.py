@@ -124,7 +124,7 @@ def test_loss_and_every_gradient_leaf_match_the_reference(tree, tokens):
     cfg = small()
 
     def program_loss(p):
-        return tfm.lm_loss_moe(state_of(tree), p, {"tokens": tokens},
+        return tfm.lm_loss_fn(state_of(tree), p, {"tokens": tokens},
                                aux_weight=cfg.moe_aux_weight,
                                z_weight=cfg.moe_z_weight)[0]
     loss, grads = jax.value_and_grad(program_loss)(tree)
@@ -149,7 +149,7 @@ def test_the_loss_is_the_sources_three_terms(tree, tokens):
     """CE + 0.01 x the source's pooled load-balance (top_k at perfect
     balance) + 0.001 x z, by the step line's own counters."""
     cfg = small()
-    loss, m = tfm.lm_loss_moe(state_of(tree), tree, {"tokens": tokens},
+    loss, m = tfm.lm_loss_fn(state_of(tree), tree, {"tokens": tokens},
                               aux_weight=cfg.moe_aux_weight,
                               z_weight=cfg.moe_z_weight)
     with jax.default_matmul_precision("highest"):
@@ -247,18 +247,58 @@ def test_fused_loss_with_moe_is_the_dense_logits_loss(tree, tokens):
     cfg = small()
     kw = dict(aux_weight=cfg.moe_aux_weight, z_weight=cfg.moe_z_weight)
     state = state_of(tree)
-    dense, dm = tfm.lm_loss_moe(state, tree, {"tokens": tokens}, **kw)
+    dense, dm = tfm.lm_loss_fn(state, tree, {"tokens": tokens}, **kw)
     fused, fm = tfm.lm_loss_fused(state, tree, {"tokens": tokens},
                                   block_rows=32, **kw)
     assert abs(float(dense) - float(fused)) < 1e-5
     assert set(dm) == set(fm) == {"ppl", "moe_balance", "moe_dropped",
                                   "moe_z", "moe_max_load"}
-    gd = jax.grad(lambda p: tfm.lm_loss_moe(
+    gd = jax.grad(lambda p: tfm.lm_loss_fn(
         state, p, {"tokens": tokens}, **kw)[0])(tree)
     gf = jax.grad(lambda p: tfm.lm_loss_fused(
         state, p, {"tokens": tokens}, block_rows=32, **kw)[0])(tree)
     for a, b in zip(jax.tree.leaves(gd), jax.tree.leaves(gf)):
         assert float(jnp.abs(a - b).max()) < 1e-5
+
+
+@pytest.mark.parametrize("loss", [tfm.lm_loss_fn, tfm.lm_loss_fused])
+def test_loss_takes_the_routers_weights_from_the_models_config(
+        loss, tree, tokens):
+    """No keyword: a moe model's own `moe_aux_weight` / `moe_z_weight`;
+    a keyword given wins, and a state bound to another model says
+    nothing once ``apply_fn`` is."""
+    cfg = small()
+    batch = {"tokens": tokens}
+    own, metrics = loss(state_of(tree), tree, batch)
+    told, _ = loss(state_of(tree), tree, batch,
+                   aux_weight=cfg.moe_aux_weight, z_weight=cfg.moe_z_weight)
+    assert float(own) == float(told)
+    assert {"moe_balance", "moe_z", "moe_max_load", "moe_dropped"} \
+        <= set(metrics)
+    ce_only, _ = loss(state_of(tree), tree, batch, aux_weight=0.0)
+    assert float(ce_only) == pytest.approx(
+        float(own) - cfg.moe_aux_weight * float(metrics["moe_balance"])
+        - cfg.moe_z_weight * float(metrics["moe_z"]), abs=1e-5)
+    heavier = tfm.Transformer(small(moe_aux_weight=1.0, moe_z_weight=0.0))
+    rebound, _ = loss(state_of(tree), tree, batch, apply_fn=heavier.apply)
+    assert float(rebound) == pytest.approx(
+        float(ce_only) + float(metrics["moe_balance"]), abs=1e-5)
+
+
+@pytest.mark.parametrize("loss", [tfm.lm_loss_fn, tfm.lm_loss_fused])
+def test_a_dense_model_has_no_router_terms(loss, tokens):
+    cfg = tfm.TransformerConfig(
+        vocab_size=VOCAB, d_model=D, n_heads=HEADS, n_layers=1, d_ff=FF,
+        max_len=SEQ, dtype=jnp.float32)
+    model = tfm.Transformer(cfg)
+    params = meta.unbox(model.init(jax.random.PRNGKey(2), tokens,
+                                   train=False))["params"]
+    state = TrainState.create(apply_fn=model.apply, params=params,
+                              tx=optax.sgd(0.1))
+    value, metrics = loss(state, params, {"tokens": tokens})
+    assert set(metrics) == {"ppl"}
+    assert float(value) == pytest.approx(float(jnp.log(metrics["ppl"])),
+                                         abs=1e-5)
 
 
 @pytest.mark.parametrize("what, least", [
