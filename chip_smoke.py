@@ -284,7 +284,7 @@ def check_generation(job: Job, pid: int, what: str, *, tpu: bool,
         flash = re.findall(r"flash attention (fwd|bwd) \((\d+), [^)]*\): "
                            r"(.*)", text)
         check(m.group(4) == "flash" and flash
-              and all(mode == "pallas kernel, compiled"
+              and all(mode.startswith("pallas kernel, compiled")
                       for _, _, mode in flash)
               and {d for d, _, _ in flash} == {"fwd", "bwd"},
               f"{what}: attention was the compiled flash kernel, fwd and "
@@ -544,9 +544,80 @@ def run_child(name: str, env: dict, timeout: float) -> dict:
 
 # -- children (these import JAX) ----------------------------------------------
 
-def child_kernels() -> dict:
+def flash_against(path: str, fa, shape, qkvdo, blk: int, kw: dict,
+                  report) -> None:
+    """This checkout's flash kernels against another checkout's (its
+    `flash_attention.py` at `path`, e.g. the parent commit unpacked
+    under `_checkout/`, which `.gitignore` lists) and both against the
+    float32 blockwise scan at matmul precision highest: o, dq, dk, dv
+    must differ from each other by no more than either differs from
+    float32. Prints max and rms of every difference and a call's time."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+
+    spec = importlib.util.spec_from_file_location("other_flash", path)
+    other = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(other)
+
+    def run(mod):
+        def f(q, k, v, do):
+            o, lse = mod._fwd(q, k, v, blk_q=blk, blk_k=blk,
+                              interpret=False, **kw)
+            return (o, *mod._bwd_pallas(q, k, v, o, lse, do, blk_q=blk,
+                                        blk_k=blk, dlse=None,
+                                        interpret=False, **kw))
+        f = jax.jit(f)
+        try:
+            out = jax.block_until_ready(f(*qkvdo))
+        except Exception as exc:  # noqa: BLE001 - the compiler's refusal
+            return None, str(exc).splitlines()[0][:160]
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = f(*qkvdo)
+        jax.block_until_ready(out)
+        return out, f"{(time.perf_counter() - t0) * 100:.3f} ms a call"
+
+    def gap(a, b):  # (rms, max) of a - b
+        d = a.astype(jnp.float32) - b.astype(jnp.float32)
+        return float(jnp.sqrt(jnp.mean(d * d))), float(jnp.max(jnp.abs(d)))
+
+    with jax.default_matmul_precision("highest"):
+        q32, k32, v32, do32 = (x.astype(jnp.float32) for x in qkvdo)
+        o32, lse32 = jax.jit(lambda q, k, v: fa._fwd_blockwise(
+            q, k, v, blk=blk, **kw))(q32, k32, v32)
+        ref = (o32, *jax.jit(lambda *a: fa._bwd_blockwise(
+            *a, blk=blk, **kw))(q32, k32, v32, o32, lse32, do32))
+    new, new_said = run(fa)
+    old, old_said = run(other)
+    if old is None:
+        report(f"flash old/new {shape}", True,
+               f"the other checkout's kernels do not run alone here "
+               f"({old_said}); new {new_said}")
+        return
+    worse = []
+    for name, n, o, r in zip(("o", "dq", "dk", "dv"), new, old, ref):
+        (nr, nrm), (orr, orm), (no, nom) = gap(n, r), gap(o, r), gap(n, o)
+        # each carries its own rounding against float32, so two good
+        # kernels differ by up to both errors together; and the new one
+        # may not sit further from float32 than the old one did
+        if no > nr + orr or nr > 1.25 * orr:
+            worse.append(name)
+        print(f"     {name}: rms (max) new-f32 {nr:.3e} ({nrm:.2e}), "
+              f"old-f32 {orr:.3e} ({orm:.2e}), new-old {no:.3e} "
+              f"({nom:.2e})", flush=True)
+    report(f"flash old/new {shape}", not worse,
+           f"new {new_said}, old {old_said}; further from each other, or "
+           f"new further from float32, than the roundings allow: "
+           f"{worse or 'none'}")
+
+
+def child_kernels(other_flash: str = "") -> dict:
     """Each Pallas kernel against its XLA expression, on the device, at
-    LM-large shapes and a 4 MiB bucket (plus a ragged one)."""
+    LM-large shapes and a 4 MiB bucket (plus a ragged one). With
+    `other_flash` (another checkout's `flash_attention.py`) also the
+    flash kernels of the two checkouts against each other."""
     import importlib
 
     import jax
@@ -576,8 +647,10 @@ def child_kernels() -> dict:
         return float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                      - b.astype(jnp.float32))))
 
-    # flash attention fwd/bwd vs the XLA blockwise scan (bf16 tolerance)
-    for shape in ((8, 1024, 16, 128), (16, 1024, 16, 64)):
+    # flash attention fwd/bwd vs the XLA blockwise scan (bf16 tolerance):
+    # LM-large, d_model 1024, and the benchmark's shapes (d8, OLMoE)
+    for shape in ((8, 1024, 16, 128), (16, 1024, 16, 64),
+                  (6, 2048, 16, 128), (4, 4096, 16, 128)):
         key = jax.random.PRNGKey(0)
         q, k, v, do = (jax.random.normal(jax.random.fold_in(key, i), shape,
                                          jnp.bfloat16) for i in range(4))
@@ -601,6 +674,9 @@ def child_kernels() -> dict:
             b.astype(jnp.float32)))) + 1e-6) for a, b in zip(g_k, g_x))
         report(f"flash bwd {shape}", worst < 3e-2,
                f"max relative |dq,dk,dv diff| {worst:.2e}")
+        if other_flash:
+            flash_against(other_flash, fa, shape, (q, k, v, do), blk, kw,
+                          report)
 
     rng = np.random.default_rng(0)
 
@@ -757,9 +833,13 @@ def main() -> int:
                     help="tiny shapes on the CPU: checks this script's "
                          "control flow, never prints a verdict")
     ap.add_argument("--child", default="", help=argparse.SUPPRESS)
+    ap.add_argument("--other-flash", default="", metavar="PATH",
+                    help="with --child kernels: another checkout's "
+                         "edl_tpu/ops/flash_attention.py, whose kernels "
+                         "are run beside this checkout's")
     args = ap.parse_args()
     if args.child == "kernels":
-        print(json.dumps(child_kernels()))
+        print(json.dumps(child_kernels(args.other_flash)))
         return 0
     tpu = not args.rehearse_cpu
     env = dict(os.environ)

@@ -12,10 +12,31 @@ Backward: the flash recipe (Dao et al.) with the saved log-sum-exp and
 delta = rowsum(dO * O), as two Pallas kernels — dK/dV (KV block
 resident, Q streamed) and dQ (Q block resident, KV streamed) — with the
 causal block skip in both directions. `_bwd_blockwise`, the plain-XLA
-scan version, is kept as the reference oracle for the kernel parity
-tests; profiling showed it at ~29% of LM step time for ~6% of model
-FLOPs (it masks instead of skipping and round-trips fp32 score tensors
-through HBM), which is what motivated the kernels.
+scan version, is kept as the off-TPU path and the reference oracle for
+the kernel parity tests. What the three kernels cost on the chip, cell
+by cell, is in PERF.md §5.
+
+What one score block pair costs inside the kernels:
+
+- Operands reach the MXU in the dtype they arrive in (bf16 under
+  `--bf16`, float32 in the CPU parity tests): no block of Q, K, V or dO
+  is upcast, `p` and `ds` are cast to the value dtype at the product's
+  input, every product accumulates in float32, and the softmax's
+  statistics, `exp`, `lse`, the row term and the accumulators are
+  float32. The scale multiplies the float32 scores, never `q`.
+- No transpose: QK^T and dO·V^T contract the last dimensions of both
+  operands (the MXU's native A·B^T); the dK/dV kernel computes the
+  transposed scores K·Q^T directly, against `lse` and the row term laid
+  along the lanes, so p^T·dO and ds^T·Q are plain products.
+- The causal mask only where it cuts: pairs wholly below the diagonal
+  run a body with no iota, compare or select; non-causal calls run that
+  body alone.
+- With equal blocks the one pair the diagonal crosses is worked as 2x2
+  sub-blocks (`_diag_sub`) and the sub-block in the future is skipped.
+- State sits in float32 VMEM scratch, whole vector registers wide, and
+  the loops carry nothing: the accumulators, and in the forward the
+  running max (the same in every lane) and the sum of exponentials
+  (lane-partial sums, reduced across lanes once a program).
 
 Layout contract: (B, S, H, D) in, (B, S, H, D) out (the transformer's
 native layout; the kernel grid works on (B*H, S, D) views). On non-TPU
@@ -40,6 +61,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from edl_tpu.utils.logging import get_logger
 
@@ -48,51 +70,135 @@ log = get_logger("edl_tpu.ops.flash_attention")
 _NEG_INF = -1e30
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, blk_k: int,
-                scale: float, causal: bool):
+def _dot(a, b):
+    """a·b on the MXU, operands as they are, float32 out."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    """a·b^T by contracting the last dimension of both: the MXU's
+    native transposed-rhs form, no transpose issued."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _visible(shape, ahead, *, q_minor: bool = False):
+    """Causal mask of one score piece whose first query sits `ahead`
+    positions after its first key: query i sees key j iff
+    i + ahead >= j. The piece is (queries, keys), or (keys, queries)
+    with the queries along the lanes (`q_minor`: the dK/dV kernel's
+    transposed scores)."""
+    row = lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0)
+    col = lax.broadcasted_iota(jnp.int32, (1, shape[1]), 1)
+    return col + ahead >= row if q_minor else row + ahead >= col
+
+
+def _rows(i: int, n: int) -> slice:
+    return slice(i * n, (i + 1) * n)
+
+
+def _lane_fold(x, width: int):
+    """Sum of the `width`-lane column slabs of x: a row sum's
+    elementwise part, with the reduction across lanes left for later."""
+    out = x[:, :width]
+    for c in range(1, x.shape[1] // width):
+        out = out + x[:, c * width:(c + 1) * width]
+    return out
+
+
+def _lane_tile(x, n: int):
+    """x (rows, W), the same in every lane, as (rows, n): for n a
+    multiple of W whole registers side by side, so nothing moves."""
+    width = x.shape[1]
+    if n <= width:
+        return x[:, :n]
+    if n % width:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return jnp.concatenate([x] * (n // width), axis=1)
+
+
+def _over_keys(pair, qi, *, blk_q: int, blk_k: int, n_k: int, sub: int,
+               causal: bool) -> None:
+    """What the forward and the dQ kernel share: `pair(rows, k_at,
+    ahead)` for every piece of keys that q block `qi` sees, in order —
+    `rows` the block's query rows, `k_at` the keys' place in the
+    sequence, `ahead` None where every key is visible (no mask)."""
+
+    def block(ki, ahead):
+        pair(slice(None), pl.ds(ki * blk_k, blk_k), ahead)
+
+    if not causal:
+        lax.fori_loop(0, n_k, lambda ki, _: block(ki, None), None)
+        return
+    # kv blocks wholly at or before this q block's first row, then the
+    # ones the diagonal crosses; later ones never contribute
+    n_full = lax.div(qi * blk_q + 1, blk_k)
+    lax.fori_loop(0, n_full, lambda ki, _: block(ki, None), None)
+    if sub:
+        # blk_q == blk_k: kv block `qi` as sub-blocks; query sub-block i
+        # sees key sub-blocks j < i whole, j == i under the mask, j > i
+        # not at all
+        for i in range(blk_q // sub):
+            for j in range(i + 1):
+                pair(_rows(i, sub), pl.ds(qi * blk_k + j * sub, sub),
+                     0 if i == j else None)
+    else:
+        lax.fori_loop(
+            n_full, lax.div((qi + 1) * blk_q + blk_k - 1, blk_k),
+            lambda ki, _: block(ki, qi * blk_q - ki * blk_k), None)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                *, blk_k: int, sub: int, scale: float, causal: bool):
     """One (batch*head, q-block) program: stream K/V blocks online.
 
     q_ref: (1, BLK_Q, D); k_ref/v_ref: (1, S, D); o_ref: (1, BLK_Q, D);
     lse_ref: (1, BLK_Q, 1) log-sum-exp for the backward (trailing 1 dim:
-    TPU block shapes need the last dims tileable-or-full).
+    TPU block shapes need the last dims tileable-or-full). Scratch, all
+    float32 and whole vector registers wide: acc_ref (BLK_Q, D) the
+    output's accumulator; m_ref (BLK_Q, W) the running max, the same in
+    every lane (a (BLK_Q, 1) column here cost the kernel a third more
+    time on the chip: PERF.md §6, PR 32); l_ref (BLK_Q, W) the sum of
+    exponentials as W lane-partial sums, reduced across lanes once, at
+    the end — one reduction through the XLU a pair (the row max), not
+    two. `sub`: side of the diagonal pair's sub-blocks (`_diag_sub`), 0
+    where the blocks differ and the pairs the diagonal crosses are
+    masked whole.
     """
     _, blk_q, d = q_ref.shape
-    s = k_ref.shape[1]
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale
-    q_pos = qi * blk_q + lax.broadcasted_iota(jnp.int32, (blk_q, 1), 0)
+    width = m_ref.shape[1]
 
-    def body(ki, carry):
-        o, m, l = carry
-        k_blk = k_ref[0, pl.ds(ki * blk_k, blk_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(ki * blk_k, blk_k), :].astype(jnp.float32)
-        sblk = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            kv_pos = ki * blk_k + lax.broadcasted_iota(
-                jnp.int32, (1, blk_k), 1)
-            sblk = jnp.where(q_pos >= kv_pos, sblk, _NEG_INF)
+    def pair(rows, k_at, ahead):
+        """Online-softmax step of query rows `rows` of this block over
+        the keys at `k_at`; `ahead` None: every key visible, no mask."""
+        sblk = _dot_nt(q_ref[0, rows, :], k_ref[0, k_at, :]) * scale
+        if ahead is not None:
+            sblk = jnp.where(_visible(sblk.shape, ahead), sblk, _NEG_INF)
+        m = m_ref[rows, :]
         m_new = jnp.maximum(m, jnp.max(sblk, axis=-1, keepdims=True))
-        p = jnp.exp(sblk - m_new)
+        p = jnp.exp(sblk - _lane_tile(m_new, sblk.shape[1]))
         corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        o = o * corr + jnp.dot(p, v_blk,
-                               preferred_element_type=jnp.float32)
-        return o, m_new, l
+        m_ref[rows, :] = m_new
+        l_ref[rows, :] = l_ref[rows, :] * corr + _lane_fold(p, width)
+        v_blk = v_ref[0, k_at, :]
+        acc_ref[rows, :] = (acc_ref[rows, :] * _lane_tile(corr, d)
+                            + _dot(p.astype(v_blk.dtype), v_blk))
 
-    o0 = jnp.zeros((blk_q, d), jnp.float32)
-    m0 = jnp.full((blk_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((blk_q, 1), jnp.float32)
-    if causal:
-        # blocks strictly after this q block never contribute
-        n_blocks = lax.div((qi + 1) * blk_q + blk_k - 1, blk_k)
-    else:
-        n_blocks = s // blk_k
-    o, m, l = lax.fori_loop(0, n_blocks, body, (o0, m0, l0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (o / l).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    _over_keys(pair, pl.program_id(1), blk_q=blk_q, blk_k=blk_k,
+               n_k=k_ref.shape[1] // blk_k, sub=sub, causal=causal)
+    l = jnp.maximum(jnp.sum(l_ref[...], axis=-1, keepdims=True), 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    lse_ref[0] = m_ref[:, :1] + jnp.log(l)
 
 
+# jitted so that a model's layers, which call this with one set of shapes,
+# share one trace and one lowering of the kernel: traced a layer each, the
+# three kernels added seconds to a trainer's start (PERF.md §6, PR 32)
+@functools.partial(jax.jit, static_argnames=(
+    "blk_q", "blk_k", "scale", "causal", "interpret"))
 def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
          interpret: bool):
     b, s, h, d = q.shape
@@ -102,8 +208,9 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
     grid = (b * h, s // blk_q)
+    sub = _diag_sub(blk_q, blk_k)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, blk_k=blk_k, scale=scale,
+        functools.partial(_fwd_kernel, blk_k=blk_k, sub=sub, scale=scale,
                           causal=causal),
         grid=grid,
         in_specs=[
@@ -119,6 +226,8 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)]
+        + [pltpu.VMEM((blk_q, _stat_width(blk_k, sub)), jnp.float32)] * 2,
         interpret=interpret,
         name="flash_fwd",
     )(qt, kt, vt)
@@ -166,98 +275,99 @@ def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool):
 
 
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
-                     dk_ref, dv_ref, *, blk_q: int, scale: float,
-                     causal: bool):
+                     dk_ref, dv_ref, dk_acc, dv_acc, *, sub: int,
+                     scale: float, causal: bool):
     """One (batch*head, kv-block) program: K/V block resident, stream Q
     blocks (causal: only blocks that can see this KV block), accumulate
-    dK/dV in fp32 VMEM.
+    dK/dV in fp32 VMEM scratch (dk_acc/dv_acc: (BLK_K, D)). Works on the
+    transposed scores K·Q^T, so no piece is transposed for p^T·dO and
+    ds^T·Q.
 
     q_ref/do_ref: (1, S, D); k_ref/v_ref/dk_ref/dv_ref: (1, BLK_K, D);
-    lse_ref/rt_ref: (1, S, 1) fp32 — lse from the forward; rt is the
-    row term delta - dlse (delta = rowsum(dO*O)), precomputed in XLA so
-    one kernel serves both the plain and the lse-cotangent vjp.
+    lse_ref/rt_ref: (1, S/BLK_Q, 1, BLK_Q) fp32, a q block's values
+    along the lanes (picked by an index of an untiled dimension: a
+    dynamic row of a tile does not compile at every width) — lse from
+    the forward; rt is the row term delta - dlse (delta =
+    rowsum(dO*O)), precomputed in XLA so one kernel serves both the
+    plain and the lse-cotangent vjp. `sub` as in `_fwd_kernel`.
     """
-    _, blk_k, d = k_ref.shape
-    s = q_ref.shape[1]
+    blk_k = k_ref.shape[1]
+    _, n_q, _, blk_q = lse_ref.shape
     ki = pl.program_id(1)
-    k_blk = k_ref[0].astype(jnp.float32)
-    v_blk = v_ref[0].astype(jnp.float32)
-    kv_pos = ki * blk_k + lax.broadcasted_iota(jnp.int32, (1, blk_k), 1)
+    to = v_ref.dtype
 
-    def body(qi, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qi * blk_q, blk_q), :]
-        rt = rt_ref[0, pl.ds(qi * blk_q, blk_q), :]
-        sblk = jnp.dot(q, k_blk.T,
-                       preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(sblk - lse)  # (blk_q, blk_k)
-        if causal:
-            q_pos = qi * blk_q + lax.broadcasted_iota(
-                jnp.int32, (blk_q, 1), 0)
-            p = jnp.where(q_pos >= kv_pos, p, 0.0)
-        dv = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - rt) * scale
-        dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-        return dk, dv
+    def pair(keys, q_at, qi, lanes, ahead):
+        """Key rows `keys` of this block against the queries at `q_at`:
+        lanes `lanes` of q block `qi`'s lse/rt rows."""
+        q, do = q_ref[0, q_at, :], do_ref[0, q_at, :]
+        pt = jnp.exp(_dot_nt(k_ref[0, keys, :], q) * scale
+                     - lse_ref[0, qi, :, lanes])
+        if ahead is not None:
+            pt = jnp.where(_visible(pt.shape, ahead, q_minor=True), pt, 0.0)
+        dv_acc[keys, :] += _dot(pt.astype(to), do)
+        dst = pt * (_dot_nt(v_ref[0, keys, :], do) - rt_ref[0, qi, :, lanes])
+        dk_acc[keys, :] += _dot(dst.astype(to), q)  # x scale: at the end
 
+    def block(qi, ahead):
+        pair(slice(None), pl.ds(qi * blk_q, blk_q), qi, slice(None), ahead)
+
+    dk_acc[...] = jnp.zeros_like(dk_acc)
+    dv_acc[...] = jnp.zeros_like(dv_acc)
+    first_full = 0
     if causal:
-        # the first q block that can see any row of this kv block
-        q_start = lax.div(ki * blk_k, blk_q)
-    else:
-        q_start = 0
-    zeros = jnp.zeros((blk_k, d), jnp.float32)
-    dk, dv = lax.fori_loop(q_start, s // blk_q, body, (zeros, zeros))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        # q blocks the diagonal crosses (from the first that can see any
+        # row of this kv block), then the ones that see all of it
+        first_full = lax.div((ki + 1) * blk_k + blk_q - 2, blk_q)
+        if sub:
+            # blk_q == blk_k: q block `ki` as sub-blocks; key sub-block j
+            # is seen by query sub-blocks i > j whole, i == j masked
+            for j in range(blk_k // sub):
+                for i in range(j, blk_k // sub):
+                    pair(_rows(j, sub), pl.ds(ki * blk_q + i * sub, sub),
+                         ki, _rows(i, sub), 0 if i == j else None)
+        else:
+            lax.fori_loop(
+                lax.div(ki * blk_k, blk_q), first_full,
+                lambda qi, _: block(qi, qi * blk_q - ki * blk_k), None)
+    lax.fori_loop(first_full, n_q, lambda qi, _: block(qi, None), None)
+    dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref, dq_ref,
-                   *, blk_k: int, scale: float, causal: bool):
+                   dq_acc, *, blk_k: int, sub: int, scale: float,
+                   causal: bool):
     """One (batch*head, q-block) program: Q block resident, stream KV
-    blocks (causal skip as in the forward), accumulate dQ."""
-    _, blk_q, d = q_ref.shape
-    s = k_ref.shape[1]
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    rt = rt_ref[0]
-    q_pos = qi * blk_q + lax.broadcasted_iota(jnp.int32, (blk_q, 1), 0)
+    blocks (causal skip and diagonal as in the forward), accumulate dQ
+    in fp32 VMEM scratch (dq_acc: (BLK_Q, D)). lse_ref/rt_ref:
+    (1, BLK_Q, 1) columns."""
+    to = v_ref.dtype
 
-    def body(ki, dq):
-        k_blk = k_ref[0, pl.ds(ki * blk_k, blk_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(ki * blk_k, blk_k), :].astype(jnp.float32)
-        sblk = jnp.dot(q, k_blk.T,
-                       preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(sblk - lse)
-        if causal:
-            kv_pos = ki * blk_k + lax.broadcasted_iota(
-                jnp.int32, (1, blk_k), 1)
-            p = jnp.where(q_pos >= kv_pos, p, 0.0)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - rt) * scale
-        return dq + jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
+    def pair(rows, k_at, ahead):
+        k_blk = k_ref[0, k_at, :]
+        p = jnp.exp(_dot_nt(q_ref[0, rows, :], k_blk) * scale
+                    - lse_ref[0, rows, :])
+        if ahead is not None:
+            p = jnp.where(_visible(p.shape, ahead), p, 0.0)
+        ds = p * (_dot_nt(do_ref[0, rows, :], v_ref[0, k_at, :])
+                  - rt_ref[0, rows, :])
+        dq_acc[rows, :] += _dot(ds.astype(to), k_blk)  # x scale: at the end
 
-    if causal:
-        n_blocks = lax.div((qi + 1) * blk_q + blk_k - 1, blk_k)
-    else:
-        n_blocks = s // blk_k
-    dq = lax.fori_loop(0, n_blocks, body,
-                       jnp.zeros((blk_q, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+    _over_keys(pair, pl.program_id(1), blk_q=q_ref.shape[1], blk_k=blk_k,
+               n_k=k_ref.shape[1] // blk_k, sub=sub, causal=causal)
+    dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "blk_q", "blk_k", "scale", "causal", "interpret"))
 def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
                 scale: float, causal: bool, dlse, interpret: bool):
     """Pallas flash backward: same math as `_bwd_blockwise` (the XLA
     reference used by the parity tests) but with scores recomputed in
     VMEM — nothing S^2-shaped touches HBM — and the causal block skip
     in BOTH directions (the XLA scan masks instead of skipping, doing
-    2x the needed work). The trace that motivated this: the scan
-    backward was ~29% of LM step time for ~6% of model FLOPs."""
+    2x the needed work)."""
     b, s, h, d = q.shape
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -265,16 +375,18 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
     dot = do.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     # row term = delta - dlse, delta_i = rowsum(dO_i * O_i): cheap
     # elementwise XLA; folding it here keeps the kernels single-purpose
-    delta = jnp.sum(dot.astype(jnp.float32)
-                    * o.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-                    .astype(jnp.float32), axis=-1, keepdims=True)
-    rt = delta if dlse is None else delta - dlse[..., None].astype(
-        jnp.float32)
-    lse3 = lse[..., None]
+    rt = jnp.sum(dot.astype(jnp.float32)
+                 * o.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+                 .astype(jnp.float32), axis=-1)
+    if dlse is not None:
+        rt = rt - dlse.astype(jnp.float32)
+    sub = _diag_sub(blk_q, blk_k)
 
-    common_in = [qt, kt, vt, dot, lse3, rt]
+    def along_lanes(x):  # a q block's values in one row of lanes
+        return x.reshape(b * h, s // blk_q, 1, blk_q)
+
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkdv_kernel, blk_q=blk_q, scale=scale,
+        functools.partial(_bwd_dkdv_kernel, sub=sub, scale=scale,
                           causal=causal),
         grid=(b * h, s // blk_k),
         in_specs=[
@@ -282,8 +394,10 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
             pl.BlockSpec((1, blk_k, d), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, blk_k, d), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, s, d), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, s, 1), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, s, 1), lambda bh, ki: (bh, 0, 0)),
+            pl.BlockSpec((1, s // blk_q, 1, blk_q),
+                         lambda bh, ki: (bh, 0, 0, 0)),
+            pl.BlockSpec((1, s // blk_q, 1, blk_q),
+                         lambda bh, ki: (bh, 0, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, blk_k, d), lambda bh, ki: (bh, ki, 0)),
@@ -293,12 +407,13 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
             jax.ShapeDtypeStruct((b * h, s, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, s, d), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32)] * 2,
         interpret=interpret,
         name="flash_bwd_dkdv",
-    )(*common_in)
+    )(qt, kt, vt, dot, along_lanes(lse), along_lanes(rt))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, blk_k=blk_k, scale=scale,
-                          causal=causal),
+        functools.partial(_bwd_dq_kernel, blk_k=blk_k, sub=sub,
+                          scale=scale, causal=causal),
         grid=(b * h, s // blk_q),
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
@@ -310,9 +425,10 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
         ],
         out_specs=pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
-    )(*common_in)
+    )(qt, kt, vt, dot, lse[..., None], rt[..., None])
 
     def back(x):
         return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
@@ -391,6 +507,48 @@ def _fit_block(s: int, want: int) -> int:
                      f"<= {want} (pad the sequence to a multiple of 128)")
 
 
+def _diag_sub(blk_q: int, blk_k: int) -> int:
+    """Side of the sub-blocks the diagonal block pair is worked in: half
+    a block where the blocks are equal (one pair a row of blocks is on
+    the diagonal) and the halves are still 128-granular, so the quarter
+    of that pair in the future is never computed. 0: the blocks differ,
+    or do not halve; the pairs the diagonal crosses are masked whole."""
+    return blk_q // 2 if blk_q == blk_k and blk_q % 256 == 0 else 0
+
+
+def _stat_width(blk_k: int, sub: int) -> int:
+    """Lanes of the forward's running max and partial sums of
+    exponentials: the 128 of a vector register where every piece of keys
+    (a block, a sub-block) is whole slabs of them, else the one piece's
+    width."""
+    piece = sub or blk_k
+    return 128 if piece % 128 == 0 else piece
+
+
+def block_pairs(s: int, blk_q: int, blk_k: int, causal: bool) -> str:
+    """What each of the three kernels works through for one head, for
+    the log: the blocking, and the block pairs that run unmasked, under
+    the mask, and not at all."""
+    n_q, n_k = s // blk_q, s // blk_k
+    if not causal:
+        return f"blocks {blk_q}x{blk_k}, pairs a head: {n_q * n_k} full"
+    # as the kernels count them: kv blocks wholly at or before a q
+    # block's first row, and those with any column at or before its last
+    full = sum((qi * blk_q + 1) // blk_k for qi in range(n_q))
+    seen = sum(-(-(qi + 1) * blk_q // blk_k) for qi in range(n_q))
+    text = (f"blocks {blk_q}x{blk_k}, pairs a head: {full} full, "
+            f"{seen - full} on the diagonal, {n_q * n_k - seen} skipped")
+    sub = _diag_sub(blk_q, blk_k)
+    if sub:
+        n = blk_q // sub
+        text += (f"; a diagonal pair as {n}x{n} of {sub}: "
+                 f"{n * (n - 1) // 2} full, {n} masked, "
+                 f"{n * (n - 1) // 2} skipped")
+    else:
+        text += ", masked whole"
+    return text
+
+
 _FORCE_INTERPRET = False
 
 
@@ -407,25 +565,28 @@ def force_interpret_kernels():
         _FORCE_INTERPRET = False
 
 
-def _kernel_interpret(what: str, q) -> bool | None:
+def _kernel_interpret(what: str, q, blk_q: int, blk_k: int,
+                      causal: bool) -> bool | None:
     """Which path this trace takes: the Pallas `interpret` flag (False
     = compiled, on TPU; True = the test hook), or None for the compiled
     XLA blockwise paths — off-TPU, where interpret-mode Pallas is
     orders of magnitude slower and would throttle the CPU
     elastic/multipod worlds. Logged per trace, so a trainer's log says
-    which attention its step was built from."""
+    which attention its step was built from, and in which blocks."""
     if jax.default_backend() == "tpu":
         mode, interpret = "pallas kernel, compiled", False
     elif _FORCE_INTERPRET:
         mode, interpret = "pallas kernel, interpret mode", True
     else:
         mode, interpret = "xla blockwise", None
+    if interpret is not None:
+        mode += "; " + block_pairs(q.shape[1], blk_q, blk_k, causal)
     log.info("flash attention %s %s: %s", what, tuple(q.shape), mode)
     return interpret
 
 
 def _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal):
-    interpret = _kernel_interpret("fwd", q)
+    interpret = _kernel_interpret("fwd", q, blk_q, blk_k, causal)
     if interpret is None:
         return _fwd_blockwise(q, k, v, blk=blk_k, scale=scale,
                               causal=causal)
@@ -446,7 +607,7 @@ def _flash_lse_fwd(q, k, v, blk_q, blk_k, scale, causal):
 def _flash_lse_bwd(blk_q, blk_k, scale, causal, res, cotangents):
     q, k, v, o, lse = res
     do, dlse = cotangents
-    interpret = _kernel_interpret("bwd", q)
+    interpret = _kernel_interpret("bwd", q, blk_q, blk_k, causal)
     if interpret is None:
         return _bwd_blockwise(q, k, v, o, lse, do, blk=blk_k,
                               scale=scale, causal=causal, dlse=dlse)

@@ -87,8 +87,11 @@ def custom_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
-# (batch, seq, heads, head dim): LM-large, and the d_model-1024 shape
-FLASH_SHAPES = [(8, 1024, 16, 128), (16, 1024, 16, 64)]
+# (batch, seq, heads, head dim): LM-large, the d_model-1024 shape, and
+# the benchmark's cells: d8, a chip of fsdp4, OLMoE
+FLASH_SHAPES = [(8, 1024, 16, 128), (16, 1024, 16, 64),
+                (6, 2048, 16, 128), (2, 2048, 16, 128),
+                (4, 4096, 16, 128)]
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)

@@ -138,28 +138,66 @@ class TestBackward:
             np.testing.assert_allclose(o_got, o_ref, atol=5e-6)
             np.testing.assert_allclose(lse_got, lse_ref, atol=5e-6)
 
-    def test_pallas_bwd_matches_xla_reference(self):
-        """The Pallas dK/dV + dQ kernels vs `_bwd_blockwise` (the plain
-        XLA scan they replaced), incl. the dlse cotangent path and
-        uneven blk_q != blk_k."""
+    # (S, blk_q, blk_k, head dim, dtype): four and eight blocks a side
+    # at 256 (the diagonal pair in 2x2 sub-blocks: full, masked and
+    # skipped pieces all occur) and at 128 (the diagonal pair masked
+    # whole), unequal blocks either way round (several pairs on the
+    # diagonal), head dimensions 64 and 128, float32 and bf16 inputs
+    KERNEL_CASES = [
+        (256, 128, 64, 64, jnp.float32),
+        (512, 128, 256, 64, jnp.float32),
+        (512, 128, 128, 128, jnp.float32),
+        (1024, 128, 128, 64, jnp.float32),
+        (1024, 256, 256, 64, jnp.float32),
+        (1024, 256, 256, 128, jnp.bfloat16),
+        (2048, 256, 256, 64, jnp.float32),
+        (2048, 256, 256, 64, jnp.bfloat16),
+        (1024, 512, 512, 128, jnp.bfloat16),
+        (1024, 256, 512, 64, jnp.bfloat16),
+    ]
+
+    @pytest.mark.parametrize("with_dlse", [False, True],
+                             ids=["plain", "dlse"])
+    @pytest.mark.parametrize("causal", [True, False],
+                             ids=["causal", "full"])
+    @pytest.mark.parametrize(
+        "case", KERNEL_CASES,
+        ids=lambda c: f"{c[0]}-{c[1]}x{c[2]}-d{c[3]}-{c[4].__name__}")
+    def test_pallas_bwd_matches_xla_reference(self, case, causal,
+                                              with_dlse):
+        """The Pallas forward, dK/dV and dQ kernels vs `_fwd_blockwise`
+        / `_bwd_blockwise` (the plain XLA scans), incl. the dlse
+        cotangent path. float32 inputs: nothing is cast in the kernels,
+        so they agree to float32 rounding; bf16 inputs: the reference
+        upcasts them, the kernels hand them to the products as they are
+        and round p and ds to bf16."""
         from edl_tpu.ops.flash_attention import (_bwd_blockwise,
-                                                 _bwd_pallas, _fwd)
-        for causal in (True, False):
-            q, k, v = _qkv(s=256)
-            scale = 1.0 / q.shape[-1] ** 0.5
-            o, lse = _fwd(q, k, v, blk_q=128, blk_k=64, scale=scale,
-                          causal=causal, interpret=True)
-            rng = np.random.default_rng(5)
-            do = jnp.asarray(rng.normal(size=q.shape), q.dtype)
-            dlse = jnp.asarray(rng.normal(size=lse.shape), jnp.float32)
-            for dl in (None, dlse):
-                ref = _bwd_blockwise(q, k, v, o, lse, do, blk=64,
-                                     scale=scale, causal=causal, dlse=dl)
-                got = _bwd_pallas(q, k, v, o, lse, do, blk_q=128,
-                                  blk_k=64, scale=scale, causal=causal,
-                                  dlse=dl, interpret=True)
-                for a, b in zip(got, ref):
-                    np.testing.assert_allclose(a, b, atol=5e-5)
+                                                 _bwd_pallas, _fwd,
+                                                 _fwd_blockwise)
+        s, blk_q, blk_k, d, dtype = case
+        q, k, v = _qkv(b=1, s=s, h=2, d=d, dtype=dtype)
+        scale = 1.0 / d ** 0.5
+        kw = dict(scale=scale, causal=causal)
+        o, lse = _fwd(q, k, v, blk_q=blk_q, blk_k=blk_k, interpret=True,
+                      **kw)
+        o_ref, lse_ref = _fwd_blockwise(q, k, v, blk=blk_k, **kw)
+        rng = np.random.default_rng(5)
+        do = jnp.asarray(rng.normal(size=q.shape), dtype)
+        dlse = (jnp.asarray(rng.normal(size=lse.shape), jnp.float32)
+                if with_dlse else None)
+        ref = _bwd_blockwise(q, k, v, o_ref, lse_ref, do, blk=blk_k,
+                             dlse=dlse, **kw)
+        got = _bwd_pallas(q, k, v, o_ref, lse_ref, do, blk_q=blk_q,
+                          blk_k=blk_k, dlse=dlse, interpret=True, **kw)
+        np.testing.assert_allclose(lse, lse_ref, atol=5e-6 if dtype
+                                   == jnp.float32 else 2e-2)
+        for a, b in zip((o, *got), (o_ref, *ref)):
+            assert a.dtype == dtype
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            if dtype == jnp.float32:
+                np.testing.assert_allclose(a, b, atol=5e-5)
+            else:  # bf16 results of values up to a few units
+                assert np.abs(a - b).max() <= 3e-2 * np.abs(b).max()
 
     def test_value_and_grad_jits(self):
         q, k, v = _qkv(s=128)
@@ -168,6 +206,120 @@ class TestBackward:
         val, grad = f(q)
         assert np.isfinite(float(val))
         assert grad.shape == q.shape
+
+
+class TestKernelBodies:
+    """What one score block pair costs inside the kernels, read off the
+    kernels' own jaxprs at bf16 inputs."""
+
+    @staticmethod
+    def _kernel_jaxprs(blk):
+        from edl_tpu.ops.flash_attention import _bwd_pallas, _fwd
+        q, k, v = _qkv(b=1, s=4 * blk, h=1, d=128, dtype=jnp.bfloat16)
+        kw = dict(blk_q=blk, blk_k=blk, scale=0.1, causal=True,
+                  interpret=True)
+
+        def both(q, k, v):
+            o, lse = _fwd(q, k, v, **kw)
+            return _bwd_pallas(q, k, v, o, lse, q, dlse=None, **kw)
+
+        calls = [e for e in TestKernelBodies._walk(
+            jax.make_jaxpr(both)(q, k, v).jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert [e.params["name"] for e in calls] == [
+            "flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"]
+        return [e.params["jaxpr"] for e in calls]
+
+    @staticmethod
+    def _walk(jaxpr):
+        """Every equation of a jaxpr and of all it holds."""
+        for e in jaxpr.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from TestKernelBodies._walk(sub)
+
+    @pytest.mark.parametrize("blk", [128, 256])
+    def test_operands_as_they_arrive_and_no_transpose(self, blk):
+        for jaxpr in self._kernel_jaxprs(blk):
+            eqns = list(self._walk(jaxpr))
+            names = {e.primitive.name for e in eqns}
+            assert "transpose" not in names
+            dots = [e for e in eqns if e.primitive.name == "dot_general"]
+            assert dots and all(
+                v.aval.dtype == jnp.bfloat16 for e in dots for v in e.invars)
+            assert all(e.params["preferred_element_type"] == jnp.float32
+                       for e in dots)
+            # nothing (blocks of Q, K, V or dO least of all) goes up to
+            # float32: the only casts are p and ds going down
+            casts = [e for e in eqns
+                     if e.primitive.name == "convert_element_type"
+                     and e.invars[0].aval.shape]
+            assert casts and all(
+                e.invars[0].aval.dtype == jnp.float32
+                and e.params["new_dtype"] == jnp.bfloat16 for e in casts)
+
+    def test_mask_only_on_the_diagonal(self):
+        """At 256 the diagonal pair is unrolled as sub-blocks, so every
+        loop body is the unmasked one; at 128 one loop carries the
+        mask, the other does not."""
+        def masked_loops(jaxpr):
+            loops = [e for e in self._walk(jaxpr)
+                     if e.primitive.name == "while"]
+            return [any(i.primitive.name == "select_n"
+                        for sub in jax.core.jaxprs_in_params(e.params)
+                        for i in self._walk(sub)) for e in loops]
+
+        for jaxpr in self._kernel_jaxprs(256):
+            assert masked_loops(jaxpr) == [False]
+            assert sum(e.primitive.name == "select_n"
+                       for e in self._walk(jaxpr)) == 2  # sub-diagonals
+        for jaxpr in self._kernel_jaxprs(128):
+            assert sorted(masked_loops(jaxpr)) == [False, True]
+
+    @pytest.mark.parametrize("args, want", [
+        ((2048, 512, 512, True),
+         "blocks 512x512, pairs a head: 6 full, 4 on the diagonal, "
+         "6 skipped; a diagonal pair as 2x2 of 256: 1 full, 2 masked, "
+         "1 skipped"),
+        ((4096, 512, 512, True),
+         "blocks 512x512, pairs a head: 28 full, 8 on the diagonal, "
+         "28 skipped; a diagonal pair as 2x2 of 256: 1 full, 2 masked, "
+         "1 skipped"),
+        ((640, 128, 128, True),
+         "blocks 128x128, pairs a head: 10 full, 5 on the diagonal, "
+         "10 skipped, masked whole"),
+        ((256, 128, 64, True),
+         "blocks 128x64, pairs a head: 2 full, 4 on the diagonal, "
+         "2 skipped, masked whole"),
+        ((1024, 512, 512, False), "blocks 512x512, pairs a head: 4 full"),
+    ], ids=lambda a: "-".join(map(str, a)) if isinstance(a, tuple) else "")
+    def test_block_pairs_line(self, args, want):
+        from edl_tpu.ops.flash_attention import block_pairs
+        assert block_pairs(*args) == want
+
+    def test_trace_logs_its_blocking(self, caplog):
+        import logging
+        q, k, v = _qkv(b=1, s=512, h=1)
+        # the framework's loggers do not propagate: listen on this one
+        fa_log = logging.getLogger("edl_tpu.ops.flash_attention")
+        fa_log.addHandler(caplog.handler)
+        try:
+            with force_interpret_kernels():
+                jax.grad(lambda q: jnp.sum(flash_attention(
+                    q, k, v, block_q=256, block_k=256)))(q)
+            flash_attention(q, k, v)
+        finally:
+            fa_log.removeHandler(caplog.handler)
+        text = [r.getMessage() for r in caplog.records]
+        pairs = ("blocks 256x256, pairs a head: 1 full, 2 on the diagonal, "
+                 "1 skipped; a diagonal pair as 2x2 of 128: 1 full, "
+                 "2 masked, 1 skipped")
+        assert text == [
+            f"flash attention fwd (1, 512, 1, 64): pallas kernel, "
+            f"interpret mode; {pairs}",
+            f"flash attention bwd (1, 512, 1, 64): pallas kernel, "
+            f"interpret mode; {pairs}",
+            "flash attention fwd (1, 512, 1, 64): xla blockwise"]
 
 
 class TestLseOutput:
