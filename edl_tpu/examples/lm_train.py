@@ -29,8 +29,8 @@ import optax
 
 from edl_tpu.data.pipeline import DataLoader, FileSource
 from edl_tpu.models.transformer import (Transformer, TransformerConfig,
-                                        lm_loss_fn, lm_loss_fused,
-                                        olmoe_config)
+                                        granite_hybrid_config, lm_loss_fn,
+                                        lm_loss_fused, olmoe_config)
 from edl_tpu.obs import trace
 from edl_tpu.parallel import distributed, mesh as mesh_lib, sharding as shd
 from edl_tpu.train import lr as lr_lib
@@ -61,6 +61,18 @@ def make_synthetic_shards(data_dir: str, n_files: int, rows: int,
             toks[:, t] = successors[toks[:, t - 1], pick]
         name = "val.npz" if i == n_files else f"train-{i:04d}.npz"
         np.savez(os.path.join(data_dir, name), tokens=toks)
+
+
+def make_optimizer(lr: float, total_steps: int, warmup_steps: int,
+                   fused_opt: str | None = None):
+    """The trainer's optimizer: AdamW (weight decay 0.01 on every
+    parameter) on a cosine schedule over ``total_steps`` with a linear
+    warm-up; the fused path where a flag or the environment asks."""
+    schedule = lr_lib.cosine_with_warmup(
+        lr, total_steps, min(warmup_steps, max(1, total_steps // 10)))
+    from edl_tpu.train.fused_opt import make_fused_tx
+    tx = make_fused_tx("adam", schedule, fused_opt, weight_decay=0.01)
+    return optax.adamw(schedule, weight_decay=0.01) if tx is None else tx
 
 
 def main(argv=None) -> int:
@@ -110,13 +122,26 @@ def main(argv=None) -> int:
                              "overlap earlier buckets' communication "
                              "(default $EDL_TPU_COMM_BUCKET_MB, else 0 "
                              "= XLA's single fused reduction)")
-    parser.add_argument("--arch", choices=("gpt2", "olmoe"),
+    parser.add_argument("--arch", choices=("gpt2", "olmoe",
+                                           "granite-hybrid"),
                         default="gpt2",
                         help="the block: gpt2 = LayerNorm, learned "
                              "positions, gelu; olmoe = models.transformer."
                              "olmoe_config (RMSNorm, RoPE, qk-norm, SwiGLU "
                              "experts, top-k gates as they are; implies "
-                             "--moe, 64 experts, 8 a token unless given)")
+                             "--moe, 64 experts, 8 a token unless given); "
+                             "granite-hybrid = models.transformer."
+                             "granite_hybrid_config (RMSNorm, no positions, "
+                             "Mamba-2 mixers with grouped-query attention "
+                             "among them, dense SwiGLU, a tied head, the "
+                             "four multipliers; --d-ff is the MLP's width)")
+    parser.add_argument("--layer-types", default="",
+                        help="granite-hybrid: one letter a layer, m = "
+                             "mamba, a = attention (default: the "
+                             "published pattern, attention at layers 5, "
+                             "15, 25, ..., cut to --n-layers). The "
+                             "mixers' sizes and the 8 key/value heads are "
+                             "granite_hybrid_config's own")
     parser.add_argument("--moe", action="store_true",
                         help="mixture-of-experts FFNs. One device: "
                              "dropless sort-and-gather dispatch into "
@@ -181,6 +206,9 @@ def main(argv=None) -> int:
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(argv)
     args.moe = args.moe or args.arch == "olmoe"
+    if args.moe and args.arch == "granite-hybrid":
+        raise SystemExit("--arch granite-hybrid has a dense MLP "
+                         "(num_local_experts 0); --moe conflicts")
     if args.profile:
         trace.collect(args.profile)  # spans from here on, start-up's too
 
@@ -265,13 +293,23 @@ def main(argv=None) -> int:
                          "wire; under --moe the wire knob is "
                          "--moe-compress (gradient compression over "
                          "the ep axis is not parity-gated yet)")
-    make_cfg, moe_kw = TransformerConfig, {}
+    make_cfg, arch_kw = TransformerConfig, {}
     if args.arch == "olmoe":
         make_cfg = olmoe_config
-        moe_kw = {k: v for k, v in (("n_experts", args.n_experts),
+        arch_kw = {k: v for k, v in (("n_experts", args.n_experts),
                                     ("moe_top_k", args.moe_top_k)) if v}
+    elif args.arch == "granite-hybrid":
+        make_cfg = granite_hybrid_config
+        if args.layer_types:
+            if set(args.layer_types) - set("ma"):
+                raise SystemExit(f"--layer-types {args.layer_types!r}: one "
+                                 "letter a layer, m (mamba) or a "
+                                 "(attention)")
+            arch_kw["layer_types"] = tuple(
+                {"m": "mamba", "a": "attention"}[c]
+                for c in args.layer_types)
     elif args.moe:
-        moe_kw = dict(moe=True,
+        arch_kw = dict(moe=True,
                       n_experts=args.n_experts or 2 * jax.device_count(),
                       moe_top_k=args.moe_top_k or 2)
     cfg = make_cfg(
@@ -282,7 +320,7 @@ def main(argv=None) -> int:
         # constraints / nested shard_maps would clash with the manual
         # dp/ep axis — each shard computes exactly one chip's backward
         mesh=None if (comm_cfg is not None or args.moe) else mesh,
-        **moe_kw)
+        **arch_kw)
     if args.remat != "off":
         from edl_tpu.models.transformer import auto_remat
         cfg = (auto_remat(cfg, local_bs)
@@ -298,14 +336,9 @@ def main(argv=None) -> int:
     total_steps = steps_per_epoch * (args.schedule_epochs or args.epochs)
     # --batch-size is GLOBAL: LR stays batch-tied across elastic resizes
     # (scale_for_world is for per-pod batch semantics)
-    schedule = lr_lib.cosine_with_warmup(
-        args.lr, total_steps,
-        min(args.warmup_steps, max(1, total_steps // 10)))
-    from edl_tpu.train.fused_opt import make_fused_tx
-    tx = make_fused_tx("adam", schedule, args.fused_opt, weight_decay=0.01)
-    if tx is None:
-        tx = optax.adamw(schedule, weight_decay=0.01)
-    else:
+    tx = make_optimizer(args.lr, total_steps, args.warmup_steps,
+                        args.fused_opt)
+    if hasattr(tx, "quant"):
         log.info("fused optimizer path: adam, moments %s", tx.quant)
 
     # one row per batch shard: the flash kernel runs under a shard_map
@@ -360,6 +393,13 @@ def main(argv=None) -> int:
              "ring" if cfg.use_ring else
              "flash" if cfg.use_flash(args.seq_len) else "dense")
     log.info("state bytes per device: %s", shd.bytes_per_device(state))
+    if cfg.layer_types:
+        from edl_tpu.ops import ssd
+        log.info("hybrid: layers %s, %s, kv heads %d of %d",
+                 "".join(k[0] for k in cfg.layer_types),
+                 ssd.describe(cfg.ssm_chunk, cfg.ssm_heads,
+                              cfg.ssm_head_dim, cfg.ssm_state),
+                 cfg.kv_heads, cfg.n_heads)
     if args.fused_loss:
         from edl_tpu.ops import fused_xent
         # the rows one chip sweeps: the manual regions (comm, ep) split

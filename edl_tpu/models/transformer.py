@@ -65,6 +65,7 @@ class TransformerConfig:
     norm: str = "layernorm"        # | "rmsnorm" (scale only, float32)
     norm_eps: float = 1e-6
     pos: str = "learned"           # | "rope" (rotate-half, whole head)
+    #                                | "none" (no table, no rotation)
     rope_theta: float = 10000.0
     qk_norm: bool = False          # RMSNorm on q and k over all heads
     moe_gated: bool = False        # SwiGLU experts: w_gate, w_up, w_down
@@ -76,13 +77,44 @@ class TransformerConfig:
     # all-to-all wire here (an object with dispatch/combine/local_slice
     # — see comm.MoEWire), and the capacity router fills its buffers.
     moe_wire: Any = dfield(default=None, hash=False, compare=False)
+    # -- what a hybrid state-space model adds (`granite_hybrid_config`
+    # sets them all; the defaults are the blocks above, untouched).
+    # layer_types: one kind a block, "attention" | "mamba" (a Mamba-2
+    # mixer, `Mamba2Mixer`); None = attention everywhere.
+    layer_types: tuple | None = None
+    n_kv_heads: int = 0            # 0 = n_heads; else grouped-query
+    attn_scale: float | None = None    # None = head_dim ** -0.5
+    mlp_gated: bool = False        # dense SwiGLU: gate, up, out
+    tie_embeddings: bool = False   # the head is the embedding table
+    embed_scale: float = 1.0       # x = E[tokens] * embed_scale
+    logits_scale: float = 1.0      # logits = h E^T / logits_scale
+    residual_scale: float = 1.0    # x = x + residual_scale * f(norm(x))
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"unknown norm={self.norm!r} "
                              "(layernorm|rmsnorm)")
-        if self.pos not in ("learned", "rope"):
-            raise ValueError(f"unknown pos={self.pos!r} (learned|rope)")
+        if self.pos not in ("learned", "rope", "none"):
+            raise ValueError(f"unknown pos={self.pos!r} "
+                             "(learned|rope|none)")
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)
+            if len(kinds) != self.n_layers or not set(kinds) <= {
+                    "attention", "mamba"}:
+                raise ValueError(
+                    f"layer_types must name n_layers={self.n_layers} "
+                    f"kinds of attention|mamba, got {kinds}")
+            if "mamba" in kinds and self.ssm_heads < 1:
+                raise ValueError("mamba layers need ssm_heads >= 1")
+        if self.n_kv_heads and self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"n_kv_heads={self.n_kv_heads} must divide n_heads="
+                f"{self.n_heads}")
         if self.moe:
             if self.n_experts < 2:
                 raise ValueError(
@@ -100,6 +132,13 @@ class TransformerConfig:
     def head_dim(self) -> int:
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    def kind(self, layer: int) -> str:
+        return self.layer_types[layer] if self.layer_types else "attention"
 
     def constrain(self, x, logical):
         return shd.constrain(x, logical, self.mesh, self.rules)
@@ -132,14 +171,14 @@ class TransformerConfig:
         a pallas_call is opaque to the XLA partitioner, so without this
         a dp-sharded input would be gathered to every device."""
         from edl_tpu.ops.flash_attention import flash_attention
+        fn = partial(flash_attention, causal=True, scale=self.attn_scale)
         if self.mesh is None or all(s == 1 for s in
                                     self.mesh.shape.values()):
-            return flash_attention(q, k, v, causal=True)
+            return fn(q, k, v)
         from jax.sharding import PartitionSpec as P
         batch = tuple(a for a in ("dp", "fsdp")
                       if self.mesh.shape.get(a, 1) > 1) or None
         spec = P(batch)
-        fn = partial(flash_attention, causal=True)
         from edl_tpu.parallel.compat import shard_map
         return shard_map(fn, mesh=self.mesh,
                          in_specs=(spec, spec, spec), out_specs=spec,
@@ -211,6 +250,14 @@ class RMSNorm(nn.Module):
         return (x * scale).astype(self.dtype)
 
 
+def _scaled(x, by: float):
+    """x * by, the product made in float32 and cast back (0.22 is no
+    bfloat16 number); x itself where ``by`` is 1."""
+    if by == 1.0:
+        return x
+    return (x.astype(jnp.float32) * by).astype(x.dtype)
+
+
 def _norm(cfg: TransformerConfig, name: str) -> nn.Module:
     if cfg.norm == "rmsnorm":
         return RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
@@ -245,9 +292,9 @@ class Attention(nn.Module):
             ("embed", "heads", "kv"))
         q = proj((cfg.n_heads, cfg.head_dim), kernel_init=qkv_init,
                  name="query")(x)
-        k = proj((cfg.n_heads, cfg.head_dim), kernel_init=qkv_init,
+        k = proj((cfg.kv_heads, cfg.head_dim), kernel_init=qkv_init,
                  name="key")(x)
-        v = proj((cfg.n_heads, cfg.head_dim), kernel_init=qkv_init,
+        v = proj((cfg.kv_heads, cfg.head_dim), kernel_init=qkv_init,
                  name="value")(x)
         if cfg.qk_norm:  # over all heads' features, before the split
             q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(
@@ -257,16 +304,25 @@ class Attention(nn.Module):
         if cfg.pos == "rope":
             with jax.named_scope("rope"):
                 q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
+        if cfg.kv_heads != cfg.n_heads:
+            # grouped-query: every key/value head serves n_heads/kv_heads
+            # query heads. Repeated here, outside the kernels, whose
+            # block specs know one head a program; autodiff sums dK and
+            # dV over each group.
+            group = cfg.n_heads // cfg.kv_heads
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
         q = cfg.constrain(q, ("batch", "seq", "heads", "kv"))
         k = cfg.constrain(k, ("batch", "seq", "heads", "kv"))
         v = cfg.constrain(v, ("batch", "seq", "heads", "kv"))
 
         if cfg.use_ring:
-            o = ra.ring_attention(q, k, v, mesh=cfg.mesh, causal=True)
+            o = ra.ring_attention(q, k, v, mesh=cfg.mesh, causal=True,
+                                  scale=cfg.attn_scale)
         elif cfg.use_flash(s):
             o = cfg.flash(q, k, v)
         else:
-            o = ra.dense_attention(q, k, v, causal=True)
+            o = ra.dense_attention(q, k, v, causal=True,
+                                   scale=cfg.attn_scale)
         o = cfg.constrain(o, ("batch", "seq", "heads", "kv"))
 
         out_init = nn.with_logical_partitioning(
@@ -483,8 +539,86 @@ class MoEMLP(nn.Module):
         return y.astype(cfg.dtype).reshape(b, s, d)
 
 
+def _ssm_a_log_init(key, shape, dtype=jnp.float32):
+    """A = -exp(A_log) with A drawn from U[1, 16], as Mamba-2's."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _ssm_dt_bias_init(key, shape, dtype=jnp.float32):
+    """softplus^-1 of a step size drawn log-uniformly from [1e-3, 0.1],
+    as Mamba-2's."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                 * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 mixer (Dao & Gu 2024) as `GraniteMoeHybridMambaLayer`
+    runs it, one B/C group: H heads of size P over a state of N.
+
+        [z | xBC | dt] = u W_in                  d -> HP + (HP + 2N) + H
+        xBC = silu(conv1d_causal_depthwise(xBC, k) + b_conv)
+        [x | B | C] = xBC                        x as (H, P)
+        dt = softplus(dt + dt_bias);  A = -exp(A_log)       per head
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t + D x_t
+        out = (RMSNorm(y * silu(z)) * w) W_out   the gate, then the norm
+                                                 over all H*P features
+
+    The recurrence runs in its chunked form (ops/ssd.py). dt, A, the
+    norm and the conv's sum are float32 inside."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u):
+        from edl_tpu.ops.ssd import ssd_scan
+        cfg = self.cfg
+        bsz, s, _ = u.shape
+        h, p, n, width = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                          cfg.ssm_conv)
+        inner, conv_dim = h * p, h * p + 2 * n
+        with jax.named_scope("ssm_in_proj"):
+            zxbcdt = _dense(inner + conv_dim + h, ("embed", "mlp"), cfg,
+                            name="in_proj")(u)
+            z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], -1)
+        with jax.named_scope("ssm_conv"):
+            # output t sums taps k of input t - (width - 1) + k
+            taps = self.param(
+                "conv_kernel", nn.initializers.variance_scaling(
+                    1.0, "fan_in", "uniform", in_axis=0, out_axis=1),
+                (width, conv_dim))
+            bias = self.param("conv_bias", nn.initializers.zeros,
+                              (conv_dim,))
+            padded = jnp.pad(xbc, ((0, 0), (width - 1, 0), (0, 0)))
+            acc = bias.astype(jnp.float32)
+            for k in range(width):
+                acc = acc + padded[:, k:k + s].astype(jnp.float32) * taps[k]
+            xbc = nn.silu(acc).astype(cfg.dtype)
+            x, b, c = jnp.split(xbc, [inner, inner + n], -1)
+        a_log = self.param("A_log", _ssm_a_log_init, (h,))
+        dt_bias = self.param("dt_bias", _ssm_dt_bias_init, (h,))
+        skip = self.param("D", nn.initializers.ones, (h,))
+        # `ssm_scan` is the scan's alone (ops/ssd.py opens it around its
+        # forward and its backward); the step sizes' softplus and the
+        # skip term are elementwise work beside it, with the gate
+        with jax.named_scope("ssm_gate_norm"):
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+        x = x.reshape(bsz, s, h, p)
+        y = ssd_scan(x, dt, -jnp.exp(a_log.astype(jnp.float32)), b, c,
+                     chunk=cfg.ssm_chunk)
+        with jax.named_scope("ssm_gate_norm"):
+            y = y.astype(jnp.float32) + skip[:, None] * x.astype(jnp.float32)
+            y = y.reshape(bsz, s, inner) * nn.silu(z.astype(jnp.float32))
+            y = RMSNorm(cfg.norm_eps, cfg.dtype, name="norm")(y)
+        with jax.named_scope("ssm_out_proj"):
+            out = _dense(cfg.d_model, ("mlp", "embed"), cfg,
+                         name="out_proj")(y)
+        return cfg.constrain(out, ("batch", "seq", "embed"))
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
+    kind: str = "attention"        # the mixer: | "mamba"
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -494,15 +628,29 @@ class Block(nn.Module):
         # of its own. Scopes are metadata: no parameter path changes.
         with jax.named_scope("ln"):
             h = _norm(cfg, "ln_attn")(x)
-        h = Attention(cfg, name="attn")(h, train)
+        if self.kind == "mamba":
+            h = Mamba2Mixer(cfg, name="ssm")(h)
+        else:
+            h = Attention(cfg, name="attn")(h, train)
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout, deterministic=not train)(h)
+        h = _scaled(h, cfg.residual_scale)
         x = x + h
         with jax.named_scope("ln"):
             h = _norm(cfg, "ln_mlp")(x)
         if cfg.moe:
             with jax.named_scope("mlp"):
                 h = MoEMLP(cfg, name="moe_mlp")(h)
+        elif cfg.mlp_gated:
+            with jax.named_scope("mlp"):
+                gate = _dense(cfg.d_ff, ("embed", "mlp"), cfg,
+                              name="mlp_gate")(h)
+                up = _dense(cfg.d_ff, ("embed", "mlp"), cfg,
+                            name="mlp_up")(h)
+                h = cfg.constrain(nn.silu(gate) * up,
+                                  ("batch", "seq", "mlp"))
+                h = _dense(cfg.d_model, ("mlp", "embed"), cfg,
+                           name="mlp_out")(h)
         else:
             with jax.named_scope("mlp"):
                 h = _dense(cfg.d_ff, ("embed", "mlp"), cfg,
@@ -513,6 +661,7 @@ class Block(nn.Module):
                            name="mlp_out")(h)
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout, deterministic=not train)(h)
+        h = _scaled(h, cfg.residual_scale)
         return x + h
 
 
@@ -546,6 +695,7 @@ class Transformer(nn.Module):
             (cfg.max_len, cfg.d_model)) if cfg.pos == "learned" else None
         with jax.named_scope("embed"):
             x = embed(tokens)
+            x = _scaled(x, cfg.embed_scale)
             if pos_embed is not None:
                 x = x + pos_embed[None, :tokens.shape[1]].astype(cfg.dtype)
         x = cfg.constrain(x, ("batch", "seq", "embed"))
@@ -553,11 +703,17 @@ class Transformer(nn.Module):
         if cfg.remat:
             block = nn.remat(Block, static_argnums=(2,))
         for i in range(cfg.n_layers):
-            x = block(cfg, name=f"block{i}")(x, train)
+            x = block(cfg, cfg.kind(i), name=f"block{i}")(x, train)
         with jax.named_scope("ln"):
             x = _norm(cfg, "ln_final")(x)
         if return_hidden:
             return x
+        if cfg.tie_embeddings:
+            # the head is the embedding table: logits = h E^T / scale
+            with jax.named_scope("lm_head"):
+                return jnp.einsum(
+                    "bsd,vd->bsv", x.astype(jnp.float32),
+                    embed.embedding.astype(jnp.float32)) / cfg.logits_scale
         # Tied-untied head: separate projection, fp32 logits for stable CE.
         logits = nn.DenseGeneral(
             cfg.vocab_size, axis=-1, dtype=jnp.float32, use_bias=False,
@@ -644,7 +800,14 @@ def lm_loss_fused(state, params, batch, *, block_rows: int | None = None,
     # so the (B, S, d) hidden states go in as they are
     targets = jnp.concatenate(
         [tokens[:, 1:], jnp.full_like(tokens[:, :1], -1)], axis=1)
-    kernel = params["lm_head"]["kernel"]
+    if cfg is not None and cfg.tie_embeddings:
+        # the head is the embedding table (V, d), vocabulary-major as
+        # the sweep holds its blocks; the logits' divisor goes onto the
+        # hidden states, which the sweep reads in float32
+        kernel = params["tok_embed"]["embedding"].T
+        hidden = _scaled(hidden, 1.0 / cfg.logits_scale)
+    else:
+        kernel = params["lm_head"]["kernel"]
     xent = cfg.xent if cfg is not None else streamed_lm_xent
     return _with_router_terms(xent(hidden, kernel, targets, block_rows),
                               mutated, moe)
@@ -714,6 +877,36 @@ def olmoe_config(*, vocab_size: int = 50304, d_model: int = 2048,
         moe=True, moe_gated=True, moe_renorm=False, n_experts=n_experts,
         moe_top_k=moe_top_k, moe_aux_weight=0.01 * moe_top_k,
         moe_z_weight=0.001, **kw)
+
+
+def granite_hybrid_config(*, vocab_size: int = 100352, d_model: int = 2048,
+                          n_heads: int = 32, n_layers: int = 40,
+                          d_ff: int = 8192, max_len: int = 131072,
+                          n_kv_heads: int = 8, layer_types=None,
+                          ssm_heads: int = 64, ssm_head_dim: int = 64,
+                          ssm_state: int = 128, ssm_conv: int = 4,
+                          ssm_chunk: int = 256, **kw) -> TransformerConfig:
+    """granite-4.0-h-micro (`model_type: granitemoehybrid`, no experts):
+    RMSNorm pre-norm (eps 1e-5), no positions at all, Mamba-2 mixers
+    with grouped-query attention at every tenth layer (5, 15, 25, 35
+    of 40: ``layer_types`` where not given), a dense SwiGLU MLP, a tied
+    head, and the four multipliers: embedding x 12, logits / 8, residual
+    branches x 0.22, attention scores x 1/64 (not 1/sqrt(64)). ``n_layers`` cuts the published pattern from its
+    start, so a depth of ten is one whole period; the sizes default to
+    the published ones."""
+    if layer_types is None:
+        layer_types = tuple(
+            "attention" if i % 10 == 5 else "mamba"
+            for i in range(n_layers))
+    return TransformerConfig(
+        vocab_size=vocab_size, d_model=d_model, n_heads=n_heads,
+        n_layers=n_layers, d_ff=d_ff, max_len=max_len, norm="rmsnorm",
+        norm_eps=1e-5, pos="none", n_kv_heads=n_kv_heads,
+        attn_scale=0.015625, mlp_gated=True, tie_embeddings=True,
+        embed_scale=12.0, logits_scale=8.0, residual_scale=0.22,
+        layer_types=tuple(layer_types), ssm_heads=ssm_heads,
+        ssm_head_dim=ssm_head_dim, ssm_state=ssm_state, ssm_conv=ssm_conv,
+        ssm_chunk=ssm_chunk, **kw)
 
 
 def choose_remat(cfg: TransformerConfig, batch_size: int,

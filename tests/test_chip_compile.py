@@ -88,10 +88,11 @@ def custom_calls(compiled) -> int:
 
 
 # (batch, seq, heads, head dim): LM-large, the d_model-1024 shape, and
-# the benchmark's cells: d8, a chip of fsdp4, OLMoE
+# the benchmark's cells: d8, a chip of fsdp4, OLMoE, the hybrid's one
+# attention layer (its 8 key/value heads repeated to 32)
 FLASH_SHAPES = [(8, 1024, 16, 128), (16, 1024, 16, 64),
                 (6, 2048, 16, 128), (2, 2048, 16, 128),
-                (4, 4096, 16, 128)]
+                (4, 4096, 16, 128), (2, 8192, 32, 64)]
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
@@ -115,6 +116,28 @@ def test_flash_kernels_compile(one_chip, no_persistent_cache, kernel,
                 q, k, v, o, lse, do, dlse=dlse, **kw),
             x, x, x, x, lse, x, lse)
         assert custom_calls(compiled) == 2
+
+
+def test_chunked_scan_compiles_at_the_hybrid_cells_shape(
+        one_chip, no_persistent_cache):
+    """`ops/ssd.py` forward and written-out backward for one mamba layer
+    of benchmark/configs/granite-4.0-h-micro-p1v4.json (2 x 8192
+    tokens, 64 heads x 64, state 128, chunk 256): plain XLA, no kernel
+    of ours, and the chunk-local (256, 256) products of all 64 chunks
+    and 64 heads pass through under 1.5 GB of temporaries."""
+    from edl_tpu.ops.ssd import ssd_scan
+    b, s, h, p, n = 2, 8192, 64, 64, 128
+    bf16 = jnp.bfloat16
+
+    def loss(x, dt, a, bm, cm):
+        y = ssd_scan(x, dt, a, bm, cm, chunk=256)
+        return jnp.sum(y.astype(F32) ** 2)
+    compiled = compile_for(
+        one_chip, jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+        sds((b, s, h, p), bf16), sds((b, s, h), F32), sds((h,), F32),
+        sds((b, s, n), bf16), sds((b, s, n), bf16))
+    assert custom_calls(compiled) == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
 @pytest.mark.parametrize("rows", [BUCKET_ROWS, RAGGED_ROWS])

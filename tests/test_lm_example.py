@@ -68,6 +68,9 @@ REFUSALS = [
     (["--moe", "--dcn-compress", "int8"], {}, "--moe-compress"),
     (["--moe", "--dcn-compress", "off", "--mesh", "fsdp"],
      {"EDL_TPU_DCN_COMPRESS": "topk"}, "--moe owns the ep mesh"),
+    (["--arch", "granite-hybrid", "--moe"], {}, "--moe conflicts"),
+    (["--arch", "granite-hybrid", "--n-layers", "2", "--layer-types",
+      "mx"], {}, "--layer-types 'mx'"),
     (["--data-dir", "nowhere"], {}, "no train-*.npz"),
 ]
 
