@@ -613,6 +613,96 @@ def flash_against(path: str, fa, shape, qkvdo, blk: int, kw: dict,
            f"{worse or 'none'}")
 
 
+def scan_against_einsums(shape, chunk: int, *, interpret: bool) -> dict:
+    """The chunked scan's two kernels (`ops/ssd.py`) against its einsums
+    on the same bfloat16 inputs, and both against the einsums computed
+    in float32: per output (y, then the gradients of x, dt, A, B, C) the
+    worst |difference| over the largest value. shape: (B, S, H, P, N)."""
+    import jax
+    import jax.numpy as jnp
+
+    from edl_tpu.ops import ssd
+    b, s, h, p, n = shape
+    if not ssd._fits(chunk, h, p, n):
+        raise SystemExit(f"the scan's kernels do not take {shape} in "
+                         f"chunks of {chunk}")
+    key = jax.random.split(jax.random.PRNGKey(0), 6)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    x, dy = (jax.random.normal(k, (b, s // chunk, chunk, h, p), f32)
+             for k in key[:2])
+    bm, cm = (jax.random.normal(k, (b, s // chunk, chunk, n), f32)
+              / n ** 0.25 for k in key[2:4])
+    # step sizes and decay rates as the initialisers spread them
+    dt = jax.nn.softplus(jax.random.normal(
+        key[4], (b, s // chunk, chunk, h)) - 3.0)
+    a = -jnp.exp(jax.random.uniform(key[5], (h,), minval=0.0, maxval=2.7))
+
+    def both(forward, backward, dtype):
+        """y and the five gradients in the dtypes `ssd_scan` hands them
+        on in."""
+        def run(x, dy, bm, cm, dt, a):
+            args = (x.astype(dtype), dt, a, bm.astype(dtype),
+                    cm.astype(dtype))
+            y, prev = forward(*args)
+            grads = backward(*args, prev, dy.astype(dtype))
+            return tuple(g.astype(like.dtype) for g, like in zip(
+                (y, *grads), (args[0], *args)))
+        return jax.jit(run)(x, dy, bm, cm, dt, a)
+    kernels = both(
+        lambda *t: ssd._forward_pallas(*t, interpret=interpret),
+        lambda *t: ssd._backward_pallas(*t, interpret=interpret),
+        bf16)
+    einsums = both(ssd._forward_einsums, ssd._backward_einsums, bf16)
+    with jax.default_matmul_precision("highest"):
+        exact = both(ssd._forward_einsums, ssd._backward_einsums, f32)
+
+    def gap(got, want):
+        want = want.astype(f32)
+        return float(jnp.max(jnp.abs(got.astype(f32) - want))
+                     / (jnp.max(jnp.abs(want)) + 1e-30))
+    names = ("y", "dx", "d_dt", "d_a", "dB", "dC")
+    return {"kernels_vs_einsums": dict(zip(names, map(
+                gap, kernels, einsums))),
+            "kernels_vs_float32": dict(zip(names, map(gap, kernels, exact))),
+            "einsums_vs_float32": dict(zip(names, map(gap, einsums, exact)))}
+
+
+def scan_verdict(report, shape, chunk, *, interpret: bool) -> dict:
+    """`scan_against_einsums`, held, output by output: the kernels no
+    further from float32 than 1.5 times what the einsums are plus 1e-3
+    of the largest value, and no further from the einsums than bfloat16
+    allows (3e-2) plus the einsums' own distance from float32 (on the
+    chip the einsums' dA is a few per cent off, the kernels' is not)."""
+    gaps = scan_against_einsums(shape, chunk, interpret=interpret)
+    far = gaps["einsums_vs_float32"]
+    ok_ = all(gaps["kernels_vs_float32"][k] <= 1.5 * far[k] + 1e-3
+              and gaps["kernels_vs_einsums"][k] <= 3e-2 + far[k]
+              for k in far)
+    report(f"ssd scan {shape} chunk {chunk}", ok_, "; ".join(
+        f"{k} " + " ".join(f"{name} {v:.1e}" for name, v in row.items())
+        for k, row in gaps.items()))
+    return gaps
+
+
+def reporter():
+    """(report(name, ok, detail), the names that failed so far)."""
+    failures = []
+
+    def report(name, ok_, detail):
+        print(f"{'ok  ' if ok_ else 'FAIL'} {name}: {detail}", flush=True)
+        if not ok_:
+            failures.append(name)
+    return report, failures
+
+
+def child_scan_rehearsal() -> dict:
+    """`--rehearse-cpu`: the scan's comparison in interpret mode at a
+    small shape (two head blocks, three chunks)."""
+    report, failures = reporter()
+    gaps = scan_verdict(report, (2, 384, 16, 64, 128), 128, interpret=True)
+    return {"ok": not failures, "failed": failures, "scan": gaps}
+
+
 def child_kernels(other_flash: str = "") -> dict:
     """Each Pallas kernel against its XLA expression, on the device, at
     LM-large shapes and a 4 MiB bucket (plus a ragged one). With
@@ -636,12 +726,7 @@ def child_kernels(other_flash: str = "") -> dict:
     if not (pack._use_pallas() and ok._use_pallas()) or ok._interpret():
         raise SystemExit("on a TPU, yet a kernel family is off its "
                          "compiled Pallas path")
-    failures = []
-
-    def report(name, ok_, detail):
-        print(f"{'ok  ' if ok_ else 'FAIL'} {name}: {detail}", flush=True)
-        if not ok_:
-            failures.append(name)
+    report, failures = reporter()
 
     def diff(a, b):
         return float(jnp.max(jnp.abs(a.astype(jnp.float32)
@@ -677,6 +762,10 @@ def child_kernels(other_flash: str = "") -> dict:
         if other_flash:
             flash_against(other_flash, fa, shape, (q, k, v, do), blk, kw,
                           report)
+
+    # the chunked scan's kernels at the hybrid cell's shape
+    scan = scan_verdict(report, (2, 8192, 64, 64, 128), 256,
+                        interpret=False)
 
     rng = np.random.default_rng(0)
 
@@ -749,7 +838,7 @@ def child_kernels(other_flash: str = "") -> dict:
                 report(f"fused {name} {quant} n={n}",
                        all(r[0] for r in res),
                        "p,moments: " + ", ".join(r[1] for r in res))
-    return {"ok": not failures, "failed": failures,
+    return {"ok": not failures, "failed": failures, "scan": scan,
             "device": {"platform": dev.platform, "kind": dev.device_kind,
                        "count": jax.device_count()}}
 
@@ -841,6 +930,9 @@ def main() -> int:
     if args.child == "kernels":
         print(json.dumps(child_kernels(args.other_flash)))
         return 0
+    if args.child == "scan-rehearsal":
+        print(json.dumps(child_scan_rehearsal()))
+        return 0
     tpu = not args.rehearse_cpu
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
@@ -863,11 +955,13 @@ def main() -> int:
             device = phase_train(work, env, tpu=tpu,
                                  lm_args=lm_args)["device"]
         else:
-            if tpu:
-                say("phase kernels")
-                res = run_child("kernels", env, 600)
-                check(res["ok"], "every Pallas kernel matches its XLA "
-                      f"expression on the chip (failed: {res['failed']})")
+            say("phase kernels")
+            res = run_child("kernels" if tpu else "scan-rehearsal", env,
+                            600)
+            check(res["ok"], "every Pallas kernel matches its XLA "
+                  f"expression on the chip (failed: {res['failed']})"
+                  if tpu else "the scan's kernels match its einsums in "
+                  "interpret mode")
             say("phase train")
             device = phase_train(work, env, tpu=tpu,
                                  lm_args=lm_args)["device"]

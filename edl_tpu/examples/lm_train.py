@@ -397,8 +397,9 @@ def main(argv=None) -> int:
         from edl_tpu.ops import ssd
         log.info("hybrid: layers %s, %s, kv heads %d of %d",
                  "".join(k[0] for k in cfg.layer_types),
-                 ssd.describe(cfg.ssm_chunk, cfg.ssm_heads,
-                              cfg.ssm_head_dim, cfg.ssm_state),
+                 ssd.describe(min(cfg.ssm_chunk, args.seq_len),
+                              cfg.ssm_heads, cfg.ssm_head_dim,
+                              cfg.ssm_state),
                  cfg.kv_heads, cfg.n_heads)
     if args.fused_loss:
         from edl_tpu.ops import fused_xent

@@ -186,15 +186,138 @@ def test_scan_refuses_a_ragged_sequence_and_takes_a_short_one():
                          - walked(*short)).max()) < 1e-4
 
 
-def test_scan_backward_holds_no_square_tensor():
+@pytest.mark.parametrize("path", ["einsums", "kernels"])
+def test_scan_backward_holds_no_square_tensor(path):
     """The residuals are the inputs and one state a chunk and head: no
-    (Q, Q) array is saved between the forward and the backward."""
-    args, _ = scan_inputs(3, jnp.float32)
-    _, vjp = jax.vjp(lambda *t: ssd.ssd_scan(*t, chunk=16), *args)
+    (Q, Q) array is saved between the forward and the backward, on the
+    einsums' path or on the kernels'."""
+    if path == "einsums":
+        (args, _), chunk, states = scan_inputs(3, jnp.float32), 16, \
+            (2, 3, 3, 4, 8)                     # (B, chunks, H, P, N)
+    else:
+        (args, _), chunk, states = kernel_inputs(jnp.float32), K_CHUNK, \
+            (2, 3, K_HEADS, K_P, K_N)
+    with ssd.force_interpret_kernels():
+        _, vjp = jax.vjp(lambda *t: ssd.ssd_scan(*t, chunk=chunk), *args)
     saved = [leaf.shape for leaf in jax.tree.leaves(vjp)
              if hasattr(leaf, "shape")]
-    assert (2, 3, 3, 4, 8) in saved        # (B, chunks, H, P, N)
-    assert not any(s[-2:] == (16, 16) for s in saved if len(s) >= 2)
+    assert states in saved
+    assert not any(s[-2:] == (chunk, chunk) for s in saved if len(s) >= 2)
+
+
+# -- the scan's kernels, in interpret mode ------------------------------------
+#
+# The smallest shape the kernels' tiles take that has several chunks,
+# two blocks of heads and batch 2: chunks of 128, a state of 128, 16
+# heads of 64. Against the einsums the kernels differ in the order of
+# float32 sums alone (their operands are rounded at the same points):
+# `TOL` and ten times `TOL` as above, and in bfloat16 one rounding of an
+# output (2^-8 of a value) where the float32 sums fall on either side.
+
+K_CHUNK, K_HEADS, K_P, K_N = 128, 16, 64, 128
+
+
+def kernel_inputs(dtype, chunks=3, decay_at_ends=False):
+    (x, dt, a, bm, cm), w = scan_inputs(
+        chunks, dtype, chunk=K_CHUNK, h=K_HEADS, p=K_P, n=K_N)
+    dt = dt * 0.05                      # step sizes of the published size
+    if decay_at_ends:
+        # a large decay at every chunk's last position: what a chunk's
+        # end state carries on (`d_last`) is then most of d(cs) there
+        dt = dt.at[:, K_CHUNK - 1::K_CHUNK].mul(40.0)
+    bm, cm = bm / K_N ** 0.25, cm / K_N ** 0.25
+    return (x, dt, a, bm, cm), w
+
+
+def outputs(args, w, chunk=K_CHUNK):
+    """y, and the gradients of sum(y * w) for x, dt, A, B and C."""
+    y, vjp = jax.vjp(lambda *t: ssd.ssd_scan(*t, chunk=chunk), *args)
+    return (y, *vjp(w.astype(y.dtype)))
+
+
+@pytest.mark.parametrize("decay_at_ends", [False, True],
+                         ids=["spread", "decay_at_chunk_ends"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_scan_kernels_are_the_einsums(dtype, decay_at_ends):
+    """y and the gradients of x, dt, A, B and C through the two Pallas
+    kernels, against the einsums on the same inputs."""
+    args, w = kernel_inputs(dtype, decay_at_ends=decay_at_ends)
+    ref = outputs(args, w)
+    with ssd.force_interpret_kernels():
+        mine = outputs(args, w)
+    for name, g, r in zip(("y", "x", "dt", "a", "b", "c"), mine, ref):
+        assert g.dtype == r.dtype and g.shape == r.shape
+        g, r = g.astype(jnp.float32), r.astype(jnp.float32)
+        size = float(jnp.abs(r).max())
+        tol = 2.0 ** -7 if dtype == jnp.bfloat16 else \
+            TOL if name == "y" else 10 * TOL
+        assert float(jnp.abs(g - r).max()) < tol * size, (name, size)
+
+
+@pytest.mark.parametrize("decay_at_ends", [False, True],
+                         ids=["spread", "decay_at_chunk_ends"])
+def test_scan_kernels_are_the_recurrence(decay_at_ends):
+    """The same through the kernels against autodiff through the
+    recurrence walked one position at a time, in float32."""
+    args, w = kernel_inputs(jnp.float32, chunks=2,
+                            decay_at_ends=decay_at_ends)
+    with ssd.force_interpret_kernels():
+        mine = outputs(args, w)
+    ref = (walked(*args), *jax.grad(lambda *t: jnp.sum(walked(*t) * w),
+                                    argnums=(0, 1, 2, 3, 4))(*args))
+    for name, g, r in zip(("y", "x", "dt", "a", "b", "c"), mine, ref):
+        size = float(jnp.abs(r).max())
+        assert float(jnp.abs(g - r).max()) < 10 * TOL * size, (name, size)
+
+
+@pytest.mark.parametrize("hooked, chunk, path", [
+    (False, K_CHUNK, "xla einsums"),
+    (True, K_CHUNK, "pallas kernel, interpret mode"),
+    (True, 96, "xla einsums"),          # a chunk the tiles do not divide
+    (True, 16, "xla einsums")])
+def test_scan_takes_the_path_it_can_see(hooked, chunk, path):
+    """Off a TPU the einsums run, the kernels only under the tests'
+    hook and only where the shapes meet their tiles; `describe` says
+    which, and the trace holds a kernel or none."""
+    import contextlib
+    (x, dt, a, bm, cm), _ = kernel_inputs(jnp.float32, chunks=3)
+    s = 3 * chunk
+    args = (x[:, :s], dt[:, :s], a, bm[:, :s], cm[:, :s])
+    hook = ssd.force_interpret_kernels() if hooked \
+        else contextlib.nullcontext()
+    with hook:
+        said = ssd.describe(chunk, K_HEADS, K_P, K_N)
+        jaxpr = str(jax.make_jaxpr(
+            lambda *t: ssd.ssd_scan(*t, chunk=chunk))(*args))
+    assert said.endswith(f"({path})") and f"ssd chunk {chunk}," in said
+    assert ("pallas_call" in jaxpr) == path.startswith("pallas")
+
+
+@pytest.mark.parametrize("seam", ["forward", "backward"])
+def test_the_seams_bite_on_the_kernels_path(seam, monkeypatch):
+    """What the benchmark's controls patch (`ssd._forward`, and
+    `ssd._ssd_bwd` re-registered on `ssd._ssd`) changes the result when
+    the kernels run, as it does on the einsums."""
+    args, w = kernel_inputs(jnp.float32, chunks=2)
+    with ssd.force_interpret_kernels():
+        mine = outputs(args, w)
+        if seam == "forward":
+            forward = ssd._forward
+            monkeypatch.setattr(ssd, "_forward", lambda *t: (
+                0 * forward(*t)[0], forward(*t)[1]))
+            other = outputs(args, w)
+            moved = float(jnp.abs(mine[0] - other[0]).max())
+        else:
+            backward = ssd._ssd_bwd
+            ssd._ssd.defvjp(ssd._ssd_fwd, lambda chunk, res, dy: tuple(
+                2 * g for g in backward(chunk, res, dy)))
+            try:
+                other = outputs(args, w)
+            finally:
+                ssd._ssd.defvjp(ssd._ssd_fwd, ssd._ssd_bwd)
+            assert float(jnp.abs(mine[0] - other[0]).max()) == 0.0
+            moved = float(jnp.abs(mine[2] - other[2]).max())
+    assert moved > 0.1, moved
 
 
 # -- the layers -------------------------------------------------------------
