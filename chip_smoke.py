@@ -734,13 +734,22 @@ def child_kernels(other_flash: str = "") -> dict:
 
     # flash attention fwd/bwd vs the XLA blockwise scan (bf16 tolerance):
     # LM-large, d_model 1024, and the benchmark's shapes (d8, OLMoE)
-    for shape in ((8, 1024, 16, 128), (16, 1024, 16, 64),
-                  (6, 2048, 16, 128), (4, 4096, 16, 128)):
+    # and the afmoe cell's: its global layer, and its sliding layers
+    # with the window's block skipping on both sides of the band
+    for shape, window in (((8, 1024, 16, 128), None),
+                          ((16, 1024, 16, 64), None),
+                          ((6, 2048, 16, 128), None),
+                          ((4, 4096, 16, 128), None),
+                          ((2, 8192, 32, 128), None),
+                          ((2, 8192, 32, 128), 2048)):
         key = jax.random.PRNGKey(0)
         q, k, v, do = (jax.random.normal(jax.random.fold_in(key, i), shape,
                                          jnp.bfloat16) for i in range(4))
         kw = dict(scale=shape[-1] ** -0.5, causal=True)
         blk = fa._fit_block(shape[1], 512)
+        if window:  # `shape` from here on is the report's label
+            kw["window"] = window
+            shape = f"{shape} window {window}"
         o_x, lse_x = jax.jit(lambda q, k, v: fa._fwd_blockwise(
             q, k, v, blk=blk, **kw))(q, k, v)
         o_k, lse_k = jax.jit(lambda q, k, v: fa._fwd(
@@ -759,9 +768,45 @@ def child_kernels(other_flash: str = "") -> dict:
             b.astype(jnp.float32)))) + 1e-6) for a, b in zip(g_k, g_x))
         report(f"flash bwd {shape}", worst < 3e-2,
                f"max relative |dq,dk,dv diff| {worst:.2e}")
-        if other_flash:
+        if other_flash and not window:
             flash_against(other_flash, fa, shape, (q, k, v, do), blk, kw,
                           report)
+        if q.shape[1] == 8192:  # what a layer of the afmoe cell costs
+            fwd = jax.jit(lambda q, k, v: fa._fwd(
+                q, k, v, blk_q=blk, blk_k=blk, interpret=False, **kw))
+            bwd = jax.jit(lambda *a: fa._bwd_pallas(
+                *a, blk_q=blk, blk_k=blk, dlse=None, interpret=False, **kw))
+            took = []
+            for fn, args in ((fwd, (q, k, v)),
+                             (bwd, (q, k, v, o_x, lse_x, do))):
+                jax.block_until_ready(fn(*args))
+                t0 = time.perf_counter()
+                for _ in range(10):
+                    out = fn(*args)
+                jax.block_until_ready(out)
+                took.append((time.perf_counter() - t0) / 10 * 1e3)
+            print(f"flash {shape}: forward {took[0]:.2f} ms, backward "
+                  f"{took[1]:.2f} ms a call (host clock, 10 calls)",
+                  flush=True)
+    # the window once more against attention with the whole masked
+    # (S, S) scores in float32, at the cell's length, two heads
+    from edl_tpu.parallel.ring_attention import dense_attention
+    q, k, v, do = (jax.random.normal(jax.random.fold_in(key, i),
+                                     (1, 8192, 2, 128), jnp.bfloat16)
+                   for i in range(4))
+
+    def pulled(fn):
+        return jax.jit(lambda q, k, v: jax.vjp(
+            lambda *a: fn(*a, window=2048), q, k, v)[1](do))(q, k, v)
+    worst = max(diff(a, b) / (float(jnp.max(jnp.abs(
+        b.astype(jnp.float32)))) + 1e-6) for a, b in zip(
+        pulled(fa.flash_attention), pulled(dense_attention)))
+    o_k = jax.jit(lambda *a: fa.flash_attention(*a, window=2048))(q, k, v)
+    o_d = jax.jit(lambda *a: dense_attention(*a, window=2048))(q, k, v)
+    report("flash window 2048 against dense masked (1, 8192, 2, 128)",
+           diff(o_k, o_d) < 3e-2 and worst < 3e-2,
+           f"max|o diff| {diff(o_k, o_d):.2e} max relative |dq,dk,dv diff| "
+           f"{worst:.2e}")
 
     # the chunked scan's kernels at the hybrid cell's shape
     scan = scan_verdict(report, (2, 8192, 64, 64, 128), 256,

@@ -29,8 +29,9 @@ import optax
 
 from edl_tpu.data.pipeline import DataLoader, FileSource
 from edl_tpu.models.transformer import (Transformer, TransformerConfig,
-                                        granite_hybrid_config, lm_loss_fn,
-                                        lm_loss_fused, olmoe_config)
+                                        afmoe_config, granite_hybrid_config,
+                                        lm_loss_fn, lm_loss_fused,
+                                        olmoe_config)
 from edl_tpu.obs import trace
 from edl_tpu.parallel import distributed, mesh as mesh_lib, sharding as shd
 from edl_tpu.train import lr as lr_lib
@@ -123,7 +124,7 @@ def main(argv=None) -> int:
                              "(default $EDL_TPU_COMM_BUCKET_MB, else 0 "
                              "= XLA's single fused reduction)")
     parser.add_argument("--arch", choices=("gpt2", "olmoe",
-                                           "granite-hybrid"),
+                                           "granite-hybrid", "afmoe"),
                         default="gpt2",
                         help="the block: gpt2 = LayerNorm, learned "
                              "positions, gelu; olmoe = models.transformer."
@@ -134,14 +135,37 @@ def main(argv=None) -> int:
                              "granite_hybrid_config (RMSNorm, no positions, "
                              "Mamba-2 mixers with grouped-query attention "
                              "among them, dense SwiGLU, a tied head, the "
-                             "four multipliers; --d-ff is the MLP's width)")
+                             "four multipliers; --d-ff is the MLP's width); "
+                             "afmoe = models.transformer.afmoe_config "
+                             "(Trinity-Mini: sliding-window and global "
+                             "attention mixed, gated, 4 key/value heads of "
+                             "128, four norms a block, sigmoid routing over "
+                             "score + bias, a shared expert, leading dense "
+                             "layers of width --d-ff; implies --moe, 128 "
+                             "experts, 8 a token unless given; one chip, "
+                             "which holds --experts-held of them)")
     parser.add_argument("--layer-types", default="",
-                        help="granite-hybrid: one letter a layer, m = "
+                        help="one letter a layer. granite-hybrid: m = "
                              "mamba, a = attention (default: the "
                              "published pattern, attention at layers 5, "
-                             "15, 25, ..., cut to --n-layers). The "
+                             "15, 25, ..., cut to --n-layers; the "
                              "mixers' sizes and the 8 key/value heads are "
-                             "granite_hybrid_config's own")
+                             "granite_hybrid_config's own). afmoe: s = "
+                             "sliding window, f = full (default: sssf "
+                             "repeated, cut to --n-layers; the head size, "
+                             "the key/value heads, an expert's width and "
+                             "the routing are afmoe_config's own)")
+    parser.add_argument("--experts-held", type=int, default=0,
+                        help="afmoe: the experts this chip holds, the "
+                             "first of --n-experts (default all): the "
+                             "router and top-k stay over all of them, and "
+                             "the layer computes the held ones' part")
+    parser.add_argument("--dense-layers", type=int, default=None,
+                        help="afmoe: leading layers with a dense MLP of "
+                             "width --d-ff (default the published 2)")
+    parser.add_argument("--window", type=int, default=0,
+                        help="afmoe: keys a sliding layer's query sees "
+                             "(default the published 2048)")
     parser.add_argument("--moe", action="store_true",
                         help="mixture-of-experts FFNs. One device: "
                              "dropless sort-and-gather dispatch into "
@@ -205,10 +229,20 @@ def main(argv=None) -> int:
                         help="jax profiler trace dir (steps 10-15, rank 0)")
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(argv)
-    args.moe = args.moe or args.arch == "olmoe"
+    args.moe = args.moe or args.arch in ("olmoe", "afmoe")
     if args.moe and args.arch == "granite-hybrid":
         raise SystemExit("--arch granite-hybrid has a dense MLP "
                          "(num_local_experts 0); --moe conflicts")
+    afmoe_only = {"--experts-held": args.experts_held, "--dense-layers":
+                  args.dense_layers, "--window": args.window}
+    if args.arch != "afmoe" and any(afmoe_only.values()):
+        raise SystemExit(
+            f"{', '.join(k for k, v in afmoe_only.items() if v)}: only "
+            f"--arch afmoe has such a size; --arch {args.arch} conflicts")
+    if args.arch == "afmoe" and (args.moe_dispatch or args.moe_compress):
+        raise SystemExit(
+            "--arch afmoe computes one chip's share of the experts and "
+            "has no exchange: --moe-dispatch / --moe-compress conflict")
     if args.profile:
         trace.collect(args.profile)  # spans from here on, start-up's too
 
@@ -308,6 +342,25 @@ def main(argv=None) -> int:
             arch_kw["layer_types"] = tuple(
                 {"m": "mamba", "a": "attention"}[c]
                 for c in args.layer_types)
+    elif args.arch == "afmoe":
+        make_cfg = afmoe_config
+        arch_kw = {k: v for k, v in (
+            ("n_experts", args.n_experts), ("moe_top_k", args.moe_top_k),
+            ("experts_held", args.experts_held), ("window", args.window))
+            if v}
+        if args.dense_layers is not None:
+            arch_kw["n_dense_layers"] = args.dense_layers
+        if args.layer_types:
+            if set(args.layer_types) - set("sf"):
+                raise SystemExit(f"--layer-types {args.layer_types!r}: one "
+                                 "letter a layer, s (sliding) or f (full)")
+            arch_kw["layer_types"] = tuple(
+                {"s": "sliding", "f": "full"}[c] for c in args.layer_types)
+        if jax.device_count() > 1:
+            raise SystemExit(
+                f"--arch afmoe trains one chip's share of the experts "
+                f"(--experts-held) with no exchange between chips; "
+                f"{jax.device_count()} devices conflict")
     elif args.moe:
         arch_kw = dict(moe=True,
                       n_experts=args.n_experts or 2 * jax.device_count(),
@@ -348,8 +401,11 @@ def main(argv=None) -> int:
     variables = shd.init_sharded(
         lambda: model.init(jax.random.PRNGKey(args.seed), toks0,
                            train=False), mesh)
+    # what no gradient trains (afmoe's routing bias) rides the state
+    # where a BatchNorm's statistics do
     state = TrainState.create(apply_fn=model.apply,
-                              params=variables["params"], tx=tx)
+                              params=variables["params"], tx=tx,
+                              batch_stats=variables.get("batch_stats"))
     jax.block_until_ready(state)
     startup.done("state_init")
     # a moe model's loss takes its routers' terms, weighted by its config
@@ -393,7 +449,18 @@ def main(argv=None) -> int:
              "ring" if cfg.use_ring else
              "flash" if cfg.use_flash(args.seq_len) else "dense")
     log.info("state bytes per device: %s", shd.bytes_per_device(state))
-    if cfg.layer_types:
+    if args.arch == "afmoe":
+        dense = cfg.n_dense_layers
+        log.info("afmoe: layers %s|%s, window %d, %d q / %d kv heads x %d, "
+                 "experts %d-%d of %d held, top-%d %s x %s, %d shared",
+                 "d" * dense,
+                 "".join(k[0] for k in cfg.layer_types[dense:]), cfg.window,
+                 cfg.n_heads, cfg.kv_heads, cfg.head_dim,
+                 cfg.experts_offset,
+                 cfg.experts_offset + cfg.held_experts - 1, cfg.n_experts,
+                 cfg.moe_top_k, cfg.moe_score, cfg.moe_route_scale,
+                 cfg.moe_shared)
+    elif cfg.layer_types:
         from edl_tpu.ops import ssd
         log.info("hybrid: layers %s, %s, kv heads %d of %d",
                  "".join(k[0] for k in cfg.layer_types),

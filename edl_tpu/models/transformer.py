@@ -94,6 +94,32 @@ class TransformerConfig:
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # -- what a mixture with sliding-window and global attention layers,
+    # a shared expert and a routing bias adds (`afmoe_config` sets them
+    # all; the defaults are the blocks above, untouched). layer_types
+    # then also knows "sliding" (causal over the `window` newest keys,
+    # positions as `pos` says) and "full" (every earlier key, and no
+    # positions whatever `pos` says).
+    head_size: int = 0             # 0 = d_model // n_heads
+    window: int = 0                # keys a "sliding" layer's query sees
+    qk_norm_heads: bool = False    # RMSNorm on q and k over each head
+    attn_gate: bool = False        # heads' output * sigmoid(x W_g)
+    sandwich_norm: bool = False    # mixer and MLP outputs normed too
+    n_dense_layers: int = 0        # moe: leading blocks keep a dense MLP
+    moe_d_ff: int = 0              # an expert's width; 0 = d_ff
+    moe_score: str = "softmax"     # | "sigmoid"
+    moe_route_scale: float = 1.0   # the kept gates are multiplied by it
+    moe_shared: int = 0            # shared experts, added to every token
+    # moe_bias_rate > 0: top-k is taken over score + bias, the gates
+    # from the scores alone; the bias (E floats a layer, collection
+    # "batch_stats": state that no gradient trains) moves by this much a
+    # step against each expert's load
+    moe_bias_rate: float = 0.0
+    # the chip's share of the experts: the router and top-k are over all
+    # n_experts, the tables hold experts_held of them from
+    # experts_offset on, and the layer computes their part of the result
+    experts_held: int = 0          # 0 = n_experts
+    experts_offset: int = 0
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -105,12 +131,14 @@ class TransformerConfig:
         if self.layer_types is not None:
             kinds = tuple(self.layer_types)
             if len(kinds) != self.n_layers or not set(kinds) <= {
-                    "attention", "mamba"}:
+                    "attention", "mamba", "sliding", "full"}:
                 raise ValueError(
                     f"layer_types must name n_layers={self.n_layers} "
-                    f"kinds of attention|mamba, got {kinds}")
+                    f"kinds of attention|mamba|sliding|full, got {kinds}")
             if "mamba" in kinds and self.ssm_heads < 1:
                 raise ValueError("mamba layers need ssm_heads >= 1")
+            if "sliding" in kinds and self.window < 1:
+                raise ValueError("sliding layers need window >= 1")
         if self.n_kv_heads and self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"n_kv_heads={self.n_kv_heads} must divide n_heads="
@@ -127,11 +155,29 @@ class TransformerConfig:
                 raise ValueError(
                     f"moe_capacity_factor must be > 0, got "
                     f"{self.moe_capacity_factor}")
+            if self.moe_score not in ("softmax", "sigmoid"):
+                raise ValueError(f"unknown moe_score={self.moe_score!r} "
+                                 "(softmax|sigmoid)")
+            if not (0 <= self.experts_offset and self.experts_offset
+                    + self.held_experts <= self.n_experts):
+                raise ValueError(
+                    f"experts {self.experts_offset} to "
+                    f"{self.experts_offset + self.held_experts - 1} are "
+                    f"not among n_experts={self.n_experts}")
 
     @property
     def head_dim(self) -> int:
+        if self.head_size:
+            return self.head_size
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    def moe_layer(self, layer: int) -> bool:
+        return self.moe and layer >= self.n_dense_layers
 
     @property
     def kv_heads(self) -> int:
@@ -166,12 +212,13 @@ class TransformerConfig:
                      or all(self.mesh.shape.get(a, 1) == 1
                             for a in ("tp", "sp"))))
 
-    def flash(self, q, k, v):
+    def flash(self, q, k, v, window: int | None = None):
         """Flash attention, shard_mapped over the mesh's batch axes —
         a pallas_call is opaque to the XLA partitioner, so without this
         a dp-sharded input would be gathered to every device."""
         from edl_tpu.ops.flash_attention import flash_attention
-        fn = partial(flash_attention, causal=True, scale=self.attn_scale)
+        fn = partial(flash_attention, causal=True, scale=self.attn_scale,
+                     window=window)
         if self.mesh is None or all(s == 1 for s in
                                     self.mesh.shape.values()):
             return fn(q, k, v)
@@ -280,11 +327,15 @@ def rope(x: jax.Array, theta: float) -> jax.Array:
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    kind: str = "attention"        # | "sliding" | "full"
 
     @nn.compact
     def __call__(self, x, train: bool = True):
         cfg = self.cfg
         b, s, _ = x.shape
+        window = cfg.window if self.kind == "sliding" else None
+        if window is not None and cfg.use_ring:
+            raise ValueError("ring attention has no sliding window")
         proj = partial(nn.DenseGeneral, axis=-1, dtype=cfg.dtype,
                        use_bias=False)
         qkv_init = nn.with_logical_partitioning(
@@ -301,7 +352,11 @@ class Attention(nn.Module):
                 q.reshape(b, s, -1)).reshape(q.shape)
             k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(
                 k.reshape(b, s, -1)).reshape(k.shape)
-        if cfg.pos == "rope":
+        if cfg.qk_norm_heads:  # over each head's features, one scale
+            with jax.named_scope("attn_qk_norm"):
+                q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(q)
+                k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(k)
+        if cfg.pos == "rope" and self.kind != "full":
             with jax.named_scope("rope"):
                 q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
         if cfg.kv_heads != cfg.n_heads:
@@ -319,11 +374,17 @@ class Attention(nn.Module):
             o = ra.ring_attention(q, k, v, mesh=cfg.mesh, causal=True,
                                   scale=cfg.attn_scale)
         elif cfg.use_flash(s):
-            o = cfg.flash(q, k, v)
+            o = cfg.flash(q, k, v, window)
         else:
             o = ra.dense_attention(q, k, v, causal=True,
-                                   scale=cfg.attn_scale)
+                                   scale=cfg.attn_scale, window=window)
         o = cfg.constrain(o, ("batch", "seq", "heads", "kv"))
+        if cfg.attn_gate:
+            with jax.named_scope("attn_gate"):
+                gate = proj((cfg.n_heads, cfg.head_dim),
+                            kernel_init=qkv_init, name="gate")(x)
+                o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(cfg.dtype)
 
         out_init = nn.with_logical_partitioning(
             nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
@@ -468,6 +529,9 @@ class MoEMLP(nn.Module):
         cfg = self.cfg
         b, s, d = x.shape
         e, k = cfg.n_experts, cfg.moe_top_k
+        held, first = cfg.held_experts, cfg.experts_offset
+        share = held != e              # some experts live on other chips
+        width = cfg.moe_d_ff or cfg.d_ff
         t = b * s
         router = self.param(
             "router",
@@ -480,12 +544,12 @@ class MoEMLP(nn.Module):
 
         def table(name, d_in, d_out, axes):
             return self.param(name, nn.with_logical_partitioning(
-                table_init, ("expert", *axes)), (e, d_in, d_out))
+                table_init, ("expert", *axes)), (held, d_in, d_out))
         names = ("w_gate", "w_up") if cfg.moe_gated else ("w_in",)
-        tables = [table(n, cfg.d_model, cfg.d_ff, ("embed", "mlp"))
+        tables = [table(n, cfg.d_model, width, ("embed", "mlp"))
                   for n in names]
         tables.append(table("w_down" if cfg.moe_gated else "w_out",
-                            cfg.d_ff, cfg.d_model, ("mlp", "embed")))
+                            width, cfg.d_model, ("mlp", "embed")))
 
         xf = x.reshape(t, d)
         with jax.named_scope("moe_router"):
@@ -493,6 +557,10 @@ class MoEMLP(nn.Module):
                                 router.astype(jnp.float32))
         wire = cfg.moe_wire
         if wire is not None:
+            if share or cfg.moe_score != "softmax" or cfg.moe_shared:
+                raise ValueError(
+                    "the wire's capacity router knows softmax gates over "
+                    "experts that are all held, and no shared expert")
             cap = moe_capacity(t, e, k, cfg.moe_capacity_factor)
             combine, dispatch, aux = router_topk(logits, k, cap)
             self.sow("intermediates", "moe_aux", aux["load_balance"])
@@ -507,35 +575,91 @@ class MoEMLP(nn.Module):
             return y.reshape(b, s, d)
 
         with jax.named_scope("moe_router"):
-            probs = jax.nn.softmax(logits, axis=-1)
-            gate, idx = jax.lax.top_k(probs, k)             # (T, k)
-            if cfg.moe_renorm:
+            if cfg.moe_score == "sigmoid":
+                probs = jax.nn.sigmoid(logits)
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)
+            if cfg.moe_bias_rate > 0:
+                # the bias chooses and never weighs: the gates are the
+                # scores of the chosen, as they are
+                bias = self.variable("batch_stats", "expert_bias",
+                                     jnp.zeros, (e,), jnp.float32)
+                _, idx = jax.lax.top_k(
+                    probs + jax.lax.stop_gradient(bias.value), k)
+                gate = jnp.take_along_axis(probs, idx, axis=-1)
+            else:
+                gate, idx = jax.lax.top_k(probs, k)         # (T, k)
+            if cfg.moe_score == "sigmoid":
+                gate = gate / (jnp.sum(gate, -1, keepdims=True) + 1e-20)
+            elif cfg.moe_renorm:
                 gate = gate / jnp.maximum(
                     jnp.sum(gate, -1, keepdims=True), 1e-9)
+            gate = _scaled(gate, cfg.moe_route_scale)
             counts = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.int32),
                              axis=(0, 1))                   # (E,)
             # what the loss pools over the layers (`_moe_terms`)
             self.sow("intermediates", "moe_frac", counts / (t * k))
-            self.sow("intermediates", "moe_probs", jnp.mean(probs, 0))
-            self.sow("intermediates", "moe_z", jnp.mean(jnp.square(
-                jax.nn.logsumexp(logits, axis=-1))))
+            # the experts of every token, for whoever asks (a checker
+            # that takes gradients on the same assignments)
+            self.sow("intermediates", "moe_idx", idx)
+            if cfg.moe_score == "softmax":
+                self.sow("intermediates", "moe_probs", jnp.mean(probs, 0))
+                self.sow("intermediates", "moe_z", jnp.mean(jnp.square(
+                    jax.nn.logsumexp(logits, axis=-1))))
             self.sow("intermediates", "moe_dropped",
                      jnp.zeros((), jnp.float32))
+        if cfg.moe_bias_rate > 0 and not self.is_initializing() \
+                and self.is_mutable_collection("batch_stats"):
+            with jax.named_scope("moe_bias_update"):
+                # towards the mean load, by the sign alone, centred
+                load = counts.astype(jnp.float32)
+                delta = cfg.moe_bias_rate * jnp.sign(jnp.mean(load) - load)
+                bias.value = bias.value + (delta - jnp.mean(delta))
         with jax.named_scope("moe_dispatch"):
             # assignment a = token * k + slot; a stable sort by expert
             # keeps the tokens of one expert in token order
-            order = jnp.argsort(idx.reshape(t * k), stable=True)
+            key = idx.reshape(t * k)
+            if share:
+                # this chip's experts first, the absent ones' rows after
+                # them in no group: neither computed nor dropped
+                local = key - first
+                here = (local >= 0) & (local < held)
+                key = jnp.where(here, local, held)
+                counts = jax.lax.dynamic_slice_in_dim(counts, first, held)
+                gate = jnp.where(here.reshape(t, k), gate, 0.0)
+                self.sow("intermediates", "moe_held",
+                         jnp.sum(counts) / (t * k))
+            order = jnp.argsort(key, stable=True)
             inv = jnp.zeros_like(order).at[order].set(
                 jnp.arange(t * k, dtype=order.dtype), unique_indices=True)
             rows = _dispatch_rows(xf, order, inv, k)        # (T*k, d)
+            if share:
+                # the grouped matmul on the chip neither reads nor
+                # writes a row that is in no group, in either direction
+                # (its time follows the groups: PERF.md §6, PR 36), so
+                # what it leaves there is whatever the buffer held.
+                # Zeros go in, whose backward zeroes what comes back,
+                # and zeros come out
+                grouped = (jnp.arange(t * k) < jnp.sum(counts))[:, None]
+                rows = jnp.where(grouped, rows, 0)
         with jax.named_scope("moe_experts"):
             out = _expert_ffn(
                 rows, tables,
                 lambda a, w: jax.lax.ragged_dot(a, w, counts), cfg.dtype)
+            if share:
+                out = jnp.where(grouped, out, 0)
         with jax.named_scope("moe_combine"):
             out = _permute_rows(out, inv, order).reshape(t, k, d)
             y = jnp.einsum("tk,tkd->td", gate.astype(cfg.dtype), out,
                            preferred_element_type=jnp.float32)
+        if cfg.moe_shared:
+            with jax.named_scope("moe_shared"):
+                wide = cfg.moe_shared * width
+                up = _dense(wide, ("embed", "mlp"), cfg, name="shared_up")(xf)
+                sh = nn.silu(_dense(wide, ("embed", "mlp"), cfg,
+                                    name="shared_gate")(xf)) * up
+                y = y + _dense(cfg.d_model, ("mlp", "embed"), cfg,
+                               name="shared_down")(sh)
         return y.astype(cfg.dtype).reshape(b, s, d)
 
 
@@ -618,7 +742,8 @@ class Mamba2Mixer(nn.Module):
 
 class Block(nn.Module):
     cfg: TransformerConfig
-    kind: str = "attention"        # the mixer: | "mamba"
+    kind: str = "attention"        # the mixer: | "mamba" | "sliding" | "full"
+    experts: bool = True           # under cfg.moe: False keeps the dense MLP
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -631,14 +756,17 @@ class Block(nn.Module):
         if self.kind == "mamba":
             h = Mamba2Mixer(cfg, name="ssm")(h)
         else:
-            h = Attention(cfg, name="attn")(h, train)
+            h = Attention(cfg, self.kind, name="attn")(h, train)
+        if cfg.sandwich_norm:
+            with jax.named_scope("ln"):
+                h = _norm(cfg, "ln_attn_out")(h)
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout, deterministic=not train)(h)
         h = _scaled(h, cfg.residual_scale)
         x = x + h
         with jax.named_scope("ln"):
             h = _norm(cfg, "ln_mlp")(x)
-        if cfg.moe:
+        if cfg.moe and self.experts:
             with jax.named_scope("mlp"):
                 h = MoEMLP(cfg, name="moe_mlp")(h)
         elif cfg.mlp_gated:
@@ -659,6 +787,9 @@ class Block(nn.Module):
                 h = cfg.constrain(h, ("batch", "seq", "mlp"))
                 h = _dense(cfg.d_model, ("mlp", "embed"), cfg,
                            name="mlp_out")(h)
+        if cfg.sandwich_norm:
+            with jax.named_scope("ln"):
+                h = _norm(cfg, "ln_mlp_out")(h)
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout, deterministic=not train)(h)
         h = _scaled(h, cfg.residual_scale)
@@ -703,7 +834,8 @@ class Transformer(nn.Module):
         if cfg.remat:
             block = nn.remat(Block, static_argnums=(2,))
         for i in range(cfg.n_layers):
-            x = block(cfg, cfg.kind(i), name=f"block{i}")(x, train)
+            x = block(cfg, cfg.kind(i), cfg.moe_layer(i),
+                      name=f"block{i}")(x, train)
         with jax.named_scope("ln"):
             x = _norm(cfg, "ln_final")(x)
         if return_hidden:
@@ -724,10 +856,6 @@ class Transformer(nn.Module):
         return logits
 
 
-# what the routers sow is kept only where a loss takes their terms
-_SOW = {"mutable": ["intermediates"]}
-
-
 def _model_of(apply_fn, aux_weight, z_weight) -> tuple:
     """(the config of the `Transformer` whose ``apply`` this is, the
     (aux, z) weights of the routers' terms). The config is None for any
@@ -740,12 +868,33 @@ def _model_of(apply_fn, aux_weight, z_weight) -> tuple:
     return cfg, None if aux_weight is None else (aux_weight, z_weight or 0.0)
 
 
+def _run(state, apply_fn, params, tokens, moe, **kw) -> tuple:
+    """(what ``apply_fn`` returns on the model's variables, what it
+    mutated: the routers' sown terms where a loss takes them, and the
+    state no gradient trains, `state.batch_stats`, where there is any)."""
+    variables, mutable = {"params": params}, []
+    if moe:
+        mutable.append("intermediates")
+    stats = getattr(state, "batch_stats", None)
+    if stats is not None:
+        variables["batch_stats"] = stats
+        mutable.append("batch_stats")
+    if not mutable:
+        return apply_fn(variables, tokens, train=True, **kw), None
+    return apply_fn(variables, tokens, train=True, mutable=mutable, **kw)
+
+
 def _with_router_terms(ce, mutated, weights) -> tuple[jax.Array, dict]:
-    """(loss, metrics) of either LM loss from its cross-entropy."""
+    """(loss, metrics) of either LM loss from its cross-entropy. The
+    new `batch_stats` ride the metrics to `make_train_step`, which
+    folds them into the state."""
+    metrics = {"ppl": jnp.exp(ce)}
+    if mutated is not None and "batch_stats" in mutated:
+        metrics["batch_stats"] = mutated["batch_stats"]
     if weights is None:
-        return ce, {"ppl": jnp.exp(ce)}
-    extra, metrics = _moe_terms(mutated, *weights)
-    return ce + extra.astype(ce.dtype), {"ppl": jnp.exp(ce), **metrics}
+        return ce, metrics
+    extra, counters = _moe_terms(mutated, *weights)
+    return ce + extra.astype(ce.dtype), {**metrics, **counters}
 
 
 def lm_loss_fn(state, params, batch, *, aux_weight: float | None = None,
@@ -760,9 +909,7 @@ def lm_loss_fn(state, params, batch, *, aux_weight: float | None = None,
     rebinds cfg.moe_wire without touching the params)."""
     apply_fn = apply_fn or state.apply_fn
     _, moe = _model_of(apply_fn, aux_weight, z_weight)
-    logits = apply_fn({"params": params}, batch["tokens"], train=True,
-                      **_SOW if moe else {})
-    logits, mutated = logits if moe else (logits, None)
+    logits, mutated = _run(state, apply_fn, params, batch["tokens"], moe)
     targets = batch["tokens"][:, 1:]
     logits = logits[:, :-1]
     logp = jax.nn.log_softmax(logits)
@@ -792,9 +939,8 @@ def lm_loss_fused(state, params, batch, *, block_rows: int | None = None,
 
     apply_fn = apply_fn or state.apply_fn
     cfg, moe = _model_of(apply_fn, aux_weight, z_weight)
-    hidden = apply_fn({"params": params}, batch["tokens"], train=True,
-                      return_hidden=True, **_SOW if moe else {})
-    hidden, mutated = hidden if moe else (hidden, None)
+    hidden, mutated = _run(state, apply_fn, params, batch["tokens"], moe,
+                           return_hidden=True)
     tokens = batch["tokens"]
     # a sequence's last position has no next token: a row of weight 0,
     # so the (B, S, d) hidden states go in as they are
@@ -843,7 +989,8 @@ def _moe_terms(mutated, aux_weight: float, z_weight: float
         return jnp.mean(jnp.stack(got), 0) if got else zero
     metrics = {"moe_dropped": mean("moe_dropped")}
     frac = _sown(inter, "moe_frac")
-    if frac:
+    extra = zero
+    if frac and _sown(inter, "moe_probs"):
         frac, p = jnp.stack(frac), mean("moe_probs")     # (layers, E), (E,)
         e = p.shape[0]
         metrics.update(
@@ -853,9 +1000,15 @@ def _moe_terms(mutated, aux_weight: float, z_weight: float
             moe_max_load=e * jnp.max(frac))
         extra = aux_weight * metrics["moe_balance"] \
             + z_weight * metrics["moe_z"]
+    elif frac:  # sigmoid scores: no term of theirs is in any loss
+        frac = jnp.stack(frac)
+        metrics["moe_max_load"] = frac.shape[1] * jnp.max(frac)
     else:
         metrics["moe_balance"] = mean("moe_aux")
         extra = aux_weight * metrics["moe_balance"]
+    if _sown(inter, "moe_held"):
+        # the share of the T*k assignments on experts this chip holds
+        metrics["moe_held"] = mean("moe_held")
     return extra, metrics
 
 
@@ -907,6 +1060,44 @@ def granite_hybrid_config(*, vocab_size: int = 100352, d_model: int = 2048,
         layer_types=tuple(layer_types), ssm_heads=ssm_heads,
         ssm_head_dim=ssm_head_dim, ssm_state=ssm_state, ssm_conv=ssm_conv,
         ssm_chunk=ssm_chunk, **kw)
+
+
+def afmoe_config(*, vocab_size: int = 200192, d_model: int = 2048,
+                 n_heads: int = 32, n_layers: int = 32, d_ff: int = 6144,
+                 max_len: int = 131072, n_kv_heads: int = 4,
+                 head_size: int = 128, window: int = 2048,
+                 layer_types=None, n_dense_layers: int = 2,
+                 moe_d_ff: int = 1024, n_experts: int = 128,
+                 moe_top_k: int = 8, experts_held: int = 0,
+                 experts_offset: int = 0, **kw) -> TransformerConfig:
+    """Trinity-Mini (arcee-ai, `model_type: afmoe`): RMSNorm (eps 1e-5)
+    before and after the mixer and the MLP, embedding x sqrt(d_model),
+    an untied head; grouped-query attention with a head size of its
+    own, RMSNorm on q and k over each head, an output gate, three layers
+    over a window of 2,048 keys with RoPE (theta 10,000) to one over
+    every key with no positions (``layer_types`` where not given);
+    ``n_dense_layers`` leading dense SwiGLU layers of width ``d_ff``,
+    then experts of width ``moe_d_ff``: sigmoid scores, top-8 of 128
+    over score + bias, gates renormalised and x 2.826, one shared
+    expert, the bias moved by 0.001 a step, and no auxiliary loss.
+    ``experts_held`` / ``experts_offset`` give a chip its share of the
+    experts (0 = all); ``n_layers`` cuts the published pattern from its
+    start. The sizes default to the published ones."""
+    if layer_types is None:
+        layer_types = tuple("full" if i % 4 == 3 else "sliding"
+                            for i in range(n_layers))
+    return TransformerConfig(
+        vocab_size=vocab_size, d_model=d_model, n_heads=n_heads,
+        n_layers=n_layers, d_ff=d_ff, max_len=max_len, norm="rmsnorm",
+        norm_eps=1e-5, pos="rope", rope_theta=10000.0,
+        n_kv_heads=n_kv_heads, head_size=head_size, window=window,
+        layer_types=tuple(layer_types), qk_norm_heads=True, attn_gate=True,
+        sandwich_norm=True, mlp_gated=True, embed_scale=d_model ** 0.5,
+        moe=True, moe_gated=True, n_dense_layers=n_dense_layers,
+        moe_d_ff=moe_d_ff, n_experts=n_experts, moe_top_k=moe_top_k,
+        moe_score="sigmoid", moe_route_scale=2.826, moe_shared=1,
+        moe_bias_rate=0.001, moe_aux_weight=0.0, moe_z_weight=0.0,
+        experts_held=experts_held, experts_offset=experts_offset, **kw)
 
 
 def choose_remat(cfg: TransformerConfig, batch_size: int,

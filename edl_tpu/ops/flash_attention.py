@@ -93,6 +93,39 @@ def _visible(shape, ahead, *, q_minor: bool = False):
     return col + ahead >= row if q_minor else row + ahead >= col
 
 
+# which of the two edges of a sliding window's band cut a piece
+_CAUSAL, _BAND, _BOTH = "causal", "band", "both"
+
+
+def _in_band(shape, ahead, window: int, cut: str, *, q_minor: bool = False):
+    """`_visible` under a window: query i sees key j iff
+    0 <= i + ahead - j < window. `cut` says which of the two bounds the
+    piece can break, so that the other is not computed: `_CAUSAL` (the
+    diagonal's), `_BAND` (the window's far edge) or `_BOTH`."""
+    if cut == _CAUSAL:
+        return _visible(shape, ahead, q_minor=q_minor)
+    row = lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0)
+    col = lax.broadcasted_iota(jnp.int32, (1, shape[1]), 1)
+    back = col + ahead - row if q_minor else row + ahead - col
+    if cut == _BAND:
+        return back < window
+    return (back >= 0) & (back < window)
+
+
+def _sub_cut(i: int, j: int, sub: int, window: int) -> str | None:
+    """How the window cuts sub-block (query i, key j <= i) of the
+    diagonal pair: queries sit (i - j) * sub - (sub - 1) to
+    (i - j) * sub + (sub - 1) positions after keys. "" = not at all
+    (unmasked), None = no key of it is visible."""
+    near, far = (i - j) * sub - (sub - 1), (i - j) * sub + (sub - 1)
+    if max(near, 0) >= window:
+        return None
+    band = far >= window
+    if i == j:
+        return _BOTH if band else _CAUSAL
+    return _BAND if band else ""
+
+
 def _rows(i: int, n: int) -> slice:
     return slice(i * n, (i + 1) * n)
 
@@ -118,15 +151,20 @@ def _lane_tile(x, n: int):
 
 
 def _over_keys(pair, qi, *, blk_q: int, blk_k: int, n_k: int, sub: int,
-               causal: bool) -> None:
+               causal: bool, window: int | None = None) -> None:
     """What the forward and the dQ kernel share: `pair(rows, k_at,
     ahead)` for every piece of keys that q block `qi` sees, in order —
     `rows` the block's query rows, `k_at` the keys' place in the
-    sequence, `ahead` None where every key is visible (no mask)."""
+    sequence, `ahead` None where every key is visible (no mask), `cut`
+    which edge of the band the mask is for (`_in_band`)."""
 
-    def block(ki, ahead):
-        pair(slice(None), pl.ds(ki * blk_k, blk_k), ahead)
+    def block(ki, ahead, cut=_CAUSAL):
+        pair(slice(None), pl.ds(ki * blk_k, blk_k), ahead, cut)
 
+    if window is not None:
+        _over_keys_in_band(pair, block, qi, blk_q=blk_q, blk_k=blk_k,
+                           sub=sub, window=window)
+        return
     if not causal:
         lax.fori_loop(0, n_k, lambda ki, _: block(ki, None), None)
         return
@@ -148,8 +186,45 @@ def _over_keys(pair, qi, *, blk_q: int, blk_k: int, n_k: int, sub: int,
             lambda ki, _: block(ki, qi * blk_q - ki * blk_k), None)
 
 
+def _over_keys_in_band(pair, block, qi, *, blk_q: int, blk_k: int, sub: int,
+                       window: int) -> None:
+    """`_over_keys` under a sliding window: of the kv blocks at or
+    before q block `qi`, only those that hold a key some query of the
+    block sees. In order: the whole pairs inside the band, unmasked;
+    the pair(s) the diagonal crosses, as without a window (a window
+    narrower than two blocks cuts those too); then the pair(s) the
+    band's far edge crosses, under its mask — last, so that a row whose
+    keys there are all too old already has its running max."""
+    q0 = qi * blk_q
+    q1 = q0 + (blk_q - 1)
+    # kv blocks whose last key the block's first query still sees; from
+    # `near` on, every key is within the window of the last query too
+    first = lax.div(jnp.maximum(q0 - (window - 1), 0), blk_k)
+    near = lax.div(jnp.maximum(q1 - window + blk_k, 0), blk_k)
+    n_full = lax.div(q0 + 1, blk_k)  # wholly at or before the first row
+    lax.fori_loop(jnp.minimum(near, n_full), n_full,
+                  lambda ki, _: block(ki, None), None)
+    if sub:
+        for i in range(blk_q // sub):
+            for j in range(i + 1):
+                cut = _sub_cut(i, j, sub, window)
+                if cut is not None:
+                    pair(_rows(i, sub), pl.ds(qi * blk_k + j * sub, sub),
+                         (i - j) * sub if cut else None, cut)
+    else:
+        # a diagonal block's first key is less than blk_q + blk_k - 1
+        # positions before the block's last query
+        cut = _BOTH if window < blk_q + blk_k - 1 else _CAUSAL
+        lax.fori_loop(
+            n_full, lax.div((qi + 1) * blk_q + blk_k - 1, blk_k),
+            lambda ki, _: block(ki, q0 - ki * blk_k, cut), None)
+    lax.fori_loop(first, jnp.minimum(near, n_full),
+                  lambda ki, _: block(ki, q0 - ki * blk_k, _BAND), None)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, blk_k: int, sub: int, scale: float, causal: bool):
+                *, blk_k: int, sub: int, scale: float, causal: bool,
+                window: int | None = None):
     """One (batch*head, q-block) program: stream K/V blocks online.
 
     q_ref: (1, BLK_Q, D); k_ref/v_ref: (1, S, D); o_ref: (1, BLK_Q, D);
@@ -168,12 +243,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     _, blk_q, d = q_ref.shape
     width = m_ref.shape[1]
 
-    def pair(rows, k_at, ahead):
+    def pair(rows, k_at, ahead, cut=_CAUSAL):
         """Online-softmax step of query rows `rows` of this block over
         the keys at `k_at`; `ahead` None: every key visible, no mask."""
         sblk = _dot_nt(q_ref[0, rows, :], k_ref[0, k_at, :]) * scale
         if ahead is not None:
-            sblk = jnp.where(_visible(sblk.shape, ahead), sblk, _NEG_INF)
+            sblk = jnp.where(_in_band(sblk.shape, ahead, window, cut),
+                             sblk, _NEG_INF)
         m = m_ref[rows, :]
         m_new = jnp.maximum(m, jnp.max(sblk, axis=-1, keepdims=True))
         p = jnp.exp(sblk - _lane_tile(m_new, sblk.shape[1]))
@@ -188,7 +264,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     _over_keys(pair, pl.program_id(1), blk_q=blk_q, blk_k=blk_k,
-               n_k=k_ref.shape[1] // blk_k, sub=sub, causal=causal)
+               n_k=k_ref.shape[1] // blk_k, sub=sub, causal=causal,
+               window=window)
     l = jnp.maximum(jnp.sum(l_ref[...], axis=-1, keepdims=True), 1e-30)
     o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
     lse_ref[0] = m_ref[:, :1] + jnp.log(l)
@@ -198,9 +275,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 # share one trace and one lowering of the kernel: traced a layer each, the
 # three kernels added seconds to a trainer's start (PERF.md §6, PR 32)
 @functools.partial(jax.jit, static_argnames=(
-    "blk_q", "blk_k", "scale", "causal", "interpret"))
+    "blk_q", "blk_k", "scale", "causal", "interpret", "window"))
 def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
-         interpret: bool):
+         interpret: bool, window: int | None = None):
     b, s, h, d = q.shape
     # (B, S, H, D) -> (B*H, S, D) program-per-head views
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -211,7 +288,7 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
     sub = _diag_sub(blk_q, blk_k)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, blk_k=blk_k, sub=sub, scale=scale,
-                          causal=causal),
+                          causal=causal, window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
@@ -235,7 +312,15 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
     return o, lse[..., 0]
 
 
-def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool):
+def _seen(q_pos, kv_pos, window: int | None):
+    """The XLA paths' mask: causal, and under a window the `window`
+    newest keys only."""
+    mask = q_pos >= kv_pos
+    return mask if window is None else mask & (q_pos - kv_pos < window)
+
+
+def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool,
+                   window: int | None = None):
     """Flash forward in plain XLA (KV-block scan with the online
     softmax) — the off-TPU fallback. Returns (o, lse) exactly as `_fwd`
     does: o (B,S,H,D) in q.dtype, lse (B*H, S) fp32."""
@@ -253,7 +338,7 @@ def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool):
                           preferred_element_type=jnp.float32) * scale
         if causal:
             kv_pos = ki * blk + jnp.arange(blk)
-            mask = q_pos[:, None] >= kv_pos[None, :]
+            mask = _seen(q_pos[:, None], kv_pos[None, :], window)
             sblk = jnp.where(mask[None, None], sblk, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sblk, axis=-1))
         p = jnp.exp(sblk - m_new[..., None])
@@ -276,7 +361,7 @@ def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool):
 
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
                      dk_ref, dv_ref, dk_acc, dv_acc, *, sub: int,
-                     scale: float, causal: bool):
+                     scale: float, causal: bool, window: int | None = None):
     """One (batch*head, kv-block) program: K/V block resident, stream Q
     blocks (causal: only blocks that can see this KV block), accumulate
     dK/dV in fp32 VMEM scratch (dk_acc/dv_acc: (BLK_K, D)). Works on the
@@ -296,24 +381,57 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
     ki = pl.program_id(1)
     to = v_ref.dtype
 
-    def pair(keys, q_at, qi, lanes, ahead):
+    def pair(keys, q_at, qi, lanes, ahead, cut=_CAUSAL):
         """Key rows `keys` of this block against the queries at `q_at`:
         lanes `lanes` of q block `qi`'s lse/rt rows."""
         q, do = q_ref[0, q_at, :], do_ref[0, q_at, :]
         pt = jnp.exp(_dot_nt(k_ref[0, keys, :], q) * scale
                      - lse_ref[0, qi, :, lanes])
         if ahead is not None:
-            pt = jnp.where(_visible(pt.shape, ahead, q_minor=True), pt, 0.0)
+            pt = jnp.where(_in_band(pt.shape, ahead, window, cut,
+                                    q_minor=True), pt, 0.0)
         dv_acc[keys, :] += _dot(pt.astype(to), do)
         dst = pt * (_dot_nt(v_ref[0, keys, :], do) - rt_ref[0, qi, :, lanes])
         dk_acc[keys, :] += _dot(dst.astype(to), q)  # x scale: at the end
 
-    def block(qi, ahead):
-        pair(slice(None), pl.ds(qi * blk_q, blk_q), qi, slice(None), ahead)
+    def block(qi, ahead, cut=_CAUSAL):
+        pair(slice(None), pl.ds(qi * blk_q, blk_q), qi, slice(None), ahead,
+             cut)
 
     dk_acc[...] = jnp.zeros_like(dk_acc)
     dv_acc[...] = jnp.zeros_like(dv_acc)
     first_full = 0
+    if window is not None:
+        # the forward's walk mirrored over the q blocks: the pair(s) the
+        # diagonal crosses, the whole pairs inside the band, the pair(s)
+        # its far edge crosses; later q blocks see no key of this block
+        k0 = ki * blk_k
+        k1 = k0 + (blk_k - 1)
+        first_full = lax.div(k1 + blk_q - 1, blk_q)
+        # q blocks from `far` on have a query that no longer sees this
+        # block's first key; from `end` on, none that sees its last
+        far = jnp.minimum(lax.div(k0 + window + blk_q, blk_q) - 1, n_q)
+        end = jnp.minimum(lax.div(k1 + window + blk_q - 1, blk_q), n_q)
+        if sub:
+            for j in range(blk_k // sub):
+                for i in range(j, blk_k // sub):
+                    cut = _sub_cut(i, j, sub, window)
+                    if cut is not None:
+                        pair(_rows(j, sub), pl.ds(ki * blk_q + i * sub, sub),
+                             ki, _rows(i, sub),
+                             (i - j) * sub if cut else None, cut)
+        else:
+            cut = _BOTH if window < blk_q + blk_k - 1 else _CAUSAL
+            lax.fori_loop(
+                lax.div(k0, blk_q), first_full,
+                lambda qi, _: block(qi, qi * blk_q - k0, cut), None)
+        lax.fori_loop(first_full, jnp.maximum(first_full, far),
+                      lambda qi, _: block(qi, None), None)
+        lax.fori_loop(jnp.maximum(first_full, far), end,
+                      lambda qi, _: block(qi, qi * blk_q - k0, _BAND), None)
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        return
     if causal:
         # q blocks the diagonal crosses (from the first that can see any
         # row of this kv block), then the ones that see all of it
@@ -336,33 +454,35 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref, dq_ref,
                    dq_acc, *, blk_k: int, sub: int, scale: float,
-                   causal: bool):
+                   causal: bool, window: int | None = None):
     """One (batch*head, q-block) program: Q block resident, stream KV
     blocks (causal skip and diagonal as in the forward), accumulate dQ
     in fp32 VMEM scratch (dq_acc: (BLK_Q, D)). lse_ref/rt_ref:
     (1, BLK_Q, 1) columns."""
     to = v_ref.dtype
 
-    def pair(rows, k_at, ahead):
+    def pair(rows, k_at, ahead, cut=_CAUSAL):
         k_blk = k_ref[0, k_at, :]
         p = jnp.exp(_dot_nt(q_ref[0, rows, :], k_blk) * scale
                     - lse_ref[0, rows, :])
         if ahead is not None:
-            p = jnp.where(_visible(p.shape, ahead), p, 0.0)
+            p = jnp.where(_in_band(p.shape, ahead, window, cut), p, 0.0)
         ds = p * (_dot_nt(do_ref[0, rows, :], v_ref[0, k_at, :])
                   - rt_ref[0, rows, :])
         dq_acc[rows, :] += _dot(ds.astype(to), k_blk)  # x scale: at the end
 
     dq_acc[...] = jnp.zeros_like(dq_acc)
     _over_keys(pair, pl.program_id(1), blk_q=q_ref.shape[1], blk_k=blk_k,
-               n_k=k_ref.shape[1] // blk_k, sub=sub, causal=causal)
+               n_k=k_ref.shape[1] // blk_k, sub=sub, causal=causal,
+               window=window)
     dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "blk_q", "blk_k", "scale", "causal", "interpret"))
+    "blk_q", "blk_k", "scale", "causal", "interpret", "window"))
 def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
-                scale: float, causal: bool, dlse, interpret: bool):
+                scale: float, causal: bool, dlse, interpret: bool,
+                window: int | None = None):
     """Pallas flash backward: same math as `_bwd_blockwise` (the XLA
     reference used by the parity tests) but with scores recomputed in
     VMEM — nothing S^2-shaped touches HBM — and the causal block skip
@@ -387,7 +507,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, sub=sub, scale=scale,
-                          causal=causal),
+                          causal=causal, window=window),
         grid=(b * h, s // blk_k),
         in_specs=[
             pl.BlockSpec((1, s, d), lambda bh, ki: (bh, 0, 0)),
@@ -413,7 +533,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
     )(qt, kt, vt, dot, along_lanes(lse), along_lanes(rt))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, blk_k=blk_k, sub=sub,
-                          scale=scale, causal=causal),
+                          scale=scale, causal=causal, window=window),
         grid=(b * h, s // blk_q),
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
@@ -437,7 +557,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
 
 
 def _bwd_blockwise(q, k, v, o, lse, do, *, blk: int, scale: float,
-                   causal: bool, dlse=None):
+                   causal: bool, dlse=None, window: int | None = None):
     """Flash backward in plain XLA, scanning KV blocks. All (B,S,H,D).
 
     With `dlse` (a (B*H, S) cotangent on the log-sum-exp output), the
@@ -467,7 +587,7 @@ def _bwd_blockwise(q, k, v, o, lse, do, *, blk: int, scale: float,
                           preferred_element_type=jnp.float32) * scale
         if causal:
             kv_pos = ki * blk + jnp.arange(blk)
-            mask = q_pos[:, None] >= kv_pos[None, :]
+            mask = _seen(q_pos[:, None], kv_pos[None, :], window)
             sblk = jnp.where(mask[None, None], sblk, _NEG_INF)
         p = jnp.exp(sblk - lse_b.transpose(0, 2, 1)[..., None])  # (B,H,S,blk)
         dv_blk = jnp.einsum("bhqk,bqhd->bkhd", p, do32,
@@ -525,7 +645,8 @@ def _stat_width(blk_k: int, sub: int) -> int:
     return 128 if piece % 128 == 0 else piece
 
 
-def block_pairs(s: int, blk_q: int, blk_k: int, causal: bool) -> str:
+def block_pairs(s: int, blk_q: int, blk_k: int, causal: bool,
+                window: int | None = None) -> str:
     """What each of the three kernels works through for one head, for
     the log: the blocking, and the block pairs that run unmasked, under
     the mask, and not at all."""
@@ -536,14 +657,33 @@ def block_pairs(s: int, blk_q: int, blk_k: int, causal: bool) -> str:
     # block's first row, and those with any column at or before its last
     full = sum((qi * blk_q + 1) // blk_k for qi in range(n_q))
     seen = sum(-(-(qi + 1) * blk_q // blk_k) for qi in range(n_q))
-    text = (f"blocks {blk_q}x{blk_k}, pairs a head: {full} full, "
-            f"{seen - full} on the diagonal, {n_q * n_k - seen} skipped")
+    text = f"blocks {blk_q}x{blk_k}, pairs a head: "
+    if window is None:
+        text += (f"{full} full, {seen - full} on the diagonal, "
+                 f"{n_q * n_k - seen} skipped")
+    else:
+        # of the pairs before the diagonal: those the window's far edge
+        # crosses, and those wholly older than it, as `_over_keys_in_band`
+        edge = old = 0
+        for qi in range(n_q):
+            q0, q1 = qi * blk_q, (qi + 1) * blk_q - 1
+            n_full = (q0 + 1) // blk_k
+            first = max(q0 - (window - 1), 0) // blk_k
+            near = min(max(q1 - window + blk_k, 0) // blk_k, n_full)
+            edge, old = edge + near - first, old + first
+        text += (f"window {window}: {full - edge - old} full, "
+                 f"{seen - full} on the diagonal, {edge} on the window's "
+                 f"edge, {n_q * n_k - seen + old} skipped")
     sub = _diag_sub(blk_q, blk_k)
     if sub:
         n = blk_q // sub
+        cuts = [_sub_cut(i, j, sub, window) if window is not None
+                else ("" if i > j else _CAUSAL)
+                for i in range(n) for j in range(i + 1)]
         text += (f"; a diagonal pair as {n}x{n} of {sub}: "
-                 f"{n * (n - 1) // 2} full, {n} masked, "
-                 f"{n * (n - 1) // 2} skipped")
+                 f"{cuts.count('')} full, "
+                 f"{len(cuts) - cuts.count('') - cuts.count(None)} masked, "
+                 f"{n * n - len(cuts) + cuts.count(None)} skipped")
     else:
         text += ", masked whole"
     return text
@@ -566,7 +706,8 @@ def force_interpret_kernels():
 
 
 def _kernel_interpret(what: str, q, blk_q: int, blk_k: int,
-                      causal: bool) -> bool | None:
+                      causal: bool, window: int | None = None
+                      ) -> bool | None:
     """Which path this trace takes: the Pallas `interpret` flag (False
     = compiled, on TPU; True = the test hook), or None for the compiled
     XLA blockwise paths — off-TPU, where interpret-mode Pallas is
@@ -580,40 +721,43 @@ def _kernel_interpret(what: str, q, blk_q: int, blk_k: int,
     else:
         mode, interpret = "xla blockwise", None
     if interpret is not None:
-        mode += "; " + block_pairs(q.shape[1], blk_q, blk_k, causal)
+        mode += "; " + block_pairs(q.shape[1], blk_q, blk_k, causal, window)
+    elif window is not None:
+        mode += f", window {window}"
     log.info("flash attention %s %s: %s", what, tuple(q.shape), mode)
     return interpret
 
 
-def _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal):
-    interpret = _kernel_interpret("fwd", q, blk_q, blk_k, causal)
+def _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window):
+    interpret = _kernel_interpret("fwd", q, blk_q, blk_k, causal, window)
     if interpret is None:
         return _fwd_blockwise(q, k, v, blk=blk_k, scale=scale,
-                              causal=causal)
+                              causal=causal, window=window)
     return _fwd(q, k, v, blk_q=blk_q, blk_k=blk_k, scale=scale,
-                causal=causal, interpret=interpret)
+                causal=causal, interpret=interpret, window=window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse(q, k, v, blk_q, blk_k, scale, causal):
-    return _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse(q, k, v, blk_q, blk_k, scale, causal, window):
+    return _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window)
 
 
-def _flash_lse_fwd(q, k, v, blk_q, blk_k, scale, causal):
-    o, lse = _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal)
+def _flash_lse_fwd(q, k, v, blk_q, blk_k, scale, causal, window):
+    o, lse = _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window)
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_lse_bwd(blk_q, blk_k, scale, causal, res, cotangents):
+def _flash_lse_bwd(blk_q, blk_k, scale, causal, window, res, cotangents):
     q, k, v, o, lse = res
     do, dlse = cotangents
-    interpret = _kernel_interpret("bwd", q, blk_q, blk_k, causal)
+    interpret = _kernel_interpret("bwd", q, blk_q, blk_k, causal, window)
     if interpret is None:
         return _bwd_blockwise(q, k, v, o, lse, do, blk=blk_k,
-                              scale=scale, causal=causal, dlse=dlse)
+                              scale=scale, causal=causal, dlse=dlse,
+                              window=window)
     return _bwd_pallas(q, k, v, o, lse, do, blk_q=blk_q, blk_k=blk_k,
                        scale=scale, causal=causal, dlse=dlse,
-                       interpret=interpret)
+                       interpret=interpret, window=window)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -621,7 +765,8 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, scale: float | None = None,
-                        block_q: int = 512, block_k: int = 512
+                        block_q: int = 512, block_k: int = 512,
+                        window: int | None = None
                         ) -> tuple[jax.Array, jax.Array]:
     """flash_attention that ALSO returns the per-row log-sum-exp
     ((B, H*... reshaped) -> (B, S, H)) — the combinable statistic for
@@ -633,18 +778,29 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if k.shape != q.shape or v.shape != q.shape:
         raise ValueError(f"q/k/v shape mismatch: {q.shape} {k.shape} "
                          f"{v.shape}")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window} counts the keys up to and "
+                         "including a query's own: it needs causal=True "
+                         "and at least 1")
+    if window is not None and window >= s:
+        window = None  # no query has that many keys behind it
     blk_q = _fit_block(s, block_q)
     blk_k = _fit_block(s, block_k)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    o, lse = _flash_lse(q, k, v, blk_q, blk_k, scale, causal)
+    o, lse = _flash_lse(q, k, v, blk_q, blk_k, scale, causal, window)
     return o, lse.reshape(b, h, s).transpose(0, 2, 1)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: float | None = None,
-                    block_q: int = 512, block_k: int = 512) -> jax.Array:
+                    block_q: int = 512, block_k: int = 512,
+                    window: int | None = None) -> jax.Array:
     """Fused causal attention. q/k/v: (B, S, H, D) -> (B, S, H, D).
+
+    `window` (static; None = every earlier key): query i sees keys
+    i - window + 1 .. i, and the kernels visit only the block pairs
+    that hold such a key (`block_pairs`).
 
     Blocks auto-fit any 128-divisible sequence (pad upstream otherwise —
     the transformer's static max_len already guarantees this). One
@@ -652,4 +808,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     output's cotangent is zero, which `_bwd_blockwise` folds away.
     """
     return flash_attention_lse(q, k, v, causal=causal, scale=scale,
-                               block_q=block_q, block_k=block_k)[0]
+                               block_q=block_q, block_k=block_k,
+                               window=window)[0]

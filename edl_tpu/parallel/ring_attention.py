@@ -173,12 +173,13 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, scale: float | None = None
-                    ) -> jax.Array:
+                    causal: bool = True, scale: float | None = None,
+                    window: int | None = None) -> jax.Array:
     """Plain (single-device / XLA-partitioned) reference attention.
 
     Used when the mesh has no sp axis, and as the numerical oracle in
-    tests. Same fp32-accumulate contract as the ring path.
+    tests. Same fp32-accumulate contract as the ring path. `window`
+    (causal only): a query sees its own key and the `window - 1` before.
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -187,7 +188,8 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         s_q, s_k = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(s_q)[:, None] >= jnp.arange(s_k)[None, :]
+        back = jnp.arange(s_q)[:, None] - jnp.arange(s_k)[None, :]
+        mask = back >= 0 if window is None else (back >= 0) & (back < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32),

@@ -89,20 +89,23 @@ def custom_calls(compiled) -> int:
 
 # (batch, seq, heads, head dim): LM-large, the d_model-1024 shape, and
 # the benchmark's cells: d8, a chip of fsdp4, OLMoE, the hybrid's one
-# attention layer (its 8 key/value heads repeated to 32)
+# attention layer (its 8 key/value heads repeated to 32); a fifth entry
+# is a sliding window: the afmoe share's global layer and its sliding ones
 FLASH_SHAPES = [(8, 1024, 16, 128), (16, 1024, 16, 64),
                 (6, 2048, 16, 128), (2, 2048, 16, 128),
-                (4, 4096, 16, 128), (2, 8192, 32, 64)]
+                (4, 4096, 16, 128), (2, 8192, 32, 64),
+                (2, 8192, 32, 128), (2, 8192, 32, 128, 2048)]
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 def test_flash_kernels_compile(one_chip, no_persistent_cache, kernel,
                                shape):
+    shape, window = shape[:4], (*shape[4:], None)[0]
     b, s, h, d = shape
     blk = fa._fit_block(s, 512)
     kw = dict(blk_q=blk, blk_k=blk, scale=d ** -0.5, causal=True,
-              interpret=False)
+              interpret=False, window=window)
     x = sds(shape, jnp.bfloat16)
     lse = sds((b * h, s), F32)
     if kernel == "fwd":

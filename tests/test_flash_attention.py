@@ -387,3 +387,123 @@ class TestTransformerIntegration:
         out_d = m_dense.apply(variables, toks, train=False)
         out_f = m_flash.apply(variables, toks, train=False)
         np.testing.assert_allclose(out_d, out_f, atol=1e-4)
+
+
+class TestWindow:
+    """A sliding window: query i sees keys i - window + 1 .. i. The
+    kernels visit only the block pairs that hold such a key; the dense
+    masked attention is the oracle."""
+
+    # (sequence, q block, k block, window): windows smaller than, equal
+    # to and larger than a block and a sub-block, across unequal blocks,
+    # of one key, and of the whole sequence and more
+    CASES = [(1024, 256, 256, 100), (1024, 256, 256, 128),
+             (1024, 256, 256, 256), (1024, 256, 256, 300),
+             (1024, 256, 256, 512), (1024, 256, 256, 1000),
+             (1024, 128, 128, 200), (1024, 128, 256, 300),
+             (1024, 256, 128, 130), (512, 128, 128, 1),
+             (512, 128, 128, 512), (512, 128, 128, 4096)]
+
+    @pytest.mark.parametrize("s, blk_q, blk_k, window", CASES,
+                             ids=lambda v: str(v))
+    def test_forward_and_backward_match_dense(self, s, blk_q, blk_k,
+                                              window, attn_path):
+        q, k, v = _qkv(b=1, s=s, h=2)
+
+        def loss(fn, **kw):
+            return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v, **kw)))
+        kw = dict(block_q=blk_q, block_k=blk_k, window=window)
+        np.testing.assert_allclose(
+            flash_attention(q, k, v, **kw),
+            dense_attention(q, k, v, window=window), atol=2e-5)
+        got = jax.grad(loss(flash_attention, **kw), argnums=(0, 1, 2))(
+            q, k, v)
+        want = jax.grad(loss(dense_attention, window=window),
+                        argnums=(0, 1, 2))(q, k, v)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, atol=1e-4)
+
+    def test_a_window_is_not_the_causal_triangle(self):
+        q, k, v = _qkv(b=1, s=512, h=1)
+        assert float(jnp.max(jnp.abs(
+            dense_attention(q, k, v, window=100)
+            - dense_attention(q, k, v)))) > 0.1
+
+    @pytest.mark.parametrize("path", ["xla_fallback", "pallas_kernels"])
+    def test_no_window_is_todays_output_to_the_bit(self, path):
+        """`window=None`, and a window no query can fill, lower to the
+        kernels as they were: the same jaxpr, the same numbers."""
+        from edl_tpu.ops.flash_attention import _bwd_pallas, _fwd
+        q, k, v = _qkv(b=1, s=512, h=2)
+        ctx = (force_interpret_kernels() if path == "pallas_kernels"
+               else contextlib.nullcontext())
+        with ctx:
+            plain = flash_attention(q, k, v, block_q=128, block_k=128)
+            none = flash_attention(q, k, v, block_q=128, block_k=128,
+                                   window=None)
+            wide = flash_attention(q, k, v, block_q=128, block_k=128,
+                                   window=512)
+        assert np.array_equal(plain, none) and np.array_equal(plain, wide)
+        kw = dict(blk_q=128, blk_k=128, scale=0.1, causal=True,
+                  interpret=True)
+
+        def both(window):
+            def fn(q, k, v):
+                o, lse = _fwd(q, k, v, window=window, **kw)
+                return _bwd_pallas(q, k, v, o, lse, q, dlse=None,
+                                   window=window, **kw)
+            return str(jax.make_jaxpr(fn)(q, k, v))
+        assert both(None) == str(jax.make_jaxpr(
+            lambda q, k, v: _bwd_pallas(
+                q, k, v, *_fwd(q, k, v, **kw), q, dlse=None, **kw))(q, k, v))
+        assert both(200) != both(None)
+
+    def test_the_band_skips_blocks_on_both_sides(self):
+        """At the cell's blocking (equal blocks, the window four of
+        them) every kernel has three loops and no more: whole pairs
+        unmasked, the band's far edge under its mask, and in the dK/dV
+        kernel the mirror image; the diagonal pair is unrolled."""
+        from edl_tpu.ops.flash_attention import _bwd_pallas, _fwd
+        q, k, v = _qkv(b=1, s=2048, h=1, d=128, dtype=jnp.bfloat16)
+        kw = dict(blk_q=256, blk_k=256, scale=0.1, causal=True,
+                  interpret=True, window=1024)
+
+        def both(q, k, v):
+            o, lse = _fwd(q, k, v, **kw)
+            return _bwd_pallas(q, k, v, o, lse, q, dlse=None, **kw)
+        walk = TestKernelBodies._walk
+        calls = [e for e in walk(jax.make_jaxpr(both)(q, k, v).jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert [e.params["name"] for e in calls] == [
+            "flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"]
+        for call in calls:
+            loops = [e for e in walk(call.params["jaxpr"])
+                     if e.primitive.name == "while"]
+            masked = [any(i.primitive.name == "select_n"
+                          for sub in jax.core.jaxprs_in_params(e.params)
+                          for i in walk(sub)) for e in loops]
+            assert sorted(masked) == [False, True]
+
+    @pytest.mark.parametrize("args, want", [
+        ((8192, 512, 512, True, 2048),
+         "blocks 512x512, pairs a head: window 2048: 42 full, 16 on the "
+         "diagonal, 12 on the window's edge, 186 skipped; a diagonal pair "
+         "as 2x2 of 256: 1 full, 2 masked, 1 skipped"),
+        ((1024, 256, 256, True, 100),
+         "blocks 256x256, pairs a head: window 100: 0 full, 4 on the "
+         "diagonal, 3 on the window's edge, 9 skipped; a diagonal pair as "
+         "2x2 of 128: 0 full, 3 masked, 1 skipped"),
+        ((1024, 128, 128, True, 200),
+         "blocks 128x128, pairs a head: window 200: 0 full, 8 on the "
+         "diagonal, 13 on the window's edge, 43 skipped, masked whole"),
+    ], ids=lambda a: "-".join(map(str, a)) if isinstance(a, tuple) else "")
+    def test_block_pairs_line_counts_the_band(self, args, want):
+        from edl_tpu.ops.flash_attention import block_pairs
+        assert block_pairs(*args) == want
+
+    def test_a_window_needs_causal_and_a_key(self):
+        q, k, v = _qkv(b=1, s=128, h=1)
+        with pytest.raises(ValueError, match="window"):
+            flash_attention(q, k, v, causal=False, window=64)
+        with pytest.raises(ValueError, match="window"):
+            flash_attention(q, k, v, window=0)
