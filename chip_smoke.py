@@ -613,6 +613,25 @@ def flash_against(path: str, fa, shape, qkvdo, blk: int, kw: dict,
            f"{worse or 'none'}")
 
 
+def worst_gap(got, want) -> float:
+    """max |got - want| over max |want|, both as float32."""
+    import jax.numpy as jnp
+    want = want.astype(jnp.float32)
+    return float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))
+                 / (jnp.max(jnp.abs(want)) + 1e-30))
+
+
+def ms_a_call(fn, args, calls: int = 10) -> float:
+    """Milliseconds a call of a compiled `fn` on the host's clock."""
+    import jax
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / calls * 1e3
+
+
 def scan_against_einsums(shape, chunk: int, *, interpret: bool) -> dict:
     """The chunked scan's two kernels (`ops/ssd.py`) against its einsums
     on the same bfloat16 inputs, and both against the einsums computed
@@ -656,15 +675,13 @@ def scan_against_einsums(shape, chunk: int, *, interpret: bool) -> dict:
     with jax.default_matmul_precision("highest"):
         exact = both(ssd._forward_einsums, ssd._backward_einsums, f32)
 
-    def gap(got, want):
-        want = want.astype(f32)
-        return float(jnp.max(jnp.abs(got.astype(f32) - want))
-                     / (jnp.max(jnp.abs(want)) + 1e-30))
     names = ("y", "dx", "d_dt", "d_a", "dB", "dC")
     return {"kernels_vs_einsums": dict(zip(names, map(
-                gap, kernels, einsums))),
-            "kernels_vs_float32": dict(zip(names, map(gap, kernels, exact))),
-            "einsums_vs_float32": dict(zip(names, map(gap, einsums, exact)))}
+                worst_gap, kernels, einsums))),
+            "kernels_vs_float32": dict(zip(names, map(
+                worst_gap, kernels, exact))),
+            "einsums_vs_float32": dict(zip(names, map(
+                worst_gap, einsums, exact)))}
 
 
 def scan_verdict(report, shape, chunk, *, interpret: bool) -> dict:
@@ -684,6 +701,109 @@ def scan_verdict(report, shape, chunk, *, interpret: bool) -> dict:
     return gaps
 
 
+def stages_against_expressions(shape, *, interpret: bool) -> dict:
+    """The mixer's two elementwise stages (`ops/ssm_stages.py`): each
+    stage's kernels, forward and written-out backward, against the
+    expressions they replace on the same bfloat16 inputs, and both
+    against the expressions in float32 on those inputs: per output and
+    gradient the worst |difference| over the largest value; and the
+    milliseconds a call (forward and backward) on the host's clock.
+    shape: (B, S, H, P, N)."""
+    import jax
+    import jax.numpy as jnp
+
+    from edl_tpu.ops import ssm_stages as st
+    b, s, h, p, n = shape
+    inner, sizes = h * p, (h * p, n, n)
+    if st._conv_plan(s, inner, sizes) is None \
+            or st._norm_plan(s, inner) is None:
+        raise SystemExit(f"the stages' kernels do not take {shape}")
+    key = jax.random.split(jax.random.PRNGKey(0), 12)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def lo(k, *dims):  # bfloat16 values, so that float32 starts level
+        return jax.random.normal(k, dims, f32).astype(bf16)
+    proj = lo(key[0], b, s, 2 * inner + 2 * n + h)
+    y, x = lo(key[1], b, s, h, p), lo(key[2], b, s, h, p)
+    taps = jax.random.uniform(key[3], (4, inner + 2 * n), f32, -0.5, 0.5)
+    bias = 0.2 * jax.random.normal(key[4], (inner + 2 * n,), f32)
+    skip = jax.random.uniform(key[5], (h,), f32, 0.5, 1.5)
+    scale = jax.random.uniform(key[6], (inner,), f32, 0.5, 1.5)
+    d_conv = tuple(lo(k, b, s, z) for k, z in zip(key[7:10], sizes))
+    d_norm = lo(key[10], b, s, inner)
+
+    def conv(kernels, dtype):
+        def run(proj, taps, bias, dys):
+            def f(proj, taps, bias):
+                if kernels:
+                    return st._conv_kernels(proj, taps, bias, inner, sizes,
+                                            interpret)
+                return st._conv_expressions(
+                    proj[..., inner:inner + sum(sizes)], taps, bias, sizes)
+            outs, pull = jax.vjp(f, proj.astype(dtype), taps, bias)
+            # the kernels hand x out twice (`ssm_stages.conv`): the
+            # second's cotangent is the first's again, so double the
+            # expressions' to compare
+            dys = [d.astype(dtype) for d in dys]
+            if kernels:
+                return outs[:3], pull((*dys, dys[0]))
+            return outs, pull((2 * dys[0], *dys[1:]))
+        return jax.jit(run), (proj, taps, bias, d_conv)
+
+    def norm(kernels, dtype):
+        def run(y, x, proj, skip, scale, do):
+            def f(y, x, proj, skip, scale):
+                if kernels:
+                    return st._norm_kernels(y, x, proj, skip, scale, 1e-5,
+                                            interpret)
+                return st._gate_norm_expressions(
+                    y, x, proj[..., :inner], skip, scale, 1e-5)
+            out, pull = jax.vjp(f, y.astype(dtype), x.astype(dtype),
+                                proj.astype(dtype), skip, scale)
+            return (out,), pull(do.astype(dtype))
+        return jax.jit(run), (y, x, proj, skip, scale, d_norm)
+
+    res = {}
+    for stage, build, names in (
+            ("conv", conv, ("x", "B", "C", "d_proj", "d_taps", "d_bias")),
+            ("gate_norm", norm, ("out", "dy", "dx", "d_proj", "d_skip",
+                                 "d_scale"))):
+        flat = {}
+        for form, kernels, dtype in (("kernels", True, bf16),
+                                     ("expressions", False, bf16),
+                                     ("float32", False, f32)):
+            fn, args = build(kernels, dtype)
+            with jax.default_matmul_precision("highest"):
+                outs, grads = fn(*args)
+            flat[form] = (*outs, *grads)
+            if dtype == bf16:
+                res[f"{stage}_{form}_ms"] = ms_a_call(fn, args)
+        for a, b_ in (("kernels", "expressions"), ("kernels", "float32"),
+                      ("expressions", "float32")):
+            res[f"{stage}_{a}_vs_{b_}"] = dict(zip(names, map(
+                worst_gap, flat[a], flat[b_])))
+    return res
+
+
+def stages_verdict(report, shape, *, interpret: bool) -> dict:
+    """`stages_against_expressions`, held like the scan's: the kernels
+    no further from float32 than 1.5 times what the expressions are plus
+    1e-3 of the largest value, and no further from the expressions than
+    bfloat16 allows (3e-2) plus the expressions' own distance."""
+    res = stages_against_expressions(shape, interpret=interpret)
+    for stage in ("conv", "gate_norm"):
+        far = res[f"{stage}_expressions_vs_float32"]
+        ok_ = all(res[f"{stage}_kernels_vs_float32"][k] <= 1.5 * far[k] + 1e-3
+                  and res[f"{stage}_kernels_vs_expressions"][k]
+                  <= 3e-2 + far[k] for k in far)
+        report(f"ssm {stage} {shape}", ok_, "; ".join(
+            f"{k[len(stage) + 1:]} " + (f"{row:.3f}" if k.endswith("_ms")
+                                        else " ".join(
+                f"{name} {v:.1e}" for name, v in row.items()))
+            for k, row in res.items() if k.startswith(stage)))
+    return res
+
+
 def reporter():
     """(report(name, ok, detail), the names that failed so far)."""
     failures = []
@@ -700,7 +820,9 @@ def child_scan_rehearsal() -> dict:
     small shape (two head blocks, three chunks)."""
     report, failures = reporter()
     gaps = scan_verdict(report, (2, 384, 16, 64, 128), 128, interpret=True)
-    return {"ok": not failures, "failed": failures, "scan": gaps}
+    stages = stages_verdict(report, (2, 2048, 8, 32, 128), interpret=True)
+    return {"ok": not failures, "failed": failures, "scan": gaps,
+            "stages": stages}
 
 
 def child_kernels(other_flash: str = "") -> dict:
@@ -776,15 +898,8 @@ def child_kernels(other_flash: str = "") -> dict:
                 q, k, v, blk_q=blk, blk_k=blk, interpret=False, **kw))
             bwd = jax.jit(lambda *a: fa._bwd_pallas(
                 *a, blk_q=blk, blk_k=blk, dlse=None, interpret=False, **kw))
-            took = []
-            for fn, args in ((fwd, (q, k, v)),
-                             (bwd, (q, k, v, o_x, lse_x, do))):
-                jax.block_until_ready(fn(*args))
-                t0 = time.perf_counter()
-                for _ in range(10):
-                    out = fn(*args)
-                jax.block_until_ready(out)
-                took.append((time.perf_counter() - t0) / 10 * 1e3)
+            took = [ms_a_call(fn, args) for fn, args in (
+                (fwd, (q, k, v)), (bwd, (q, k, v, o_x, lse_x, do)))]
             print(f"flash {shape}: forward {took[0]:.2f} ms, backward "
                   f"{took[1]:.2f} ms a call (host clock, 10 calls)",
                   flush=True)
@@ -811,6 +926,8 @@ def child_kernels(other_flash: str = "") -> dict:
     # the chunked scan's kernels at the hybrid cell's shape
     scan = scan_verdict(report, (2, 8192, 64, 64, 128), 256,
                         interpret=False)
+    # and the mixer's two elementwise stages' (times on the host's clock)
+    stages = stages_verdict(report, (2, 8192, 64, 64, 128), interpret=False)
 
     rng = np.random.default_rng(0)
 
@@ -884,6 +1001,7 @@ def child_kernels(other_flash: str = "") -> dict:
                        all(r[0] for r in res),
                        "p,moments: " + ", ".join(r[1] for r in res))
     return {"ok": not failures, "failed": failures, "scan": scan,
+            "stages": stages,
             "device": {"platform": dev.platform, "kind": dev.device_kind,
                        "count": jax.device_count()}}
 
@@ -1005,8 +1123,8 @@ def main() -> int:
                             600)
             check(res["ok"], "every Pallas kernel matches its XLA "
                   f"expression on the chip (failed: {res['failed']})"
-                  if tpu else "the scan's kernels match its einsums in "
-                  "interpret mode")
+                  if tpu else "the scan's and the stages' kernels match "
+                  "their expressions in interpret mode")
             say("phase train")
             device = phase_train(work, env, tpu=tpu,
                                  lm_args=lm_args)["device"]

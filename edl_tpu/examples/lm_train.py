@@ -461,12 +461,12 @@ def main(argv=None) -> int:
                  cfg.moe_top_k, cfg.moe_score, cfg.moe_route_scale,
                  cfg.moe_shared)
     elif cfg.layer_types:
-        from edl_tpu.ops import ssd
-        log.info("hybrid: layers %s, %s, kv heads %d of %d",
+        from edl_tpu.ops import ssd, ssm_stages
+        sizes = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+        log.info("hybrid: layers %s, %s, %s, kv heads %d of %d",
                  "".join(k[0] for k in cfg.layer_types),
-                 ssd.describe(min(cfg.ssm_chunk, args.seq_len),
-                              cfg.ssm_heads, cfg.ssm_head_dim,
-                              cfg.ssm_state),
+                 ssd.describe(min(cfg.ssm_chunk, args.seq_len), *sizes),
+                 ssm_stages.describe(args.seq_len, *sizes),
                  cfg.kv_heads, cfg.n_heads)
     if args.fused_loss:
         from edl_tpu.ops import fused_xent

@@ -297,6 +297,16 @@ class RMSNorm(nn.Module):
         return (x * scale).astype(self.dtype)
 
 
+class _NormScale(nn.Module):
+    """The scale an `RMSNorm` of this name would hold, alone: for a
+    caller that runs the norm itself (the mixer's, inside its gate's
+    kernel)."""
+
+    @nn.compact
+    def __call__(self, features: int):
+        return self.param("scale", nn.initializers.ones, (features,))
+
+
 def _scaled(x, by: float):
     """x * by, the product made in float32 and cast back (0.22 is no
     bfloat16 number); x itself where ``by`` is 1."""
@@ -688,13 +698,15 @@ class Mamba2Mixer(nn.Module):
         out = (RMSNorm(y * silu(z)) * w) W_out   the gate, then the norm
                                                  over all H*P features
 
-    The recurrence runs in its chunked form (ops/ssd.py). dt, A, the
-    norm and the conv's sum are float32 inside."""
+    The recurrence runs in its chunked form (ops/ssd.py), the conv and
+    the gate with its norm as ops/ssm_stages.py has them (a kernel each
+    on a TPU). dt, A, the norm and the conv's sum are float32 inside."""
 
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, u):
+        from edl_tpu.ops import ssm_stages
         from edl_tpu.ops.ssd import ssd_scan
         cfg = self.cfg
         bsz, s, _ = u.shape
@@ -702,38 +714,35 @@ class Mamba2Mixer(nn.Module):
                           cfg.ssm_conv)
         inner, conv_dim = h * p, h * p + 2 * n
         with jax.named_scope("ssm_in_proj"):
+            # z | xBC | dt, left whole: the two stages read their columns
+            # out of it (ops/ssm_stages.py)
             zxbcdt = _dense(inner + conv_dim + h, ("embed", "mlp"), cfg,
                             name="in_proj")(u)
-            z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], -1)
-        with jax.named_scope("ssm_conv"):
-            # output t sums taps k of input t - (width - 1) + k
-            taps = self.param(
-                "conv_kernel", nn.initializers.variance_scaling(
-                    1.0, "fan_in", "uniform", in_axis=0, out_axis=1),
-                (width, conv_dim))
-            bias = self.param("conv_bias", nn.initializers.zeros,
-                              (conv_dim,))
-            padded = jnp.pad(xbc, ((0, 0), (width - 1, 0), (0, 0)))
-            acc = bias.astype(jnp.float32)
-            for k in range(width):
-                acc = acc + padded[:, k:k + s].astype(jnp.float32) * taps[k]
-            xbc = nn.silu(acc).astype(cfg.dtype)
-            x, b, c = jnp.split(xbc, [inner, inner + n], -1)
+            dt = zxbcdt[..., inner + conv_dim:]
+        # output t sums taps k of input t - (width - 1) + k
+        taps = self.param(
+            "conv_kernel", nn.initializers.variance_scaling(
+                1.0, "fan_in", "uniform", in_axis=0, out_axis=1),
+            (width, conv_dim))
+        bias = self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
+        # x twice, one for each of its two users (see `conv`)
+        x, b, c, x_skip = ssm_stages.conv(zxbcdt, taps, bias, start=inner,
+                                          sizes=(inner, n, n))
+        x, x_skip = (t.reshape(bsz, s, h, p) for t in (x, x_skip))
         a_log = self.param("A_log", _ssm_a_log_init, (h,))
         dt_bias = self.param("dt_bias", _ssm_dt_bias_init, (h,))
         skip = self.param("D", nn.initializers.ones, (h,))
         # `ssm_scan` is the scan's alone (ops/ssd.py opens it around its
-        # forward and its backward); the step sizes' softplus and the
-        # skip term are elementwise work beside it, with the gate
+        # forward and its backward), `ssm_conv` and the rest of
+        # `ssm_gate_norm` the two stages' (ops/ssm_stages.py likewise);
+        # the step sizes' softplus is elementwise work beside them
         with jax.named_scope("ssm_gate_norm"):
             dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-        x = x.reshape(bsz, s, h, p)
         y = ssd_scan(x, dt, -jnp.exp(a_log.astype(jnp.float32)), b, c,
                      chunk=cfg.ssm_chunk)
-        with jax.named_scope("ssm_gate_norm"):
-            y = y.astype(jnp.float32) + skip[:, None] * x.astype(jnp.float32)
-            y = y.reshape(bsz, s, inner) * nn.silu(z.astype(jnp.float32))
-            y = RMSNorm(cfg.norm_eps, cfg.dtype, name="norm")(y)
+        y = ssm_stages.gate_norm(y, x_skip, zxbcdt, skip,
+                                 _NormScale(name="norm")(inner),
+                                 eps=cfg.norm_eps)
         with jax.named_scope("ssm_out_proj"):
             out = _dense(cfg.d_model, ("mlp", "embed"), cfg,
                          name="out_proj")(y)

@@ -153,6 +153,50 @@ def test_chunked_scan_compiles_at_the_hybrid_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.45e9
 
 
+@pytest.mark.parametrize("stage", ["conv", "gate_norm"])
+def test_mixer_stages_compile_at_the_hybrid_cells_shape(
+        one_chip, no_persistent_cache, monkeypatch, stage):
+    """`ops/ssm_stages.py`, forward and written-out backward of one
+    stage of one mamba layer of the same configuration (the projection
+    (2, 8192, 8512), read by block index) on the path a TPU takes: the
+    conv as two calls a direction (the x columns, the B|C columns), the
+    gate and norm as one, inside the kernels' VMEM (the backward of the
+    conv's x columns holds five double-buffered (1024, 1024) blocks:
+    over the default 16 MiB), and temporaries of a few bfloat16
+    activations."""
+    from edl_tpu.ops import ssm_stages
+    # the dispatch asks the backend, and the backend here is the CPU
+    monkeypatch.setattr(ssm_stages, "_path", lambda plan: (
+        "pallas kernel, compiled", False))
+    b, s, h, p, n = 2, 8192, 64, 64, 128
+    inner, bf16 = h * p, jnp.bfloat16
+    proj = sds((b, s, 2 * inner + 2 * n + h), bf16)
+
+    if stage == "conv":
+        def loss(proj, taps, bias):
+            outs = ssm_stages.conv(proj, taps, bias, start=inner,
+                                   sizes=(inner, n, n))
+            return sum(jnp.sum(t.astype(F32) ** 2) for t in outs)
+        args = (proj, sds((4, inner + 2 * n), F32), sds((inner + 2 * n,), F32))
+        names, calls = ("ssm_conv_fwd", "ssm_conv_bwd"), 4
+    else:
+        def loss(y, x, proj, skip, scale):
+            return jnp.sum(ssm_stages.gate_norm(
+                y, x, proj, skip, scale, eps=1e-5).astype(F32) ** 2)
+        args = (sds((b, s, h, p), bf16), sds((b, s, h, p), bf16), proj,
+                sds((h,), F32), sds((inner,), F32))
+        names, calls = ("ssm_gate_norm_fwd", "ssm_gate_norm_bwd"), 2
+    compiled = compile_for(one_chip, jax.grad(
+        loss, argnums=tuple(range(len(args)))), *args)
+    text = compiled.as_text()
+    assert custom_calls(compiled) == calls
+    assert all(name in text for name in names)
+    # conv 415,365,120 B, gate and norm 683,800,576 B: the stage's
+    # outputs and this loss's cotangents of them, in bfloat16
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        0.45e9 if stage == "conv" else 0.72e9)
+
+
 @pytest.mark.parametrize("rows", [BUCKET_ROWS, RAGGED_ROWS])
 def test_pack_kernel_compiles(one_chip, no_persistent_cache, rows):
     compiled = compile_for(

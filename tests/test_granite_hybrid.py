@@ -26,6 +26,7 @@ move the logits by 50 x TOL or more.
 
 import dataclasses
 import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +38,7 @@ from flax.core import meta
 
 from benchmark.reference import granite_hybrid_plain as plain
 from edl_tpu.models import transformer as tfm
-from edl_tpu.ops import ssd
+from edl_tpu.ops import ssd, ssm_stages
 from edl_tpu.train.state import TrainState
 
 TOL = 2e-5
@@ -318,6 +319,233 @@ def test_the_seams_bite_on_the_kernels_path(seam, monkeypatch):
             assert float(jnp.abs(mine[0] - other[0]).max()) == 0.0
             moved = float(jnp.abs(mine[2] - other[2]).max())
     assert moved > 0.1, moved
+
+
+# -- the mixer's two elementwise stages, in interpret mode --------------------
+#
+# `ops/ssm_stages.py`: the conv and the gate with its norm as Pallas
+# kernels with written-out backwards, against the expressions they
+# replace (which run wherever the kernels do not). Shapes: the smallest
+# the tiles take with two row blocks of the conv (1,024 rows each), 16
+# of the gate's, batch 2, 8 heads of 32 (two column slabs of x) and a
+# state of 128. Kernels and expressions differ in the order of float32
+# sums alone; in bfloat16 an output sits one rounding apart where the
+# sums fall on either side (2^-8 of a value), and the conv's d(input)
+# two, because autodiff of the expressions rounds each of the four taps'
+# terms before it adds them and the kernel rounds their float32 sum once.
+
+G_S, G_H, G_P, G_N = 2048, 8, 32, 128
+G_INNER = G_H * G_P
+G_SIZES = (G_INNER, G_N, G_N)
+
+
+def stage_inputs(dtype, s=G_S, h=G_H, p=G_P, n=G_N):
+    """The projection (z | xBC | dt), the scan's y and x, the stages'
+    parameters and a cotangent for every output."""
+    inner = h * p
+    k = jax.random.split(jax.random.PRNGKey(s + h), 10)
+    proj = jax.random.normal(k[0], (2, s, 2 * inner + 2 * n + h)
+                             ).astype(dtype)
+    y, x = (jax.random.normal(q, (2, s, h, p)).astype(dtype) for q in k[1:3])
+    return dict(
+        proj=proj, y=y, x=x,
+        taps=jax.random.uniform(k[3], (4, inner + 2 * n), minval=-0.5,
+                                maxval=0.5),
+        bias=0.2 * jax.random.normal(k[4], (inner + 2 * n,)),
+        skip=jax.random.uniform(k[5], (h,), minval=0.5, maxval=1.5),
+        scale=jax.random.uniform(k[6], (inner,), minval=0.5, maxval=1.5),
+        w_conv=tuple(jax.random.normal(q, (2, s, z)).astype(dtype)
+                     for q, z in zip(k[7:10], (inner, n, n))),
+        w_norm=jax.random.normal(k[9], (2, s, inner)).astype(dtype))
+
+
+def stage_outputs(stage, t):
+    """A stage's outputs and every gradient, by name."""
+    inner, n = t["scale"].shape[0], t["w_conv"][1].shape[-1]
+    if stage == "conv":
+        outs, pull = jax.vjp(lambda *a: ssm_stages.conv(
+            *a, start=inner, sizes=(inner, n, n)),
+            t["proj"], t["taps"], t["bias"])
+        # x comes twice, for its two users: both cotangents go in
+        assert outs[3] is outs[0] or bool(jnp.all(outs[3] == outs[0]))
+        half = t["w_conv"][0] / 2
+        return dict(zip(("x", "B", "C", "d_proj", "d_taps", "d_bias"),
+                        (*outs[:3], *pull((half, *t["w_conv"][1:], half)))))
+    out, pull = jax.vjp(lambda *a: ssm_stages.gate_norm(*a, eps=1e-5),
+                        t["y"], t["x"], t["proj"], t["skip"], t["scale"])
+    return dict(zip(("out", "dy", "dx", "d_proj", "d_skip", "d_scale"),
+                    (out, *pull(t["w_norm"]))))
+
+
+def worst(got, ref):
+    """max |got - ref| over max |ref|, in float32."""
+    got, ref = got.astype(jnp.float32), ref.astype(jnp.float32)
+    return float(jnp.abs(got - ref).max()) / float(jnp.abs(ref).max())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("stage", ["conv", "gate_norm"])
+def test_stage_kernels_are_the_expressions(stage, dtype):
+    """Outputs and every gradient (the input, the taps, the bias; y, x,
+    z, D and the norm's scale) through the kernels against the
+    expressions on the same inputs."""
+    t = stage_inputs(dtype)
+    ref = stage_outputs(stage, t)
+    with ssd.force_interpret_kernels():
+        mine = stage_outputs(stage, t)
+    for name, r in ref.items():
+        g = mine[name]
+        assert g.dtype == r.dtype and g.shape == r.shape, name
+        # sums over 4,096 rows for the parameters' gradients
+        tol = 10 * TOL if dtype == jnp.float32 or r.dtype == jnp.float32 \
+            else 2.0 ** -6 if (stage, name) == ("conv", "d_proj") \
+            else 2.0 ** -7
+        assert worst(g, r) < tol, (name, worst(g, r))
+    # the cotangent of the projection is zero outside a stage's columns
+    cols = slice(G_INNER, 2 * G_INNER + 2 * G_N) if stage == "conv" \
+        else slice(0, G_INNER)
+    outside = jnp.ones(mine["d_proj"].shape[-1], bool).at[cols].set(False)
+    assert float(jnp.abs(mine["d_proj"][..., outside]).max()) == 0.0
+    assert float(jnp.abs(mine["d_proj"][..., cols]).min()) > 0.0
+
+
+def test_conv_kernel_crosses_row_blocks_and_stops_at_a_sequences_start():
+    """Rows 1024-1026 take rows 1021-1023 of the block before them (a
+    halo read), in both directions; positions 0-2 of the batch's second
+    sequence take nothing of the first."""
+    t = stage_inputs(jnp.float32)
+    ref = stage_outputs("conv", t)
+    with ssd.force_interpret_kernels():
+        mine = stage_outputs("conv", t)
+        rows = ssm_stages._conv_plan(G_S, G_INNER, G_SIZES)["rows"]
+        assert G_S == 2 * rows
+        for at in (slice(rows - 3, rows + 3), slice(0, 3)):
+            for name in ("x", "B", "C", "d_proj"):
+                assert worst(mine[name][:, at], ref[name][:, at]) < TOL, name
+        # another first sequence: the second's outputs stay, bit for bit
+        other = dict(t, proj=t["proj"].at[0].mul(-3.0))
+        moved = stage_outputs("conv", other)
+        for name in ("x", "B", "C"):
+            assert float(jnp.abs(moved[name][1] - mine[name][1]).max()) == 0.0
+            assert float(jnp.abs(moved[name][0] - mine[name][0]).max()) > 0.1
+        assert float(jnp.abs(moved["d_proj"][1] - mine["d_proj"][1]
+                             ).max()) == 0.0
+    # and a sequence's first rows see zeros before them: the taps on
+    # absent rows drop out
+    first = jax.nn.silu(t["bias"] + t["taps"][3] * t["proj"][
+        :, 0, G_INNER:2 * G_INNER + 2 * G_N])
+    got = jnp.concatenate([mine[k][:, 0] for k in ("x", "B", "C")], -1)
+    assert float(jnp.abs(got - first).max()) < TOL
+
+
+@pytest.mark.parametrize("hooked, s, h, conv_path, norm_path", [
+    (False, G_S, G_H, "xla expressions", "xla expressions"),
+    (True, G_S, G_H, "pallas kernel, interpret mode",
+     "pallas kernel, interpret mode"),
+    (True, 24, G_H, "xla expressions", "xla expressions"),   # rows off 16
+    (True, G_S, 3, "xla expressions", "xla expressions")])   # 96 lanes
+def test_stages_take_the_path_they_can_see(hooked, s, h, conv_path,
+                                           norm_path, caplog):
+    """Off a TPU the expressions run, the kernels only under the tests'
+    hook and only where the shapes meet their tiles; the line the
+    trainer logs and the lines a trace logs say which, and the trace
+    holds kernels or none."""
+    import contextlib
+    import logging
+    t = stage_inputs(jnp.float32, s=s, h=h)
+    hook = ssd.force_interpret_kernels() if hooked \
+        else contextlib.nullcontext()
+    # the framework's loggers do not propagate: listen on this one
+    stages_log = logging.getLogger("edl_tpu.ops.ssm_stages")
+    stages_log.addHandler(caplog.handler)
+    try:
+        with hook:
+            said = ssm_stages.describe(s, h, G_P, G_N)
+            jaxprs = {stage: str(jax.make_jaxpr(
+                lambda t, stage=stage: stage_outputs(stage, t))(t))
+                for stage in ("conv", "gate_norm")}
+    finally:
+        stages_log.removeHandler(caplog.handler)
+    assert said.startswith(f"conv ({conv_path}") \
+        and f"gate and norm ({norm_path}" in said
+    if conv_path.startswith("pallas"):
+        assert "blocks 1024 x 256 and x 256" in said \
+            and f"blocks 128 x {h * G_P})" in said
+    for stage, path in (("conv", conv_path), ("gate_norm", norm_path)):
+        assert ("pallas_call" in jaxprs[stage]) == path.startswith("pallas")
+    logged = [r.getMessage() for r in caplog.records]
+    assert any(m.startswith("ssm conv") and m.endswith(conv_path)
+               for m in logged)
+    assert any(m.startswith("ssm gate and norm") and m.endswith(norm_path)
+               for m in logged)
+
+
+K_MIXER = dict(ssm_heads=G_H, ssm_head_dim=G_P, ssm_state=G_N, ssm_chunk=128)
+
+
+@pytest.fixture(scope="module")
+def kernel_mixer():
+    """A mixer whose scan and stages all meet their kernels' tiles (256
+    positions, two chunks), seeded like `tree`."""
+    cfg = small(**K_MIXER)
+    u = jax.random.normal(jax.random.PRNGKey(8), (2, 256, D))
+    params = meta.unbox(tfm.Mamba2Mixer(cfg).init(
+        jax.random.PRNGKey(9), u))["params"]
+    rng = np.random.default_rng(23)
+    params = dict(params, D=jnp.asarray(rng.uniform(0.5, 1.5, (G_H,)),
+                                        jnp.float32),
+                  conv_bias=jnp.asarray(
+                      rng.normal(0, 0.2, (G_INNER + 2 * G_N,)), jnp.float32),
+                  norm={"scale": jnp.asarray(
+                      rng.uniform(0.5, 1.5, (G_INNER,)), jnp.float32)})
+    return cfg, params, u
+
+
+def test_mixer_is_the_same_through_both_paths(kernel_mixer):
+    """The mixer's output and every parameter's gradient with the scan
+    and both stages as kernels, against the einsums and expressions."""
+    cfg, params, u = kernel_mixer
+    w = jax.random.normal(jax.random.PRNGKey(10), u.shape)
+
+    def run():
+        return jax.value_and_grad(lambda p, u: jnp.sum(
+            tfm.Mamba2Mixer(cfg).apply({"params": p}, u) * w),
+            argnums=(0, 1), has_aux=False)(params, u)
+    ref = run()
+    with ssd.force_interpret_kernels():
+        jaxpr = str(jax.make_jaxpr(lambda p, u: tfm.Mamba2Mixer(cfg).apply(
+            {"params": p}, u))(params, u))
+        mine = run()
+    assert sorted(set(re.findall(r"name=(ss\w+)", jaxpr))) == [
+        "ssd_fwd", "ssm_conv_fwd", "ssm_gate_norm_fwd"]
+    assert set(params) == {"in_proj", "conv_kernel", "conv_bias", "A_log",
+                           "dt_bias", "D", "norm", "out_proj"}
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(mine)[0],
+                            jax.tree.leaves(ref)):
+        assert worst(g, r) < 20 * TOL, (jax.tree_util.keystr(path),
+                                        worst(g, r))
+
+
+@pytest.mark.parametrize("path", ["expressions", "kernels"])
+def test_both_stages_scopes_are_on_the_steps_forward_and_backward(
+        kernel_mixer, path):
+    """`benchmark/reduce/ssm_scopes.py` sorts device time by scope:
+    every operation of the two stages, the kernels' calls among them,
+    carries `ssm_conv` or `ssm_gate_norm` in the lowered gradient, in
+    the forward and in the backward."""
+    import contextlib
+    cfg, params, u = kernel_mixer
+    hook = ssd.force_interpret_kernels() if path == "kernels" \
+        else contextlib.nullcontext()
+    with hook:
+        text = jax.jit(jax.grad(lambda p, u: jnp.sum(tfm.Mamba2Mixer(
+            cfg).apply({"params": p}, u)))).lower(params, u).as_text(
+                debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    for scope in ("ssm_conv", "ssm_gate_norm"):
+        under = [n for n in names if f"/{scope}/" in n]
+        assert any("transpose(" in n for n in under), scope
+        assert any("transpose(" not in n for n in under), scope
 
 
 # -- the layers -------------------------------------------------------------
