@@ -28,9 +28,19 @@ cached read + ``if`` (no record, no clock, no file):
 - a profile directory — ``EDL_TPU_PROFILE_DIR`` in the environment, or
   :func:`collect` called by an entry point that parsed ``--profile``.
   Finished spans are kept in memory from then on and written to
-  ``<profile_dir>/spans-<pid>.jsonl`` only by :func:`flush` (the train
-  loop calls it when its profiler stops; also at exit and on SIGTERM):
-  no write per span on the loop's thread.
+  ``<profile_dir>/spans-<pid>.jsonl`` only by :func:`flush` (the clock
+  sampler's thread calls it about once a second, and at once when the
+  train loop asks through :func:`flush_soon` after a save; the loop
+  itself when its profiler stops; also at exit and on SIGTERM): no
+  write per span on the loop's thread, and a SIGKILL loses the last
+  second at most.
+
+While spans are on, one daemon thread watches the host's clock beside
+the program (:func:`_sample_clock`): it sleeps a fixed tick, and a tick
+that comes back late leaves a ``host.clock_gap`` span, so that a stall
+of the machine, of the process or of the interpreter lock is a record
+and not a guess; its first record, ``host.clock_sampler``, says that it
+watched. With neither switch the thread does not exist.
 
 One clock: a span's ``t0`` is wall-clock (files of several processes
 merge) and its ``dur`` is a ``perf_counter`` difference. While a device
@@ -82,6 +92,16 @@ _cached: tuple[bool, str | None, bool] | None = None
 _exit_armed = False
 _annotate = None      # (name, attrs) -> context manager, or None
 
+# The clock sampler: constants, not knobs. A tick this many ticks late
+# is a gap (a busy but healthy host wakes a sleeper within a tick).
+TICK_S = 0.05
+GAP_TICKS = 4
+FLUSH_EVERY_S = 1.0
+SAMPLER_THREAD = "edl-clock-sampler"
+SAMPLER_MARK = "host.clock_sampler"
+# guarded-by: _lock; (thread, its wake event, its stop event)
+_sampler = None
+
 
 def _setting() -> tuple[bool, str | None, bool]:
     """(enabled, sink_dir, buffered) — parsed once per process; tests
@@ -98,6 +118,8 @@ def _setting() -> tuple[bool, str | None, bool]:
             _arm_exit_flush()
         else:
             _cached = (False, None, False)
+        if _cached[0]:
+            _start_sampler()
     return _cached
 
 
@@ -109,6 +131,7 @@ def collect(profile_dir: str) -> None:
     if not _setting()[0]:
         _cached = (True, profile_dir, True)
         _arm_exit_flush()
+        _start_sampler()
 
 
 def _arm_exit_flush() -> None:
@@ -132,11 +155,88 @@ def _flush_and_die(signum, frame) -> None:
     os.kill(os.getpid(), signum)
 
 
+def _start_sampler() -> None:
+    """One clock sampler a process, started where spans switch on."""
+    global _sampler
+    with _lock:
+        if _sampler is not None:
+            return
+        wake, stop = threading.Event(), threading.Event()
+        thread = threading.Thread(target=_sample_clock, args=(wake, stop),
+                                  name=SAMPLER_THREAD, daemon=True)
+        _sampler = (thread, wake, stop)
+        thread.start()
+
+
+def _stop_sampler() -> None:
+    global _sampler
+    with _lock:
+        sampler, _sampler = _sampler, None
+    if sampler is not None:
+        thread, wake, stop = sampler
+        stop.set()
+        wake.set()
+        thread.join(timeout=10 * TICK_S)
+
+
+def flush_soon() -> None:
+    """Have the sampler's thread flush now, not at its next second: for
+    a record that a SIGKILL may follow within milliseconds (a save's
+    snapshot, whose step line a supervisor may act on). No write on the
+    caller's thread; nothing where spans are off."""
+    sampler = _sampler
+    if sampler is not None:
+        sampler[1].set()
+
+
+def _sample_clock(wake: threading.Event, stop: threading.Event) -> None:
+    """The sampler's thread: sleep a tick, see when it came back. A tick
+    more than ``GAP_TICKS`` ticks late becomes a finished span
+    ``host.clock_gap`` (``t0`` when the tick was due, ``dur`` how late it
+    was). Its one attribute is what tells the causes apart on the chip's
+    host: ``cpu_s``, this process's CPU seconds over the tick, is about 0
+    when nothing of ours ran (the machine stood still, or the process
+    was stopped or kept off the cores) and about the gap or more when
+    one of our threads ran all through it and kept the interpreter lock
+    from this one. (The kernel there keeps neither a thread's run-queue
+    delay nor ``/proc/pressure``, so neither is read; PERF.md §6, PR 38.)
+
+    The same thread flushes what is buffered about once a second, and at
+    once when :func:`flush_soon` wakes it. Neither blinds it: a wake
+    that comes back late is as late as a tick that does (an early one
+    reads negative), and the next tick is due from before the flush, so
+    a write that stood still shows as a gap too.
+
+    Its first record is ``host.clock_sampler``, of no duration: a reader
+    then knows that a window without gaps was watched."""
+    _, _, buffered = _setting()
+    event(SAMPLER_MARK, 0.0)
+    cpu = time.process_time()
+    due = time.monotonic() + TICK_S
+    next_flush = due + FLUSH_EVERY_S
+    while True:
+        woken = wake.wait(max(0.0, due - time.monotonic()))
+        if stop.is_set():
+            return
+        now = time.monotonic()
+        cpu, cpu_before = time.process_time(), cpu
+        if now - due > GAP_TICKS * TICK_S:
+            event("host.clock_gap", now - due, t0=time.time() - (now - due),
+                  attrs={"cpu_s": round(cpu - cpu_before, 6)})
+        due = now + TICK_S
+        if woken:
+            wake.clear()
+        if buffered and (woken or now >= next_flush):
+            next_flush = now + FLUSH_EVERY_S
+            flush()
+
+
 def reconfigure() -> None:
     """Re-read the environment and drop the sink file handle, the ring,
-    what waits for a flush and the annotator (tests flip the env
-    mid-process; real processes never need this)."""
+    what waits for a flush, the annotator and the clock sampler (tests
+    flip the env mid-process; real processes never need this)."""
     global _cached, _file, _file_pid, _annotate
+    _stop_sampler()
     with _lock:
         _cached = None
         _annotate = None

@@ -236,15 +236,16 @@ class CheckpointManager:
         os.makedirs(self.directory, exist_ok=True)
         tmp = tempfile.mkdtemp(prefix=".tmp-ckpt-", dir=self.directory)
         try:
-            payload = serialization.to_bytes(host_state)
-            # serialized size AS STORED — quantized resident moments
-            # (train/fused_opt.py int8 planes) msgpack their codes, so
-            # the ~2x opt-state cut is visible here and in bench's
-            # checkpoint row, not only in HBM
-            with self._cond:
-                self._stats["state_bytes_last"] = len(payload)
-            with open(os.path.join(tmp, "state.msgpack"), "wb") as f:
-                f.write(payload)
+            with trace.span("ckpt.chunks"):
+                payload = serialization.to_bytes(host_state)
+                # serialized size AS STORED — quantized resident moments
+                # (train/fused_opt.py int8 planes) msgpack their codes, so
+                # the ~2x opt-state cut is visible here and in bench's
+                # checkpoint row, not only in HBM
+                with self._cond:
+                    self._stats["state_bytes_last"] = len(payload)
+                with open(os.path.join(tmp, "state.msgpack"), "wb") as f:
+                    f.write(payload)
             meta = {"version": version, "status": status.to_dict()}
             with open(os.path.join(tmp, "meta.json"), "w") as f:
                 json.dump(meta, f)
@@ -265,7 +266,8 @@ class CheckpointManager:
             # skip the fold and renumber over a published checkpoint.
             self._remote_folded = True
         if mirror_this:
-            self._mirror(version)
+            with trace.span("ckpt.mirror"):
+                self._mirror(version)
         self._gc()
         return version
 
@@ -343,19 +345,21 @@ class CheckpointManager:
         # the same deterministic name (possibly from a different world
         # shape); sealing them in would corrupt the restore, so rank 0
         # clears the dir before anyone writes.
-        if self.process_index == 0:
-            shutil.rmtree(tmp, ignore_errors=True)
-        # Every rank clears its OWN stale pending dirs from earlier
-        # versions: on non-shared dirs only rank 0 ever renames or runs
-        # _gc, so without this each save would leak a full shard copy
-        # per pod (at most the CURRENT pending dir remains between
-        # saves). Safe on shared dirs too — anything below the agreed
-        # version is an orphan by the begin barrier.
-        for n in os.listdir(self.directory):
-            if (n.startswith(".tmp-ckpt-")
-                    and n != os.path.basename(tmp)):
-                shutil.rmtree(os.path.join(self.directory, n),
-                              ignore_errors=True)
+        with trace.span("ckpt.clean"):
+            if self.process_index == 0:
+                shutil.rmtree(tmp, ignore_errors=True)
+            # Every rank clears its OWN stale pending dirs from earlier
+            # versions: on non-shared dirs only rank 0 ever renames or
+            # runs _gc, so without this each save would leak a full
+            # shard copy per pod (at most the CURRENT pending dir
+            # remains between saves). Safe on shared dirs too —
+            # anything below the agreed version is an orphan by the
+            # begin barrier.
+            for n in os.listdir(self.directory):
+                if (n.startswith(".tmp-ckpt-")
+                        and n != os.path.basename(tmp)):
+                    shutil.rmtree(os.path.join(self.directory, n),
+                                  ignore_errors=True)
         self._sync("edl_ckpt_clean")
         # A process that fails mid-write must still reach the barrier
         # (otherwise the healthy ranks hang in it until the coordination
@@ -371,7 +375,8 @@ class CheckpointManager:
                     if sp is not None:
                         sp.attrs["bytes"] = _nbytes(
                             a for _, a in snap["chunks"])
-            my_files = sc.write_snapshot(tmp, snap)
+            with trace.span("ckpt.chunks"):
+                my_files = sc.write_snapshot(tmp, snap)
             with self._cond:
                 self._stats["files_last"] = len(my_files)
         except BaseException as exc:  # noqa: BLE001 — re-raised below
@@ -394,8 +399,9 @@ class CheckpointManager:
             # raising first would strand the healthy world in the mirror
             # barriers until the coordination timeout. A rank that
             # failed (or saw poison) participates without uploading.
-            mirror_ok = self._mirror_sharded_upload(
-                tmp, version, my_files, ok=ok and remote_read_ok)
+            with trace.span("ckpt.mirror"):
+                mirror_ok = self._mirror_sharded_upload(
+                    tmp, version, my_files, ok=ok and remote_read_ok)
         else:
             mirror_ok = False
         if not ok:
@@ -445,7 +451,8 @@ class CheckpointManager:
             # files from a crashed earlier attempt at this version,
             # which (same world shape) could pass the exact-set check
             # and flip LATEST to old-step data.
-            self._mirror_sharded_finalize(version)
+            with trace.span("ckpt.mirror"):
+                self._mirror_sharded_finalize(version)
         self._gc()
         return version
 
@@ -529,16 +536,20 @@ class CheckpointManager:
                         self.remote, exc)  # not kill a sealed local save
 
     def _gc(self, *, sealed_only: bool = False) -> None:
-        versions = self.versions()
-        for version in versions[: max(0, len(versions) - self.max_to_keep)]:
-            shutil.rmtree(self._path(version), ignore_errors=True)
-        if sealed_only:
-            return
-        # clean any orphaned temp dirs from crashed saves
-        for name in os.listdir(self.directory):
-            if name.startswith(".tmp-ckpt-"):
-                path = os.path.join(self.directory, name)
-                shutil.rmtree(path, ignore_errors=True)
+        with trace.span("ckpt.gc") as sp:
+            versions = self.versions()
+            stale = versions[: max(0, len(versions) - self.max_to_keep)]
+            for version in stale:
+                shutil.rmtree(self._path(version), ignore_errors=True)
+            if sp is not None:
+                sp.attrs["removed"] = len(stale)
+            if sealed_only:
+                return
+            # clean any orphaned temp dirs from crashed saves
+            for name in os.listdir(self.directory):
+                if name.startswith(".tmp-ckpt-"):
+                    path = os.path.join(self.directory, name)
+                    shutil.rmtree(path, ignore_errors=True)
 
     def gc_stale_tmp(self) -> None:
         """Startup GC: remove torn ``.tmp-*`` dirs — partial saves from a
@@ -636,6 +647,7 @@ class CheckpointManager:
             # memory is free again while this one's is allocated (at
             # most one in-flight + one pending snapshot live).
             with self._cond:
+                writer_inflight = self._inflight
                 superseded = self._pending is not None
                 if superseded:
                     self._pending = None
@@ -664,8 +676,10 @@ class CheckpointManager:
             if sp is not None:
                 job["bytes"] = _nbytes(arrays)
                 sp.attrs.update(bytes=job["bytes"], copied_bytes=copied,
-                                superseded=superseded)
-        stall_ms = (time.perf_counter() - t0) * 1e3
+                                superseded=superseded,
+                                writer_inflight=writer_inflight)
+        job["queued_at"] = time.perf_counter()
+        stall_ms = (job["queued_at"] - t0) * 1e3
         with self._cond:
             if self._closed:
                 raise RuntimeError("CheckpointManager is closed")
@@ -714,7 +728,8 @@ class CheckpointManager:
                 t0 = time.perf_counter()
                 with trace.span("ckpt.write", attrs={
                         "step": job["status"].step,
-                        "bytes": job.get("bytes", 0)}) as sp:
+                        "bytes": job.get("bytes", 0),
+                        "queued_s": round(t0 - job["queued_at"], 6)}) as sp:
                     if job["kind"] == "sharded":
                         ver = self._save_sharded(None, job["status"],
                                                  snap=job["snap"])
@@ -729,10 +744,6 @@ class CheckpointManager:
                         with self._cond:
                             sp.attrs["files"] = self._stats["files_last"]
                 dt = time.perf_counter() - t0
-                # a profiled process keeps its spans in memory: this
-                # thread writes files anyway, so it also writes those
-                # (a SIGKILL then loses the spans since the last seal)
-                trace.flush()
                 with self._cond:
                     self._stats["writes"] += 1
                     self._stats["write_s_last"] = dt

@@ -122,10 +122,9 @@ class TrainLoop:
             # here on (entry points that parse --profile call this
             # earlier, for their start-up spans); written at flush()
             trace.collect(self.config.profile_dir)
-        # Save-stall accounting (benchlog/timeline): step-loop-visible ms
-        # spent in _save calls — full write under sync, snapshot copy
-        # under async — plus the restore seconds of this run's resume.
-        self.ckpt_stall_ms_total = 0.0
+        # Saves this loop asked for (the stall each cost is the
+        # manager's `save_stall_ms_total`), plus the restore seconds of
+        # this run's resume.
         self.ckpt_saves = 0
         self.restore_s: float | None = None
         self._first_step_done = False
@@ -246,12 +245,17 @@ class TrainLoop:
         if self.ckpt is None:
             return
         use_sync = (not self.config.ckpt_async) if sync is None else sync
-        t0 = time.perf_counter()
-        if use_sync:
-            self.ckpt.save(self.state, self.status)
-        else:
-            self.ckpt.save_async(self.state, self.status)
-        self.ckpt_stall_ms_total += (time.perf_counter() - t0) * 1e3
+        # `ckpt.snapshot` is its child: what a save costs the loop
+        # outside the snapshot is this span's self time
+        with trace.span("train.save"):
+            if use_sync:
+                self.ckpt.save(self.state, self.status)
+            else:
+                self.ckpt.save_async(self.state, self.status)
+        # a supervisor may act on this save's step line within
+        # milliseconds (a SIGKILL, say): its record goes to disk now, on
+        # the sampler's thread
+        trace.flush_soon()
         self.ckpt_saves += 1
 
     def _adopt(self, reform) -> str:
@@ -419,12 +423,12 @@ class TrainLoop:
         self.restore_source = "disk"
 
     def ckpt_stats(self) -> dict:
-        """Checkpoint-plane accounting for benchlog extras: loop-side
-        stall totals + the manager's snapshot/write/supersede stats."""
-        out = {"ckpt_save_stall_ms_total": round(self.ckpt_stall_ms_total, 3),
-               "ckpt_save_stall_ms_mean": round(
-                   self.ckpt_stall_ms_total / self.ckpt_saves, 3)
-               if self.ckpt_saves else 0.0,
+        """Checkpoint-plane accounting for benchlog extras: the loop's
+        save count + the manager's stall/snapshot/write/supersede stats
+        (`ckpt_save_stall_ms_total` and `_mean` are the manager's; 0
+        without a checkpoint directory)."""
+        out = {"ckpt_save_stall_ms_total": 0.0,
+               "ckpt_save_stall_ms_mean": 0.0,
                "ckpt_saves": self.ckpt_saves,
                "ckpt_async": bool(self.config.ckpt_async)}
         if self.restore_s is not None:
@@ -591,7 +595,8 @@ class TrainLoop:
                      cfg.profile_start_step,
                      cfg.profile_start_step + cfg.profile_steps,
                      cfg.profile_dir)
-            jax.profiler.start_trace(cfg.profile_dir)
+            with trace.span("train.profiler"):
+                jax.profiler.start_trace(cfg.profile_dir)
             # from here every span of this process, on any thread, is
             # also an event of the profiler's host plane: one file, one
             # clock with the device's lines
@@ -599,19 +604,20 @@ class TrainLoop:
             self._profiling = True
         elif self._profiling and self.status.step >= \
                 cfg.profile_start_step + cfg.profile_steps:
-            # force pending dispatches to land inside the trace; the
-            # state is the live device data (last_metrics is already
-            # host numpy by the time it's stored)
-            jax.block_until_ready(self.state)
             self._stop_profiler()
             log.info("profiler: trace written to %s", cfg.profile_dir)
             _log_device_memory()
 
     def _stop_profiler(self) -> None:
         trace.set_annotator(None)
-        jax.profiler.stop_trace()
-        self._profiling = False
-        trace.flush()   # spans-<pid>.jsonl beside the profiler's file
+        with trace.span("train.profiler"):
+            # force pending dispatches to land inside the trace; the
+            # state is the live device data (last_metrics is already
+            # host numpy by the time it's stored)
+            jax.block_until_ready(self.state)
+            jax.profiler.stop_trace()
+            self._profiling = False
+            trace.flush()   # spans-<pid>.jsonl beside the profiler's file
 
     def _step_annotation(self):
         """The profiler's own step marker around one iteration, while
@@ -799,10 +805,12 @@ class TrainLoop:
                     self.last_metrics = metrics
                     elapsed = time.perf_counter() - window_start
                     rate = window_samples / max(elapsed, 1e-9)
-                    log.info("epoch %d step %d: %s %.1f samples/s",
-                             epoch, self.status.step, _fmt(metrics), rate)
-                    for hook in self.hooks:
-                        hook(self, epoch, self.status.step, metrics)
+                    with trace.span("train.log_line"):
+                        log.info("epoch %d step %d: %s %.1f samples/s",
+                                 epoch, self.status.step, _fmt(metrics),
+                                 rate)
+                        for hook in self.hooks:
+                            hook(self, epoch, self.status.step, metrics)
                     window_start = time.perf_counter()
                     window_samples = 0
 

@@ -24,6 +24,10 @@ from edl_tpu.obs import metrics, recorder, trace
 @pytest.fixture
 def traced(tmp_path, monkeypatch):
     """Tracing on with a per-test sink dir; ring cleared both ways."""
+    # the traces counted here are the tests' own: no `host.clock_gap`
+    # of a loaded test machine among them (tests/test_tracing.py has
+    # the sampler's tests)
+    monkeypatch.setattr(trace, "_start_sampler", lambda: None)
     monkeypatch.setenv("EDL_TPU_TRACE", str(tmp_path / "trace"))
     trace.reconfigure()
     yield str(tmp_path / "trace")

@@ -17,9 +17,11 @@ import pytest
 from edl_tpu.obs import trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LOOP_SPANS = ("train.dispatch", "train.loader_wait", "ckpt.snapshot",
-              "ckpt.d2h", "ckpt.stage")
-WRITER_SPANS = ("ckpt.write", "ckpt.seal")
+LOOP_SPANS = ("train.dispatch", "train.loader_wait", "train.save",
+              "ckpt.snapshot", "ckpt.d2h", "ckpt.stage")
+WRITER_SPANS = ("ckpt.write", "ckpt.clean", "ckpt.chunks", "ckpt.seal",
+                "ckpt.gc")
+START_SAMPLER = trace._start_sampler
 STARTUP = ("imports", "args", "runtime", "mesh_model", "state_init",
            "loop_init")
 
@@ -28,8 +30,10 @@ STARTUP = ("imports", "args", "runtime", "mesh_model", "state_init",
 def _clean_trace_state(monkeypatch):
     monkeypatch.delenv("EDL_TPU_TRACE", raising=False)
     monkeypatch.delenv("EDL_TPU_PROFILE_DIR", raising=False)
-    # a test process keeps its own SIGTERM and exit behaviour
+    # a test process keeps its own SIGTERM and exit behaviour, and its
+    # records are those the test made (the sampler's tests start it)
     monkeypatch.setattr(trace, "_arm_exit_flush", lambda: None)
+    monkeypatch.setattr(trace, "_start_sampler", lambda: None)
     trace.reconfigure()
     yield
     trace.reconfigure()
@@ -148,6 +152,49 @@ def test_snapshot_record_carries_bytes_and_children(traced_run):
     assert {s["parent"] for s in seals} == {w["sid"] for w in writes}
 
 
+def test_a_save_is_one_span_and_the_writers_phases_have_names(traced_run):
+    recs = traced_run["records"]
+    saves = [r for r in recs if r["name"] == "train.save"]
+    snaps = [r for r in recs if r["name"] == "ckpt.snapshot"]
+    assert [s["attrs"]["step"] for s in snaps] == [5, 10, 15, 16]
+    assert [s["parent"] for s in snaps] == [r["sid"] for r in saves]
+    assert all(isinstance(s["attrs"]["writer_inflight"], bool)
+               for s in snaps)
+    for write in (r for r in recs if r["name"] == "ckpt.write"):
+        kids = [r for r in recs if r["parent"] == write["sid"]]
+        assert [k["name"] for k in kids] == [
+            "ckpt.clean", "ckpt.chunks", "ckpt.seal", "ckpt.gc"]
+        assert kids[3]["attrs"]["removed"] >= 0
+        assert sum(k["dur"] for k in kids) <= write["dur"] + 1e-3
+        # the job lay in the slot from the snapshot's end until the
+        # writer took it: the write begins that much after the hand-over
+        assert 0 <= write["attrs"]["queued_s"] < 60
+        snap = next(s for s in snaps
+                    if s["attrs"]["step"] == write["attrs"]["step"])
+        assert write["t0"] >= snap["t0"] + snap["dur"] - 1e-3
+    # the profiler's start and its stop
+    assert [r["name"] for r in recs].count("train.profiler") == 2
+
+
+def test_the_loops_thread_is_tiled_by_its_own_spans(traced_run):
+    """Between the second and the last dispatch (the first is followed
+    by the wait for its compile, which is start-up's and no window's)
+    the loop's thread is under one of its own spans at least 95 % of
+    the time (a CPU's steps are short; on the chip 99 %, PERF.md)."""
+    recs = traced_run["records"]
+    dispatch = [r for r in recs if r["name"] == "train.dispatch"]
+    lo = dispatch[1]["t0"]
+    hi = dispatch[-1]["t0"] + dispatch[-1]["dur"]
+    mine = sorted((max(r["t0"], lo), min(r["t0"] + r["dur"], hi))
+                  for r in recs if r["thread"] == dispatch[0]["thread"])
+    covered, at = 0.0, lo
+    for a, b in mine:
+        if b > max(a, at):
+            covered += b - max(a, at)
+            at = b
+    assert covered / (hi - lo) >= 0.95, covered / (hi - lo)
+
+
 def test_first_dispatch_carries_the_cache_counts(traced_run):
     first, second = [r for r in traced_run["records"]
                      if r["name"] == "train.dispatch"][:2]
@@ -179,8 +226,8 @@ def test_restore_leaves_a_span_only_when_it_restored(traced_run, tmp_path):
     assert span["attrs"] == {"source": "disk", "version": 0, "bytes": 32}
     assert status.step == 3 and restored["w"][7] == 7
     names = [r["name"] for r in trace.finished("ckpt.")]
-    assert names == ["ckpt.snapshot", "ckpt.seal", "ckpt.write",
-                     "ckpt.restore"]
+    assert names == ["ckpt.snapshot", "ckpt.chunks", "ckpt.seal", "ckpt.gc",
+                     "ckpt.write", "ckpt.restore"]
 
 
 def test_startup_children_sum_to_their_parent(traced_run):
@@ -213,6 +260,7 @@ def _no_clock(*_a, **_k):
 def test_off_makes_no_record_reads_no_clock_touches_no_file(
         tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(trace, "_start_sampler", START_SAMPLER)
     emitted = []
     monkeypatch.setattr(trace, "_emit", emitted.append)
     fake_time = type("T", (), {"time": _no_clock,
@@ -227,8 +275,16 @@ def test_off_makes_no_record_reads_no_clock_touches_no_file(
     trace.instant("launch.exit_seen")
     assert trace.event("startup.imports", 1.0) is None
     trace.flush()
+    trace.flush_soon()
     assert emitted == [] and annotated == []
     assert trace.finished() == [] and os.listdir(tmp_path) == []
+    _assert_no_sampler()
+
+
+def _assert_no_sampler():
+    assert trace._sampler is None
+    assert trace.SAMPLER_THREAD not in [
+        t.name for t in threading.enumerate()]
 
 
 def test_loop_without_switches_emits_nothing(tmp_path, monkeypatch):
@@ -239,6 +295,7 @@ def test_loop_without_switches_emits_nothing(tmp_path, monkeypatch):
     from edl_tpu.train.loop import LoopConfig, TrainLoop
 
     monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(trace, "_start_sampler", START_SAMPLER)
     calls = []
     monkeypatch.setattr(trace, "_emit", calls.append)
 
@@ -253,6 +310,11 @@ def test_loop_without_switches_emits_nothing(tmp_path, monkeypatch):
     assert calls == []
     assert not glob.glob(str(tmp_path / "**" / "spans-*.jsonl"),
                          recursive=True)
+    _assert_no_sampler()
+    # the stall the loop's line reports is the manager's count
+    stats = loop.ckpt_stats()
+    assert stats["ckpt_save_stall_ms_total"] == round(
+        loop.ckpt.stats()["save_stall_ms_total"], 3) > 0
 
 
 def test_profile_dir_buffers_until_flush(tmp_path, monkeypatch):
@@ -358,6 +420,7 @@ def test_sigterm_flushes_a_profiled_process(tmp_path):
     code = (
         "import os, sys, time\n"
         "from edl_tpu.obs import trace\n"
+        "trace._start_sampler = lambda: None   # SIGTERM alone flushes\n"
         "with trace.span('train.dispatch', attrs={'step': 1}):\n"
         "    pass\n"
         "print('ready', flush=True)\n"
@@ -374,6 +437,150 @@ def test_sigterm_flushes_a_profiled_process(tmp_path):
     (rec,) = [json.loads(line) for line in
               open(tmp_path / f"spans-{child.pid}.jsonl")]
     assert rec["name"] == "train.dispatch" and rec["pid"] == child.pid
+
+
+# -- the clock sampler -------------------------------------------------------
+
+def test_a_switch_starts_one_sampler_and_reconfigure_stops_it(
+        tmp_path, monkeypatch):
+    monkeypatch.setattr(trace, "_start_sampler", START_SAMPLER)
+    _assert_no_sampler()
+    trace.collect(str(tmp_path))
+    trace.collect(str(tmp_path))
+    assert trace.enabled()
+    assert [t.name for t in threading.enumerate()].count(
+        trace.SAMPLER_THREAD) == 1
+    trace.reconfigure()
+    _assert_no_sampler()
+    monkeypatch.setenv("EDL_TPU_TRACE", str(tmp_path / "t"))
+    assert trace.enabled()
+    assert [t.name for t in threading.enumerate()].count(
+        trace.SAMPLER_THREAD) == 1
+
+
+def _child(code: str, env_switch: dict):
+    env = {**os.environ, "PYTHONPATH": ROOT, **env_switch}
+    for other in {"EDL_TPU_TRACE", "EDL_TPU_PROFILE_DIR"} - set(env_switch):
+        env.pop(other, None)
+    child = subprocess.Popen([sys.executable, "-c", code], env=env,
+                             stdout=subprocess.PIPE, text=True)
+    assert child.stdout.readline().strip() == "ready"
+    return child
+
+
+def _records_of(child, directory) -> list[dict]:
+    with open(os.path.join(directory, f"spans-{child.pid}.jsonl")) as f:
+        return [json.loads(line) for line in f]
+
+
+def _largest_gap(child, directory) -> dict:
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        try:
+            gaps = [r for r in _records_of(child, directory)
+                    if r["name"] == "host.clock_gap"]
+        except FileNotFoundError:
+            gaps = []
+        if gaps:
+            return max(gaps, key=lambda r: r["dur"])
+        time.sleep(0.05)
+    raise AssertionError("the sampler recorded no gap")
+
+
+def test_a_stopped_process_leaves_a_gap_with_no_cpu_in_it(tmp_path):
+    code = ("import time\n"
+            "from edl_tpu.obs import trace\n"
+            "assert trace.enabled()\n"
+            "time.sleep(0.3)\n"           # the sampler is in its loop
+            "print('ready', flush=True)\n"
+            "time.sleep(60)\n")
+    child = _child(code, {"EDL_TPU_TRACE": str(tmp_path)})
+    try:
+        child.send_signal(signal.SIGSTOP)
+        time.sleep(0.6)
+        child.send_signal(signal.SIGCONT)
+        gap = _largest_gap(child, tmp_path)
+    finally:
+        child.kill()
+        child.wait(timeout=30)
+    assert gap["thread"] == trace.SAMPLER_THREAD and gap["parent"] is None
+    assert 0.3 < gap["dur"] < 5.0, gap
+    # nothing of the process ran while it was stopped
+    assert gap["attrs"]["cpu_s"] < 0.1, gap
+
+
+@pytest.mark.parametrize("switch, asked", [
+    ("EDL_TPU_TRACE", ""),
+    # woken to flush and then kept from the lock: the wake is late as a
+    # tick would be (a save's writer serialises right after `_save`
+    # asked for the flush)
+    ("EDL_TPU_PROFILE_DIR", "trace.flush_soon(), ")])
+def test_a_held_interpreter_lock_leaves_a_gap_full_of_our_cpu(
+        tmp_path, switch, asked):
+    code = ("import threading, time\n"
+            "from edl_tpu.obs import trace\n"
+            "assert trace.enabled()\n"
+            "t0 = time.perf_counter(); sum(range(2_000_000))\n"
+            "n = int(2_000_000 * 0.8 / (time.perf_counter() - t0))\n"
+            "time.sleep(0.3)\n"
+            "print('ready', flush=True)\n"
+            # one C call that never lets go of the lock
+            f"t = threading.Thread(target=lambda: ({asked}sum(range(n))))\n"
+            "t.start(); t.join(); time.sleep(60)\n")
+    child = _child(code, {switch: str(tmp_path)})
+    try:
+        gap = _largest_gap(child, tmp_path)
+    finally:
+        child.kill()
+        child.wait(timeout=30)
+    assert 0.3 < gap["dur"] < 10.0, gap
+    # one of our threads ran all through the gap
+    assert gap["attrs"]["cpu_s"] > 0.5 * gap["dur"], gap
+
+
+def test_a_flush_that_stands_still_is_a_gap_too(tmp_path, monkeypatch):
+    """The next tick is due from before the sampler's own flush."""
+    monkeypatch.setattr(trace, "_start_sampler", START_SAMPLER)
+    write, slow = trace.flush, []
+
+    def flush():
+        if not slow:
+            slow.append(time.sleep(0.5))
+        write()
+    monkeypatch.setattr(trace, "flush", flush)
+    trace.collect(str(tmp_path))
+    trace.flush_soon()
+    deadline = time.monotonic() + 30
+    while not trace.finished("host.clock_gap"):
+        assert time.monotonic() < deadline
+        time.sleep(0.05)
+    gap = trace.finished("host.clock_gap")[0]
+    assert slow and 0.2 < gap["dur"] < 5.0, gap
+    # the sampler's first record says that the clock was watched
+    assert trace.finished()[0]["name"] == "host.clock_sampler"
+    assert trace.finished()[0]["thread"] == trace.SAMPLER_THREAD
+
+
+@pytest.mark.parametrize("asked, after_s", [("", 2.0),
+                                            ("trace.flush_soon()", 0.25)])
+def test_a_buffered_span_outlives_sigkill_by_the_samplers_flush(
+        tmp_path, asked, after_s):
+    """Within a second of its end by the sampler's own flush; at once
+    where the program asked (a save's records, whose step line a
+    supervisor may answer with SIGKILL)."""
+    code = ("import time\n"
+            "from edl_tpu.obs import trace\n"
+            "with trace.span('ckpt.snapshot', attrs={'step': 60}):\n"
+            "    pass\n"
+            f"{asked}\n"
+            "print('ready', flush=True)\n"
+            "time.sleep(60)\n")
+    child = _child(code, {"EDL_TPU_PROFILE_DIR": str(tmp_path)})
+    time.sleep(after_s)
+    child.kill()
+    assert child.wait(timeout=30) == -signal.SIGKILL
+    names = [r["name"] for r in _records_of(child, tmp_path)]
+    assert "ckpt.snapshot" in names
 
 
 # -- names on the device work ------------------------------------------------
