@@ -375,11 +375,18 @@ def main(argv=None) -> int:
         mesh=None if (comm_cfg is not None or args.moe) else mesh,
         **arch_kw)
     if args.remat != "off":
-        from edl_tpu.models.transformer import auto_remat
+        from edl_tpu.models.transformer import auto_remat, kept_bytes
         cfg = (auto_remat(cfg, local_bs)
                if args.remat == "auto"
                else dataclasses.replace(cfg, remat=True))
         log.info("remat=%s (mode %s)", cfg.remat, args.remat)
+        if cfg.remat:
+            kept = kept_bytes(cfg, local_bs, args.seq_len)
+            log.info("remat keeps beside each block's input, of %d x %d "
+                     "tokens a step: %s, %d B in all", local_bs,
+                     args.seq_len, ", ".join(
+                         f"{k} {v} B" for k, v in kept.items()),
+                     sum(kept.values()))
     model = Transformer(cfg)
 
     source = FileSource(files)

@@ -21,8 +21,10 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
+from edl_tpu.ops.flash_attention import KEPT_LSE, KEPT_O
 from edl_tpu.parallel import ring_attention as ra
 from edl_tpu.parallel import sharding as shd
 
@@ -749,6 +751,28 @@ class Mamba2Mixer(nn.Module):
         return cfg.constrain(out, ("batch", "seq", "embed"))
 
 
+# What a rematerialised block (`cfg.remat`) keeps beside its input: the
+# values whose bytes are small against the time their replay takes. The
+# flash forward's `o` and `lse` (ops/flash_attention.py names them where
+# its backward's residuals are formed): with them the replay holds no
+# forward kernel. The first half's result, as the mixer returns it: its
+# output projection then is dead code in the replay, with or without a
+# norm behind it (the residual stream `x + h` would not do: a sandwich
+# norm's backward reads the projection's output). The second half's
+# result, named only where a sandwich norm reads it (`Block`): without
+# one the replay never needed it.
+#
+# Not kept, by what a GB of each buys at 2 x 8192 tokens on a v5e
+# (doc/design_step.md): the mixer's `in_proj` output (279 MB a layer for
+# 3.3 ms), `mlp_gate` / `mlp_up` (268 MB each for 3.0 ms), a gated
+# attention's query / gate (134 MB for about 2 ms), the T x k-row expert
+# buffers (537 MB), q / k / v after the repeat (134 MB each for less than
+# their projections, norms and rope cost): 11-17 ms a GB, against 21 for
+# a half's result and 39-116 for `o`.
+KEPT_MIXER_OUT, KEPT_MLP_OUT = "block_mixer_out", "block_mlp_out"
+KEPT = (KEPT_O, KEPT_LSE, KEPT_MIXER_OUT, KEPT_MLP_OUT)
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     kind: str = "attention"        # the mixer: | "mamba" | "sliding" | "full"
@@ -766,6 +790,7 @@ class Block(nn.Module):
             h = Mamba2Mixer(cfg, name="ssm")(h)
         else:
             h = Attention(cfg, self.kind, name="attn")(h, train)
+        h = checkpoint_name(h, KEPT_MIXER_OUT)
         if cfg.sandwich_norm:
             with jax.named_scope("ln"):
                 h = _norm(cfg, "ln_attn_out")(h)
@@ -797,6 +822,7 @@ class Block(nn.Module):
                 h = _dense(cfg.d_model, ("mlp", "embed"), cfg,
                            name="mlp_out")(h)
         if cfg.sandwich_norm:
+            h = checkpoint_name(h, KEPT_MLP_OUT)
             with jax.named_scope("ln"):
                 h = _norm(cfg, "ln_mlp_out")(h)
         if cfg.dropout > 0:
@@ -841,7 +867,9 @@ class Transformer(nn.Module):
         x = cfg.constrain(x, ("batch", "seq", "embed"))
         block = Block
         if cfg.remat:
-            block = nn.remat(Block, static_argnums=(2,))
+            block = nn.remat(
+                Block, static_argnums=(2,),
+                policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
         for i in range(cfg.n_layers):
             x = block(cfg, cfg.kind(i), cfg.moe_layer(i),
                       name=f"block{i}")(x, train)
@@ -1161,6 +1189,26 @@ def choose_remat(cfg: TransformerConfig, batch_size: int,
             hbm_bytes = 16 * (1 << 30)
     return activations > budget_frac * max(hbm_bytes - resident,
                                            hbm_bytes // 8)
+
+
+def kept_bytes(cfg: TransformerConfig, batch_size: int,
+               seq_len: int | None = None) -> dict[str, int]:
+    """Bytes a step that a rematerialised model holds under each name of
+    `KEPT`, beside its blocks' inputs, for ``batch_size`` sequences:
+    arithmetic over the config, as `choose_remat` is. A name that no
+    layer of this model carries reads 0 (`o` and `lse` where attention
+    does not run through ops/flash_attention.py)."""
+    seq = seq_len or cfg.max_len
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    rows = batch_size * seq
+    flash = 0       # layers whose attention is a flash call, as `Attention`
+    if not cfg.use_ring and cfg.use_flash(seq):
+        flash = sum(cfg.kind(i) != "mamba" for i in range(cfg.n_layers))
+    half = cfg.n_layers * rows * cfg.d_model * itemsize
+    return {KEPT_O: flash * rows * cfg.n_heads * cfg.head_dim * itemsize,
+            KEPT_LSE: flash * rows * cfg.n_heads * 4,
+            KEPT_MIXER_OUT: half,
+            KEPT_MLP_OUT: half if cfg.sandwich_norm else 0}
 
 
 def auto_remat(cfg: TransformerConfig, batch_size: int,

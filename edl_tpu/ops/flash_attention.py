@@ -60,6 +60,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -742,8 +743,19 @@ def _flash_lse(q, k, v, blk_q, blk_k, scale, causal, window):
     return _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window)
 
 
+# What a `jax.checkpoint` around a caller may keep of a call, by name
+# (`save_only_these_names`): the forward's two results, which are also
+# the backward's residuals. Kept, the replay has no use for the forward
+# kernel; outside a checkpoint the names lower to nothing.
+KEPT_O, KEPT_LSE = "flash_o", "flash_lse"
+
+
 def _flash_lse_fwd(q, k, v, blk_q, blk_k, scale, causal, window):
     o, lse = _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window)
+    # named before the residuals are formed: the backward reads the
+    # named values, and naming the call's result in the caller would
+    # name another variable and leave the kernel in the replay
+    o, lse = checkpoint_name(o, KEPT_O), checkpoint_name(lse, KEPT_LSE)
     return (o, lse), (q, k, v, o, lse)
 
 

@@ -248,6 +248,17 @@ def test_remat_changes_no_number(variables, tokens):
     assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_remat_changes_no_gradient(variables, tokens):
+    """Through the flash call's backward rule (its XLA path here), the
+    sandwich norms and the expert layers: what a rematerialised block
+    keeps (`transformer.KEPT`) is what its replay would rebuild."""
+    ga, gb = (jax.grad(lambda p, r=r: tfm.lm_loss_fn(
+        state_of(variables, remat=r, attention="flash"), p,
+        {"tokens": tokens})[0])(variables["params"]) for r in (False, True))
+    for a, b in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
+        assert float(jnp.abs(a - b).max()) < 1e-6
+
+
 # -- the routing bias --------------------------------------------------------
 
 def routed(variables, x, **kw):
@@ -494,6 +505,11 @@ def test_lm_train_arch_afmoe_logs_its_counters_and_resumes_its_bias(
             "experts 0-3 of 16 held, top-4 sigmoid x 2.826, 1 shared") \
         in out.stderr
     assert "params=2392480" in out.stderr
+    # float32 and attention without the flash call here: the two halves'
+    # results of five layers, 4 x 64 x 32 x 4 B each
+    assert ("remat keeps beside each block's input, of 4 x 64 tokens a "
+            "step: flash_o 0 B, flash_lse 0 B, block_mixer_out 163840 B, "
+            "block_mlp_out 163840 B, 327680 B in all") in out.stderr
     first = {int(s): tuple(map(float, rest))
              for s, *rest in STEP.findall(out.stderr)}
     assert sorted(first) == [1, 2, 3, 4]

@@ -646,6 +646,17 @@ def test_remat_changes_no_number(tree, tokens):
         assert float(jnp.abs(a - b).max()) < 1e-6
 
 
+def test_remat_changes_no_gradient_through_the_flash_call(tree, tokens):
+    """As above with attention through ops/flash_attention.py (its XLA
+    path here), whose `o` and `lse` a rematerialised block keeps
+    (`transformer.KEPT`) and whose backward reads the kept ones."""
+    ga, gb = (jax.grad(lambda p, r=r: tfm.lm_loss_fn(
+        state_of(tree, remat=r, attention="flash"), p,
+        {"tokens": tokens})[0])(tree) for r in (False, True))
+    for a, b in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
+        assert float(jnp.abs(a - b).max()) < 1e-6
+
+
 # -- the configuration ------------------------------------------------------
 
 def test_parameter_tree_is_the_sources():
