@@ -464,23 +464,33 @@ def router_topk(logits: jax.Array, top_k: int, capacity: int
     return combine, dispatch, aux
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch_rows(x, order, inv, k: int):
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch_rows(x, order, inv, here, k: int):
     """Rows of x (T, d) in expert order: x[order // k], where ``order``
     sorts the T*k token-major assignments by expert and ``inv`` is its
     inverse. The backward is a gather as well (the assignments' rows
     back in token order, summed over the k slots), so no scatter-add
-    over repeated rows appears in either direction."""
+    over repeated rows appears in either direction.
+
+    ``here`` (T, k) says which assignments are in a group of the
+    grouped matmul, or is None where all are. The others' rows lie past
+    the groups, where that matmul's backward writes nothing: what the
+    backward's gather brings from there is selected away before the
+    sum, in token order, where the select fuses into the reduction."""
     return x[order // k]
 
 
-def _dispatch_rows_fwd(x, order, inv, k):
-    return x[order // k], inv
+def _dispatch_rows_fwd(x, order, inv, here, k):
+    return x[order // k], (inv, here)
 
 
-def _dispatch_rows_bwd(k, inv, g):
+def _dispatch_rows_bwd(k, res, g):
+    inv, here = res
     g = g[inv].reshape(-1, k, g.shape[-1])
-    return jnp.sum(g.astype(jnp.float32), 1).astype(g.dtype), None, None
+    if here is not None:
+        g = jnp.where(here[..., None], g, 0)
+    return (jnp.sum(g.astype(jnp.float32), 1).astype(g.dtype),
+            None, None, None)
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
@@ -527,6 +537,12 @@ class MoEMLP(nn.Module):
       grouped matmuls over the ragged groups (`jax.lax.ragged_dot`; a
       Mosaic kernel of XLA's on a TPU), and the results un-permuted
       and weighted. No capacity, no (T, E, C) array, nothing dropped.
+      A chip's share (`cfg.held_experts` < n_experts) sorts the absent
+      experts' assignments last, into no group; the kernel leaves their
+      rows unwritten, forward and backward, and the layer masks them in
+      token order where the gathers come out, by a select (the rows may
+      hold NaN, which a zero gate would not stop), never by a pass over
+      the (T*k, d) buffer.
     - cfg.moe_wire set (inside train/comm's manual shard_map region):
       the capacity router `router_topk`, whose fixed-shape (E, C, d)
       buffer the wire object carries to the experts' owner chips
@@ -631,6 +647,7 @@ class MoEMLP(nn.Module):
             # assignment a = token * k + slot; a stable sort by expert
             # keeps the tokens of one expert in token order
             key = idx.reshape(t * k)
+            here = None     # (T, k): the slot's expert is held; None: all
             if share:
                 # this chip's experts first, the absent ones' rows after
                 # them in no group: neither computed nor dropped
@@ -638,30 +655,33 @@ class MoEMLP(nn.Module):
                 here = (local >= 0) & (local < held)
                 key = jnp.where(here, local, held)
                 counts = jax.lax.dynamic_slice_in_dim(counts, first, held)
-                gate = jnp.where(here.reshape(t, k), gate, 0.0)
+                here = here.reshape(t, k)
+                gate = jnp.where(here, gate, 0.0)
                 self.sow("intermediates", "moe_held",
                          jnp.sum(counts) / (t * k))
             order = jnp.argsort(key, stable=True)
             inv = jnp.zeros_like(order).at[order].set(
                 jnp.arange(t * k, dtype=order.dtype), unique_indices=True)
-            rows = _dispatch_rows(xf, order, inv, k)        # (T*k, d)
-            if share:
-                # the grouped matmul on the chip neither reads nor
-                # writes a row that is in no group, in either direction
-                # (its time follows the groups: PERF.md §6, PR 36), so
-                # what it leaves there is whatever the buffer held.
-                # Zeros go in, whose backward zeroes what comes back,
-                # and zeros come out
-                grouped = (jnp.arange(t * k) < jnp.sum(counts))[:, None]
-                rows = jnp.where(grouped, rows, 0)
+            # the grouped matmul on the chip neither reads nor writes a
+            # row that is in no group, in either direction (its time
+            # follows the groups: PERF.md §6, PR 36), so past the groups
+            # its output and its cotangent hold whatever the buffer held,
+            # NaN for all anyone knows. No pass over the buffer zeroes
+            # that tail: `here` masks each of the two where its gather
+            # brings it back to token order (`_dispatch_rows`' backward;
+            # `moe_combine`)
+            rows = _dispatch_rows(xf, order, inv, here, k)  # (T*k, d)
         with jax.named_scope("moe_experts"):
             out = _expert_ffn(
                 rows, tables,
                 lambda a, w: jax.lax.ragged_dot(a, w, counts), cfg.dtype)
-            if share:
-                out = jnp.where(grouped, out, 0)
         with jax.named_scope("moe_combine"):
             out = _permute_rows(out, inv, order).reshape(t, k, d)
+            if share:
+                # a select and not the zero gate: 0 x NaN is NaN. Its
+                # transpose zeroes the same slots of gate x dy, so the
+                # backward's gather carries zeros to the tail
+                out = jnp.where(here[..., None], out, 0)
             y = jnp.einsum("tk,tkd->td", gate.astype(cfg.dtype), out,
                            preferred_element_type=jnp.float32)
         if cfg.moe_shared:

@@ -388,6 +388,10 @@ def shared_alone(cfg, v, x):
         return plain.swiglu(x.reshape(-1, D), m).reshape(x.shape)
 
 
+def x_gradient(f, x, dy):
+    return jax.grad(lambda x: jnp.sum(f(x) * dy))(x)
+
+
 @pytest.mark.parametrize("shares", [2, 4, 8, 16])
 def test_the_shares_add_up(shares):
     """The routed parts of all the shares, plus the shared expert once,
@@ -404,6 +408,21 @@ def test_the_shares_add_up(shares):
     sizes = [float(jnp.abs(p).max()) for p in parts]
     assert min(sizes) > 100 * TOL
     assert float(jnp.abs(parts[0] - (whole - shared)).max()) > 100 * TOL
+    # and so do their gradients with respect to x (through the experts
+    # and through the gates), which is what the backward's mask guards:
+    # a share that let the absent experts' slots through would add rows
+    # of other shares' cotangents to its own
+    dy = jax.random.normal(jax.random.PRNGKey(21), whole.shape)
+    d_whole = x_gradient(lambda x: tfm.MoEMLP(cfg).apply(v, x), x, dy)
+    d_shared = x_gradient(lambda x: shared_alone(cfg, v, x), x, dy)
+    d_parts = [x_gradient(
+        lambda x, i=i: tfm.MoEMLP(small(
+            experts_held=held, experts_offset=i * held)).apply(
+                share_of(v, i * held, held), x), x, dy) - d_shared
+        for i in range(shares)]
+    np.testing.assert_allclose(sum(d_parts) + d_shared, d_whole,
+                               atol=10 * TOL)
+    assert min(float(jnp.abs(p).max()) for p in d_parts) > 100 * TOL
     # the uncut layer against the reference, given every expert
     p = {"router": v["params"]["router"],
          "bias": v["batch_stats"]["expert_bias"],
@@ -433,6 +452,79 @@ def test_a_share_counts_what_it_holds_and_drops_nothing():
                             mutable=["intermediates"])
     assert float(sown["intermediates"]["moe_held"][0]) == 0.0
     np.testing.assert_allclose(out, shared_alone(cfg, v, x), atol=TOL)
+    # and its gradient is the shared expert's alone
+    dy = jax.random.normal(jax.random.PRNGKey(21), out.shape)
+    away = {**share_of(v, 8, 4), "batch_stats": away}
+    np.testing.assert_allclose(
+        x_gradient(lambda x: layer.apply(away, x), x, dy),
+        x_gradient(lambda x: shared_alone(cfg, v, x), x, dy), atol=TOL)
+
+
+def as_the_chip_leaves_the_tail(real):
+    """`jax.lax.ragged_dot` as XLA's kernel on the chip treats the rows
+    past the groups (`tools/ragged_dot_tail.py`): it reads none of them,
+    whatever they hold, and writes none, so that they come back holding
+    anything: NaN here, forward and in d(lhs). The CPU's zeroes them,
+    which is why no other test of this file can see a missing mask."""
+    def tail(rows, sizes):
+        return (jnp.arange(rows.shape[0]) >= jnp.sum(sizes))[:, None]
+
+    @jax.custom_vjp
+    def dot(a, w, sizes):
+        t = tail(a, sizes)
+        return jnp.where(t, jnp.nan, real(jnp.where(t, 0, a), w, sizes))
+
+    def fwd(a, w, sizes):
+        return dot(a, w, sizes), (a, w, sizes)
+
+    def bwd(res, g):
+        a, w, sizes = res
+        t = tail(a, sizes)
+        da, dw = jax.vjp(lambda a, w: real(a, w, sizes),
+                         jnp.where(t, 0, a), w)[1](jnp.where(t, 0, g))
+        return jnp.where(t, jnp.nan, da), dw, None
+
+    dot.defvjp(fwd, bwd)
+    return dot
+
+
+@pytest.mark.parametrize("held, first", [(4, 8), (E, 0)],
+                         ids=["a_share", "every_expert"])
+def test_what_the_kernel_leaves_past_the_groups_reaches_nothing(
+        monkeypatch, held, first):
+    """The layer's output and every gradient leaf with the grouped
+    matmul's tail poisoned, forward and backward, are finite and are the
+    unpoisoned run's to the bit. Holding every expert there is no tail,
+    and the same holds trivially."""
+    _, v, x = whole_layer()
+    layer = tfm.MoEMLP(small(experts_held=held, experts_offset=first))
+    v = share_of(v, first, held)
+    dy = jax.random.normal(jax.random.PRNGKey(21), x.shape)
+
+    def run():
+        def loss(params, x):
+            y = layer.apply({**v, "params": params}, x)
+            return jnp.sum(y * dy), y
+        (_, y), grads = jax.value_and_grad(loss, argnums=(0, 1),
+                                           has_aux=True)(v["params"], x)
+        return jax.tree.leaves((y, grads))
+
+    clean = run()
+    poisoned = as_the_chip_leaves_the_tail(jax.lax.ragged_dot)
+    monkeypatch.setattr(jax.lax, "ragged_dot", poisoned)
+    dirty = run()
+    assert len(clean) == 2 + len(jax.tree.leaves(v["params"]))
+    for a, b in zip(clean, dirty):
+        assert np.isfinite(np.asarray(b)).all()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the poison is there: a share's buffer is mostly tail, and the
+    # wrapped product's tail is NaN both ways
+    sizes = jnp.full((4,), 2, jnp.int32)
+    a, w = jnp.ones((16, 8)), jnp.ones((4, 8, 8))
+    out, back = jax.vjp(lambda a: poisoned(a, w, sizes), a)
+    for rows in (out, back(jnp.ones_like(out))[0]):
+        assert np.isfinite(np.asarray(rows[:8])).all()
+        assert np.isnan(np.asarray(rows[8:])).all()
 
 
 def test_holding_every_expert_is_the_path_that_was_there():
