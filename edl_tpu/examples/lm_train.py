@@ -124,7 +124,8 @@ def main(argv=None) -> int:
                              "(default $EDL_TPU_COMM_BUCKET_MB, else 0 "
                              "= XLA's single fused reduction)")
     parser.add_argument("--arch", choices=("gpt2", "olmoe",
-                                           "granite-hybrid", "afmoe"),
+                                           "granite-hybrid", "afmoe",
+                                           "sdar"),
                         default="gpt2",
                         help="the block: gpt2 = LayerNorm, learned "
                              "positions, gelu; olmoe = models.transformer."
@@ -143,7 +144,14 @@ def main(argv=None) -> int:
                              "score + bias, a shared expert, leading dense "
                              "layers of width --d-ff; implies --moe, 128 "
                              "experts, 8 a token unless given; one chip, "
-                             "which holds --experts-held of them)")
+                             "which holds --experts-held of them); sdar = "
+                             "models.transformer.sdar_config (SDAR-30B-A3B: "
+                             "the Qwen3-MoE block, 4 key/value heads of "
+                             "128, softmax top-8 of 128 experts of width "
+                             "--d-ff, trained by diffusion over blocks of "
+                             "--block-length: a noised and a clean copy of "
+                             "every row in one pass, the loss on the masked "
+                             "tokens; one chip, which holds --experts-held)")
     parser.add_argument("--layer-types", default="",
                         help="one letter a layer. granite-hybrid: m = "
                              "mamba, a = attention (default: the "
@@ -155,8 +163,11 @@ def main(argv=None) -> int:
                              "repeated, cut to --n-layers; the head size, "
                              "the key/value heads, an expert's width and "
                              "the routing are afmoe_config's own)")
+    parser.add_argument("--block-length", type=int, default=0,
+                        help="sdar: tokens a block of the diffusion "
+                             "objective (default 4, the family's)")
     parser.add_argument("--experts-held", type=int, default=0,
-                        help="afmoe: the experts this chip holds, the "
+                        help="afmoe, sdar: the experts this chip holds, the "
                              "first of --n-experts (default all): the "
                              "router and top-k stay over all of them, and "
                              "the layer computes the held ones' part")
@@ -229,20 +240,27 @@ def main(argv=None) -> int:
                         help="jax profiler trace dir (steps 10-15, rank 0)")
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(argv)
-    args.moe = args.moe or args.arch in ("olmoe", "afmoe")
+    args.moe = args.moe or args.arch in ("olmoe", "afmoe", "sdar")
     if args.moe and args.arch == "granite-hybrid":
         raise SystemExit("--arch granite-hybrid has a dense MLP "
                          "(num_local_experts 0); --moe conflicts")
-    afmoe_only = {"--experts-held": args.experts_held, "--dense-layers":
-                  args.dense_layers, "--window": args.window}
+    afmoe_only = {"--dense-layers": args.dense_layers,
+                  "--window": args.window}
+    if args.arch != "sdar":  # a chip's share of the experts is sdar's too
+        afmoe_only["--experts-held"] = args.experts_held
     if args.arch != "afmoe" and any(afmoe_only.values()):
         raise SystemExit(
             f"{', '.join(k for k, v in afmoe_only.items() if v)}: only "
             f"--arch afmoe has such a size; --arch {args.arch} conflicts")
-    if args.arch == "afmoe" and (args.moe_dispatch or args.moe_compress):
+    if args.block_length and args.arch != "sdar":
+        raise SystemExit(f"--block-length: only --arch sdar trains by "
+                         f"diffusion over blocks; --arch {args.arch} "
+                         "conflicts")
+    if args.arch in ("afmoe", "sdar") and (args.moe_dispatch
+                                           or args.moe_compress):
         raise SystemExit(
-            "--arch afmoe computes one chip's share of the experts and "
-            "has no exchange: --moe-dispatch / --moe-compress conflict")
+            f"--arch {args.arch} computes one chip's share of the experts "
+            "and has no exchange: --moe-dispatch / --moe-compress conflict")
     if args.profile:
         trace.collect(args.profile)  # spans from here on, start-up's too
 
@@ -361,6 +379,17 @@ def main(argv=None) -> int:
                 f"--arch afmoe trains one chip's share of the experts "
                 f"(--experts-held) with no exchange between chips; "
                 f"{jax.device_count()} devices conflict")
+    elif args.arch == "sdar":
+        from edl_tpu.models.transformer import sdar_config as make_cfg
+        arch_kw = {k: v for k, v in (
+            ("n_experts", args.n_experts), ("moe_top_k", args.moe_top_k),
+            ("experts_held", args.experts_held),
+            ("block_length", args.block_length)) if v}
+        if jax.device_count() > 1:
+            raise SystemExit(
+                f"--arch sdar trains one chip's share of the experts "
+                f"(--experts-held) with no exchange between chips; "
+                f"{jax.device_count()} devices conflict")
     elif args.moe:
         arch_kw = dict(moe=True,
                       n_experts=args.n_experts or 2 * jax.device_count(),
@@ -390,6 +419,13 @@ def main(argv=None) -> int:
     model = Transformer(cfg)
 
     source = FileSource(files)
+    if cfg.block_length:
+        # the noise is the batch's: a row's from (seed, epoch, its index)
+        from edl_tpu.data import block_noise
+        if args.seq_len % cfg.block_length:
+            raise SystemExit(f"--block-length {cfg.block_length} does not "
+                             f"divide --seq-len {args.seq_len}")
+        source = block_noise.RowIndexed(source)
     loader = DataLoader(source, local_bs, rank=rank, world=world,
                         seed=args.seed, num_workers=args.loader_workers)
     steps_per_epoch = loader.steps_per_epoch()
@@ -454,9 +490,20 @@ def main(argv=None) -> int:
     log.info("device: platform=%s kind=%r count=%d attention=%s",
              dev.platform, dev.device_kind, jax.device_count(),
              "ring" if cfg.use_ring else
-             "flash" if cfg.use_flash(args.seq_len) else "dense")
+             "flash" if cfg.block_length or cfg.use_flash(args.seq_len)
+             else "dense")
     log.info("state bytes per device: %s", shd.bytes_per_device(state))
-    if args.arch == "afmoe":
+    if args.arch == "sdar":
+        log.info("sdar: diffusion over blocks of %d, %d + %d positions a "
+                 "row (noised + clean), mask token %d, t uniform on "
+                 "(%g, 1]; %d q / %d kv heads x %d, experts %d-%d of %d "
+                 "held, top-%d softmax, renormalised",
+                 cfg.block_length, args.seq_len, args.seq_len, cfg.mask_id,
+                 block_noise.T_MIN, cfg.n_heads, cfg.kv_heads, cfg.head_dim,
+                 cfg.experts_offset,
+                 cfg.experts_offset + cfg.held_experts - 1, cfg.n_experts,
+                 cfg.moe_top_k)
+    elif args.arch == "afmoe":
         dense = cfg.n_dense_layers
         log.info("afmoe: layers %s|%s, window %d, %d q / %d kv heads x %d, "
                  "experts %d-%d of %d held, top-%d %s x %s, %d shared",
@@ -490,6 +537,20 @@ def main(argv=None) -> int:
         with np.load(val_path) as z:
             eval_toks = z["tokens"][: 4 * local_bs]
 
+    def eval_batches():
+        """The validation rows a batch at a time; under the diffusion
+        objective each with a noise of its own, the same at every epoch
+        (an epoch of -1: no training row's)."""
+        batches = ({"tokens": eval_toks[lo:lo + local_bs],
+                    "row": np.arange(lo, lo + local_bs)}
+                   for lo in range(0, len(eval_toks) - local_bs + 1,
+                                   local_bs))
+        if cfg.block_length:
+            return block_noise.with_noise(
+                batches, seed=args.seed, epoch=-1,
+                block_length=cfg.block_length)
+        return ({"tokens": b["tokens"]} for b in batches)
+
     # eval must honor the fused path too — the dense loss would
     # materialize exactly the logits tensor --fused-loss exists to avoid
     # (MoE eval rides the dropless dispatch, whatever the training step)
@@ -507,9 +568,8 @@ def main(argv=None) -> int:
         results = {"examples_per_sec": seqs_per_sec,
                    "tokens_per_sec": seqs_per_sec * args.seq_len * world}
         if eval_toks is not None:
-            losses = [float(eval_step(state, {"tokens": jnp.asarray(
-                eval_toks[lo:lo + local_bs])}))
-                for lo in range(0, len(eval_toks) - local_bs + 1, local_bs)]
+            losses = [float(eval_step(state, jax.tree.map(jnp.asarray, b)))
+                      for b in eval_batches()]
             results["eval_loss"] = float(np.mean(losses))
         blog.epoch(epoch, **results)
         epoch_t0[0] = time.perf_counter()
@@ -529,6 +589,10 @@ def main(argv=None) -> int:
     del state, variables
 
     def data_fn(epoch):
+        if cfg.block_length:
+            return block_noise.with_noise(
+                loader.epoch(epoch), seed=args.seed, epoch=epoch,
+                block_length=cfg.block_length)
         return ({"tokens": b["tokens"]} for b in loader.epoch(epoch))
 
     data_fn.close = loader.close  # TrainLoop tears down the mp workers

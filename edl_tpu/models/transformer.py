@@ -122,6 +122,14 @@ class TransformerConfig:
     # experts_offset on, and the layer computes their part of the result
     experts_held: int = 0          # 0 = n_experts
     experts_offset: int = 0
+    # -- training by diffusion over blocks (`sdar_config` sets it; 0 is
+    # the next-token model above, untouched). block_length > 0: the model
+    # runs a noised and a clean copy of every row in one pass,
+    # [noised ; clean] along the sequence, both at positions 0..L-1, and
+    # attention sees by block index (models/blockdiff.py); the loss is
+    # on the noised copy's masked tokens, no shift. The mask token is
+    # the vocabulary's last id.
+    block_length: int = 0
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -141,6 +149,13 @@ class TransformerConfig:
                 raise ValueError("mamba layers need ssm_heads >= 1")
             if "sliding" in kinds and self.window < 1:
                 raise ValueError("sliding layers need window >= 1")
+        if self.block_length and (
+                self.block_length < 0 or self.layer_types is not None
+                or self.pos == "learned" or self.attention == "dense"):
+            raise ValueError(
+                "block_length > 0 (diffusion over blocks) runs every layer "
+                "as attention by block index through ops/flash_attention: "
+                "no layer_types, no learned positions, no dense attention")
         if self.n_kv_heads and self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"n_kv_heads={self.n_kv_heads} must divide n_heads="
@@ -173,6 +188,10 @@ class TransformerConfig:
             return self.head_size
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def mask_id(self) -> int:
+        return self.vocab_size - 1
 
     @property
     def held_experts(self) -> int:
@@ -244,7 +263,7 @@ class TransformerConfig:
             return 1
         return math.prod(sharded.values())
 
-    def xent(self, hidden, kernel, targets, block_rows=None):
+    def xent(self, hidden, kernel, targets, block_rows=None, weights=None):
         """The streamed CE (ops/fused_xent.py), shard_mapped over the
         mesh's batch axes as `flash` is. Each chip sweeps its own
         sequences against the whole head kernel, so the op sizes its
@@ -253,6 +272,12 @@ class TransformerConfig:
         chips once after the sweep: left to the partitioner, the loop
         would reduce it once a block."""
         from edl_tpu.ops.fused_xent import streamed_lm_xent
+        if weights is not None:
+            if self.xent_shards() != 1:
+                raise ValueError("a weight a row rides one chip's sweep; "
+                                 "the sharded sweep takes none yet")
+            return streamed_lm_xent(hidden, kernel, targets, block_rows,
+                                    weights=weights)
         if self.xent_shards() == 1:
             return streamed_lm_xent(hidden, kernel, targets, block_rows)
         from jax.sharding import PartitionSpec as P
@@ -323,18 +348,47 @@ def _norm(cfg: TransformerConfig, name: str) -> nn.Module:
     return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
 
 
-def rope(x: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, theta: float, positions=None) -> jax.Array:
     """Rotary positions on (B, S, H, D), over the whole head, in the
     rotate-half convention: x*cos + cat(-x[D/2:], x[:D/2])*sin with
     angles pos * theta^(-2i/D), i < D/2, repeated twice. Float32
-    inside, cast back."""
+    inside, cast back. ``positions`` (S,): each place's position where
+    that is not its index."""
     s, d = x.shape[1], x.shape[-1]
     freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None]
+    if positions is None:
+        positions = jnp.arange(s, dtype=jnp.float32)
+    angles = positions.astype(jnp.float32)[:, None] * freqs[None]
     angles = jnp.concatenate([angles, angles], -1)[None, :, None, :]
     x32 = x.astype(jnp.float32)
     half = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
     return (x32 * jnp.cos(angles) + half * jnp.sin(angles)).astype(x.dtype)
+
+
+def _causal_attention(cfg: TransformerConfig, kind: str, q, k, v, window):
+    """Causal attention of one sequence a row: positions, the
+    key/value heads repeated, and the path the config picks."""
+    if cfg.pos == "rope" and kind != "full":
+        with jax.named_scope("rope"):
+            q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
+    if cfg.kv_heads != cfg.n_heads:
+        # grouped-query: every key/value head serves n_heads/kv_heads
+        # query heads. Repeated here, outside the kernels, whose
+        # block specs know one head a program; autodiff sums dK and
+        # dV over each group.
+        group = cfg.n_heads // cfg.kv_heads
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    q = cfg.constrain(q, ("batch", "seq", "heads", "kv"))
+    k = cfg.constrain(k, ("batch", "seq", "heads", "kv"))
+    v = cfg.constrain(v, ("batch", "seq", "heads", "kv"))
+
+    if cfg.use_ring:
+        return ra.ring_attention(q, k, v, mesh=cfg.mesh, causal=True,
+                                 scale=cfg.attn_scale)
+    if cfg.use_flash(q.shape[1]):
+        return cfg.flash(q, k, v, window)
+    return ra.dense_attention(q, k, v, causal=True,
+                              scale=cfg.attn_scale, window=window)
 
 
 class Attention(nn.Module):
@@ -368,28 +422,18 @@ class Attention(nn.Module):
             with jax.named_scope("attn_qk_norm"):
                 q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(q)
                 k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(k)
-        if cfg.pos == "rope" and self.kind != "full":
-            with jax.named_scope("rope"):
-                q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
-        if cfg.kv_heads != cfg.n_heads:
-            # grouped-query: every key/value head serves n_heads/kv_heads
-            # query heads. Repeated here, outside the kernels, whose
-            # block specs know one head a program; autodiff sums dK and
-            # dV over each group.
-            group = cfg.n_heads // cfg.kv_heads
-            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-        q = cfg.constrain(q, ("batch", "seq", "heads", "kv"))
-        k = cfg.constrain(k, ("batch", "seq", "heads", "kv"))
-        v = cfg.constrain(v, ("batch", "seq", "heads", "kv"))
-
-        if cfg.use_ring:
-            o = ra.ring_attention(q, k, v, mesh=cfg.mesh, causal=True,
-                                  scale=cfg.attn_scale)
-        elif cfg.use_flash(s):
-            o = cfg.flash(q, k, v, window)
+        if cfg.block_length:
+            # [noised ; clean]: each copy at its place within the row
+            from edl_tpu.models import blockdiff
+            if cfg.pos == "rope":
+                with jax.named_scope("rope"):
+                    at = jnp.arange(s) % (s // 2)
+                    q = rope(q, cfg.rope_theta, at)
+                    k = rope(k, cfg.rope_theta, at)
+            o = blockdiff.attention(q, k, v, block=cfg.block_length,
+                                    scale=cfg.attn_scale)
         else:
-            o = ra.dense_attention(q, k, v, causal=True,
-                                   scale=cfg.attn_scale, window=window)
+            o = _causal_attention(cfg, self.kind, q, k, v, window)
         o = cfg.constrain(o, ("batch", "seq", "heads", "kv"))
         if cfg.attn_gate:
             with jax.named_scope("attn_gate"):
@@ -858,13 +902,24 @@ class Transformer(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, train: bool = True,
-                 return_hidden: bool = False):
+                 return_hidden: bool = False, noised=None):
         """return_hidden=True skips the lm_head and yields the final-LN
         hidden states (B, S, d) — the input of the streamed-vocab fused
         CE (ops/fused_xent.py), which reads the head kernel straight
         from the param tree. Init must use the default path so the
-        lm_head params exist."""
+        lm_head params exist.
+
+        Under cfg.block_length ``noised`` (B, S) is the rows' noised
+        copy (the rows themselves where not given: init). Both copies
+        run as one (B, 2S) batch, and what comes back, hidden states or
+        logits, is the noised copy's (B, S): the clean copy never meets
+        the head."""
         cfg = self.cfg
+        half = tokens.shape[1]
+        if cfg.block_length:
+            with jax.named_scope("blockdiff_assemble"):
+                tokens = jnp.concatenate(
+                    [tokens if noised is None else noised, tokens], axis=1)
         # Table axes use the dedicated (vocab_table, embed_table) logical
         # names: vocab stays unsharded so the token gather partitions
         # trivially (no involuntary table rematerialization), embed splits
@@ -893,6 +948,9 @@ class Transformer(nn.Module):
         for i in range(cfg.n_layers):
             x = block(cfg, cfg.kind(i), cfg.moe_layer(i),
                       name=f"block{i}")(x, train)
+        if cfg.block_length:
+            with jax.named_scope("blockdiff_assemble"):
+                x = x[:, :half]
         with jax.named_scope("ln"):
             x = _norm(cfg, "ln_final")(x)
         if return_hidden:
@@ -965,7 +1023,16 @@ def lm_loss_fn(state, params, batch, *, aux_weight: float | None = None,
     binding than the state was built with (the manual-dispatch path
     rebinds cfg.moe_wire without touching the params)."""
     apply_fn = apply_fn or state.apply_fn
-    _, moe = _model_of(apply_fn, aux_weight, z_weight)
+    cfg, moe = _model_of(apply_fn, aux_weight, z_weight)
+    if cfg is not None and cfg.block_length:
+        from edl_tpu.models import blockdiff
+        noised, weights = blockdiff.noised_batch(batch, cfg.mask_id)
+        logits, mutated = _run(state, apply_fn, params, batch["tokens"],
+                               moe, noised=noised)
+        ll = jnp.take_along_axis(jax.nn.log_softmax(logits),
+                                 batch["tokens"][..., None], axis=-1)[..., 0]
+        return blockdiff.with_masked_share(_with_router_terms(
+            -jnp.sum(weights * ll), mutated, moe), batch)
     logits, mutated = _run(state, apply_fn, params, batch["tokens"], moe)
     targets = batch["tokens"][:, 1:]
     logits = logits[:, :-1]
@@ -996,6 +1063,16 @@ def lm_loss_fused(state, params, batch, *, block_rows: int | None = None,
 
     apply_fn = apply_fn or state.apply_fn
     cfg, moe = _model_of(apply_fn, aux_weight, z_weight)
+    if cfg is not None and cfg.block_length:
+        # the noised copy's hidden states against the clean tokens at the
+        # same places, each under its own weight: no shift, no row left out
+        from edl_tpu.models import blockdiff
+        noised, weights = blockdiff.noised_batch(batch, cfg.mask_id)
+        hidden, mutated = _run(state, apply_fn, params, batch["tokens"],
+                               moe, return_hidden=True, noised=noised)
+        return blockdiff.with_masked_share(_with_router_terms(
+            cfg.xent(hidden, params["lm_head"]["kernel"], batch["tokens"],
+                     block_rows, weights=weights), mutated, moe), batch)
     hidden, mutated = _run(state, apply_fn, params, batch["tokens"], moe,
                            return_hidden=True)
     tokens = batch["tokens"]
@@ -1157,6 +1234,35 @@ def afmoe_config(*, vocab_size: int = 200192, d_model: int = 2048,
         experts_held=experts_held, experts_offset=experts_offset, **kw)
 
 
+def sdar_config(*, vocab_size: int = 151936, d_model: int = 2048,
+                n_heads: int = 32, n_layers: int = 48, d_ff: int = 768,
+                max_len: int = 32768, n_kv_heads: int = 4,
+                head_size: int = 128, n_experts: int = 128,
+                moe_top_k: int = 8, experts_held: int = 0,
+                experts_offset: int = 0, block_length: int = 4,
+                **kw) -> TransformerConfig:
+    """SDAR-30B-A3B-Chat (JetLM, `model_type: sdar_moe`; arXiv:2510.06303),
+    the Qwen3-MoE block trained by diffusion over blocks (BD3-LMs,
+    arXiv:2503.09573): RMSNorm pre-norm (eps 1e-6), RoPE (theta 1e6),
+    grouped-query attention with a head size of its own and RMSNorm on q
+    and k over each head, no biases, an untied head; 128 SwiGLU experts
+    of width ``d_ff``, softmax scores, top-8, the kept gates
+    renormalised, no shared expert, no router term in the loss.
+    ``block_length`` is the objective's (`TransformerConfig.
+    block_length`; the family's released chat models generate in blocks
+    of 4); ``experts_held`` / ``experts_offset`` give a chip its share of
+    the experts (0 = all). The sizes default to the published ones."""
+    return TransformerConfig(
+        vocab_size=vocab_size, d_model=d_model, n_heads=n_heads,
+        n_layers=n_layers, d_ff=d_ff, max_len=max_len, norm="rmsnorm",
+        norm_eps=1e-6, pos="rope", rope_theta=1000000.0,
+        n_kv_heads=n_kv_heads, head_size=head_size, qk_norm_heads=True,
+        moe=True, moe_gated=True, moe_renorm=True, n_experts=n_experts,
+        moe_top_k=moe_top_k, moe_aux_weight=0.0, moe_z_weight=0.0,
+        experts_held=experts_held, experts_offset=experts_offset,
+        block_length=block_length, **kw)
+
+
 def choose_remat(cfg: TransformerConfig, batch_size: int,
                  seq_len: int | None = None,
                  hbm_bytes: int | None = None,
@@ -1177,7 +1283,8 @@ def choose_remat(cfg: TransformerConfig, batch_size: int,
     error, and the CPU harness (which reports none) is sized as one
     16 GiB chip so tests decide as a v5e would.
     """
-    seq = seq_len or cfg.max_len
+    # under block_length a row is two copies' positions
+    seq = (seq_len or cfg.max_len) * (2 if cfg.block_length else 1)
     itemsize = jnp.dtype(cfg.dtype).itemsize
     per_block = 12 * batch_size * seq * cfg.d_model * itemsize
     ffn = 2 * cfg.d_model * cfg.d_ff
@@ -1220,9 +1327,10 @@ def kept_bytes(cfg: TransformerConfig, batch_size: int,
     does not run through ops/flash_attention.py)."""
     seq = seq_len or cfg.max_len
     itemsize = jnp.dtype(cfg.dtype).itemsize
-    rows = batch_size * seq
+    # under block_length a row is two copies, each with its own call
+    rows = batch_size * seq * (2 if cfg.block_length else 1)
     flash = 0       # layers whose attention is a flash call, as `Attention`
-    if not cfg.use_ring and cfg.use_flash(seq):
+    if cfg.block_length or (not cfg.use_ring and cfg.use_flash(seq)):
         flash = sum(cfg.kind(i) != "mamba" for i in range(cfg.n_layers))
     half = cfg.n_layers * rows * cfg.d_model * itemsize
     return {KEPT_O: flash * rows * cfg.n_heads * cfg.head_dim * itemsize,

@@ -131,6 +131,53 @@ def _rows(i: int, n: int) -> slice:
     return slice(i * n, (i + 1) * n)
 
 
+# Visibility by block index (`blocks`, static: (size, strict)): with
+# b(i) = i // size, query i sees key j iff b(j) <= b(i), or b(j) < b(i)
+# where `strict`. The geometry of training by diffusion over blocks: a
+# clean row's queries see the blocks up to and including their own, a
+# noised row's queries the clean row's blocks before their own.
+def _block_of(x, size: int):
+    """x // size for int32 x >= 0: a shift where size is a power of two."""
+    if size & (size - 1) == 0:
+        return x >> (size.bit_length() - 1)
+    return lax.div(x, jnp.int32(size))
+
+
+def _edge(r, blocks):
+    """The first key that query r does not see."""
+    size, strict = blocks
+    return (_block_of(r, size) + (0 if strict else 1)) * size
+
+
+def _first_query(j, blocks):
+    """The first query that sees key j."""
+    size, strict = blocks
+    return (_block_of(j, size) + (1 if strict else 0)) * size
+
+
+def _by_block(shape, q0, k0, blocks, *, q_minor: bool = False):
+    """The mask of one score piece whose first query is at q0 and whose
+    first key is at k0, (queries, keys) or, `q_minor`, (keys, queries)."""
+    size, strict = blocks
+    n_q, n_k = (shape[1], shape[0]) if q_minor else shape
+    qb = _block_of(lax.broadcasted_iota(
+        jnp.int32, (1, n_q) if q_minor else (n_q, 1), 1 if q_minor else 0)
+        + q0, size)
+    kb = _block_of(lax.broadcasted_iota(
+        jnp.int32, (n_k, 1) if q_minor else (1, n_k), 0 if q_minor else 1)
+        + k0, size)
+    return kb < qb if strict else kb <= qb
+
+
+def _mask(shape, ahead, window, cut, blocks, *, q_minor: bool = False):
+    """The mask of a piece that `ahead` says is cut: under `blocks`
+    `ahead` is (first query, first key), else the distance between
+    them (`_in_band`)."""
+    if blocks is not None:
+        return _by_block(shape, *ahead, blocks, q_minor=q_minor)
+    return _in_band(shape, ahead, window, cut, q_minor=q_minor)
+
+
 def _lane_fold(x, width: int):
     """Sum of the `width`-lane column slabs of x: a row sum's
     elementwise part, with the reduction across lanes left for later."""
@@ -152,7 +199,8 @@ def _lane_tile(x, n: int):
 
 
 def _over_keys(pair, qi, *, blk_q: int, blk_k: int, n_k: int, sub: int,
-               causal: bool, window: int | None = None) -> None:
+               causal: bool, window: int | None = None,
+               blocks: tuple | None = None) -> None:
     """What the forward and the dQ kernel share: `pair(rows, k_at,
     ahead)` for every piece of keys that q block `qi` sees, in order —
     `rows` the block's query rows, `k_at` the keys' place in the
@@ -165,6 +213,26 @@ def _over_keys(pair, qi, *, blk_q: int, blk_k: int, n_k: int, sub: int,
     if window is not None:
         _over_keys_in_band(pair, block, qi, blk_q=blk_q, blk_k=blk_k,
                            sub=sub, window=window)
+        return
+    if blocks is not None:
+        # kv blocks every query of this q block sees whole, unmasked as
+        # below the diagonal; then the pair(s) the blocks' staircase
+        # crosses, under its mask
+        q0 = qi * blk_q
+        n_full = jnp.minimum(lax.div(_edge(q0, blocks), blk_k), n_k)
+        lax.fori_loop(0, n_full, lambda ki, _: block(ki, None), None)
+        if sub and sub % blocks[0] == 0:
+            # the staircase stays inside the diagonal sub-blocks
+            for i in range(blk_q // sub):
+                for j in range(i + 1):
+                    k0 = qi * blk_k + j * sub
+                    pair(_rows(i, sub), pl.ds(k0, sub),
+                         (q0 + i * sub, k0) if i == j else None)
+        else:
+            n_seen = jnp.minimum(lax.div(
+                _edge(q0 + (blk_q - 1), blocks) + (blk_k - 1), blk_k), n_k)
+            lax.fori_loop(n_full, n_seen,
+                          lambda ki, _: block(ki, (q0, ki * blk_k)), None)
         return
     if not causal:
         lax.fori_loop(0, n_k, lambda ki, _: block(ki, None), None)
@@ -225,7 +293,7 @@ def _over_keys_in_band(pair, block, qi, *, blk_q: int, blk_k: int, sub: int,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, blk_k: int, sub: int, scale: float, causal: bool,
-                window: int | None = None):
+                window: int | None = None, blocks: tuple | None = None):
     """One (batch*head, q-block) program: stream K/V blocks online.
 
     q_ref: (1, BLK_Q, D); k_ref/v_ref: (1, S, D); o_ref: (1, BLK_Q, D);
@@ -249,7 +317,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         the keys at `k_at`; `ahead` None: every key visible, no mask."""
         sblk = _dot_nt(q_ref[0, rows, :], k_ref[0, k_at, :]) * scale
         if ahead is not None:
-            sblk = jnp.where(_in_band(sblk.shape, ahead, window, cut),
+            sblk = jnp.where(_mask(sblk.shape, ahead, window, cut, blocks),
                              sblk, _NEG_INF)
         m = m_ref[rows, :]
         m_new = jnp.maximum(m, jnp.max(sblk, axis=-1, keepdims=True))
@@ -266,7 +334,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     l_ref[...] = jnp.zeros_like(l_ref)
     _over_keys(pair, pl.program_id(1), blk_q=blk_q, blk_k=blk_k,
                n_k=k_ref.shape[1] // blk_k, sub=sub, causal=causal,
-               window=window)
+               window=window, blocks=blocks)
     l = jnp.maximum(jnp.sum(l_ref[...], axis=-1, keepdims=True), 1e-30)
     o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
     lse_ref[0] = m_ref[:, :1] + jnp.log(l)
@@ -276,9 +344,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 # share one trace and one lowering of the kernel: traced a layer each, the
 # three kernels added seconds to a trainer's start (PERF.md §6, PR 32)
 @functools.partial(jax.jit, static_argnames=(
-    "blk_q", "blk_k", "scale", "causal", "interpret", "window"))
+    "blk_q", "blk_k", "scale", "causal", "interpret", "window", "blocks"))
 def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
-         interpret: bool, window: int | None = None):
+         interpret: bool, window: int | None = None,
+         blocks: tuple | None = None):
     b, s, h, d = q.shape
     # (B, S, H, D) -> (B*H, S, D) program-per-head views
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -289,7 +358,8 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
     sub = _diag_sub(blk_q, blk_k)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, blk_k=blk_k, sub=sub, scale=scale,
-                          causal=causal, window=window),
+                          causal=causal, window=window,
+                          blocks=blocks),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
@@ -313,15 +383,19 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
     return o, lse[..., 0]
 
 
-def _seen(q_pos, kv_pos, window: int | None):
+def _seen(q_pos, kv_pos, window: int | None, blocks: tuple | None = None):
     """The XLA paths' mask: causal, and under a window the `window`
-    newest keys only."""
+    newest keys only; under `blocks` by block index."""
+    if blocks is not None:
+        size, strict = blocks
+        qb, kb = q_pos // size, kv_pos // size
+        return kb < qb if strict else kb <= qb
     mask = q_pos >= kv_pos
     return mask if window is None else mask & (q_pos - kv_pos < window)
 
 
 def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool,
-                   window: int | None = None):
+                   window: int | None = None, blocks: tuple | None = None):
     """Flash forward in plain XLA (KV-block scan with the online
     softmax) — the off-TPU fallback. Returns (o, lse) exactly as `_fwd`
     does: o (B,S,H,D) in q.dtype, lse (B*H, S) fp32."""
@@ -339,7 +413,7 @@ def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool,
                           preferred_element_type=jnp.float32) * scale
         if causal:
             kv_pos = ki * blk + jnp.arange(blk)
-            mask = _seen(q_pos[:, None], kv_pos[None, :], window)
+            mask = _seen(q_pos[:, None], kv_pos[None, :], window, blocks)
             sblk = jnp.where(mask[None, None], sblk, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sblk, axis=-1))
         p = jnp.exp(sblk - m_new[..., None])
@@ -362,7 +436,8 @@ def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool,
 
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
                      dk_ref, dv_ref, dk_acc, dv_acc, *, sub: int,
-                     scale: float, causal: bool, window: int | None = None):
+                     scale: float, causal: bool, window: int | None = None,
+                     blocks: tuple | None = None):
     """One (batch*head, kv-block) program: K/V block resident, stream Q
     blocks (causal: only blocks that can see this KV block), accumulate
     dK/dV in fp32 VMEM scratch (dk_acc/dv_acc: (BLK_K, D)). Works on the
@@ -389,8 +464,8 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
         pt = jnp.exp(_dot_nt(k_ref[0, keys, :], q) * scale
                      - lse_ref[0, qi, :, lanes])
         if ahead is not None:
-            pt = jnp.where(_in_band(pt.shape, ahead, window, cut,
-                                    q_minor=True), pt, 0.0)
+            pt = jnp.where(_mask(pt.shape, ahead, window, cut, blocks,
+                                 q_minor=True), pt, 0.0)
         dv_acc[keys, :] += _dot(pt.astype(to), do)
         dst = pt * (_dot_nt(v_ref[0, keys, :], do) - rt_ref[0, qi, :, lanes])
         dk_acc[keys, :] += _dot(dst.astype(to), q)  # x scale: at the end
@@ -433,7 +508,25 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
         return
-    if causal:
+    if blocks is not None:
+        # the forward's walk mirrored: the q block(s) the staircase
+        # crosses (from the first with a query that sees a key of this
+        # block), then the ones whose every query sees all of it
+        k0 = ki * blk_k
+        first_full = jnp.minimum(lax.div(
+            _first_query(k0 + (blk_k - 1), blocks) + (blk_q - 1), blk_q), n_q)
+        if sub and sub % blocks[0] == 0:
+            for j in range(blk_k // sub):
+                for i in range(j, blk_k // sub):
+                    q0 = ki * blk_q + i * sub
+                    pair(_rows(j, sub), pl.ds(q0, sub), ki, _rows(i, sub),
+                         (q0, k0 + j * sub) if i == j else None)
+        else:
+            first_seen = jnp.minimum(
+                lax.div(_first_query(k0, blocks), blk_q), n_q)
+            lax.fori_loop(first_seen, first_full,
+                          lambda qi, _: block(qi, (qi * blk_q, k0)), None)
+    elif causal:
         # q blocks the diagonal crosses (from the first that can see any
         # row of this kv block), then the ones that see all of it
         first_full = lax.div((ki + 1) * blk_k + blk_q - 2, blk_q)
@@ -455,7 +548,8 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref, dq_ref,
                    dq_acc, *, blk_k: int, sub: int, scale: float,
-                   causal: bool, window: int | None = None):
+                   causal: bool, window: int | None = None,
+                   blocks: tuple | None = None):
     """One (batch*head, q-block) program: Q block resident, stream KV
     blocks (causal skip and diagonal as in the forward), accumulate dQ
     in fp32 VMEM scratch (dq_acc: (BLK_Q, D)). lse_ref/rt_ref:
@@ -467,7 +561,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref, dq_ref,
         p = jnp.exp(_dot_nt(q_ref[0, rows, :], k_blk) * scale
                     - lse_ref[0, rows, :])
         if ahead is not None:
-            p = jnp.where(_in_band(p.shape, ahead, window, cut), p, 0.0)
+            p = jnp.where(_mask(p.shape, ahead, window, cut, blocks), p, 0.0)
         ds = p * (_dot_nt(do_ref[0, rows, :], v_ref[0, k_at, :])
                   - rt_ref[0, rows, :])
         dq_acc[rows, :] += _dot(ds.astype(to), k_blk)  # x scale: at the end
@@ -475,15 +569,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref, dq_ref,
     dq_acc[...] = jnp.zeros_like(dq_acc)
     _over_keys(pair, pl.program_id(1), blk_q=q_ref.shape[1], blk_k=blk_k,
                n_k=k_ref.shape[1] // blk_k, sub=sub, causal=causal,
-               window=window)
+               window=window, blocks=blocks)
     dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "blk_q", "blk_k", "scale", "causal", "interpret", "window"))
+    "blk_q", "blk_k", "scale", "causal", "interpret", "window", "blocks"))
 def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
                 scale: float, causal: bool, dlse, interpret: bool,
-                window: int | None = None):
+                window: int | None = None, blocks: tuple | None = None):
     """Pallas flash backward: same math as `_bwd_blockwise` (the XLA
     reference used by the parity tests) but with scores recomputed in
     VMEM — nothing S^2-shaped touches HBM — and the causal block skip
@@ -508,7 +602,7 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, sub=sub, scale=scale,
-                          causal=causal, window=window),
+                          causal=causal, window=window, blocks=blocks),
         grid=(b * h, s // blk_k),
         in_specs=[
             pl.BlockSpec((1, s, d), lambda bh, ki: (bh, 0, 0)),
@@ -534,7 +628,8 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
     )(qt, kt, vt, dot, along_lanes(lse), along_lanes(rt))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, blk_k=blk_k, sub=sub,
-                          scale=scale, causal=causal, window=window),
+                          scale=scale, causal=causal, window=window,
+                          blocks=blocks),
         grid=(b * h, s // blk_q),
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
@@ -558,7 +653,8 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
 
 
 def _bwd_blockwise(q, k, v, o, lse, do, *, blk: int, scale: float,
-                   causal: bool, dlse=None, window: int | None = None):
+                   causal: bool, dlse=None, window: int | None = None,
+                   blocks: tuple | None = None):
     """Flash backward in plain XLA, scanning KV blocks. All (B,S,H,D).
 
     With `dlse` (a (B*H, S) cotangent on the log-sum-exp output), the
@@ -588,7 +684,7 @@ def _bwd_blockwise(q, k, v, o, lse, do, *, blk: int, scale: float,
                           preferred_element_type=jnp.float32) * scale
         if causal:
             kv_pos = ki * blk + jnp.arange(blk)
-            mask = _seen(q_pos[:, None], kv_pos[None, :], window)
+            mask = _seen(q_pos[:, None], kv_pos[None, :], window, blocks)
             sblk = jnp.where(mask[None, None], sblk, _NEG_INF)
         p = jnp.exp(sblk - lse_b.transpose(0, 2, 1)[..., None])  # (B,H,S,blk)
         dv_blk = jnp.einsum("bhqk,bqhd->bkhd", p, do32,
@@ -647,13 +743,36 @@ def _stat_width(blk_k: int, sub: int) -> int:
 
 
 def block_pairs(s: int, blk_q: int, blk_k: int, causal: bool,
-                window: int | None = None) -> str:
+                window: int | None = None, blocks: tuple | None = None
+                ) -> str:
     """What each of the three kernels works through for one head, for
     the log: the blocking, and the block pairs that run unmasked, under
     the mask, and not at all."""
     n_q, n_k = s // blk_q, s // blk_k
     if not causal:
         return f"blocks {blk_q}x{blk_k}, pairs a head: {n_q * n_k} full"
+    if blocks is not None:
+        # as `_over_keys` counts them
+        size, strict = blocks
+
+        def edge(r):
+            return (r // size + (0 if strict else 1)) * size
+        full = sum(min(edge(qi * blk_q) // blk_k, n_k) for qi in range(n_q))
+        seen = sum(min(-(-edge((qi + 1) * blk_q - 1) // blk_k), n_k)
+                   for qi in range(n_q))
+        text = (f"blocks {blk_q}x{blk_k}, keys of "
+                f"{'earlier blocks' if strict else 'blocks up to the own'} "
+                f"of {size}, pairs a head: ")
+        sub = _diag_sub(blk_q, blk_k)
+        if sub and sub % size == 0:
+            n = blk_q // sub
+            return text + (
+                f"{full} full, {n_q} on the staircase, "
+                f"{n_q * n_k - full - n_q} skipped; a staircase pair as "
+                f"{n}x{n} of {sub}: {n * (n - 1) // 2} full, {n} masked, "
+                f"{n * (n - 1) // 2} skipped")
+        return text + (f"{full} full, {seen - full} on the staircase, "
+                       f"{n_q * n_k - seen} skipped, masked whole")
     # as the kernels count them: kv blocks wholly at or before a q
     # block's first row, and those with any column at or before its last
     full = sum((qi * blk_q + 1) // blk_k for qi in range(n_q))
@@ -707,8 +826,8 @@ def force_interpret_kernels():
 
 
 def _kernel_interpret(what: str, q, blk_q: int, blk_k: int,
-                      causal: bool, window: int | None = None
-                      ) -> bool | None:
+                      causal: bool, window: int | None = None,
+                      blocks: tuple | None = None) -> bool | None:
     """Which path this trace takes: the Pallas `interpret` flag (False
     = compiled, on TPU; True = the test hook), or None for the compiled
     XLA blockwise paths — off-TPU, where interpret-mode Pallas is
@@ -722,25 +841,31 @@ def _kernel_interpret(what: str, q, blk_q: int, blk_k: int,
     else:
         mode, interpret = "xla blockwise", None
     if interpret is not None:
-        mode += "; " + block_pairs(q.shape[1], blk_q, blk_k, causal, window)
+        mode += "; " + block_pairs(q.shape[1], blk_q, blk_k, causal, window,
+                                   blocks)
     elif window is not None:
         mode += f", window {window}"
+    elif blocks is not None:
+        mode += f", by blocks of {blocks[0]}" + ", strictly" * blocks[1]
     log.info("flash attention %s %s: %s", what, tuple(q.shape), mode)
     return interpret
 
 
-def _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window):
-    interpret = _kernel_interpret("fwd", q, blk_q, blk_k, causal, window)
+def _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window, blocks):
+    interpret = _kernel_interpret("fwd", q, blk_q, blk_k, causal, window,
+                                  blocks)
     if interpret is None:
         return _fwd_blockwise(q, k, v, blk=blk_k, scale=scale,
-                              causal=causal, window=window)
+                              causal=causal, window=window, blocks=blocks)
     return _fwd(q, k, v, blk_q=blk_q, blk_k=blk_k, scale=scale,
-                causal=causal, interpret=interpret, window=window)
+                causal=causal, interpret=interpret, window=window,
+                blocks=blocks)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_lse(q, k, v, blk_q, blk_k, scale, causal, window):
-    return _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q, k, v, blk_q, blk_k, scale, causal, window, blocks):
+    return _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window,
+                         blocks)
 
 
 # What a `jax.checkpoint` around a caller may keep of a call, by name
@@ -750,8 +875,9 @@ def _flash_lse(q, k, v, blk_q, blk_k, scale, causal, window):
 KEPT_O, KEPT_LSE = "flash_o", "flash_lse"
 
 
-def _flash_lse_fwd(q, k, v, blk_q, blk_k, scale, causal, window):
-    o, lse = _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window)
+def _flash_lse_fwd(q, k, v, blk_q, blk_k, scale, causal, window, blocks):
+    o, lse = _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window,
+                           blocks)
     # named before the residuals are formed: the backward reads the
     # named values, and naming the call's result in the caller would
     # name another variable and leave the kernel in the replay
@@ -759,17 +885,19 @@ def _flash_lse_fwd(q, k, v, blk_q, blk_k, scale, causal, window):
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_lse_bwd(blk_q, blk_k, scale, causal, window, res, cotangents):
+def _flash_lse_bwd(blk_q, blk_k, scale, causal, window, blocks, res,
+                   cotangents):
     q, k, v, o, lse = res
     do, dlse = cotangents
-    interpret = _kernel_interpret("bwd", q, blk_q, blk_k, causal, window)
+    interpret = _kernel_interpret("bwd", q, blk_q, blk_k, causal, window,
+                                  blocks)
     if interpret is None:
         return _bwd_blockwise(q, k, v, o, lse, do, blk=blk_k,
                               scale=scale, causal=causal, dlse=dlse,
-                              window=window)
+                              window=window, blocks=blocks)
     return _bwd_pallas(q, k, v, o, lse, do, blk_q=blk_q, blk_k=blk_k,
                        scale=scale, causal=causal, dlse=dlse,
-                       interpret=interpret, window=window)
+                       interpret=interpret, window=window, blocks=blocks)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -778,7 +906,8 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, scale: float | None = None,
                         block_q: int = 512, block_k: int = 512,
-                        window: int | None = None
+                        window: int | None = None,
+                        blocks: tuple[int, bool] | None = None
                         ) -> tuple[jax.Array, jax.Array]:
     """flash_attention that ALSO returns the per-row log-sum-exp
     ((B, H*... reshaped) -> (B, S, H)) — the combinable statistic for
@@ -796,23 +925,40 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          "and at least 1")
     if window is not None and window >= s:
         window = None  # no query has that many keys behind it
+    if blocks is not None:
+        size, strict = blocks
+        if not causal or window is not None or size < 1 or s % size:
+            raise ValueError(
+                f"blocks={blocks} is visibility by block index, a causal "
+                f"mask made coarser: it needs causal=True, no window and "
+                f"a size that divides the sequence ({s})")
+        blocks = (int(size), bool(strict))
     blk_q = _fit_block(s, block_q)
     blk_k = _fit_block(s, block_k)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    o, lse = _flash_lse(q, k, v, blk_q, blk_k, scale, causal, window)
+    o, lse = _flash_lse(q, k, v, blk_q, blk_k, scale, causal, window,
+                        blocks)
     return o, lse.reshape(b, h, s).transpose(0, 2, 1)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: float | None = None,
                     block_q: int = 512, block_k: int = 512,
-                    window: int | None = None) -> jax.Array:
+                    window: int | None = None,
+                    blocks: tuple[int, bool] | None = None) -> jax.Array:
     """Fused causal attention. q/k/v: (B, S, H, D) -> (B, S, H, D).
 
     `window` (static; None = every earlier key): query i sees keys
     i - window + 1 .. i, and the kernels visit only the block pairs
     that hold such a key (`block_pairs`).
+
+    `blocks` (static; (size, strict)): visibility by block index
+    instead, b(i) = i // size: query i sees key j iff b(j) <= b(i), or
+    b(j) < b(i) where `strict`. A strict query of the first block sees
+    no key: its output is no attention at all (a mean of values), and
+    its `lse` is `_NEG_INF`, so that a merge by `lse` with a part that
+    saw a key gives it the weight 0 exactly, forward and backward.
 
     Blocks auto-fit any 128-divisible sequence (pad upstream otherwise —
     the transformer's static max_len already guarantees this). One
@@ -821,4 +967,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     return flash_attention_lse(q, k, v, causal=causal, scale=scale,
                                block_q=block_q, block_k=block_k,
-                               window=window)[0]
+                               window=window, blocks=blocks)[0]

@@ -67,17 +67,23 @@ def describe(rows: int, vocab: int) -> str:
 # anonymous fusions; the narrower ones name its three matmuls. Names are
 # metadata: the compiled loop is the same with and without them.
 @jax.named_scope("xent")
-def _sweep(hidden, kernel, targets, n_rows, block_rows, with_grad):
+def _sweep(hidden, kernel, targets, n_rows, block_rows, with_grad,
+           weights=None):
     """Sum over the rows with a target >= 0 of (lse - target's logit) / n_rows,
-    and with ``with_grad`` its gradient for hidden and kernel."""
+    and with ``with_grad`` its gradient for hidden and kernel. With
+    ``weights`` (float32, one a row) the sum over all rows of weight x
+    (lse - target's logit) instead, and ``n_rows`` is not read."""
     n, d = hidden.shape
     v = kernel.shape[1]
     blocks, r = blocking(n, v, block_rows)
     if blocks * r > n:
         hidden = jnp.pad(hidden, ((0, blocks * r - n), (0, 0)))
         targets = jnp.pad(targets, (0, blocks * r - n), constant_values=-1)
+        if weights is not None:
+            weights = jnp.pad(weights, (0, blocks * r - n))
     k32 = kernel.astype(jnp.float32)
-    scale = 1.0 / n_rows.astype(jnp.float32)
+    if weights is None:
+        scale = 1.0 / n_rows.astype(jnp.float32)
 
     # A block is held vocabulary-major, (V, R), and d_kernel as (V, d):
     # the layouts the compiler picks by itself for a vocabulary that is
@@ -95,7 +101,10 @@ def _sweep(hidden, kernel, targets, n_rows, block_rows, with_grad):
         lse = m + jnp.log(jnp.sum(jnp.exp(logits - m), axis=0))
         picked = jnp.take_along_axis(
             logits, jnp.maximum(t, 0)[None, :], axis=0)[0]
-        w = jnp.where(t >= 0, scale, 0.0)
+        if weights is None:
+            w = jnp.where(t >= 0, scale, 0.0)
+        else:
+            w = lax.dynamic_slice(weights, (i * r,), (r,))
         loss = carry[0] + jnp.sum(w * (lse - picked))
         if not with_grad:
             return (loss,)
@@ -139,8 +148,24 @@ def _xent_bwd(block_rows, res, g):
 _xent.defvjp(_xent_fwd, _xent_bwd)
 
 
+# The same sweep under a weight a row: a rule of its own, so that the
+# callers that give no weights trace the program they always did.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _xent_weighted(hidden, kernel, targets, weights, block_rows):
+    return _sweep(hidden, kernel, targets, None, block_rows, False, weights)
+
+
+def _xent_weighted_fwd(hidden, kernel, targets, weights, block_rows):
+    loss, dh, dk = _sweep(hidden, kernel, targets, None, block_rows, True,
+                          weights)
+    return loss, (dh, dk)
+
+
+_xent_weighted.defvjp(_xent_weighted_fwd, _xent_bwd)
+
+
 def streamed_lm_xent(hidden, kernel, targets, block_rows: int | None = None,
-                     n_rows=None):
+                     n_rows=None, weights=None):
     """Mean CE of softmax(hidden @ kernel) against integer targets.
 
     hidden: (..., d); kernel: (d, V); targets: (...) int32 in [0, V), or
@@ -150,7 +175,16 @@ def streamed_lm_xent(hidden, kernel, targets, block_rows: int | None = None,
     count, without ever holding more than a block of the logits.
     ``n_rows`` is what the sum is divided by where that is not this
     call's own count of rows (a caller that holds a share of the batch
-    gives the whole batch's)."""
+    gives the whole batch's).
+
+    ``weights`` (...) float32: the sum over the rows of weight x CE
+    instead of the mean (an objective that weighs its tokens one by one,
+    each target in [0, V)); they get no gradient."""
+    if weights is not None:
+        return _xent_weighted(
+            hidden.reshape(-1, hidden.shape[-1]), kernel,
+            targets.reshape(-1), weights.reshape(-1).astype(jnp.float32),
+            block_rows)
     if n_rows is None:
         n_rows = jnp.sum(targets >= 0)
     return _xent(hidden.reshape(-1, hidden.shape[-1]), kernel,

@@ -121,6 +121,30 @@ def test_flash_kernels_compile(one_chip, no_persistent_cache, kernel,
         assert custom_calls(compiled) == 2
 
 
+# one row of the block-diffusion cell: a copy's 8,192 positions, the
+# clean copy's mask and the noised queries' (strictly earlier blocks)
+@pytest.mark.parametrize("strict", [False, True], ids=["own", "before"])
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_flash_kernels_compile_by_block_index(one_chip, no_persistent_cache,
+                                              kernel, strict):
+    b, s, h, d = shape = (1, 8192, 32, 128)
+    kw = dict(blk_q=512, blk_k=512, scale=d ** -0.5, causal=True,
+              interpret=False, blocks=(4, strict))
+    x = sds(shape, jnp.bfloat16)
+    lse = sds((b * h, s), F32)
+    if kernel == "fwd":
+        compiled = compile_for(one_chip, functools.partial(fa._fwd, **kw),
+                               x, x, x)
+        assert custom_calls(compiled) == 1
+    else:
+        compiled = compile_for(
+            one_chip,
+            lambda q, k, v, o, lse, do, dlse: fa._bwd_pallas(
+                q, k, v, o, lse, do, dlse=dlse, **kw),
+            x, x, x, x, lse, x, lse)
+        assert custom_calls(compiled) == 2
+
+
 def test_chunked_scan_compiles_at_the_hybrid_cells_shape(
         one_chip, no_persistent_cache, monkeypatch):
     """`ops/ssd.py` forward and written-out backward for one mamba layer
