@@ -370,7 +370,7 @@ def _causal_attention(cfg: TransformerConfig, kind: str, q, k, v, window):
     key/value heads repeated, and the path the config picks."""
     if cfg.pos == "rope" and kind != "full":
         with jax.named_scope("rope"):
-            q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
+            q, k = _rope(cfg, q), _rope(cfg, k)
     if cfg.kv_heads != cfg.n_heads:
         # grouped-query: every key/value head serves n_heads/kv_heads
         # query heads. Repeated here, outside the kernels, whose
@@ -428,8 +428,8 @@ class Attention(nn.Module):
             if cfg.pos == "rope":
                 with jax.named_scope("rope"):
                     at = jnp.arange(s) % (s // 2)
-                    q = rope(q, cfg.rope_theta, at)
-                    k = rope(k, cfg.rope_theta, at)
+                    q = _rope(cfg, q, at)
+                    k = _rope(cfg, k, at)
             o = blockdiff.attention(q, k, v, block=cfg.block_length,
                                     scale=cfg.attn_scale)
         else:
@@ -1348,3 +1348,15 @@ def auto_remat(cfg: TransformerConfig, batch_size: int,
 
     return dataclasses.replace(
         cfg, remat=choose_remat(cfg, batch_size, seq_len, hbm_bytes))
+
+
+def _rope(cfg: TransformerConfig, x, positions=None):
+    """`rope` as the blocks apply it: one pass in `ops/rope.py`'s kernel
+    where its rule takes x (a TPU, heads of 128 lanes, nothing sharded
+    by the mesh), the formula above everywhere else. Down here because
+    the lines above a kernel's call are in the compile cache's key
+    (ROADMAP S13)."""
+    from edl_tpu.ops import rope as kernel
+    if rows := kernel.rows_for(x, cfg.mesh):
+        return kernel.rotate(x, cfg.rope_theta, positions, rows)
+    return rope(x, cfg.rope_theta, positions)

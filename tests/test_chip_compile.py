@@ -26,8 +26,10 @@ from jax.sharding import SingleDeviceSharding
 
 from edl_tpu.models.transformer import (Transformer, TransformerConfig,
                                         lm_loss_fused)
+from edl_tpu.models import transformer as tfm
 from edl_tpu.ops import opt_kernels as ok
 from edl_tpu.ops import pack
+from edl_tpu.ops import rope as rope_kernel
 from edl_tpu.train.state import TrainState
 from edl_tpu.train.step import make_train_step
 
@@ -143,6 +145,51 @@ def test_flash_kernels_compile_by_block_index(one_chip, no_persistent_cache,
                 q, k, v, o, lse, do, dlse=dlse, **kw),
             x, x, x, x, lse, x, lse)
         assert custom_calls(compiled) == 2
+
+
+# q of the block-diffusion cell and k of the afmoe share's: the widest
+# and the narrowest call of `ops/rope.py` a cell makes
+ROPE_SHAPES = [(1, 16384, 32, 128), (2, 8192, 4, 128)]
+
+
+@pytest.mark.parametrize("shape", ROPE_SHAPES, ids=str)
+@pytest.mark.parametrize("name", ["rope_fwd", "rope_bwd"])
+def test_rope_kernel_compiles(one_chip, no_persistent_cache, name, shape):
+    rows = rope_kernel.ROWS
+    tables = sds((shape[1], shape[3]), F32)
+    compiled = compile_for(
+        one_chip, functools.partial(rope_kernel._call, rows=rows,
+                                    interpret=False, name=name),
+        sds(shape, jnp.bfloat16), tables, tables)
+    assert custom_calls(compiled) == 1
+    assert f"%{name}" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", ROPE_SHAPES, ids=str)
+def test_rope_and_its_gradient_are_two_passes_in_bfloat16(
+        one_chip, no_persistent_cache, monkeypatch, shape):
+    """`jax.grad` through the blocks' rope on a TPU: one Mosaic call a
+    direction and nothing of the formula's float32 halves (XLA wrote a
+    head's two halves, `f32[..., 64]`, to HBM between three passes)."""
+    # the rule asks the backend which path to build
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = TransformerConfig(vocab_size=64, d_model=128, n_heads=1,
+                            n_layers=1, d_ff=64, max_len=shape[1],
+                            pos="rope", rope_theta=1e6)
+    at = jnp.arange(shape[1]) % (shape[1] // 2)
+
+    def value_and_grad(x, g):
+        return jax.value_and_grad(lambda x: jnp.sum(
+            tfm._rope(cfg, x, at).astype(F32) * g))(x)
+
+    text = compile_for(one_chip, value_and_grad, sds(shape, jnp.bfloat16),
+                       sds(shape, jnp.bfloat16)).as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "%rope_fwd" in text and "%rope_bwd" in text
+    b, s, h, d = shape
+    for halves in (f"f32[{b},{s},{h},{d // 2}]", f"f32[{b},{h},{s},{d // 2}]",
+                   f"f32[{b * h},{s},{d // 2}]"):
+        assert halves not in text
 
 
 def test_chunked_scan_compiles_at_the_hybrid_cells_shape(
