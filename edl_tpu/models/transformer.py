@@ -541,21 +541,21 @@ _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
 @jax.custom_vjp
-def _permute_rows(x, perm, inv):
-    """x[perm] for a permutation whose inverse is ``inv``: the backward
-    is g[inv], a gather over unique indices."""
-    return x[perm]
-
-
-def _permute_rows_fwd(x, perm, inv):
-    return x[perm], inv
-
-
-def _permute_rows_bwd(inv, g):
-    return g[inv], None, None
-
-
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+def _combine_rows(out, gate, order, inv, here):
+    """sum_k gate[t, k] * out[inv][t, k]: the expert buffer ``out``
+    (T*k, d), in expert order, weighted back to its tokens, (T, d) in
+    float32. A slot that is not ``here`` (as `_dispatch_rows` has it)
+    gives nothing, by a select and not by a zero gate: its row may hold
+    NaN. The backward (`_combine_rows_bwd`, at the end of this file as
+    `_rope` is, for the compile cache's key) keeps the buffer in expert
+    order, its own residual, and makes nothing of the shape (T, k, d):
+    the weighted sum keeps no copy of the buffer in token order, so a
+    rematerialised block replays this gather for no one."""
+    out = out[inv].reshape(*gate.shape, out.shape[-1])
+    if here is not None:
+        out = jnp.where(here[..., None], out, 0)
+    return jnp.einsum("tk,tkd->td", gate.astype(out.dtype), out,
+                      preferred_element_type=jnp.float32)
 
 
 def _expert_ffn(x, tables, matmul, dtype):
@@ -580,13 +580,13 @@ class MoEMLP(nn.Module):
       stable-sorted by expert, their rows gathered, the FFN run as
       grouped matmuls over the ragged groups (`jax.lax.ragged_dot`; a
       Mosaic kernel of XLA's on a TPU), and the results un-permuted
-      and weighted. No capacity, no (T, E, C) array, nothing dropped.
-      A chip's share (`cfg.held_experts` < n_experts) sorts the absent
-      experts' assignments last, into no group; the kernel leaves their
-      rows unwritten, forward and backward, and the layer masks them in
-      token order where the gathers come out, by a select (the rows may
-      hold NaN, which a zero gate would not stop), never by a pass over
-      the (T*k, d) buffer.
+      and weighted, once: the combine's backward stays in expert order
+      (`_combine_rows`), so remat replays no gather of the buffer. No
+      capacity, no (T, E, C) array, nothing dropped. A chip's share
+      (`cfg.held_experts` < n_experts) sorts the absent experts'
+      assignments last, into no group; the kernel leaves their rows
+      unwritten, both ways, and a select where they return to token
+      order (a zero gate would not stop NaN) masks them, no pass.
     - cfg.moe_wire set (inside train/comm's manual shard_map region):
       the capacity router `router_topk`, whose fixed-shape (E, C, d)
       buffer the wire object carries to the experts' owner chips
@@ -720,14 +720,14 @@ class MoEMLP(nn.Module):
                 rows, tables,
                 lambda a, w: jax.lax.ragged_dot(a, w, counts), cfg.dtype)
         with jax.named_scope("moe_combine"):
-            out = _permute_rows(out, inv, order).reshape(t, k, d)
-            if share:
-                # a select and not the zero gate: 0 x NaN is NaN. Its
-                # transpose zeroes the same slots of gate x dy, so the
-                # backward's gather carries zeros to the tail
-                out = jnp.where(here[..., None], out, 0)
-            y = jnp.einsum("tk,tkd->td", gate.astype(cfg.dtype), out,
-                           preferred_element_type=jnp.float32)
+            # to token order once, forward. The backward stays in expert
+            # order: d(out) is dy gathered as the dispatch gathers x,
+            # times the slot's gate (0 on an absent slot, so zeros go to
+            # the tail), d(gate) a row's dot with the buffer, and only
+            # those T x k scalars are un-permuted. A rematerialised
+            # block's replay stops at the buffer: nothing reads `y`
+            # again, and no (T, k, d) array is anyone's residual
+            y = _combine_rows(out, gate, order, inv, here)
         if cfg.moe_shared:
             with jax.named_scope("moe_shared"):
                 wide = cfg.moe_shared * width
@@ -1360,3 +1360,40 @@ def _rope(cfg: TransformerConfig, x, positions=None):
     if rows := kernel.rows_for(x, cfg.mesh):
         return kernel.rotate(x, cfg.rope_theta, positions, rows)
     return rope(x, cfg.rope_theta, positions)
+
+
+def _combine_rows_fwd(out, gate, order, inv, here):
+    return _combine_rows(out, gate, order, inv, here), (
+        out, gate, order, inv, here)
+
+
+def _rows_to(places, values):
+    """``values`` with element i moved to ``places[i]``, a permutation:
+    values[inverse of places], made by a sort on ``places``. XLA's gather
+    on a TPU goes index by index (0.93 ms for the T*k = 131,072 scalars
+    of a layer on a v5e, the time of a gather of as many 4 KB rows from
+    fast memory); its sort of as many pairs takes under 0.3."""
+    return jax.lax.sort((places, values), num_keys=1)[1]
+
+
+def _combine_rows_bwd(res, dy):
+    """From dy (T, d), in expert order throughout: dy's rows gathered as
+    `_dispatch_rows` gathers x's (a (T, d) source), d(out) their product
+    with the slots' gates, d(gate) their row-wise dot with the buffer.
+    Past the groups the buffer may hold NaN and so may that dot: the
+    select comes after its T*k scalars are back in token order."""
+    out, gate, order, inv, here = res
+    k = gate.shape[1]
+    rows = dy.astype(out.dtype)[order // k]                 # (T*k, d)
+    held = gate if here is None else jnp.where(here, gate, 0)
+    d_out = _rows_to(inv, held.reshape(-1))[:, None] * rows.astype(
+        jnp.float32)
+    d_gate = jnp.sum(out.astype(jnp.float32) * rows.astype(jnp.float32), -1)
+    d_gate = _rows_to(order, d_gate).reshape(gate.shape)
+    if here is not None:
+        d_gate = jnp.where(here, d_gate, 0)
+    return (d_out.astype(out.dtype), d_gate.astype(gate.dtype),
+            None, None, None)
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)

@@ -527,6 +527,22 @@ def test_what_the_kernel_leaves_past_the_groups_reaches_nothing(
         assert np.isnan(np.asarray(rows[8:])).all()
 
 
+def test_remat_replays_no_gather_of_a_shares_buffer(variables, tokens):
+    """`tests/test_olmoe.py`'s count with a share of the experts and the
+    sandwich norms, whose `block_mlp_out` is kept: five gathers with a
+    (T*k, d) result an expert layer, two of them from the buffer, and
+    the replay holds the dispatch's alone."""
+    from tests.test_olmoe import expert_buffer_gathers
+    cfg = small(remat=True)
+    gathers, scattered = expert_buffer_gathers(cfg, variables, tokens)
+    layers = len(KINDS) - cfg.n_dense_layers
+    t, rows = tokens.size, tokens.size * K
+    assert sorted(gathers) == sorted(
+        layers * [(False, t), (False, rows), (True, t), (True, t),
+                  (True, rows)])
+    assert scattered == {(t, E), (VOCAB, D)}
+
+
 def test_holding_every_expert_is_the_path_that_was_there():
     """`experts_held == n_experts`, said or not, is OLMoE's dispatch to
     the bit: the same jaxpr, the same logits."""

@@ -218,6 +218,151 @@ def test_dispatch_backward_is_gathers_only(tree):
     assert "ragged_dot" in text
 
 
+# -- the combine, whose backward stays in expert order ----------------------
+
+def buffer_and_routing(dtype, share, seed=31, t=2 * SEQ):
+    """An expert buffer (t*K, D) in expert order with the routing that
+    sorted it, as `MoEMLP` makes them: with a share, three of the E
+    experts are held and their rows come first."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    idx = jax.random.randint(keys[0], (t, K), 0, E)
+    key, here = idx.reshape(-1), None
+    if share:
+        here = (key >= 2) & (key < 5)
+        key = jnp.where(here, key - 2, 3)
+        here = here.reshape(t, K)
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(t * K))
+    out = jax.random.normal(keys[1], (t * K, D)).astype(dtype)
+    gate = jax.nn.softmax(jax.random.normal(keys[2], (t, K)))
+    # as the step hands it over: the cotangent of a result rounded to
+    # the buffer's dtype
+    dy = jax.random.normal(keys[3], (t, D)).astype(dtype).astype(jnp.float32)
+    return out, gate, order, inv, here, dy
+
+
+def plain_combine(out, gate, order, inv, here):
+    """What `_combine_rows` replaces: un-permute, select, weigh, sum."""
+    rows = out[inv].reshape(*gate.shape, -1)
+    if here is not None:
+        rows = jnp.where(here[..., None], rows, 0)
+    return jnp.einsum("tk,tkd->td", gate.astype(out.dtype), rows,
+                      preferred_element_type=jnp.float32)
+
+
+def value_and_gradients(combine, out, gate, order, inv, here, dy):
+    y, back = jax.vjp(lambda o, g: combine(o, g, order, inv, here),
+                      out, gate)
+    return (y, *back(dy))
+
+
+def rel_err(a, b):
+    a, b = (np.asarray(v, np.float64) for v in (a, b))
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("share", [False, True], ids=["all_held", "a_share"])
+@pytest.mark.parametrize("dtype, tol", [
+    (jnp.float32, 1e-6),
+    # the plain form rounds the gates and d(gate) to bfloat16, half a
+    # unit of 2**-8 each: read 2.7e-3 on d(out), 1.8e-3 on d(gate)
+    (jnp.bfloat16, 6e-3)], ids=["float32", "bfloat16"])
+def test_combine_backward_in_expert_order_is_the_plain_gradient(
+        dtype, tol, share):
+    args = buffer_and_routing(dtype, share)
+    mine = value_and_gradients(tfm._combine_rows, *args)
+    want = value_and_gradients(plain_combine, *args)
+    np.testing.assert_array_equal(mine[0], want[0])   # one forward
+    for a, b in zip(mine[1:], want[1:]):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert rel_err(a, b) < tol
+    if share:
+        out, gate, order, inv, here, dy = args
+        # the slots that are not held: nothing to their rows, nothing
+        # from their gates
+        grouped = int(here.sum())
+        assert 0 < grouped < here.size // 2
+        assert not np.asarray(mine[1][grouped:], np.float32).any()
+        assert not np.asarray(mine[2])[~np.asarray(here)].any()
+        assert np.asarray(mine[2])[np.asarray(here)].all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_combine_takes_nothing_from_the_rows_past_the_groups(dtype):
+    """The chip's grouped matmul leaves the buffer's tail unwritten
+    (`tools/ragged_dot_tail.py`): with NaN there the value, d(gate) and
+    d(out) are finite and what they were."""
+    out, gate, order, inv, here, dy = buffer_and_routing(dtype, True)
+    grouped = int(here.sum())
+    poisoned = out.at[grouped:].set(jnp.nan)
+    clean = value_and_gradients(tfm._combine_rows, out, gate, order, inv,
+                                here, dy)
+    dirty = value_and_gradients(tfm._combine_rows, poisoned, gate, order,
+                                inv, here, dy)
+    for a, b in zip(clean, dirty):
+        assert np.isfinite(np.asarray(b, np.float32)).all()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # a zero gate in the select's place would not do
+    leaky = value_and_gradients(tfm._combine_rows, poisoned,
+                                jnp.where(here, gate, 0), order, inv,
+                                None, dy)
+    assert np.isnan(np.asarray(leaky[0])).any()
+    assert np.isnan(np.asarray(leaky[2])).any()
+
+
+def expert_buffer_gathers(cfg, variables, tokens):
+    """The gathers with a (T*k, d) result in the gradient of a model's
+    loss, as (inside a block's replay-and-backward?, rows of the
+    operand), and the results of its scatter-adds."""
+    from tests.test_transformer import _equations
+    model = tfm.Transformer(cfg)
+    state = {c: v for c, v in variables.items() if c != "params"}
+
+    def loss(p):
+        out = model.apply({"params": p, **state}, tokens, train=True,
+                          mutable=list(state))[0]
+        return jnp.mean(out ** 2)
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(variables["params"])
+    rows = tokens.size * cfg.moe_top_k
+    gathers, scattered = [], set()
+    for inside, e in _equations(jaxpr.jaxpr):
+        shape = e.outvars[0].aval.shape if e.outvars else None
+        if e.primitive.name == "gather" and shape == (rows, cfg.d_model):
+            gathers.append((inside, e.invars[0].aval.shape[0]))
+        if e.primitive.name == "scatter-add":
+            scattered.add(shape)
+    return gathers, scattered
+
+
+def test_remat_replays_no_gather_of_the_expert_buffer(tree, tokens):
+    """Five gathers with a (T*k, d) result a layer under `--remat on`:
+    the dispatch's and the combine's forward; the dispatch's again in
+    the replay, dy's rows and the dispatch's backward. Two of the five
+    read the buffer (the combine's forward and the dispatch's backward);
+    the replay's copy of the combine's went with its only reader."""
+    cfg = small(remat=True)
+    gathers, scattered = expert_buffer_gathers(cfg, {"params": tree}, tokens)
+    t, rows = tokens.size, tokens.size * K
+    assert sorted(gathers) == sorted(
+        LAYERS * [(False, t), (False, rows), (True, t), (True, t),
+                  (True, rows)])
+    # top_k's backward and the embedding's: no row of the buffer is added
+    assert scattered == {(t, E), (VOCAB, D)}
+
+
+def test_combine_keeps_the_buffer_in_expert_order_and_no_copy(tree):
+    """What the layer's backward holds of the buffer's size: arrays of
+    (T*k, .) rows, in expert order; nothing of the shape (T, k, d)."""
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, SEQ, D))
+    _, back = jax.vjp(
+        lambda p, x: moe_layer({"block0": {"moe_mlp": p}}, x)[0],
+        tree["block0"]["moe_mlp"], x)
+    shapes = [leaf.shape for leaf in jax.tree.leaves(back)]
+    assert (2 * SEQ * K, D) in shapes
+    assert (2 * SEQ, K, D) not in shapes
+
+
 def test_rope_alone_matches_the_reference():
     x = jax.random.normal(jax.random.PRNGKey(8), (2, SEQ, HEADS, 16))
     ref = jnp.stack([plain.rope(row, 10000.0) for row in x])
