@@ -24,37 +24,103 @@ from benchmark.harness import logs
 from benchmark.harness.procs import BenchFailure, kill_group, say, wait_for
 
 
+ATTEMPTS = 3  # generations after the kill, as `cell.start_training` allows
+POLL = 0.05
+
+
+def _lost_line(job, gen: dict) -> str:
+    """One line for a generation that ended before its first step: its
+    pid, when the launcher started the next, and what the launcher wrote
+    between the two `started trainer` lines."""
+    between = logs.after_start(job.launcher_tail.lines, gen["pid"])
+    return (f"generation lost after the kill: trainer {gen['pid']} ended "
+            "before its first step, the launcher started the next "
+            f"{gen['replaced_after_s']:.1f}s after it; the launcher "
+            "meanwhile: " + (" | ".join(between) or "(no line)"))
+
+
 def kill_and_resume(job, pid: int, deadline_s: float) -> dict:
-    """SIGKILL trainer ``pid`` now; wait for the generation the launcher
-    starts in its place to finish its first step. Stamps are the log
-    tail's; the polls here only watch."""
-    nth = len(job.trainer_pids)  # of the launcher's trainers, from 0
+    """SIGKILL trainer ``pid`` now; wait for the first generation the
+    launcher starts after it that finishes a first step. A generation
+    that the launcher replaces before that is lost, as one is at the
+    start of a job (`cell.start_training`): it is said, counted, and the
+    next is waited for, `ATTEMPTS` generations in all, the first with
+    ``deadline_s`` from the kill and each later one from its own `started
+    trainer` line. Stamps are the log tail's; the polls here only watch."""
+    known = len(job.trainer_pids)  # of the launcher's trainers, from 0
     t_kill = time.monotonic()
     kill_group(pid)
     say(f"SIGKILL -> trainer {pid}")
     most_live = 0
+    lost: list[dict] = []
+    gens: list[tuple[float, int]] = []  # this attempt's trainer, the next
 
-    def resumed():
-        nonlocal most_live
+    def failure(what) -> BenchFailure:
+        return BenchFailure(
+            f"{what} ({time.monotonic() - t_kill:.0f}s after the kill, "
+            f"generations lost since: {len(lost)})\n" + "".join(
+                _lost_line(job, g) + "\n" for g in lost)
+            + job.worker_tail.text())
+
+    def first_step_or_next():
+        nonlocal most_live, gens
         most_live = max(most_live, len(job.live_trainers()))
-        pids = [p for _, p in logs.started_trainers(job.launcher_tail.lines)]
-        return len(pids) > nth and logs.first_step_complete(
-            job.lines(pids[nth]))
-    try:
-        done = wait_for(resumed, deadline_s, "the resumed first step",
-                        proc=job.launcher, poll=0.05)
-    except BenchFailure as e:
-        raise BenchFailure(f"{e}\n{job.worker_tail.text()}") from None
-    new = job.next_trainer(1)
+        gens = logs.started_trainers(
+            job.launcher_tail.lines)[known + len(lost):]
+        return gens and (logs.first_step_complete(job.lines(gens[0][1]))
+                         or len(gens) > 1)
+
+    t_from = t_kill
+    while True:
+        try:
+            done = wait_for(first_step_or_next,
+                            t_from + deadline_s - time.monotonic(),
+                            "the resumed first step", proc=job.launcher,
+                            poll=POLL)
+        except BenchFailure as e:
+            raise failure(e) from None
+        (t_started, new), *later = gens
+        if done is not True:
+            break
+        t_from = later[0][0]
+        lost.append({"pid": new, "replaced_after_s": t_from - t_started})
+        say(_lost_line(job, lost[-1]))
+        if len(lost) == ATTEMPTS:
+            raise failure(f"the resumed first step: {ATTEMPTS} trainers in "
+                          "a row ended before it")
+    while len(job.trainer_pids) <= known + len(lost):
+        job.next_trainer(1)  # `job.trainer_pids` holds every generation
     lines = job.lines(new)
+    # the launcher's part: the first trainer it started, lost or not
+    first_lines = [ls for ls in (job.lines(p) for p in
+                                 job.trainer_pids[known:]) if ls][0]
     out = {"pid": new, "resumed": done, "resume_s": done["t"] - t_kill,
-           "respawn_s": lines[0][0] - t_kill, "most_live": most_live,
+           "respawn_s": first_lines[0][0] - t_kill, "most_live": most_live,
+           "generations_lost": len(lost), "lost": lost,
            "restored": logs.restored(lines),
            "first_step": logs.first_step_wall(lines) or {}}
-    say(f"resume: {out['resume_s']:.2f}s (first line of trainer {new} "
-        f"after {out['respawn_s']:.2f}s, restore {done['restore_s']}s, "
-        f"first step {out['first_step'].get('first_step_s')}s)")
+    say(f"resume: {out['resume_s']:.2f}s (first line of a trainer "
+        f"{out['respawn_s']:.2f}s after the kill, generations lost "
+        f"{len(lost)}, trainer {new}: restore {done['restore_s']}s, first "
+        f"step {out['first_step'].get('first_step_s')}s)")
     return out
+
+
+def checks_of(resume: dict, sealed1: list[int], replayed: list[tuple],
+              bad: int, reference_ok: bool) -> dict:
+    """What `correct` holds the cell to, all of the generation that
+    resumed but the one that is of the whole wait."""
+    return {
+        # the new generation's first step follows a step the old sealed
+        "follows_a_seal": bool(resume["restored"])
+        and resume["restored"][0][0] in sealed1
+        and resume["resumed"]["global_step"] == resume["restored"][0][0] + 1,
+        "replay_equal": bool(replayed) and all(
+            abs(a - b) < 5e-5 for _, a, b in replayed),
+        # over the whole wait, lost generations included: the launcher
+        # has ended one trainer before it starts the next
+        "one_trainer_at_a_time": resume["most_live"] <= 1,
+        "finite": bad == 0, "reference": reference_ok}
 
 
 def run(cell: cl.Cell) -> dict:
@@ -102,15 +168,7 @@ def run(cell: cl.Cell) -> dict:
     replayed = [(n, v, before[n]) for _, n, v in steps2 if n in before]
     bad = cl.bad_steps(steps1, 1) + cl.bad_steps(steps2, 1)
     ref = cl.reference_check(cell, steps1[0][1], steps1[0][2])
-    checks = {
-        # the new generation's first step follows a step the old sealed
-        "follows_a_seal": bool(resume["restored"])
-        and resume["restored"][0][0] in sealed1
-        and resume["resumed"]["global_step"] == resume["restored"][0][0] + 1,
-        "replay_equal": bool(replayed) and all(
-            abs(a - b) < 5e-5 for _, a, b in replayed),
-        "one_trainer_at_a_time": resume["most_live"] <= 1,
-        "finite": bad == 0, "reference": ref["ok"]}
+    checks = checks_of(resume, sealed1, replayed, bad, ref["ok"])
     say(f"sealed before the kill {sealed1}; restored {resume['restored']}; "
         f"replayed {replayed}; checks {checks}")
     return {

@@ -99,3 +99,17 @@ def started_trainers(lines: Lines) -> list[tuple[float, int]]:
     return [(t, int(m.group(1))) for t, ln in lines
             for m in [_STARTED.search(ln)] if m]
 
+
+def after_start(lines: Lines, pid: int) -> list[str]:
+    """What the launcher wrote after it started trainer ``pid`` and
+    before it started the next: why that generation ended, if it says."""
+    out = None
+    for _, ln in lines:
+        m = _STARTED.search(ln)
+        if m and out is not None:
+            break
+        if m and int(m.group(1)) == pid:
+            out = []
+        elif out is not None:
+            out.append(ln)
+    return out or []

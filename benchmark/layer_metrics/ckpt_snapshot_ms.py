@@ -1,6 +1,6 @@
-"""Loop + checkpoints: the loop's own `ckpt.snapshot` span (device ->
-host copy and the copy into the staging arena, inside `save_async`):
-the median over the saves of the measured window that the trainer's
+"""Loop + checkpoints: the loop's own `ckpt.snapshot` span (the device ->
+host fetch, `ckpt.d2h`, inside `save_async`; since PR 25 the fetched
+arrays go to the writer as they are, with no second copy): the median over the saves of the measured window that the trainer's
 span file holds. `ckpt_stall_ms` times the same stall from outside, as
 the difference between log windows with and without a save."""
 

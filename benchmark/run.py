@@ -13,7 +13,9 @@ is named in this file or in a driver.
 
 This process never touches JAX's devices: the children it starts hold
 the chip, one at a time. The last line of stdout is the result; a run
-that finds no TPU prints none and exits 3.
+that finds no TPU prints none and exits 3. A run that fails (exit 1)
+leaves its logs in `.bench_failed/<cell>.<seed>/` (`harness/failed.py`)
+and says so in its last lines; any other end leaves nothing.
 """
 
 from __future__ import annotations
@@ -32,7 +34,7 @@ import sys  # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from benchmark.harness import procs  # noqa: E402
+from benchmark.harness import failed, procs  # noqa: E402
 from benchmark.harness.cell import Cell  # noqa: E402
 
 
@@ -133,6 +135,11 @@ def main(argv=None) -> int:
         return 3
     except procs.BenchFailure as e:
         print(f"failed: {e}", file=sys.stderr)
+        procs.stop_all()  # the logs are whole before they are copied
+        kept = failed.keep(work, ROOT, args.workload, args.seed)
+        print("the launcher's last lines:\n"
+              f"{failed.tail(kept, 'launcher.log')}\n"
+              f"this run's logs are kept in {kept}", file=sys.stderr)
         return 1
     finally:
         procs.stop_all()
