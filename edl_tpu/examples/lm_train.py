@@ -44,6 +44,14 @@ from edl_tpu.utils.logging import get_logger
 
 log = get_logger("edl_tpu.examples.lm_train")
 
+# --arch values that train one chip's share of the experts
+# (--experts-held) with no exchange between chips, and the archs each
+# size option belongs to: what the refusals below name
+SHARE_ARCHS = ("afmoe", "sdar", "joyai")
+SIZE_FLAGS = {"--experts-held": SHARE_ARCHS,
+              "--dense-layers": ("afmoe", "joyai"),
+              "--window": ("afmoe",)}
+
 
 def make_synthetic_shards(data_dir: str, n_files: int, rows: int,
                           seq_len: int, vocab: int, seed: int = 0) -> None:
@@ -125,7 +133,7 @@ def main(argv=None) -> int:
                              "= XLA's single fused reduction)")
     parser.add_argument("--arch", choices=("gpt2", "olmoe",
                                            "granite-hybrid", "afmoe",
-                                           "sdar"),
+                                           "sdar", "joyai"),
                         default="gpt2",
                         help="the block: gpt2 = LayerNorm, learned "
                              "positions, gelu; olmoe = models.transformer."
@@ -151,7 +159,15 @@ def main(argv=None) -> int:
                              "--d-ff, trained by diffusion over blocks of "
                              "--block-length: a noised and a clean copy of "
                              "every row in one pass, the loss on the masked "
-                             "tokens; one chip, which holds --experts-held)")
+                             "tokens; one chip, which holds --experts-held); "
+                             "joyai = models.transformer.joyai_config "
+                             "(JoyAI-LLM-Flash: latent attention, heads of "
+                             "128 + 64 for q and k and 128 for v, sigmoid "
+                             "top-8 of 256 experts over score + bias, a "
+                             "shared expert, --dense-layers leading dense "
+                             "layers of width --d-ff, one multi-token-"
+                             "prediction module whose loss is added x 0.3; "
+                             "one chip, which holds --experts-held)")
     parser.add_argument("--layer-types", default="",
                         help="one letter a layer. granite-hybrid: m = "
                              "mamba, a = attention (default: the "
@@ -167,13 +183,15 @@ def main(argv=None) -> int:
                         help="sdar: tokens a block of the diffusion "
                              "objective (default 4, the family's)")
     parser.add_argument("--experts-held", type=int, default=0,
-                        help="afmoe, sdar: the experts this chip holds, the "
+                        help="afmoe, sdar, joyai: the experts this chip "
+                             "holds, the "
                              "first of --n-experts (default all): the "
                              "router and top-k stay over all of them, and "
                              "the layer computes the held ones' part")
     parser.add_argument("--dense-layers", type=int, default=None,
-                        help="afmoe: leading layers with a dense MLP of "
-                             "width --d-ff (default the published 2)")
+                        help="afmoe, joyai: leading layers with a dense "
+                             "MLP of width --d-ff (default the published "
+                             "2, joyai 1)")
     parser.add_argument("--window", type=int, default=0,
                         help="afmoe: keys a sliding layer's query sees "
                              "(default the published 2048)")
@@ -240,24 +258,22 @@ def main(argv=None) -> int:
                         help="jax profiler trace dir (steps 10-15, rank 0)")
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(argv)
-    args.moe = args.moe or args.arch in ("olmoe", "afmoe", "sdar")
+    args.moe = args.moe or args.arch in ("olmoe", *SHARE_ARCHS)
     if args.moe and args.arch == "granite-hybrid":
         raise SystemExit("--arch granite-hybrid has a dense MLP "
                          "(num_local_experts 0); --moe conflicts")
-    afmoe_only = {"--dense-layers": args.dense_layers,
-                  "--window": args.window}
-    if args.arch != "sdar":  # a chip's share of the experts is sdar's too
-        afmoe_only["--experts-held"] = args.experts_held
-    if args.arch != "afmoe" and any(afmoe_only.values()):
-        raise SystemExit(
-            f"{', '.join(k for k, v in afmoe_only.items() if v)}: only "
-            f"--arch afmoe has such a size; --arch {args.arch} conflicts")
+    for flag, archs in SIZE_FLAGS.items():
+        if getattr(args, flag[2:].replace("-", "_")) \
+                and args.arch not in archs:
+            raise SystemExit(
+                f"{flag}: only --arch {', '.join(archs)} has such a size; "
+                f"--arch {args.arch} conflicts")
     if args.block_length and args.arch != "sdar":
         raise SystemExit(f"--block-length: only --arch sdar trains by "
                          f"diffusion over blocks; --arch {args.arch} "
                          "conflicts")
-    if args.arch in ("afmoe", "sdar") and (args.moe_dispatch
-                                           or args.moe_compress):
+    if args.arch in SHARE_ARCHS and (args.moe_dispatch
+                                     or args.moe_compress):
         raise SystemExit(
             f"--arch {args.arch} computes one chip's share of the experts "
             "and has no exchange: --moe-dispatch / --moe-compress conflict")
@@ -374,26 +390,28 @@ def main(argv=None) -> int:
                                  "letter a layer, s (sliding) or f (full)")
             arch_kw["layer_types"] = tuple(
                 {"s": "sliding", "f": "full"}[c] for c in args.layer_types)
-        if jax.device_count() > 1:
-            raise SystemExit(
-                f"--arch afmoe trains one chip's share of the experts "
-                f"(--experts-held) with no exchange between chips; "
-                f"{jax.device_count()} devices conflict")
     elif args.arch == "sdar":
         from edl_tpu.models.transformer import sdar_config as make_cfg
         arch_kw = {k: v for k, v in (
             ("n_experts", args.n_experts), ("moe_top_k", args.moe_top_k),
             ("experts_held", args.experts_held),
             ("block_length", args.block_length)) if v}
-        if jax.device_count() > 1:
-            raise SystemExit(
-                f"--arch sdar trains one chip's share of the experts "
-                f"(--experts-held) with no exchange between chips; "
-                f"{jax.device_count()} devices conflict")
+    elif args.arch == "joyai":
+        from edl_tpu.models.transformer import joyai_config as make_cfg
+        arch_kw = {k: v for k, v in (
+            ("n_experts", args.n_experts), ("moe_top_k", args.moe_top_k),
+            ("experts_held", args.experts_held)) if v}
+        if args.dense_layers is not None:
+            arch_kw["n_dense_layers"] = args.dense_layers
     elif args.moe:
         arch_kw = dict(moe=True,
                       n_experts=args.n_experts or 2 * jax.device_count(),
                       moe_top_k=args.moe_top_k or 2)
+    if args.arch in SHARE_ARCHS and jax.device_count() > 1:
+        raise SystemExit(
+            f"--arch {args.arch} trains one chip's share of the experts "
+            f"(--experts-held) with no exchange between chips; "
+            f"{jax.device_count()} devices conflict")
     cfg = make_cfg(
         vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
         n_layers=args.n_layers, d_ff=args.d_ff, max_len=args.seq_len,
@@ -514,6 +532,18 @@ def main(argv=None) -> int:
                  cfg.experts_offset + cfg.held_experts - 1, cfg.n_experts,
                  cfg.moe_top_k, cfg.moe_score, cfg.moe_route_scale,
                  cfg.moe_shared)
+    elif args.arch == "joyai":
+        dense = cfg.n_dense_layers
+        log.info("joyai: %d dense + %d expert layers + %d mtp, mla q %d / "
+                 "kv %d, %d heads x (%d + %d | %d), experts %d-%d of %d "
+                 "held, top-%d %s x %s, %d shared, mtp x %s",
+                 dense, cfg.n_layers - dense, cfg.mtp_layers,
+                 cfg.q_lora_rank, cfg.kv_lora_rank, cfg.n_heads,
+                 cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+                 cfg.experts_offset,
+                 cfg.experts_offset + cfg.held_experts - 1, cfg.n_experts,
+                 cfg.moe_top_k, cfg.moe_score, cfg.moe_route_scale,
+                 cfg.moe_shared, cfg.mtp_weight)
     elif cfg.layer_types:
         from edl_tpu.ops import ssd, ssm_stages
         sizes = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)

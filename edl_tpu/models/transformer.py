@@ -130,6 +130,23 @@ class TransformerConfig:
     # on the noised copy's masked tokens, no shift. The mask token is
     # the vocabulary's last id.
     block_length: int = 0
+    # -- latent attention and a multi-token-prediction module
+    # (`joyai_config` sets them all; 0 is the blocks above, untouched).
+    # kv_lora_rank > 0: every attention layer is models/mla.py's mixer:
+    # queries through a latent of q_lora_rank, keys and values through
+    # one of kv_lora_rank, heads of qk_nope_head_dim + qk_rope_head_dim
+    # for q and k (rotary positions on the second part alone, by
+    # interleaved pairs, its key shared by all heads) and of v_head_dim
+    # for v. mtp_layers = 1: one more block behind the final norm that
+    # predicts the token after the next through the same embedding and
+    # head, its loss added with mtp_weight.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mtp_layers: int = 0
+    mtp_weight: float = 0.3
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -156,6 +173,24 @@ class TransformerConfig:
                 "block_length > 0 (diffusion over blocks) runs every layer "
                 "as attention by block index through ops/flash_attention: "
                 "no layer_types, no learned positions, no dense attention")
+        if self.kv_lora_rank and not (
+                self.q_lora_rank > 0 and self.qk_nope_head_dim > 0
+                and self.qk_rope_head_dim > 0 and self.v_head_dim > 0
+                and self.qk_rope_head_dim % 2 == 0 and self.pos == "rope"
+                and not self.n_kv_heads and not self.block_length
+                and self.layer_types is None):
+            raise ValueError(
+                "kv_lora_rank > 0 (latent attention) needs q_lora_rank, "
+                "qk_nope_head_dim, an even qk_rope_head_dim and v_head_dim "
+                "above 0 and pos='rope', and knows no key/value groups, "
+                "layer_types or block_length")
+        if self.mtp_layers not in (0, 1) or (
+                self.mtp_layers and (self.block_length
+                                     or self.tie_embeddings)):
+            raise ValueError(
+                "mtp_layers is 0 or 1 (one module that predicts the token "
+                "after the next, through an untied head), and not under "
+                "block_length")
         if self.n_kv_heads and self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"n_kv_heads={self.n_kv_heads} must divide n_heads="
@@ -184,10 +219,16 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.kv_lora_rank:  # a query's and a key's; `value_head_dim`
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         if self.head_size:
             return self.head_size
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def value_head_dim(self) -> int:
+        return self.v_head_dim if self.kv_lora_rank else self.head_dim
 
     @property
     def mask_id(self) -> int:
@@ -852,6 +893,9 @@ class Block(nn.Module):
             h = _norm(cfg, "ln_attn")(x)
         if self.kind == "mamba":
             h = Mamba2Mixer(cfg, name="ssm")(h)
+        elif cfg.kv_lora_rank:
+            from edl_tpu.models.mla import LatentAttention
+            h = LatentAttention(cfg, name="attn")(h, train)
         else:
             h = Attention(cfg, self.kind, name="attn")(h, train)
         h = checkpoint_name(h, KEPT_MIXER_OUT)
@@ -902,7 +946,8 @@ class Transformer(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, train: bool = True,
-                 return_hidden: bool = False, noised=None):
+                 return_hidden: bool = False, noised=None,
+                 mtp: bool = False):
         """return_hidden=True skips the lm_head and yields the final-LN
         hidden states (B, S, d) — the input of the streamed-vocab fused
         CE (ops/fused_xent.py), which reads the head kernel straight
@@ -913,7 +958,13 @@ class Transformer(nn.Module):
         copy (the rows themselves where not given: init). Both copies
         run as one (B, 2S) batch, and what comes back, hidden states or
         logits, is the noised copy's (B, S): the clean copy never meets
-        the head."""
+        the head.
+
+        Under cfg.mtp_layers ``mtp=True`` returns a pair: the above, and
+        the same from the multi-token-prediction module (`_mtp_hidden`),
+        hidden states or logits through the same head, place i's for
+        token i + 2. The default leaves the module out (inference needs
+        none of it); init runs it, so that its parameters exist."""
         cfg = self.cfg
         half = tokens.shape[1]
         if cfg.block_length:
@@ -940,11 +991,7 @@ class Transformer(nn.Module):
             if pos_embed is not None:
                 x = x + pos_embed[None, :tokens.shape[1]].astype(cfg.dtype)
         x = cfg.constrain(x, ("batch", "seq", "embed"))
-        block = Block
-        if cfg.remat:
-            block = nn.remat(
-                Block, static_argnums=(2,),
-                policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+        block = remat_block(cfg)
         for i in range(cfg.n_layers):
             x = block(cfg, cfg.kind(i), cfg.moe_layer(i),
                       name=f"block{i}")(x, train)
@@ -953,8 +1000,11 @@ class Transformer(nn.Module):
                 x = x[:, :half]
         with jax.named_scope("ln"):
             x = _norm(cfg, "ln_final")(x)
+        z = None
+        if cfg.mtp_layers and (mtp or self.is_initializing()):
+            z = _mtp_hidden(cfg, embed, x, tokens, train)
         if return_hidden:
-            return x
+            return (x, z) if mtp else x
         if cfg.tie_embeddings:
             # the head is the embedding table: logits = h E^T / scale
             with jax.named_scope("lm_head"):
@@ -962,13 +1012,13 @@ class Transformer(nn.Module):
                     "bsd,vd->bsv", x.astype(jnp.float32),
                     embed.embedding.astype(jnp.float32)) / cfg.logits_scale
         # Tied-untied head: separate projection, fp32 logits for stable CE.
-        logits = nn.DenseGeneral(
+        head = nn.DenseGeneral(
             cfg.vocab_size, axis=-1, dtype=jnp.float32, use_bias=False,
             kernel_init=nn.with_logical_partitioning(
                 nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
                 ("embed", "vocab")),
-            name="lm_head")(x)
-        return logits
+            name="lm_head")
+        return (head(x), head(z)) if mtp else head(x)
 
 
 def _model_of(apply_fn, aux_weight, z_weight) -> tuple:
@@ -1033,12 +1083,22 @@ def lm_loss_fn(state, params, batch, *, aux_weight: float | None = None,
                                  batch["tokens"][..., None], axis=-1)[..., 0]
         return blockdiff.with_masked_share(_with_router_terms(
             -jnp.sum(weights * ll), mutated, moe), batch)
-    logits, mutated = _run(state, apply_fn, params, batch["tokens"], moe)
+    mtp = {"mtp": True} if cfg is not None and cfg.mtp_layers else {}
+    logits, mutated = _run(state, apply_fn, params, batch["tokens"], moe,
+                           **mtp)
+    if mtp:
+        logits, mtp_logits = logits
     targets = batch["tokens"][:, 1:]
     logits = logits[:, :-1]
     logp = jax.nn.log_softmax(logits)
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return _with_router_terms(-jnp.mean(ll), mutated, moe)
+    out = _with_router_terms(-jnp.mean(ll), mutated, moe)
+    if mtp:  # place i's logits against token i + 2
+        ll = jnp.take_along_axis(
+            jax.nn.log_softmax(mtp_logits[:, :-2]),
+            batch["tokens"][:, 2:, None], axis=-1)[..., 0]
+        out = _with_mtp(cfg, out, -jnp.mean(ll))
+    return out
 
 
 def lm_loss_fused(state, params, batch, *, block_rows: int | None = None,
@@ -1073,8 +1133,11 @@ def lm_loss_fused(state, params, batch, *, block_rows: int | None = None,
         return blockdiff.with_masked_share(_with_router_terms(
             cfg.xent(hidden, params["lm_head"]["kernel"], batch["tokens"],
                      block_rows, weights=weights), mutated, moe), batch)
+    mtp = {"mtp": True} if cfg is not None and cfg.mtp_layers else {}
     hidden, mutated = _run(state, apply_fn, params, batch["tokens"], moe,
-                           return_hidden=True)
+                           return_hidden=True, **mtp)
+    if mtp:
+        hidden, mtp_hidden = hidden
     tokens = batch["tokens"]
     # a sequence's last position has no next token: a row of weight 0,
     # so the (B, S, d) hidden states go in as they are
@@ -1089,8 +1152,17 @@ def lm_loss_fused(state, params, batch, *, block_rows: int | None = None,
     else:
         kernel = params["lm_head"]["kernel"]
     xent = cfg.xent if cfg is not None else streamed_lm_xent
-    return _with_router_terms(xent(hidden, kernel, targets, block_rows),
-                              mutated, moe)
+    out = _with_router_terms(xent(hidden, kernel, targets, block_rows),
+                             mutated, moe)
+    if mtp:
+        # the same sweep again on the same head kernel: place i's state
+        # of the module against token i + 2, the last two places of a
+        # row at weight 0
+        with jax.named_scope("mtp"):
+            out = _with_mtp(cfg, out, xent(mtp_hidden, kernel, jnp.concatenate(
+                [tokens[:, 2:], jnp.full_like(tokens[:, :2], -1)], axis=1),
+                block_rows))
+    return out
 
 
 def _sown(intermediates, name: str) -> list:
@@ -1263,6 +1335,45 @@ def sdar_config(*, vocab_size: int = 151936, d_model: int = 2048,
         block_length=block_length, **kw)
 
 
+def joyai_config(*, vocab_size: int = 129280, d_model: int = 2048,
+                 n_heads: int = 32, n_layers: int = 40, d_ff: int = 7168,
+                 max_len: int = 131072, n_dense_layers: int = 1,
+                 moe_d_ff: int = 768, n_experts: int = 256,
+                 moe_top_k: int = 8, experts_held: int = 0,
+                 experts_offset: int = 0, q_lora_rank: int = 1536,
+                 kv_lora_rank: int = 512, qk_nope_head_dim: int = 128,
+                 qk_rope_head_dim: int = 64, v_head_dim: int = 128,
+                 mtp_layers: int = 1, **kw) -> TransformerConfig:
+    """JoyAI-LLM-Flash (jdopensource, `model_type: joyai_llm_flash`;
+    DeepSeek-V3's block, arXiv:2412.19437, at smaller sizes): RMSNorm
+    pre-norm (eps 1e-6), no biases, an untied head; latent attention
+    (models/mla.py: queries through a latent of 1,536, keys and values
+    through one of 512, 32 heads of 128 + 64 for q and k and 128 for v,
+    RoPE theta 32e6 by interleaved pairs on the 64 alone); one leading
+    dense SwiGLU layer of width ``d_ff``, then 256 SwiGLU experts of
+    width ``moe_d_ff``: sigmoid scores, top-8 over score + bias, gates
+    renormalised and x 2.5, one shared expert, the bias moved by 0.001
+    a step, no auxiliary loss; one multi-token-prediction module whose
+    loss is added x 0.3. ``experts_held`` / ``experts_offset`` give a
+    chip its share of the experts (0 = all). The sizes default to the
+    published ones; the bias's rate and the module's weight are the
+    paper's, the config.json holds neither."""
+    return TransformerConfig(
+        vocab_size=vocab_size, d_model=d_model, n_heads=n_heads,
+        n_layers=n_layers, d_ff=d_ff, max_len=max_len, norm="rmsnorm",
+        norm_eps=1e-6, pos="rope", rope_theta=32000000.0,
+        q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+        qk_nope_head_dim=qk_nope_head_dim,
+        qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+        mlp_gated=True, moe=True, moe_gated=True,
+        n_dense_layers=n_dense_layers, moe_d_ff=moe_d_ff,
+        n_experts=n_experts, moe_top_k=moe_top_k, moe_score="sigmoid",
+        moe_route_scale=2.5, moe_shared=1, moe_bias_rate=0.001,
+        moe_aux_weight=0.0, moe_z_weight=0.0, experts_held=experts_held,
+        experts_offset=experts_offset, mtp_layers=mtp_layers,
+        mtp_weight=0.3, **kw)
+
+
 def choose_remat(cfg: TransformerConfig, batch_size: int,
                  seq_len: int | None = None,
                  hbm_bytes: int | None = None,
@@ -1330,10 +1441,13 @@ def kept_bytes(cfg: TransformerConfig, batch_size: int,
     # under block_length a row is two copies, each with its own call
     rows = batch_size * seq * (2 if cfg.block_length else 1)
     flash = 0       # layers whose attention is a flash call, as `Attention`
+    layers = cfg.n_layers + cfg.mtp_layers  # the module holds a block
     if cfg.block_length or (not cfg.use_ring and cfg.use_flash(seq)):
-        flash = sum(cfg.kind(i) != "mamba" for i in range(cfg.n_layers))
-    half = cfg.n_layers * rows * cfg.d_model * itemsize
-    return {KEPT_O: flash * rows * cfg.n_heads * cfg.head_dim * itemsize,
+        flash = sum(cfg.kind(i) != "mamba" for i in range(cfg.n_layers)) \
+            + cfg.mtp_layers
+    half = layers * rows * cfg.d_model * itemsize
+    return {KEPT_O: flash * rows * cfg.n_heads * cfg.value_head_dim
+            * itemsize,
             KEPT_LSE: flash * rows * cfg.n_heads * 4,
             KEPT_MIXER_OUT: half,
             KEPT_MLP_OUT: half if cfg.sandwich_norm else 0}
@@ -1397,3 +1511,32 @@ def _combine_rows_bwd(res, dy):
 
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def remat_block(cfg: TransformerConfig):
+    """`Block`, rematerialised under `KEPT` where the config says so."""
+    if not cfg.remat:
+        return Block
+    return nn.remat(
+        Block, static_argnums=(2,),
+        policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+
+
+def _mtp_hidden(cfg: TransformerConfig, embed, h, tokens, train: bool):
+    """What the multi-token-prediction module (models/mla.py) makes of
+    the main model's output ``h``: place i is fed token i + 1's
+    embedding, the tokens rolled left by one. A row's last place gets
+    its first token, which no earlier place sees (causal) and whose own
+    target has weight 0 in the losses."""
+    from edl_tpu.models.mla import MTPModule
+    with jax.named_scope("mtp"):
+        e = _scaled(embed(jnp.roll(tokens, -1, axis=1)), cfg.embed_scale)
+    return MTPModule(cfg, name="mtp")(h, e, train)
+
+
+def _with_mtp(cfg: TransformerConfig, out: tuple, mtp_ce) -> tuple:
+    """(loss, metrics) with the module's cross-entropy added at the
+    config's weight, and on the step line beside it."""
+    loss, metrics = out
+    return (loss + cfg.mtp_weight * mtp_ce.astype(loss.dtype),
+            {**metrics, "mtp_loss": mtp_ce})

@@ -38,8 +38,9 @@ What one score block pair costs inside the kernels:
   running max (the same in every lane) and the sum of exponentials
   (lane-partial sums, reduced across lanes once a program).
 
-Layout contract: (B, S, H, D) in, (B, S, H, D) out (the transformer's
-native layout; the kernel grid works on (B*H, S, D) views). On non-TPU
+Layout contract: q, k (B, S, H, D) and v (B, S, H, Dv) in, (B, S, H, Dv)
+out (the transformer's native layout; the kernel grid works on
+(B*H, S, D) views); dV and dO are at the value size too. On non-TPU
 backends both directions dispatch to compiled XLA blockwise paths
 (`_fwd_blockwise` / `_bwd_blockwise`) — interpret-mode Pallas is orders
 of magnitude slower and would throttle the CPU elastic/multipod worlds.
@@ -296,10 +297,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 window: int | None = None, blocks: tuple | None = None):
     """One (batch*head, q-block) program: stream K/V blocks online.
 
-    q_ref: (1, BLK_Q, D); k_ref/v_ref: (1, S, D); o_ref: (1, BLK_Q, D);
-    lse_ref: (1, BLK_Q, 1) log-sum-exp for the backward (trailing 1 dim:
-    TPU block shapes need the last dims tileable-or-full). Scratch, all
-    float32 and whole vector registers wide: acc_ref (BLK_Q, D) the
+    q_ref: (1, BLK_Q, D); k_ref: (1, S, D); v_ref: (1, S, Dv); o_ref:
+    (1, BLK_Q, Dv); lse_ref: (1, BLK_Q, 1) log-sum-exp for the backward
+    (trailing 1 dim: TPU block shapes need the last dims tileable-or-
+    full). Scratch, all float32: acc_ref (BLK_Q, Dv) the
     output's accumulator; m_ref (BLK_Q, W) the running max, the same in
     every lane (a (BLK_Q, 1) column here cost the kernel a third more
     time on the chip: PERF.md §6, PR 32); l_ref (BLK_Q, W) the sum of
@@ -309,7 +310,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     where the blocks differ and the pairs the diagonal crosses are
     masked whole.
     """
-    _, blk_q, d = q_ref.shape
+    blk_q, d = q_ref.shape[1], acc_ref.shape[1]
     width = m_ref.shape[1]
 
     def pair(rows, k_at, ahead, cut=_CAUSAL):
@@ -349,10 +350,11 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
          interpret: bool, window: int | None = None,
          blocks: tuple | None = None):
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     # (B, S, H, D) -> (B*H, S, D) program-per-head views
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
 
     grid = (b * h, s // blk_q)
     sub = _diag_sub(blk_q, blk_k)
@@ -364,22 +366,22 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, s, dv), lambda bh, qi: (bh, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, blk_q, dv), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, blk_q, 1), lambda bh, qi: (bh, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, s, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)]
+        scratch_shapes=[pltpu.VMEM((blk_q, dv), jnp.float32)]
         + [pltpu.VMEM((blk_q, _stat_width(blk_k, sub)), jnp.float32)] * 2,
         interpret=interpret,
         name="flash_fwd",
     )(qt, kt, vt)
-    o = o.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    o = o.reshape(b, h, s, dv).transpose(0, 2, 1, 3)
     return o, lse[..., 0]
 
 
@@ -398,15 +400,15 @@ def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool,
                    window: int | None = None, blocks: tuple | None = None):
     """Flash forward in plain XLA (KV-block scan with the online
     softmax) — the off-TPU fallback. Returns (o, lse) exactly as `_fwd`
-    does: o (B,S,H,D) in q.dtype, lse (B*H, S) fp32."""
-    b, s, h, d = q.shape
+    does: o (B,S,H,Dv) in q.dtype, lse (B*H, S) fp32."""
+    b, s, h, _ = q.shape
     q32 = q.astype(jnp.float32)
     k32 = k.astype(jnp.float32)
     v32 = v.astype(jnp.float32)
     q_pos = jnp.arange(s)
 
     def kv_step(carry, ki):
-        m, l, acc = carry  # (B,H,S), (B,H,S), (B,S,H,D)
+        m, l, acc = carry  # (B,H,S), (B,H,S), (B,S,H,Dv)
         ksl = lax.dynamic_slice_in_dim(k32, ki * blk, blk, axis=1)
         vsl = lax.dynamic_slice_in_dim(v32, ki * blk, blk, axis=1)
         sblk = jnp.einsum("bqhd,bkhd->bhqk", q32, ksl,
@@ -426,7 +428,7 @@ def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool,
 
     init = (jnp.full((b, h, s), _NEG_INF, jnp.float32),
             jnp.zeros((b, h, s), jnp.float32),
-            jnp.zeros((b, s, h, d), jnp.float32))
+            jnp.zeros((b, s, h, v.shape[-1]), jnp.float32))
     (m, l, acc), _ = lax.scan(kv_step, init, jnp.arange(s // blk))
     l = jnp.maximum(l, 1e-30)  # same guard as the kernel
     o = (acc / l.transpose(0, 2, 1)[..., None]).astype(q.dtype)
@@ -440,11 +442,12 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
                      blocks: tuple | None = None):
     """One (batch*head, kv-block) program: K/V block resident, stream Q
     blocks (causal: only blocks that can see this KV block), accumulate
-    dK/dV in fp32 VMEM scratch (dk_acc/dv_acc: (BLK_K, D)). Works on the
-    transposed scores K·Q^T, so no piece is transposed for p^T·dO and
-    ds^T·Q.
+    dK/dV in fp32 VMEM scratch (dk_acc: (BLK_K, D), dv_acc: (BLK_K, Dv)).
+    Works on the transposed scores K·Q^T, so no piece is transposed for
+    p^T·dO and ds^T·Q.
 
-    q_ref/do_ref: (1, S, D); k_ref/v_ref/dk_ref/dv_ref: (1, BLK_K, D);
+    q_ref: (1, S, D); do_ref: (1, S, Dv); k_ref/dk_ref: (1, BLK_K, D);
+    v_ref/dv_ref: (1, BLK_K, Dv);
     lse_ref/rt_ref: (1, S/BLK_Q, 1, BLK_Q) fp32, a q block's values
     along the lanes (picked by an index of an untiled dimension: a
     dynamic row of a tile does not compile at every width) — lse from
@@ -552,8 +555,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref, dq_ref,
                    blocks: tuple | None = None):
     """One (batch*head, q-block) program: Q block resident, stream KV
     blocks (causal skip and diagonal as in the forward), accumulate dQ
-    in fp32 VMEM scratch (dq_acc: (BLK_Q, D)). lse_ref/rt_ref:
-    (1, BLK_Q, 1) columns."""
+    in fp32 VMEM scratch (dq_acc: (BLK_Q, D)); v_ref: (1, S, Dv),
+    do_ref: (1, BLK_Q, Dv). lse_ref/rt_ref: (1, BLK_Q, 1) columns."""
     to = v_ref.dtype
 
     def pair(rows, k_at, ahead, cut=_CAUSAL):
@@ -584,14 +587,15 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
     in BOTH directions (the XLA scan masks instead of skipping, doing
     2x the needed work)."""
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    dot = do.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
+    dot = do.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
     # row term = delta - dlse, delta_i = rowsum(dO_i * O_i): cheap
     # elementwise XLA; folding it here keeps the kernels single-purpose
     rt = jnp.sum(dot.astype(jnp.float32)
-                 * o.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+                 * o.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
                  .astype(jnp.float32), axis=-1)
     if dlse is not None:
         rt = rt - dlse.astype(jnp.float32)
@@ -600,15 +604,15 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
     def along_lanes(x):  # a q block's values in one row of lanes
         return x.reshape(b * h, s // blk_q, 1, blk_q)
 
-    dk, dv = pl.pallas_call(
+    dk, dv_ = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, sub=sub, scale=scale,
                           causal=causal, window=window, blocks=blocks),
         grid=(b * h, s // blk_k),
         in_specs=[
             pl.BlockSpec((1, s, d), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, blk_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, s, d), lambda bh, ki: (bh, 0, 0)),
+            pl.BlockSpec((1, blk_k, dv), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, s, dv), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, s // blk_q, 1, blk_q),
                          lambda bh, ki: (bh, 0, 0, 0)),
             pl.BlockSpec((1, s // blk_q, 1, blk_q),
@@ -616,13 +620,14 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
         ],
         out_specs=[
             pl.BlockSpec((1, blk_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, blk_k, dv), lambda bh, ki: (bh, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, s, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, s, dv), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
+                        pltpu.VMEM((blk_k, dv), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkdv",
     )(qt, kt, vt, dot, along_lanes(lse), along_lanes(rt))
@@ -634,8 +639,8 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, s, dv), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, blk_q, dv), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, blk_q, 1), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, blk_q, 1), lambda bh, qi: (bh, qi, 0)),
         ],
@@ -647,15 +652,16 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
     )(qt, kt, vt, dot, lse[..., None], rt[..., None])
 
     def back(x):
-        return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+        return x.reshape(b, h, s, x.shape[-1]).transpose(0, 2, 1, 3)
 
-    return back(dq), back(dk), back(dv)
+    return back(dq), back(dk), back(dv_)
 
 
 def _bwd_blockwise(q, k, v, o, lse, do, *, blk: int, scale: float,
                    causal: bool, dlse=None, window: int | None = None,
                    blocks: tuple | None = None):
-    """Flash backward in plain XLA, scanning KV blocks. All (B,S,H,D).
+    """Flash backward in plain XLA, scanning KV blocks. q, k (B,S,H,D);
+    v, o, do (B,S,H,Dv).
 
     With `dlse` (a (B*H, S) cotangent on the log-sum-exp output), the
     score gradient gains the softmax term: d(lse)/d(s_ij) = p_ij, so
@@ -707,7 +713,7 @@ def _bwd_blockwise(q, k, v, o, lse, do, *, blk: int, scale: float,
     dq, (dk_blocks, dv_blocks) = lax.scan(
         kv_step, jnp.zeros_like(q32), jnp.arange(n_blocks))
     dk = dk_blocks.transpose(1, 0, 2, 3, 4).reshape(b, s, h, d)
-    dv = dv_blocks.transpose(1, 0, 2, 3, 4).reshape(b, s, h, d)
+    dv = dv_blocks.transpose(1, 0, 2, 3, 4).reshape(b, s, h, v.shape[-1])
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -916,9 +922,11 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     Fully differentiable through both outputs.
     """
     b, s, h, d = q.shape
-    if k.shape != q.shape or v.shape != q.shape:
-        raise ValueError(f"q/k/v shape mismatch: {q.shape} {k.shape} "
-                         f"{v.shape}")
+    if k.shape != q.shape or v.ndim != 4 or v.shape[:3] != q.shape[:3]:
+        raise ValueError(
+            f"q/k/v shape mismatch: q {q.shape}, k {k.shape}, v {v.shape}: "
+            "q and k are one shape (B, S, H, D), and v is (B, S, H, Dv) "
+            "with a head size of its own or the same")
     if window is not None and (not causal or window < 1):
         raise ValueError(f"window={window} counts the keys up to and "
                          "including a query's own: it needs causal=True "
@@ -947,7 +955,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: int = 512, block_k: int = 512,
                     window: int | None = None,
                     blocks: tuple[int, bool] | None = None) -> jax.Array:
-    """Fused causal attention. q/k/v: (B, S, H, D) -> (B, S, H, D).
+    """Fused causal attention. q, k: (B, S, H, D); v: (B, S, H, Dv), a
+    head size of its own (latent attention: keys of 192, values of 128)
+    or the same -> (B, S, H, Dv). The scale, where not given, is from
+    the key size: 1 / sqrt(D). D need not be whole 128-lane registers:
+    the products contract it as it is (PERF.md §5).
 
     `window` (static; None = every earlier key): query i sees keys
     i - window + 1 .. i, and the kernels visit only the block pairs

@@ -147,6 +147,32 @@ def test_flash_kernels_compile_by_block_index(one_chip, no_persistent_cache,
         assert custom_calls(compiled) == 2
 
 
+# latent attention at the joyai cell's shape: keys and queries of 192
+# (no multiple of the 128 lanes, contracted as they are), values of 128
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_flash_kernels_compile_at_a_value_head_size_of_its_own(
+        one_chip, no_persistent_cache, kernel):
+    b, s, h, d, dv = 2, 8192, 32, 192, 128
+    kw = dict(blk_q=512, blk_k=512, scale=d ** -0.5, causal=True,
+              interpret=False)
+    qk, vo = sds((b, s, h, d), jnp.bfloat16), sds((b, s, h, dv), jnp.bfloat16)
+    lse = sds((b * h, s), F32)
+    if kernel == "fwd":
+        compiled = compile_for(one_chip, functools.partial(fa._fwd, **kw),
+                               qk, qk, vo)
+        assert custom_calls(compiled) == 1
+        o, _ = compiled.out_info
+        assert o.shape == (b, s, h, dv)
+    else:
+        compiled = compile_for(
+            one_chip,
+            lambda q, k, v, o, lse, do, dlse: fa._bwd_pallas(
+                q, k, v, o, lse, do, dlse=dlse, **kw),
+            qk, qk, vo, vo, lse, vo, lse)
+        assert custom_calls(compiled) == 2
+        assert [x.shape[-1] for x in compiled.out_info] == [d, d, dv]
+
+
 # q of the block-diffusion cell and k of the afmoe share's: the widest
 # and the narrowest call of `ops/rope.py` a cell makes
 ROPE_SHAPES = [(1, 16384, 32, 128), (2, 8192, 4, 128)]

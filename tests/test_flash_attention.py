@@ -507,3 +507,168 @@ class TestWindow:
             flash_attention(q, k, v, causal=False, window=64)
         with pytest.raises(ValueError, match="window"):
             flash_attention(q, k, v, window=0)
+
+
+def _masked_dense(q, k, v, *, window=None, blocks=None):
+    """The oracle at any mask the kernels know: a dense masked softmax
+    in float32, the scale from the key size."""
+    s = q.shape[1]
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    if blocks is not None:
+        size, strict = blocks
+        seen = j // size < i // size if strict else j // size <= i // size
+    else:
+        seen = j <= i if window is None else (j <= i) & (i - j < window)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / q.shape[-1] ** 0.5
+    probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+class TestValueHeadSize:
+    """v with a head size of its own (latent attention: keys of 192,
+    values of 128): q, k (B, S, H, D), v, o, dO, dV (B, S, H, Dv), the
+    scale from D. Both dispatch modes against the dense oracle."""
+
+    # (D, Dv): keys wider than values at the cell's ratio and at its
+    # sizes, values wider than keys, and one size on neither's lanes
+    SIZES = [(24, 16), (192, 128), (16, 32), (40, 24)]
+    MASKS = [{}, {"window": 100}, {"blocks": (4, False)},
+             {"blocks": (4, True)}]
+
+    @staticmethod
+    def _qkv(d, dv, s=256, h=2, dtype=jnp.float32):
+        q, k, _ = _qkv(b=1, s=s, h=h, d=d, dtype=dtype)
+        return q, k, _qkv(b=1, s=s, h=h, d=dv, dtype=dtype, seed=1)[2]
+
+    @pytest.mark.parametrize("mask", MASKS, ids=lambda m: "-".join(
+        f"{k}{v}" for k, v in m.items()) or "causal")
+    @pytest.mark.parametrize("d, dv", SIZES)
+    def test_forward_and_backward_match_dense(self, d, dv, mask, attn_path):
+        q, k, v = self._qkv(d, dv)
+        kw = dict(block_q=128, block_k=128, **mask)
+        # a strict query of the first block sees no key: what it gets is
+        # the kernels' own convention (tests/test_sdar.py), left out here
+        first = 4 if mask.get("blocks", (0, False))[1] else 0
+        out = flash_attention(q, k, v, **kw)
+        assert out.shape == v.shape
+        np.testing.assert_allclose(
+            out[:, first:], _masked_dense(q, k, v, **mask)[:, first:],
+            atol=2e-5)
+
+        def loss(fn, **kw):
+            return lambda q, k, v: jnp.sum(jnp.sin(
+                fn(q, k, v, **kw)[:, first:]))
+        got = jax.grad(loss(flash_attention, **kw), argnums=(0, 1, 2))(
+            q, k, v)
+        want = jax.grad(loss(_masked_dense, **mask), argnums=(0, 1, 2))(
+            q, k, v)
+        for g, w, x in zip(got, want, (q, k, v)):
+            assert g.shape == x.shape
+            np.testing.assert_allclose(g, w, atol=1e-4)
+
+    @pytest.mark.parametrize("blk_q, blk_k", [(256, 256), (128, 256),
+                                              (512, 512)])
+    def test_sub_blocks_and_unequal_blocks(self, blk_q, blk_k, attn_path):
+        q, k, v = self._qkv(24, 16, s=512)
+        out = flash_attention(q, k, v, block_q=blk_q, block_k=blk_k)
+        np.testing.assert_allclose(out, dense_attention(q, k, v), atol=2e-5)
+
+    def test_dense_attention_takes_it_too(self):
+        q, k, v = self._qkv(24, 16)
+        np.testing.assert_allclose(dense_attention(q, k, v),
+                                   _masked_dense(q, k, v), atol=2e-5)
+        np.testing.assert_allclose(
+            dense_attention(q, k, v, window=100),
+            _masked_dense(q, k, v, window=100), atol=2e-5)
+
+    def test_the_scale_is_from_the_key_size(self, attn_path):
+        q, k, v = self._qkv(24, 16)
+        np.testing.assert_allclose(
+            flash_attention(q, k, v),
+            flash_attention(q, k, v, scale=24 ** -0.5), atol=1e-6)
+        assert float(jnp.max(jnp.abs(
+            flash_attention(q, k, v)
+            - flash_attention(q, k, v, scale=16 ** -0.5)))) > 1e-3
+
+    def test_lse_and_its_cotangent(self, attn_path):
+        from edl_tpu.ops.flash_attention import flash_attention_lse
+        q, k, v = self._qkv(24, 16)
+
+        def dense(q, k, v):
+            s = jnp.einsum("bqhd,bkhd->bqhk", q, k) / 24 ** 0.5
+            seen = jnp.arange(256)[:, None] >= jnp.arange(256)[None, :]
+            return jax.nn.logsumexp(
+                jnp.where(seen[None, :, None, :], s, -1e30), axis=-1)
+
+        def through(q, k, v):
+            o, lse = flash_attention_lse(q, k, v)
+            return jnp.sum(jnp.sin(o)) + jnp.sum(jnp.cos(lse))
+
+        def oracle(q, k, v):
+            return jnp.sum(jnp.sin(_masked_dense(q, k, v))) + jnp.sum(
+                jnp.cos(dense(q, k, v)))
+        np.testing.assert_allclose(flash_attention_lse(q, k, v)[1],
+                                   dense(q, k, v), atol=2e-5)
+        for g, w in zip(jax.grad(through, argnums=(0, 1, 2))(q, k, v),
+                        jax.grad(oracle, argnums=(0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(g, w, atol=1e-4)
+
+    def test_bfloat16_at_the_cells_sizes(self, attn_path):
+        q, k, v = self._qkv(192, 128, s=512, dtype=jnp.bfloat16)
+        out = flash_attention(q, k, v)
+        assert out.dtype == jnp.bfloat16 and out.shape == v.shape
+        want = _masked_dense(*(x.astype(jnp.float32) for x in (q, k, v)))
+        np.testing.assert_allclose(out.astype(jnp.float32), want, atol=3e-2)
+
+    @pytest.mark.parametrize("bad", ["k", "v_rows", "v_rank"])
+    def test_shapes_that_are_not_legal_are_named(self, bad):
+        q, k, v = self._qkv(24, 16)
+        if bad == "k":
+            k = k[..., :16]
+        elif bad == "v_rows":
+            v = v[:, :128]
+        else:
+            v = v[..., 0]
+        with pytest.raises(ValueError, match=r"v is \(B, S, H, Dv\)"):
+            flash_attention(q, k, v)
+
+    # sha256[:16] of str(jax.make_jaxpr(...)) of the forward and the
+    # backward at Dv == D, taken on the tree before v had a size of its
+    # own (commit 92c3a63): the Mosaic kernels (interpret=True changes
+    # no equation of the body) and the XLA blockwise pair. A JAX that
+    # prints jaxprs differently needs them taken again from that commit.
+    PINS = {
+        "causal": ((1, 512, 2, 64, jnp.float32), {},
+                   "523a0d8d7bd4ef85", "53e517beecb4a4f2"),
+        "window": ((1, 512, 2, 128, jnp.bfloat16), {"window": 200},
+                   "3608f08a42b99d7b", "5432af9ba5184d92"),
+        "blocks": ((1, 512, 2, 128, jnp.bfloat16), {"blocks": (4, True)},
+                   "624d2a0c916a8928", "7227247b27a8cadd"),
+    }
+
+    @pytest.mark.parametrize("path", ["pallas_kernels", "xla_blockwise"])
+    @pytest.mark.parametrize("case", sorted(PINS))
+    def test_equal_head_sizes_lower_to_the_jaxpr_they_did(self, case, path):
+        import hashlib
+
+        from edl_tpu.ops.flash_attention import (_bwd_blockwise,
+                                                 _bwd_pallas, _fwd,
+                                                 _fwd_blockwise)
+        (b, s, h, d, dtype), mask, kernels, blockwise = self.PINS[case]
+        q, k, v = _qkv(b=b, s=s, h=h, d=d, dtype=dtype)
+        if path == "pallas_kernels":
+            kw = dict(blk_q=256, blk_k=256, scale=0.1, causal=True,
+                      interpret=True, **mask)
+
+            def both(q, k, v):
+                o, lse = _fwd(q, k, v, **kw)
+                return _bwd_pallas(q, k, v, o, lse, q, dlse=None, **kw)
+        else:
+            kw = dict(blk=256, scale=0.1, causal=True, **mask)
+
+            def both(q, k, v):
+                o, lse = _fwd_blockwise(q, k, v, **kw)
+                return _bwd_blockwise(q, k, v, o, lse, q, **kw)
+        text = str(jax.make_jaxpr(both)(q, k, v))
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
+            kernels if path == "pallas_kernels" else blockwise)
