@@ -498,8 +498,12 @@ def main(argv=None) -> int:
     else:
         step = make_train_step(loss, donate=True)
         if args.moe:
-            log.info("moe path: E=%d top_k=%d dropless, in the jit step",
-                     cfg.n_experts, cfg.moe_top_k)
+            log.info("moe path: E=%d top_k=%d dropless, in the jit step; "
+                     "experts: %s", cfg.n_experts, cfg.moe_top_k,
+                     "gate|up one grouped product in the backward"
+                     if cfg.experts_one_cotangent else
+                     "gate, up two grouped products" if cfg.moe_gated
+                     else "gelu, two tables")
     log.info("world=%d rank=%d devices=%d params=%s steps/epoch=%d",
              world, rank, jax.device_count(),
              sum(p.size for p in jax.tree.leaves(state.params)),

@@ -238,6 +238,16 @@ class TransformerConfig:
     def held_experts(self) -> int:
         return self.experts_held or self.n_experts
 
+    @property
+    def experts_one_cotangent(self) -> bool:
+        """SwiGLU experts hand their rows one cotangent, from one grouped
+        product against [w_gate | w_up] (`_swiglu_rows`): wherever no
+        mesh axis splits the tables' "mlp" axis, along which the two are
+        put side by side (a concatenation along a split axis would move
+        weights between chips)."""
+        return self.moe_gated and (self.mesh is None or not tuple(
+            shd.logical_to_spec(("mlp",), self.rules, self.mesh)))
+
     def moe_layer(self, layer: int) -> bool:
         return self.moe and layer >= self.n_dense_layers
 
@@ -609,6 +619,63 @@ def _expert_ffn(x, tables, matmul, dtype):
     return matmul(h, w_out)
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _swiglu_rows(x, w_gate, w_up, counts, replayed: bool):
+    """silu(x @ w_gate) * (x @ w_up) over rows grouped by expert
+    (``counts``), as `_expert_ffn` computes it, with a backward of its
+    own (`_swiglu_rows_bwd`): x is the left operand of two grouped
+    products, so autodiff makes two d(lhs) results of x's size and adds
+    them (`add_any`: two reads and a write of the T*k-row buffer a
+    layer). The backward makes d(h) for h = [gate | up] whole and takes
+    both transposes as one product each against [w_gate | w_up]: one
+    cotangent of x, summed over the 2f columns in float32 in the
+    kernel's accumulator. The forward stays two products: one against
+    the joined table measured slower (PERF.md §6, PR 53)."""
+    return _swiglu_rows_fwd(x, w_gate, w_up, counts, replayed)[0]
+
+
+def _swiglu_rows_fwd(x, w_gate, w_up, counts, replayed):
+    gate, up = (jax.lax.ragged_dot(x, w, counts) for w in (w_gate, w_up))
+    return nn.silu(gate) * up, (x, w_gate, w_up, counts, gate, up)
+
+
+def _swiglu_rows_bwd(replayed, res, da):
+    """d(h) = [da up silu'(gate) | da silu(gate)] as one pass that reads
+    gate, up and da and writes (T*k, 2f), every factor widened to 2f
+    columns first and the halves told apart by a select, in float32 and
+    rounded once. Written as `concatenate([d_gate, d_up])` XLA makes two
+    results of f columns and a second pass that joins them, because it
+    fuses nothing into a pad's operand unless that is a whole array; the
+    three widened here are."""
+    x, w_gate, w_up, counts, gate, up = res
+    f = gate.shape[-1]
+
+    def wide(a):
+        return jnp.concatenate([a, a], -1).astype(jnp.float32)
+    gate, up, da = wide(gate), wide(up), wide(da)
+    s = jax.nn.sigmoid(gate)
+    left = jax.lax.broadcasted_iota(jnp.int32, gate.shape, gate.ndim - 1) < f
+    d_h = (da * s * jnp.where(left, up * (1 + gate * (1 - s)), gate)
+           ).astype(x.dtype)
+    # the product at which `jax.vjp` linearises is read by no one: XLA
+    # drops it (seven grouped matmuls a layer in the compiled step)
+    d_x, d_w = jax.vjp(lambda x, w: jax.lax.ragged_dot(x, w, counts), x,
+                       jnp.concatenate([w_gate, w_up], -1))[1](d_h)
+    d_ws = d_w[..., :f], d_w[..., f:]
+    if replayed:
+        # cut out here, not inside each table's AdamW pass: those passes
+        # then run where two products' results let them run, and XLA's
+        # memory-space assignment keeps the next replayed block's rows
+        # in fast memory for its gather (JoyAI: four gathers a step from
+        # HBM otherwise, 3.6 ms each). Nothing is replayed without
+        # remat, and the slices fuse into AdamW: OLMoE's are 268 MB each
+        d_ws = jax.lax.optimization_barrier(d_ws)
+    return d_x, *d_ws, None
+
+
+_swiglu_rows.defvjp(_swiglu_rows_fwd, _swiglu_rows_bwd)
+
+
 class MoEMLP(nn.Module):
     """Mixture-of-experts MLP: a softmax top-k router over n_experts
     FFNs (gelu, or SwiGLU under cfg.moe_gated) whose (E, ...) tables
@@ -623,7 +690,9 @@ class MoEMLP(nn.Module):
       Mosaic kernel of XLA's on a TPU), and the results un-permuted
       and weighted, once: the combine's backward stays in expert order
       (`_combine_rows`), so remat replays no gather of the buffer. No
-      capacity, no (T, E, C) array, nothing dropped. A chip's share
+      capacity, no (T, E, C) array, nothing dropped. SwiGLU experts
+      take `_swiglu_rows`, whose backward hands the rows one cotangent
+      (`cfg.experts_one_cotangent`). A chip's share
       (`cfg.held_experts` < n_experts) sorts the absent experts'
       assignments last, into no group; the kernel leaves their rows
       unwritten, both ways, and a select where they return to token
@@ -757,9 +826,15 @@ class MoEMLP(nn.Module):
             # `moe_combine`)
             rows = _dispatch_rows(xf, order, inv, here, k)  # (T*k, d)
         with jax.named_scope("moe_experts"):
-            out = _expert_ffn(
-                rows, tables,
-                lambda a, w: jax.lax.ragged_dot(a, w, counts), cfg.dtype)
+            if cfg.experts_one_cotangent:
+                w_gate, w_up, w_down = (t.astype(cfg.dtype) for t in tables)
+                out = jax.lax.ragged_dot(
+                    _swiglu_rows(rows, w_gate, w_up, counts, cfg.remat),
+                    w_down, counts)
+            else:
+                out = _expert_ffn(
+                    rows, tables,
+                    lambda a, w: jax.lax.ragged_dot(a, w, counts), cfg.dtype)
         with jax.named_scope("moe_combine"):
             # to token order once, forward. The backward stays in expert
             # order: d(out) is dy gathered as the dispatch gathers x,

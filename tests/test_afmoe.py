@@ -527,6 +527,223 @@ def test_what_the_kernel_leaves_past_the_groups_reaches_nothing(
         assert np.isnan(np.asarray(rows[8:])).all()
 
 
+# -- the rows' one cotangent: gate | up joined in the backward ---------------
+
+def two_products(monkeypatch):
+    """The backward the parent had: autodiff's, through `w_gate` and
+    `w_up` as a grouped product each, what `experts_one_cotangent` still
+    says where a mesh axis splits the tables' "mlp" axis."""
+    monkeypatch.setattr(tfm.TransformerConfig, "experts_one_cotangent",
+                        property(lambda self: False))
+
+
+def layer_and_gradients(layer, v, x, dy):
+    def loss(params, x):
+        y = layer.apply({**v, "params": params}, x)
+        return jnp.sum(y.astype(jnp.float32) * dy), y
+    (_, y), (d_params, d_x) = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(v["params"], x)
+    return {"y": y, "x": d_x, **d_params}
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5),
+                                        (jnp.bfloat16, 2 ** -6)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("held, first, remat", [(4, 8, True), (E, 0, False)],
+                         ids=["a_share_replayed", "every_expert"])
+def test_the_joined_backward_is_the_two_products(monkeypatch, held, first,
+                                                 remat, dtype, tol):
+    """The layer's output and every gradient leaf (the three tables, the
+    router, the shared expert, the rows) with the backward against
+    `[w_gate | w_up]` as one grouped product, against autodiff's through
+    the two products on the same tables and rows, the kernel's tail NaN
+    in both: the output to the bit (the forward is the same two
+    products), the gradients the same to the order of the sums in
+    float32 (the rows' cotangent is one sum over 2f columns where it was
+    two over f, added), and within bfloat16's rounding, 2^-6 of a leaf's
+    largest entry (0.6 % read), with bfloat16 rows and tables."""
+    _, v, x = whole_layer()
+    layer = tfm.MoEMLP(small(experts_held=held, experts_offset=first,
+                             dtype=dtype, remat=remat))
+    v, x = share_of(v, first, held), x.astype(dtype)
+    dy = jax.random.normal(jax.random.PRNGKey(21), x.shape)
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        as_the_chip_leaves_the_tail(jax.lax.ragged_dot))
+    assert layer.cfg.experts_one_cotangent
+    one = layer_and_gradients(layer, v, x, dy)
+    two_products(monkeypatch)
+    two = layer_and_gradients(layer, v, x, dy)
+    assert set(one) == {"y", "x", "router", "w_gate", "w_up", "w_down",
+                        "shared_gate", "shared_up", "shared_down"}
+    np.testing.assert_array_equal(np.asarray(one["y"], np.float32),
+                                  np.asarray(two["y"], np.float32))
+    for name, want in two.items():
+        for a, b in zip(jax.tree.leaves(one[name]), jax.tree.leaves(want)):
+            a, b = (np.asarray(z, np.float32) for z in (a, b))
+            assert np.isfinite(a).all() and a.shape == b.shape, name
+            assert np.abs(b).max() > 0, name
+            np.testing.assert_allclose(a, b, rtol=0,
+                                       atol=tol * np.abs(b).max(),
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("replayed", [False, True])
+def test_swiglu_rows_is_the_expert_ffns_first_half(replayed):
+    """`_swiglu_rows` against the expression `_expert_ffn` computes,
+    `silu(x @ w_gate) * (x @ w_up)` over ragged groups (one of them
+    empty, rows left over past the last): the value to the bit, and the
+    three cotangents autodiff's; ``replayed`` cuts the tables' halves
+    out behind a barrier and changes no number."""
+    e, d, f = 4, 16, 12
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    x = jax.random.normal(keys[0], (30, d))
+    w_gate, w_up = (jax.random.normal(k, (e, d, f)) / 4 for k in keys[1:3])
+    sizes = jnp.asarray([3, 9, 0, 12], jnp.int32)
+    dy = jax.random.normal(keys[3], (30, f))
+
+    def plain(x, w_gate, w_up):
+        return tfm._expert_ffn(
+            x, [w_gate, w_up, jnp.broadcast_to(jnp.eye(f), (e, f, f))],
+            lambda a, w: jax.lax.ragged_dot(a, w, sizes), jnp.float32)
+
+    def mine(x, w_gate, w_up):
+        return tfm._swiglu_rows(x, w_gate, w_up, sizes, replayed)
+    got, back = jax.vjp(mine, x, w_gate, w_up)
+    want, auto = jax.vjp(plain, x, w_gate, w_up)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    for a, b in zip(back(dy), auto(dy)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+def test_the_experts_parameters_are_the_parents():
+    """What checkpoints, the resume, `trainer_draw` and the reference
+    checkers read by name: `w_gate` and `w_up` stay two float32 leaves
+    of (held, d, f) with their logical axes, whatever the step's
+    backward makes of them (the list is the parent's, commit daa975e)."""
+    x = jnp.zeros((1, SEQ, D))
+    boxed = tfm.MoEMLP(small()).init(jax.random.PRNGKey(0), x)["params"]
+    flat = traverse_util.flatten_dict(boxed, sep="/", is_leaf=lambda _, v:
+                                      isinstance(v, meta.Partitioned))
+    assert {k: (v.value.shape, str(v.value.dtype), v.names)
+            for k, v in flat.items()} == {
+        "router": ((D, E), "float32", ("embed", "expert_router")),
+        "w_gate": ((HELD, D, EFF), "float32", ("expert", "embed", "mlp")),
+        "w_up": ((HELD, D, EFF), "float32", ("expert", "embed", "mlp")),
+        "w_down": ((HELD, EFF, D), "float32", ("expert", "mlp", "embed")),
+        "shared_up/kernel": ((D, EFF), "float32", ("embed", "mlp")),
+        "shared_gate/kernel": ((D, EFF), "float32", ("embed", "mlp")),
+        "shared_down/kernel": ((EFF, D), "float32", ("mlp", "embed")),
+    }
+    v = meta.unbox(boxed)
+    assert not np.array_equal(np.asarray(v["w_up"]), np.asarray(v["w_gate"]))
+
+
+def grouped_products_and_sums(cfg, rows):
+    """The gradient of one expert layer as a jaxpr: shapes of the grouped
+    products' results, and of what `add_any` adds over ``rows`` rows."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, SEQ, D))
+    layer = tfm.MoEMLP(cfg)
+    v = meta.unbox(layer.init(jax.random.PRNGKey(4), x))
+
+    def loss(params, x):
+        return jnp.sum(layer.apply({**v, "params": params}, x))
+    products, sums = [], []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            shape = tuple(eqn.outvars[0].aval.shape)
+            if eqn.primitive.name.startswith("ragged_dot"):
+                products.append(shape)
+            elif eqn.primitive.name == "add_any" and shape[0] == rows:
+                sums.append(shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(v["params"], x).jaxpr)
+    return sorted(products), sorted(sums)
+
+
+def test_the_rows_get_one_cotangent_and_no_sum(monkeypatch):
+    """The backward of a dropless layer, as a jaxpr: seven live grouped
+    products where nine stood (forward gate, up and down as they were;
+    d(lhs) of gate|up and of down, two where three stood; d(rhs) of
+    each, two where three stood), and no `add_any` over T*k rows:
+    neither the two cotangents of the dispatched rows, (T*k, d), nor two
+    halves of d(h) padded to (T*k, 2f). The other form counts as the
+    parent did."""
+    f, rows = 24, 2 * SEQ * K                  # 2f = 48: no other size
+    cfg = small(moe_d_ff=f)
+    products, sums = grouped_products_and_sums(cfg, rows)
+    assert products == sorted([
+        (rows, f), (rows, f), (rows, D),       # forward
+        (rows, D), (rows, f),                  # d(lhs)
+        (HELD, D, 2 * f), (HELD, f, D),        # d(rhs)
+        # where `jax.vjp` linearises the joined product: read by no one,
+        # and not in the compiled layer (`tests/test_chip_compile.py`)
+        (rows, 2 * f)])
+    assert sums == []
+    two_products(monkeypatch)
+    products, sums = grouped_products_and_sums(cfg, rows)
+    assert len(products) == 9
+    assert products.count((rows, D)) == 3 and (rows, D) in sums
+
+
+def test_one_cotangent_wherever_no_mesh_axis_splits_the_tables():
+    """`experts_one_cotangent` by what the config can see: the logical
+    rules on its mesh. No mesh, or one that leaves "mlp" whole: the
+    joined backward; a mesh axis that splits "mlp" (tp, by the default
+    rules): autodiff's two products, for the concatenation would run
+    along the split axis."""
+    from jax.sharding import Mesh
+    devices = np.asarray(jax.devices()[:4])
+    assert small().experts_one_cotangent
+    assert small(mesh=Mesh(devices.reshape(2, 2),
+                           ("dp", "fsdp"))).experts_one_cotangent
+    assert small(mesh=Mesh(devices.reshape(4, 1),
+                           ("fsdp", "tp"))).experts_one_cotangent
+    assert not small(mesh=Mesh(devices.reshape(2, 2),
+                               ("fsdp", "tp"))).experts_one_cotangent
+    assert small(mesh=Mesh(devices.reshape(2, 2), ("fsdp", "tp")),
+                 rules=(("mlp", None),)).experts_one_cotangent
+    # gelu's two tables have nothing to put side by side
+    assert not dataclasses.replace(small(), moe_gated=False
+                                   ).experts_one_cotangent
+
+
+def test_a_checkpoint_of_the_two_products_restores_in_the_one(
+        monkeypatch, tmp_path, variables, tokens):
+    """A checkpoint written by the program with autodiff's backward (the
+    parent's step) restores leaf for leaf into the program with the
+    joined one, and both step on from it to the same parameters, within
+    float32's order of sums: nothing of the form is in the state."""
+    from edl_tpu.train.checkpoint import CheckpointManager
+    batch = {"tokens": tokens}
+    with monkeypatch.context() as parent:
+        two_products(parent)
+        step = make_train_step(tfm.lm_loss_fused, donate=False)
+        state, _ = step(state_of(variables, tx=optax.adamw(1e-2)), batch)
+        CheckpointManager(str(tmp_path)).save(
+            state, TrainStatus(epoch=0, step=1))
+        on, _ = step(state, batch)
+    assert small().experts_one_cotangent
+    fresh = state_of(jax.tree.map(jnp.zeros_like, variables),
+                     tx=optax.adamw(1e-2))
+    restored, status = CheckpointManager(str(tmp_path)).restore(fresh)
+    assert status.step == 1
+    mine = jax.tree_util.tree_flatten_with_path(state)[0]
+    back = jax.tree_util.tree_flatten_with_path(restored)[0]
+    assert [p for p, _ in mine] == [p for p, _ in back]
+    for (path, a), (_, b) in zip(mine, back):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
+    resumed, _ = make_train_step(tfm.lm_loss_fused, donate=False)(
+        restored, batch)
+    for (path, a), (_, b) in zip(
+            jax.tree_util.tree_flatten_with_path(on.params)[0],
+            jax.tree_util.tree_flatten_with_path(resumed.params)[0]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=TOL,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
 def test_remat_replays_no_gather_of_a_shares_buffer(variables, tokens):
     """`tests/test_olmoe.py`'s count with a share of the experts and the
     sandwich norms, whose `block_mlp_out` is kept: five gathers with a

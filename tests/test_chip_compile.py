@@ -17,6 +17,7 @@ this one file.
 
 import functools
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -371,9 +372,11 @@ def test_olmoe_d1_train_step_compiles(one_chip, no_persistent_cache,
     """The step `lm_train --arch olmoe --bf16 --fused-loss` builds for
     benchmark/configs/olmoe-1b-7b-d1.json (published widths, one layer,
     4 x 4096 tokens), for one v5e chip: the three flash kernels, the
-    nine grouped expert matmuls as XLA's own Mosaic kernel (three
-    tables x forward, d-lhs, d-rhs; a dense fallback would be 64 times
-    the FLOPs), and it fits 16 GB beside 7.5 GB of state."""
+    seven grouped expert matmuls as XLA's own Mosaic kernel (three
+    tables forward; d-lhs and d-rhs of gate|up as one product each and
+    of down: nine while the backward took gate and up apart; a dense
+    fallback would be 64 times the FLOPs), and it fits 16 GB beside
+    7.5 GB of state."""
     from edl_tpu.models.transformer import olmoe_config
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = olmoe_config(n_layers=1, dtype=jnp.bfloat16)
@@ -398,10 +401,61 @@ def test_olmoe_d1_train_step_compiles(one_chip, no_persistent_cache,
     text = compiled.as_text()
     grouped = sum("%ragged-dot-none" in ln.split(" = ")[0]
                   for ln in text.splitlines())
-    assert grouped == 9, grouped
+    assert grouped == 7, grouped
     assert sum(f"%{name}" in text for name in
                ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")) == 3
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 12e9 < held < 14.5e9, (mem.argument_size_in_bytes,
                                   mem.temp_size_in_bytes)
+
+
+def test_a_shares_expert_layer_moves_the_buffer_no_more_than_it_must(
+        one_chip, no_persistent_cache, monkeypatch):
+    """One expert layer, forward and backward, at the share cells' shape
+    (SDAR's: 16,384 rows, top-8 of 128 experts, 16 held, d 2,048, f 768;
+    T*k = 131,072 rows in the buffer), compiled for one v5e chip. With
+    gate | up joined in the backward: seven grouped matmuls (the product
+    at which the backward's `jax.vjp` linearises is dropped); no
+    `add_any` over the buffer's rows (two cotangents of the dispatched
+    rows, or two halves of d(h) padded and added); and nothing stands
+    alone between the products and the activation: of the buffer's rows,
+    (T*k, 2f) is written once, by the fusion that makes d(h) whole, and
+    (T*k, f) four times: gate, up, the activation and its cotangent (a
+    concatenation, a copy or the halves of d(h) written out would each
+    be more)."""
+    from flax.core import meta
+    from edl_tpu.models.transformer import MoEMLP, sdar_config
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t, k, d, f = 16384, 8, 2048, 768
+    cfg = sdar_config(vocab_size=256, n_layers=1, d_ff=f, n_experts=128,
+                      moe_top_k=k, experts_held=16, dtype=jnp.bfloat16)
+    layer = MoEMLP(cfg)
+    x = sds((2, t // 2, d), jnp.bfloat16)
+    params = jax.eval_shape(lambda: meta.unbox(layer.init(
+        jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype)))["params"])
+
+    def gradients(params, x, dy):
+        def loss(params, x):
+            y = layer.apply({"params": params}, x,
+                            mutable=["intermediates"])[0]
+            return jnp.sum(y.astype(F32) * dy.astype(F32))
+        return jax.grad(loss, argnums=(0, 1))(params, x)
+
+    text = compile_for(one_chip, gradients, params, x, x).as_text()
+    rows = t * k
+    # ENTRY's instructions that write something: name, result(s), operation
+    written = [m.groups() for m in re.finditer(
+        r"^\s*(?:ROOT )?(%\S+) = (\(.*?\)|\S+) ([\w-]+)\(",
+        text[text.index("\nENTRY"):], re.M)
+        if m[3] not in ("get-tuple-element", "bitcast", "parameter")]
+    assert sum(n.startswith("%ragged-dot-none") for n, _, _ in written) == 7
+    assert not [n for n, shapes, _ in written
+                if "add_any" in n and f"[{rows}," in shapes]
+
+    def results(width):
+        return [(n, op) for n, shapes, op in written
+                for _ in range(shapes.count(f"bf16[{rows},{width}]"))]
+    assert [op for _, op in results(2 * f)] == ["fusion"], results(2 * f)
+    assert sorted(op for _, op in results(f)) == [
+        "custom-call", "custom-call", "custom-call", "fusion"], results(f)

@@ -584,6 +584,8 @@ def test_lm_train_arch_olmoe_saves_restores_and_replays(tmp_path):
     text, first = run([*common, "--make-synthetic", "2",
                        "--rows-per-file", "16"])
     assert "dropless, in the jit step" in text and "count=1" in text
+    # the start line says which form of the experts the run measured
+    assert "experts: gate|up one grouped product in the backward" in text
     assert sorted(first) == list(range(1, 9))
     assert all(d == 0.0 for _, d in first.values())
     # throw the later checkpoints away: resume from step 4
