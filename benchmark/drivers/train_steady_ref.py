@@ -10,14 +10,12 @@ kept as evidence for the per-layer readers."""
 
 from __future__ import annotations
 
-import json
 import re
-import subprocess
 import time
 
 from benchmark.harness import cell as cl
 from benchmark.harness import logs
-from benchmark.harness.procs import PY, BenchFailure, alive, say
+from benchmark.harness.procs import BenchFailure, alive, say
 
 _COUNTER = re.compile(r"(\w+)=(-?[\d.]+(?:e-?\d+)?)(?=\s)")
 
@@ -39,25 +37,11 @@ def reference_check(cell: cl.Cell, step: int, logged_loss: float) -> dict:
     trainer's logged loss at ``step`` against the plain float32
     reference on the same parameters and batch, and the program's
     forward pass against it token by token, both computed by a child
-    that gets the chip after the trainer has ended."""
+    that gets the chip after the trainer has ended and keeps what it
+    compiles in `cell.REF_CACHE`: it compiles once a checkout."""
     limits = cell.config["reference"]
-    env = cell.child_env()
-    if cell.rehearse:
-        env["JAX_PLATFORMS"] = "cpu"
-    for attempt in range(8):
-        out = subprocess.run(
-            [PY, "-m", limits["checker"], cell.config_path, cell.data_dir,
-             str(step)], cwd=cell.root, env=env, capture_output=True,
-            text=True, timeout=600)
-        # a killed trainer's chips can stay busy for a while after it
-        if "Device or resource busy" not in out.stderr:
-            break
-        say(f"the chip is still busy (attempt {attempt + 1}); waiting")
-        time.sleep(10)
-    if out.returncode != 0:
-        raise BenchFailure("the reference child failed:\n"
-                           + out.stderr[-2000:])
-    ref = json.loads(out.stdout.strip().splitlines()[-1])
+    ref = cl.reference_child(cell, limits["checker"], step,
+                             cell.reference_env(), 600)
     diff = abs(ref["loss"] - logged_loss)
     say(f"reference on {ref['platform']}: {ref}; trainer logged "
         f"{logged_loss:.4f} at step {step}: |diff| {diff:.5f} (tolerance "

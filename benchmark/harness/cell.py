@@ -10,8 +10,15 @@ import subprocess
 import time
 
 from benchmark.harness.job import Job, lm_args
-from benchmark.harness.procs import PY, BenchFailure, say
+from benchmark.harness.procs import PY, BenchFailure, kill_group, say, spawn
 from benchmark.harness.shards import make_shards
+
+# The reference children's compile cache, beside the trainer's and never
+# the same directory: a child's programs (the reference's, and the
+# trainer's step once more under a key of its own) pushed the trainer's
+# step out of a capped directory (PERF.md section 6), and with no cache
+# at all a child compiled them anew in every run, 240 s of JoyAI's 346.
+REF_CACHE = ".jax_cache_ref"
 
 
 @dataclasses.dataclass
@@ -53,6 +60,13 @@ class Cell:
             env["EDL_TPU_PROFILE_STEPS"] = str(
                 self.traffic["profile"]["steps"])
         return env
+
+    def reference_env(self) -> dict:
+        """`child_env` for a reference child that compiles minutes of
+        programs: they go to a directory of the child's own in the
+        checkout, whatever the caller gave the trainer."""
+        return {**self.child_env(), "JAX_COMPILATION_CACHE_DIR":
+                os.path.join(self.root, REF_CACHE)}
 
     @property
     def trace_dir(self) -> str:
@@ -133,28 +147,57 @@ def bad_steps(lines: list[tuple[float, int, float]], log_every: int) -> int:
     return log_every * sum(not math.isfinite(v) for _, _, v in lines)
 
 
+def reference_child(cell: Cell, checker: str, step: int, env: dict,
+                    timeout: float) -> dict:
+    """The last line of what the module ``checker`` prints, started once
+    the trainer has ended (a chip belongs to one process) through
+    `procs.spawn`: `run.py` ends it with everything else it started, and
+    the kernel ends it when `run.py` is killed. Its standard error is
+    kept in the run's work directory, and its phase lines are repeated
+    here: the last says what it compiled and what it read from its
+    cache."""
+    if cell.rehearse:
+        env["JAX_PLATFORMS"] = "cpu"
+    out_path = os.path.join(cell.work, "reference.out")
+    err_path = os.path.join(cell.work, "reference.err")
+    for attempt in range(8):
+        for path in (out_path, err_path):
+            open(path, "wb").close()
+        child = spawn([PY, "-m", checker, cell.config_path, cell.data_dir,
+                       str(step)], err_path, env, cell.root,
+                      stdout_path=out_path)
+        try:
+            child.wait(timeout)
+        except subprocess.TimeoutExpired:
+            kill_group(child.pid)
+            child.wait()
+            with open(err_path, errors="replace") as f:
+                raise BenchFailure(f"the reference child had not ended "
+                                   f"after {timeout:.0f}s and was killed:"
+                                   "\n" + f.read()[-2000:])
+        with open(err_path, errors="replace") as f:
+            stderr = f.read()
+        # a killed trainer's chips can stay busy for a while after it
+        if "Device or resource busy" not in stderr:
+            break
+        say(f"the chip is still busy (attempt {attempt + 1}); waiting")
+        time.sleep(10)
+    if child.returncode != 0:
+        raise BenchFailure("the reference child failed:\n" + stderr[-2000:])
+    for phase in stderr.splitlines():
+        if phase.startswith("[check"):  # the last says what it compiled
+            say("the reference child: " + phase)
+    with open(out_path) as f:
+        return json.loads(f.read().strip().splitlines()[-1])
+
+
 def reference_check(cell: Cell, step: int, logged_loss: float) -> dict:
     """`correct` for a trained LM: the trainer's logged loss at ``step``
     against the plain float32 reference on the same parameters and batch,
     and the program's forward pass against it token by token, both
     computed by a child that gets the chip after the trainer has ended."""
-    env = cell.child_env()
-    if cell.rehearse:
-        env["JAX_PLATFORMS"] = "cpu"
-    for attempt in range(8):
-        out = subprocess.run(
-            [PY, "-m", "benchmark.reference.check_lm", cell.config_path,
-             cell.data_dir, str(step)], cwd=cell.root, env=env,
-            capture_output=True, text=True, timeout=300)
-        # a killed trainer's chips can stay busy for a while after it
-        if "Device or resource busy" not in out.stderr:
-            break
-        say(f"the chip is still busy (attempt {attempt + 1}); waiting")
-        time.sleep(10)
-    if out.returncode != 0:
-        raise BenchFailure("the reference child failed:\n"
-                           + out.stderr[-2000:])
-    ref = json.loads(out.stdout.strip().splitlines()[-1])
+    ref = reference_child(cell, "benchmark.reference.check_lm", step,
+                          cell.child_env(), 300)
     limits = cell.config["reference"]
     diff = abs(ref["loss"] - logged_loss)
     say(f"reference on {ref['platform']}: plain float32 loss "

@@ -8,6 +8,7 @@ benchmark times the program's progress on its own clock.
 
 from __future__ import annotations
 
+import ctypes
 import glob
 import os
 import signal
@@ -51,13 +52,37 @@ def connects(port: int) -> bool:
         return False
 
 
-def spawn(cmd: list[str], log_path: str, env: dict, cwd: str
-          ) -> subprocess.Popen:
-    """Start a child in its own session, all output to ``log_path``."""
-    with open(log_path, "ab") as out:
+_libc = ctypes.CDLL(None, use_errno=True)
+_PR_SET_PDEATHSIG = 1
+
+
+def _end_with(parent: int):
+    """A `preexec_fn`: the child asks the kernel for SIGKILL when the
+    thread that started it ends. `stop_all` ends what this process
+    started, but a process that is itself killed (the check's time
+    limit, a memory guard) ends without a word to its children, and a
+    child that holds the chip would keep it from the next run. The
+    request outlives the exec; the kernel ties it to the starting
+    thread, so `spawn` is called from the main thread only."""
+    def ask() -> None:
+        _libc.prctl(_PR_SET_PDEATHSIG, int(signal.SIGKILL), 0, 0, 0)
+        if os.getppid() != parent:  # it ended before the call
+            os._exit(1)
+    return ask
+
+
+def spawn(cmd: list[str], log_path: str, env: dict, cwd: str,
+          stdout_path: str | None = None) -> subprocess.Popen:
+    """Start a child in its own session, all output to ``log_path``
+    (its standard output to ``stdout_path`` where that is given). The
+    child ends with this process, whatever ends this process."""
+    with open(log_path, "ab") as err, \
+            open(stdout_path or log_path, "ab") as out:
         proc = subprocess.Popen(cmd, cwd=cwd, env=env, stdout=out,
-                                stderr=subprocess.STDOUT,
-                                start_new_session=True)
+                                stderr=err if stdout_path
+                                else subprocess.STDOUT,
+                                start_new_session=True,
+                                preexec_fn=_end_with(os.getpid()))
     _procs.append(proc)
     return proc
 

@@ -176,24 +176,27 @@ def main(argv: list[str]) -> int:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    # The harness gives JAX_COMPILATION_CACHE_DIR. This child reads it
-    # and writes nothing there: its programs are the trainer's step once
-    # more under a key of its own (a Pallas kernel's debug locations
-    # hold the call stack) and the reference's, and where the directory
-    # is capped they push the trainer's own entry out, so that every
-    # run of the cell starts cold (PERF.md section 6).
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
+    # The harness gives JAX_COMPILATION_CACHE_DIR, a directory of the
+    # reference children's own and never the trainer's: what this child
+    # compiles it keeps there, and the next run of the checkout reads it
+    # (child_cache.py).
+    from benchmark.reference.child_cache import (keep_programs, phase_log,
+                                                 sentence)
+    programs = keep_programs()
 
     from benchmark.reference import granite_hybrid_plain as plain
     from benchmark.reference.trainer_draw import seeded_params, step_batch
     from edl_tpu.models.transformer import Transformer
+    phase = phase_log()  # seconds after the imports
 
     batch, per_epoch = step_batch(config, data_dir, step)
     program = Transformer(program_config(config))
     tree = seeded_params(program, config)
     hp = reference_hp(config)
+    phase("parameters drawn")
     theirs = plain.batch_losses(plain.from_program(tree), batch, hp)
     loss = float(np.mean(np.concatenate(theirs)))
+    phase("the plain forward")
 
     @jax.jit
     def program_forward(tree, toks):
@@ -206,12 +209,15 @@ def main(argv: list[str]) -> int:
         for row in batch[:TOKEN_ROWS]])
     rms = float(np.sqrt(np.mean(np.square(
         mine - np.stack(theirs[:TOKEN_ROWS])))))
+    phase("the program's forward")
 
     timed_losses, grads, update, before = timed_program(
         config, program, tree, batch, per_epoch)
+    phase("the trainer's step, twice")
     wanted = plain.batch_grads(
         plain.from_program(jax.device_put(before)), batch, hp)
     errors = leaf_errors(plain.from_program(grads), wanted)
+    phase("the plain gradient")
     for name, e, r, along in errors:
         print(f"gradient {name}: |diff| {e:.4g} / |plain| {r:.4g} = "
               f"{e / r if r else float('nan'):.4g}, along the plain one "
@@ -230,6 +236,7 @@ def main(argv: list[str]) -> int:
         ("timed_loss_diff", timed_diff, "loss_tolerance"))
         if not value <= limits[key]]
     dev = jax.devices()[0]
+    phase("done: " + sentence(programs()))
     print(json.dumps({
         "loss": float("nan") if refused else loss, "reference_loss": loss,
         "step": step, "rows": int(len(batch)), "token_loss_rms_diff": rms,
@@ -240,7 +247,8 @@ def main(argv: list[str]) -> int:
         "grad_along_plain": sum(a * r * r for _, _, r, a in errors)
         / sum(r * r for _, _, r, _ in errors),
         "update_rel_err": update, "refused": refused,
-        "platform": dev.platform, "kind": dev.device_kind}))
+        "programs": programs(), "platform": dev.platform,
+        "kind": dev.device_kind}))
     return 0
 
 

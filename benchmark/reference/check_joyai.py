@@ -191,25 +191,6 @@ def timed_program(config: dict, program, tree, stats, batch,
     return first, grads, biases, update, before
 
 
-def end_with_parent() -> None:
-    """This child holds the chip and tens of GB of the host for minutes.
-    The driver that started it waits for it, but a driver that is itself
-    killed (a time limit, a memory guard) ends without a word to its
-    children, and the next run would find the chip taken: the kernel
-    sends this process SIGKILL when the thread that started it ends
-    (the benchmark's main thread; a tool that calls `main` itself does
-    not ask for this)."""
-    import ctypes
-    import os
-    import signal
-    parent = os.getppid()
-    pr_set_pdeathsig = 1
-    ctypes.CDLL(None, use_errno=True).prctl(pr_set_pdeathsig,
-                                            int(signal.SIGKILL), 0, 0, 0)
-    if os.getppid() != parent:  # it ended before the call
-        os._exit(1)
-
-
 def main(argv: list[str]) -> int:
     config_path, data_dir, step = argv
     step = int(step)
@@ -219,21 +200,19 @@ def main(argv: list[str]) -> int:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    # The harness gives JAX_COMPILATION_CACHE_DIR. This child reads it
-    # and writes nothing there (check_trinity_mini.py says why).
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
-
-    import time
+    # The harness gives JAX_COMPILATION_CACHE_DIR, a directory of the
+    # reference children's own and never the trainer's: what this child
+    # compiles it keeps there, and the next run of the checkout reads it
+    # (child_cache.py).
+    from benchmark.reference.child_cache import (keep_programs, phase_log,
+                                                 sentence)
+    programs = keep_programs()
 
     from benchmark.reference import joyai_plain as plain
     from benchmark.reference.trainer_draw import step_batch
     from edl_tpu.models.transformer import Transformer
+    phase = phase_log()  # seconds after the imports
 
-    t0 = time.monotonic()
-
-    def phase(what):
-        print(f"[check +{time.monotonic() - t0:6.1f}s] {what}",
-              file=sys.stderr, flush=True)
     batch, per_epoch = step_batch(config, data_dir, step)
     cfg = program_config(config)
     program = Transformer(cfg)
@@ -344,6 +323,7 @@ def main(argv: list[str]) -> int:
         ("timed_mtp_loss_diff", timed_mtp_diff, "loss_tolerance"))
         if not value <= limits[key]]
     dev = jax.devices()[0]
+    phase("done: " + sentence(programs()))
     print(json.dumps({
         "loss": float("nan") if refused else loss, "reference_loss": loss,
         "main_loss": main_loss, "mtp_loss": mtp_loss,
@@ -365,11 +345,11 @@ def main(argv: list[str]) -> int:
         "grad_along_plain": sum(a * r * r for _, _, r, a in errors)
         / sum(r * r for _, _, r, _ in errors),
         "update_rel_err": update, "bias_update_err": bias_update,
-        "refused": refused, "platform": dev.platform,
+        "refused": refused, "programs": programs(),
+        "platform": dev.platform,
         "kind": dev.device_kind}))
     return 0
 
 
 if __name__ == "__main__":
-    end_with_parent()
     sys.exit(main(sys.argv[1:]))

@@ -58,36 +58,29 @@ def main(argv: list[str]) -> int:
     with open(config_path) as f:
         config = json.load(f)
     run = config["run"]
-    seed = run["trainer_seed"]
-    # the harness gives JAX_COMPILATION_CACHE_DIR; keep quick compiles too
+    # the harness gives JAX_COMPILATION_CACHE_DIR, a directory of the
+    # reference children's own: keep every program there (child_cache.py)
     import jax
     import jax.numpy as jnp
     import numpy as np
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+    from benchmark.reference.child_cache import (keep_programs, phase_log,
+                                                 sentence)
+    programs = keep_programs()
 
     from benchmark.reference import olmoe_plain
-    from edl_tpu.data.pipeline import DataLoader, FileSource
+    from benchmark.reference.trainer_draw import seeded_params, step_batch
     from edl_tpu.models.transformer import Transformer
+    phase = phase_log()  # seconds after the imports
 
-    files = sorted(os.path.join(data_dir, f) for f in os.listdir(data_dir)
-                   if f.startswith("train-") and f.endswith(".npz"))
-    loader = DataLoader(FileSource(files), run["global_batch"], rank=0,
-                        world=1, seed=seed)
-    per_epoch = loader.steps_per_epoch()
-    epoch, index = divmod(step - 1, per_epoch)
-    batch = next(iter(loader.epoch(epoch, index)))["tokens"]
-    loader.close()
-
-    cfg = program_config(config)
-    program = Transformer(cfg)
-    toks0 = jnp.zeros((1, run["seq_len"]), jnp.int32)
-    from flax.core import meta
-    tree = jax.jit(lambda: meta.unbox(program.init(
-        jax.random.PRNGKey(seed), toks0, train=False)))()["params"]
+    batch, _ = step_batch(config, data_dir, step)
+    program = Transformer(program_config(config))
+    tree = seeded_params(program, config)
     hp = reference_hp(config)
+    phase("parameters drawn")
     stats = olmoe_plain.batch_stats(olmoe_plain.from_program(tree), batch, hp)
     plain = olmoe_plain.pool(stats, hp)
+    phase("the plain forward")
 
     @jax.jit
     def program_forward(tree, toks):
@@ -108,6 +101,7 @@ def main(argv: list[str]) -> int:
     moved = float(np.abs(mine_counts - plain_counts).sum() / 2)
     mean_load = assigned / plain_counts.shape[-1]
     dev = jax.devices()[0]
+    phase("the program's forward; done: " + sentence(programs()))
     print(json.dumps({
         "loss": float(plain["loss"]), "ce": float(plain["ce"]),
         "balance": float(plain["balance"]), "z": float(plain["z"]),
@@ -116,7 +110,8 @@ def main(argv: list[str]) -> int:
                     "moved_between_experts": moved,
                     "max_load_program": float(mine_counts.max() / mean_load),
                     "max_load_plain": float(plain_counts.max() / mean_load)},
-        "platform": dev.platform, "kind": dev.device_kind}))
+        "programs": programs(), "platform": dev.platform,
+        "kind": dev.device_kind}))
     return 0
 
 

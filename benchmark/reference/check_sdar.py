@@ -196,10 +196,12 @@ def main(argv: list[str]) -> int:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    # The harness gives JAX_COMPILATION_CACHE_DIR. This child reads it
-    # and writes nothing there (PERF.md section 6: its programs would
-    # push the trainer's own entry out of a capped directory).
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
+    # The harness gives JAX_COMPILATION_CACHE_DIR, a directory of the
+    # reference children's own and never the trainer's: what this child
+    # compiles it keeps there, and the next run of the checkout reads it
+    # (child_cache.py).
+    from benchmark.reference.child_cache import keep_programs, sentence
+    programs = keep_programs()
 
     import time
 
@@ -291,6 +293,7 @@ def main(argv: list[str]) -> int:
         ("timed_loss_diff", timed_diff, "loss_tolerance"))
         if not value <= limits[key]]
     dev = jax.devices()[0]
+    phase("done: " + sentence(programs()))
     print(json.dumps({
         "loss": float("nan") if refused else loss, "reference_loss": loss,
         "step": step, "rows": int(len(batch["tokens"])),
@@ -308,7 +311,8 @@ def main(argv: list[str]) -> int:
         "grad_along_plain": sum(a * r * r for _, _, r, a in errors)
         / sum(r * r for _, _, r, _ in errors),
         "update_rel_err": update, "refused": refused,
-        "platform": dev.platform, "kind": dev.device_kind}))
+        "programs": programs(), "platform": dev.platform,
+        "kind": dev.device_kind}))
     return 0
 
 

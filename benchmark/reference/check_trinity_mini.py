@@ -76,6 +76,7 @@ import sys
 from benchmark.reference.check_granite_hybrid import (B1, B2, EPS,
                                                       WEIGHT_DECAY,
                                                       leaf_errors, pooled)
+from benchmark.reference.trainer_draw import seeded_variables
 
 TOKEN_ROWS = 3  # sequences compared token by token: all of the batch
 BIAS_STD = 0.05
@@ -124,18 +125,6 @@ def reference_hp(config: dict, cfg) -> dict:
             "route_scale": config.get("route_scale", cfg.moe_route_scale),
             "first_expert": cfg.experts_offset,
             "layer_types": [k.split("_")[0] for k in config["layer_types"]]}
-
-
-def seeded_variables(program, config: dict) -> dict:
-    """What `lm_train` starts from, unboxed: the parameters and the
-    balancing biases (zeros)."""
-    import jax
-    import jax.numpy as jnp
-    from flax.core import meta
-    run = config["run"]
-    toks0 = jnp.zeros((1, run["seq_len"]), jnp.int32)
-    return jax.jit(lambda: meta.unbox(program.init(
-        jax.random.PRNGKey(run["trainer_seed"]), toks0, train=False)))()
 
 
 def drawn_bias(stats: dict, seed: int) -> dict:
@@ -211,25 +200,19 @@ def main(argv: list[str]) -> int:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    # The harness gives JAX_COMPILATION_CACHE_DIR. This child reads it
-    # and writes nothing there: its programs are the trainer's step once
-    # more under a key of its own (a Pallas kernel's debug locations
-    # hold the call stack) and the reference's, and where the directory
-    # is capped they push the trainer's own entry out, so that every
-    # run of the cell starts cold (PERF.md section 6).
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
-
-    import time
+    # The harness gives JAX_COMPILATION_CACHE_DIR, a directory of the
+    # reference children's own and never the trainer's: what this child
+    # compiles it keeps there, and the next run of the checkout reads it
+    # (child_cache.py).
+    from benchmark.reference.child_cache import (keep_programs, phase_log,
+                                                 sentence)
+    programs = keep_programs()
 
     from benchmark.reference import trinity_mini_plain as plain
     from benchmark.reference.trainer_draw import step_batch
     from edl_tpu.models.transformer import Transformer
+    phase = phase_log()  # seconds after the imports
 
-    t0 = time.monotonic()
-
-    def phase(what):
-        print(f"[check +{time.monotonic() - t0:6.1f}s] {what}",
-              file=sys.stderr, flush=True)
     batch, per_epoch = step_batch(config, data_dir, step)
     cfg = program_config(config)
     program = Transformer(cfg)
@@ -321,6 +304,7 @@ def main(argv: list[str]) -> int:
         ("timed_loss_diff", timed_diff, "loss_tolerance"))
         if not value <= limits[key]]
     dev = jax.devices()[0]
+    phase("done: " + sentence(programs()))
     print(json.dumps({
         "loss": float("nan") if refused else loss, "reference_loss": loss,
         "step": step, "rows": int(len(batch)), "token_loss_rms_diff": rms,
@@ -336,7 +320,8 @@ def main(argv: list[str]) -> int:
         "grad_along_plain": sum(a * r * r for _, _, r, a in errors)
         / sum(r * r for _, _, r, _ in errors),
         "update_rel_err": update, "bias_update_err": bias_update,
-        "refused": refused, "platform": dev.platform,
+        "refused": refused, "programs": programs(),
+        "platform": dev.platform,
         "kind": dev.device_kind}))
     return 0
 

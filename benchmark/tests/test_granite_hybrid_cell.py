@@ -62,7 +62,7 @@ TINY = os.path.join(ROOT, "benchmark", "tests", "tiny_granite_hybrid.json")
 
 
 @pytest.fixture(scope="module")
-def tiny_cell(tmp_path_factory):
+def tiny_cell(tmp_path_factory, stand_in_cell):
     """What `train_steady_ref.reference_check` reads of a cell, on
     shards of the tiny configuration, with the checker replaced by
     `tools/hybrid_controls.py` (the checker itself unless
@@ -81,9 +81,7 @@ def tiny_cell(tmp_path_factory):
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "1",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
            "PYTHONPATH": ROOT}
-    return NS(root=ROOT, config=config, config_path=str(path),
-              data_dir=str(data), rehearse=True, env=env,
-              child_env=lambda: dict(env))
+    return stand_in_cell(work, config, path, data, env)
 
 
 @pytest.fixture(scope="module")

@@ -32,7 +32,19 @@ NEW = ("mla_flash_roofline", "joyai_mfu", "mla_proj_time_share",
        "mtp_time_share", "mtp_loss_gap")
 
 
-def test_cpu_rehearsal_of_the_cell_is_refused():
+READINGS = ("token_loss_rms_diff", "mtp_token_loss_rms_diff",
+            "routing_diff_share", "grad_rel_err", "update_rel_err",
+            "bias_update_err", "timed_loss_diff", "timed_mtp_loss_diff")
+_rehearsed: dict = {}  # the first rehearsal's readings, for the second
+
+
+@pytest.mark.parametrize("run", ["first", "again"])
+def test_cpu_rehearsal_of_the_cell_is_refused(run):
+    """Twice in this checkout, on the same seed: the second run's
+    reference child compiles nothing (the first, or any earlier run
+    here, left its programs in `cell.REF_CACHE`) and reads what the
+    first read, to the digit."""
+    import re
     out = subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload", CELL, "--seed",
          "2147483655", "--seconds", "12", "--trace", "1", "--rehearse",
@@ -43,15 +55,22 @@ def test_cpu_rehearsal_of_the_cell_is_refused():
     assert "no TPU: refused" in out.stderr
     assert out.stdout.strip() == ""
     assert "correct=True" in out.stderr, out.stderr[-3000:]
-    for reading in ("token_loss_rms_diff", "mtp_token_loss_rms_diff",
-                    "routing_diff_share", "grad_rel_err", "update_rel_err",
-                    "bias_update_err", "timed_loss_diff",
-                    "timed_mtp_loss_diff"):
-        assert reading in out.stderr
+    said = re.search(r"the reference child: \[check \+ *[\d.]+s\] done: "
+                     r"compiled (\d+) programs and read (\d+) from (\S+)",
+                     out.stderr)
+    assert said and said.group(3) == os.path.join(ROOT, ".jax_cache_ref")
+    readings = {name: re.search(rf"'{name}': ([^,}}]+)", out.stderr).group(1)
+                for name in READINGS + ("reference_loss",)}
+    if run == "first":
+        _rehearsed.update(readings)
+        return
+    assert int(said.group(1)) == 0 and int(said.group(2)) > 0
+    if _rehearsed:  # the first ran in this process
+        assert readings == _rehearsed
 
 
 @pytest.fixture(scope="module")
-def tiny_cell(tmp_path_factory):
+def tiny_cell(tmp_path_factory, stand_in_cell):
     """What `train_steady_ref.reference_check` reads of a cell, on
     shards of the tiny configuration, with the checker replaced by
     `tools/joyai_controls.py` (the checker itself unless
@@ -67,9 +86,7 @@ def tiny_cell(tmp_path_factory):
     make_shards(str(data), 1, 8, config["run"]["seq_len"],
                 config["vocab_size"], 2290051100)
     env = {**os.environ, **ONE_DEVICE, "PYTHONPATH": ROOT}
-    return NS(root=ROOT, config=config, config_path=str(path),
-              data_dir=str(data), rehearse=True, env=env,
-              child_env=lambda: dict(env))
+    return stand_in_cell(work, config, path, data, env)
 
 
 @pytest.fixture(scope="module")
@@ -336,34 +353,3 @@ def test_the_cell_is_in_the_lists_the_issue_names():
     assert entry["chips"] == 1 and entry["traffic"] == "steady_ref"
     assert BENCH["workloads"][-1] is entry and BENCH["configs"][-1] is ENTRY
     assert [m["name"] for m in BENCH["per_layer"][-5:]] == list(NEW)
-
-
-def test_the_reference_child_ends_with_the_process_that_started_it():
-    """`run.py` stops what `procs.spawn` started and the reference child
-    is not among it: killed, `run.py` would leave the child behind with
-    the chip. A stand-in for `run.py` starts a child that asks as
-    `check_joyai` does under `__main__`, and is killed."""
-    import time
-    child = ("import os, sys, time; sys.path.insert(0, %r); "
-             "from benchmark.reference.check_joyai import end_with_parent;"
-             " end_with_parent(); print(os.getpid(), flush=True); "
-             "time.sleep(60)" % ROOT)
-    stand_in = subprocess.Popen(
-        [sys.executable, "-c",
-         "import subprocess, sys, time; "
-         f"subprocess.Popen([sys.executable, '-c', {child!r}]); "
-         "time.sleep(60)"], stdout=subprocess.PIPE, text=True)
-    pid = int(stand_in.stdout.readline())
-    stand_in.kill()
-    stand_in.wait()
-    deadline = time.monotonic() + 10
-    while time.monotonic() < deadline:
-        try:  # an orphan that has ended is a zombie until init reaps it
-            with open(f"/proc/{pid}/stat") as f:
-                if f.read().rsplit(")", 1)[1].split()[0] == "Z":
-                    return
-        except FileNotFoundError:
-            return
-        time.sleep(0.05)
-    os.kill(pid, 9)
-    pytest.fail(f"the child {pid} outlived the process that started it")

@@ -60,7 +60,7 @@ def test_cpu_rehearsal_of_the_cell_is_refused():
 
 
 @pytest.fixture(scope="module")
-def tiny_cell(tmp_path_factory):
+def tiny_cell(tmp_path_factory, stand_in_cell):
     """What `train_steady_ref.reference_check` reads of a cell, on
     shards of the tiny configuration, with the checker replaced by
     `tools/afmoe_controls.py` (the checker itself unless
@@ -76,9 +76,7 @@ def tiny_cell(tmp_path_factory):
     make_shards(str(data), 1, 8, config["run"]["seq_len"],
                 config["vocab_size"], 2290033100)
     env = {**os.environ, **ONE_DEVICE, "PYTHONPATH": ROOT}
-    return NS(root=ROOT, config=config, config_path=str(path),
-              data_dir=str(data), rehearse=True, env=env,
-              child_env=lambda: dict(env))
+    return stand_in_cell(work, config, path, data, env)
 
 
 @pytest.fixture(scope="module")

@@ -24,8 +24,21 @@ import subprocess
 import sys
 import tempfile
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))))
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+
+def fault_env(fault: str) -> dict:
+    """The environment of one fault's process: the fault's name, and the
+    reference children's compile cache, as the driver gives it. Sharing
+    it with the honest child is sound: a fault that patches a function
+    changes the program that holds it and so that program's key, and a
+    fault that rounds the reference's matrices changes arguments, which
+    are in no key (`reference/child_cache.py`)."""
+    from benchmark.harness.cell import REF_CACHE
+    return {**os.environ, "EDL_BENCH_CONTROL": fault,
+            "JAX_COMPILATION_CACHE_DIR": os.path.join(ROOT, REF_CACHE)}
 
 
 def _program(**changed):
@@ -110,7 +123,7 @@ def every_fault(config_path: str, seed: int, faults: list[str]) -> int:
             out = subprocess.run(
                 [sys.executable, "-m", "benchmark.tools.hybrid_controls",
                  config_path, tmp, "1"], capture_output=True, text=True,
-                env={**os.environ, "EDL_BENCH_CONTROL": fault})
+                env=fault_env(fault))
             line = out.stdout.strip().splitlines()[-1:] or [
                 json.dumps({"failed": out.stderr[-1500:]})]
             print(json.dumps({"fault": fault, **json.loads(line[0])}),

@@ -31,7 +31,8 @@ import tempfile
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-from benchmark.tools.hybrid_controls import _optimizer  # noqa: E402
+from benchmark.tools.hybrid_controls import (_optimizer,  # noqa: E402
+                                             fault_env)
 
 
 def _fields(**changed):
@@ -53,16 +54,27 @@ def _plain(**seams):
 
 
 def _reference_rounded(name: str):
-    """The reference computed on matrices rounded to a narrower type."""
+    """The reference computed on matrices rounded to a narrower type.
+    The gradient is the last thing the checker does with the parameters
+    it hands over, and at the cell's size the chip does not hold them,
+    their rounded copy and the gradient's program at once (8.00 GB asked
+    for at its load, 7.65 free; PERF.md section 6, PR 55): there each
+    matrix is let go as its rounded copy is made."""
     import jax
     import jax.numpy as jnp
     dtype = getattr(jnp, name)
 
-    def on_rounded(plain, fn):
-        return lambda params, *rest, **kw: fn(jax.tree.map(
-            lambda w: w.astype(dtype).astype(jnp.float32)
-            if w.ndim >= 2 else w, params), *rest, **kw)
-    _plain(batch_losses=on_rounded, batch_grads=on_rounded)
+    def on_rounded(let_go: bool):
+        def rounded(w):
+            if w.ndim < 2:
+                return w
+            narrow = w.astype(dtype).astype(jnp.float32)
+            if let_go:
+                w.delete()
+            return narrow
+        return lambda plain, fn: lambda params, *rest, **kw: fn(
+            jax.tree.map(rounded, params), *rest, **kw)
+    _plain(batch_losses=on_rounded(False), batch_grads=on_rounded(True))
 
 
 def _turned(how):
@@ -157,7 +169,7 @@ def every_fault(config_path: str, seed: int, faults: list[str]) -> int:
             out = subprocess.run(
                 [sys.executable, "-m", "benchmark.tools.joyai_controls",
                  config_path, tmp, "1"], capture_output=True, text=True,
-                env={**os.environ, "EDL_BENCH_CONTROL": fault})
+                env=fault_env(fault))
             for text in out.stderr.splitlines():
                 if text.startswith("[check"):  # the checker's phases
                     print(f"{fault}: {text}", file=sys.stderr, flush=True)

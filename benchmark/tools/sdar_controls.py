@@ -32,7 +32,8 @@ import tempfile
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-from benchmark.tools.hybrid_controls import _optimizer  # noqa: E402
+from benchmark.tools.hybrid_controls import (_optimizer,  # noqa: E402
+                                             fault_env)
 
 
 def _fields(**changed):
@@ -140,7 +141,7 @@ def every_fault(config_path: str, seed: int, faults: list[str]) -> int:
             out = subprocess.run(
                 [sys.executable, "-m", "benchmark.tools.sdar_controls",
                  config_path, tmp, "1"], capture_output=True, text=True,
-                env={**os.environ, "EDL_BENCH_CONTROL": fault})
+                env=fault_env(fault))
             for text in out.stderr.splitlines():
                 if text.startswith("[check"):  # the checker's phases
                     print(f"{fault}: {text}", file=sys.stderr, flush=True)
