@@ -551,7 +551,10 @@ def flash_against(path: str, fa, shape, qkvdo, blk: int, kw: dict,
     under `_checkout/`, which `.gitignore` lists) and both against the
     float32 blockwise scan at matmul precision highest: o, dq, dk, dv
     must differ from each other by no more than either differs from
-    float32. Prints max and rms of every difference and a call's time."""
+    float32. Prints max and rms of every difference and a call's time.
+    With fewer key/value heads than query heads the other checkout,
+    whose kernels know one head a program, gets them repeated and its
+    dk, dv summed over each group, as its callers did."""
     import importlib.util
 
     import jax
@@ -561,13 +564,23 @@ def flash_against(path: str, fa, shape, qkvdo, blk: int, kw: dict,
     other = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(other)
 
+    h, kv = qkvdo[0].shape[2], qkvdo[1].shape[2]
+
     def run(mod):
+        repeat = mod is other and kv != h
+
         def f(q, k, v, do):
+            if repeat:
+                k, v = (jnp.repeat(x, h // kv, axis=2) for x in (k, v))
             o, lse = mod._fwd(q, k, v, blk_q=blk, blk_k=blk,
                               interpret=False, **kw)
-            return (o, *mod._bwd_pallas(q, k, v, o, lse, do, blk_q=blk,
-                                        blk_k=blk, dlse=None,
-                                        interpret=False, **kw))
+            dq, dk, dv = mod._bwd_pallas(q, k, v, o, lse, do, blk_q=blk,
+                                         blk_k=blk, dlse=None,
+                                         interpret=False, **kw)
+            if repeat:
+                dk, dv = (x.reshape(*x.shape[:2], kv, h // kv, -1).sum(3)
+                          for x in (dk, dv))
+            return o, dq, dk, dv
         f = jax.jit(f)
         try:
             out = jax.block_until_ready(f(*qkvdo))
@@ -857,19 +870,28 @@ def child_kernels(other_flash: str = "") -> dict:
     # flash attention fwd/bwd vs the XLA blockwise scan (bf16 tolerance):
     # LM-large, d_model 1024, and the benchmark's shapes (d8, OLMoE)
     # and the afmoe cell's: its global layer, and its sliding layers
-    # with the window's block skipping on both sides of the band
-    for shape, window in (((8, 1024, 16, 128), None),
-                          ((16, 1024, 16, 64), None),
-                          ((6, 2048, 16, 128), None),
-                          ((4, 4096, 16, 128), None),
-                          ((2, 8192, 32, 128), None),
-                          ((2, 8192, 32, 128), 2048)):
+    # with the window's block skipping on both sides of the band; then
+    # the same two and the hybrid's layer on the key/value heads the
+    # cells have (4 and 8), found by index
+    for shape, window, kv in (((8, 1024, 16, 128), None, 16),
+                              ((16, 1024, 16, 64), None, 16),
+                              ((6, 2048, 16, 128), None, 16),
+                              ((4, 4096, 16, 128), None, 16),
+                              ((2, 8192, 32, 128), None, 32),
+                              ((2, 8192, 32, 128), 2048, 32),
+                              ((2, 8192, 32, 128), None, 4),
+                              ((2, 8192, 32, 128), 2048, 4),
+                              ((2, 8192, 32, 64), None, 8)):
         key = jax.random.PRNGKey(0)
-        q, k, v, do = (jax.random.normal(jax.random.fold_in(key, i), shape,
-                                         jnp.bfloat16) for i in range(4))
+        q, k, v, do = (jax.random.normal(
+            jax.random.fold_in(key, i),
+            (*shape[:2], kv if i in (1, 2) else shape[2], shape[3]),
+            jnp.bfloat16) for i in range(4))
         kw = dict(scale=shape[-1] ** -0.5, causal=True)
         blk = fa._fit_block(shape[1], 512)
-        if window:  # `shape` from here on is the report's label
+        if kv != shape[2]:  # `shape` from here on is the report's label
+            shape = f"{shape} kv {kv}"
+        if window:
             kw["window"] = window
             shape = f"{shape} window {window}"
         o_x, lse_x = jax.jit(lambda q, k, v: fa._fwd_blockwise(

@@ -74,15 +74,10 @@ def attention(q, k, v, *, block: int, scale: float | None = None):
         raise ValueError(f"blocks of {block} do not divide a row of {n}")
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    group = q.shape[2] // k.shape[2]
     with jax.named_scope("blockdiff_assemble"):
         q_noised, q_clean = q[:, :n], q[:, n:]
         k_noised, v_noised = k[:, :n], v[:, :n]
         k_clean, v_clean = k[:, n:], v[:, n:]
-    if group > 1:
-        # the kernels' block specs know one head a program (`Attention`)
-        k_clean = jnp.repeat(k_clean, group, axis=2)
-        v_clean = jnp.repeat(v_clean, group, axis=2)
     with jax.named_scope("attn_clean"):
         o_clean = flash_attention(q_clean, k_clean, v_clean, scale=scale,
                                   blocks=(block, False))

@@ -16,8 +16,9 @@ For a token x at position p, H heads:
 interleaved pairs (x_2j, x_2j+1) by p * theta^(-2j/rope): positions on
 a part of the head only. Keys and queries are nope + rope wide (192),
 values v wide (128): `ops/flash_attention.py` takes a value head size of
-its own. `k_pe` is repeated to the H heads outside the kernels, as a
-grouped-query model's key/value heads are.
+its own. `k_pe` is repeated to the H heads outside the kernels: it is a
+part of every head's key, not a head that an index could pick, as a
+grouped-query model's key/value heads are picked.
 """
 
 from __future__ import annotations

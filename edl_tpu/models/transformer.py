@@ -417,16 +417,18 @@ def rope(x: jax.Array, theta: float, positions=None) -> jax.Array:
 
 
 def _causal_attention(cfg: TransformerConfig, kind: str, q, k, v, window):
-    """Causal attention of one sequence a row: positions, the
-    key/value heads repeated, and the path the config picks."""
+    """Causal attention of one sequence a row: positions, and the path
+    the config picks."""
     if cfg.pos == "rope" and kind != "full":
         with jax.named_scope("rope"):
             q, k = _rope(cfg, q), _rope(cfg, k)
-    if cfg.kv_heads != cfg.n_heads:
+    flash = not cfg.use_ring and cfg.use_flash(q.shape[1])
+    if cfg.kv_heads != cfg.n_heads and not flash:
         # grouped-query: every key/value head serves n_heads/kv_heads
-        # query heads. Repeated here, outside the kernels, whose
-        # block specs know one head a program; autodiff sums dK and
-        # dV over each group.
+        # query heads. The flash kernels find a query head's key/value
+        # head by index; the ring and dense paths are XLA's, so the
+        # heads are repeated for them and autodiff sums dK and dV over
+        # each group.
         group = cfg.n_heads // cfg.kv_heads
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     q = cfg.constrain(q, ("batch", "seq", "heads", "kv"))
@@ -436,7 +438,7 @@ def _causal_attention(cfg: TransformerConfig, kind: str, q, k, v, window):
     if cfg.use_ring:
         return ra.ring_attention(q, k, v, mesh=cfg.mesh, causal=True,
                                  scale=cfg.attn_scale)
-    if cfg.use_flash(q.shape[1]):
+    if flash:
         return cfg.flash(q, k, v, window)
     return ra.dense_attention(q, k, v, causal=True,
                               scale=cfg.attn_scale, window=window)
@@ -946,9 +948,10 @@ class Mamba2Mixer(nn.Module):
 # (doc/design_step.md): the mixer's `in_proj` output (279 MB a layer for
 # 3.3 ms), `mlp_gate` / `mlp_up` (268 MB each for 3.0 ms), a gated
 # attention's query / gate (134 MB for about 2 ms), the T x k-row expert
-# buffers (537 MB), q / k / v after the repeat (134 MB each for less than
-# their projections, norms and rope cost): 11-17 ms a GB, against 21 for
-# a half's result and 39-116 for `o`.
+# buffers (537 MB), q (134 MB) and the key/value heads (17 MB each; the
+# flash kernels take them unrepeated) for less than their projections,
+# norms and rope cost: 11-17 ms a GB, against 21 for a half's result and
+# 39-116 for `o`.
 KEPT_MIXER_OUT, KEPT_MLP_OUT = "block_mixer_out", "block_mlp_out"
 KEPT = (KEPT_O, KEPT_LSE, KEPT_MIXER_OUT, KEPT_MLP_OUT)
 

@@ -38,9 +38,18 @@ What one score block pair costs inside the kernels:
   running max (the same in every lane) and the sum of exponentials
   (lane-partial sums, reduced across lanes once a program).
 
-Layout contract: q, k (B, S, H, D) and v (B, S, H, Dv) in, (B, S, H, Dv)
-out (the transformer's native layout; the kernel grid works on
-(B*H, S, D) views); dV and dO are at the value size too. On non-TPU
+Layout contract: q (B, S, H, D), k (B, S, KV, D) and v (B, S, KV, Dv)
+in, (B, S, H, Dv) out (the transformer's native layout; the kernel grid
+works on (B*H, S, D) views); dV and dO are at the value size too. KV
+divides H and query head h reads key/value head h // (H / KV) by its
+block index (`_Heads`), so grouped-query callers hand the key/value
+heads over as they project them, unrepeated, and get dK and dV back at
+KV heads: summed over each group inside the dK/dV kernel where a whole
+sequence's accumulators fit its VMEM (`_bwd_dkdv_group_kernel`), by one
+XLA sum of the kernel's slabs where not. Head-major views, not column
+blocks of (B, S, H*D): XLA's TPU tiles of (B, S, H, D) are over (H, D),
+so that view is no bitcast and costs a copy an operand (PERF.md §6,
+PR 56). On non-TPU
 backends both directions dispatch to compiled XLA blockwise paths
 (`_fwd_blockwise` / `_bwd_blockwise`) — interpret-mode Pallas is orders
 of magnitude slower and would throttle the CPU elastic/multipod worlds.
@@ -57,6 +66,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +80,100 @@ from edl_tpu.utils.logging import get_logger
 log = get_logger("edl_tpu.ops.flash_attention")
 
 _NEG_INF = -1e30
+# what the dK/dV kernel that sums over a group asks for: its blocks are
+# whole sequences and pass the default 16 MiB (`ops/ssm_stages._VMEM`)
+_GROUP_VMEM = 64 << 20
+
+
+class _Heads(NamedTuple):
+    """Where a program (bh, i) of the three kernels finds its heads in
+    the head-major views the kernels take, (B*N, S, D) of a (B, S, N, D)
+    operand: query head bh of the H a batch row has, and its key/value
+    head bh // group of the H / group, so that no key or value is
+    written once a query head."""
+    h: int
+    group: int
+
+    @classmethod
+    def of(cls, q, k) -> "_Heads":
+        return cls(q.shape[2], q.shape[2] // k.shape[2])
+
+    @staticmethod
+    def view(x):
+        b, s, n, d = x.shape
+        return x.transpose(0, 2, 1, 3).reshape(b * n, s, d)
+
+    @staticmethod
+    def back(x, b: int):
+        """A (B*N, S, D) result as (B, S, N, D)."""
+        return x.reshape(b, -1, *x.shape[1:]).transpose(0, 2, 1, 3)
+
+    def spec(self, rows: int, d: int, *, kv: bool = False,
+             whole: bool = False):
+        """Block i of `rows` of the sequence (`whole`: all of it) of the
+        program's query head, or (`kv`) of its key/value head."""
+        group = self.group if kv else 1
+        return pl.BlockSpec((1, rows, d), lambda bh, i: (
+            bh // group if group > 1 else bh, 0 if whole else i, 0))
+
+    def sums_in_kernel(self, s: int, d: int, dv: int, itemsize: int) -> bool:
+        """Whether the dK/dV kernel sums over a group itself
+        (`_bwd_dkdv_group_kernel`): where a head's whole sequence of K,
+        V, Q and dO, double-buffered, dK and dV and their two float32
+        accumulators fit the VMEM a call may ask for (33.5 MB of
+        `_GROUP_VMEM` at 8,192 positions, heads of 128, bfloat16). A
+        longer or wider head leaves the kernel a query head, in slabs
+        (`slab_spec`), for one XLA sum."""
+        lanes = -(-d // 128) * 128 + -(-dv // 128) * 128
+        return self.group > 1 and (
+            s * lanes * (6 * itemsize + 4) <= _GROUP_VMEM * 5 // 8)
+
+    def group_spec(self, s: int, d: int, *, kv: bool = False):
+        """The whole sequence of the query head, or (`kv`) of the
+        key/value head, of program (b * KV + kv head, place in group)."""
+        group = self.group
+        if kv:
+            return pl.BlockSpec((1, s, d), lambda bk, place: (bk, 0, 0))
+        return pl.BlockSpec(
+            (1, s, d), lambda bk, place: (bk * group + place, 0, 0))
+
+    # Where the sum is XLA's, dK and dV come out a query head, each in
+    # the slab of its place in its group, (group, B*KV, S, D), so that
+    # the sum over a group is a sum of slabs and moves nothing; equal
+    # heads have no slabs
+    def slab_spec(self, rows: int, d: int):
+        """`spec` for dK or dV: the program's block at its key/value
+        head, in the slab of its query head's place in the group."""
+        group = self.group
+        if group == 1:
+            return self.spec(rows, d)
+        return pl.BlockSpec((None, 1, rows, d),
+                            lambda bh, i: (bh % group, bh // group, i, 0))
+
+    def slab_shape(self, b: int, s: int, d: int, dtype):
+        heads = (b * self.h // self.group, s, d)
+        return jax.ShapeDtypeStruct(
+            heads if self.group == 1 else (self.group, *heads), dtype)
+
+    def slab_sum(self, x, b: int):
+        """dK or dV laid as `slab_shape` says, summed over each
+        key/value head's query heads in float32: (B, S, KV, D)."""
+        if self.group > 1:
+            x = x.astype(jnp.float32).sum(0).astype(x.dtype)
+        return self.back(x, b)
+
+
+def _group_sum(x, kv: int):
+    """The XLA paths' dK or dV a query head, (B, S, H, D) float32,
+    summed over each key/value head's group: (B, S, KV, D)."""
+    b, s, h, d = x.shape
+    return x if h == kv else x.reshape(b, s, kv, h // kv, d).sum(3)
+
+
+def _repeated(x, h: int):
+    """The XLA paths' key/value heads, one a query head."""
+    group = h // x.shape[2]
+    return x if group == 1 else jnp.repeat(x, group, axis=2)
 
 
 def _dot(a, b):
@@ -351,11 +455,7 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
          blocks: tuple | None = None):
     b, s, h, d = q.shape
     dv = v.shape[-1]
-    # (B, S, H, D) -> (B*H, S, D) program-per-head views
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
-
+    at = _Heads.of(q, k)
     grid = (b * h, s // blk_q)
     sub = _diag_sub(blk_q, blk_k)
     o, lse = pl.pallas_call(
@@ -364,12 +464,12 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
                           blocks=blocks),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, s, dv), lambda bh, qi: (bh, 0, 0)),
+            at.spec(blk_q, d),
+            at.spec(s, d, kv=True, whole=True),
+            at.spec(s, dv, kv=True, whole=True),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk_q, dv), lambda bh, qi: (bh, qi, 0)),
+            at.spec(blk_q, dv),
             pl.BlockSpec((1, blk_q, 1), lambda bh, qi: (bh, qi, 0)),
         ],
         out_shape=[
@@ -380,9 +480,8 @@ def _fwd(q, k, v, *, blk_q: int, blk_k: int, scale: float, causal: bool,
         + [pltpu.VMEM((blk_q, _stat_width(blk_k, sub)), jnp.float32)] * 2,
         interpret=interpret,
         name="flash_fwd",
-    )(qt, kt, vt)
-    o = o.reshape(b, h, s, dv).transpose(0, 2, 1, 3)
-    return o, lse[..., 0]
+    )(at.view(q), at.view(k), at.view(v))
+    return at.back(o, b), lse[..., 0]
 
 
 def _seen(q_pos, kv_pos, window: int | None, blocks: tuple | None = None):
@@ -403,8 +502,8 @@ def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool,
     does: o (B,S,H,Dv) in q.dtype, lse (B*H, S) fp32."""
     b, s, h, _ = q.shape
     q32 = q.astype(jnp.float32)
-    k32 = k.astype(jnp.float32)
-    v32 = v.astype(jnp.float32)
+    k32 = _repeated(k, h).astype(jnp.float32)
+    v32 = _repeated(v, h).astype(jnp.float32)
     q_pos = jnp.arange(s)
 
     def kv_step(carry, ki):
@@ -436,18 +535,17 @@ def _fwd_blockwise(q, k, v, *, blk: int, scale: float, causal: bool,
     return o, lse
 
 
-def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
-                     dk_ref, dv_ref, dk_acc, dv_acc, *, sub: int,
-                     scale: float, causal: bool, window: int | None = None,
-                     blocks: tuple | None = None):
-    """One (batch*head, kv-block) program: K/V block resident, stream Q
-    blocks (causal: only blocks that can see this KV block), accumulate
-    dK/dV in fp32 VMEM scratch (dk_acc: (BLK_K, D), dv_acc: (BLK_K, Dv)).
-    Works on the transposed scores K·Q^T, so no piece is transposed for
-    p^T·dO and ds^T·Q.
+def _dkdv_block(ki, at, q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
+                dk_acc, dv_acc, *, blk_k: int, sub: int, scale: float,
+                causal: bool, window: int | None, blocks: tuple | None):
+    """dK and dV of kv block `ki` for one query head, added into the
+    float32 accumulators: stream the Q blocks (causal: only blocks that
+    can see this KV block). Works on the transposed scores K·Q^T, so no
+    piece is transposed for p^T·dO and ds^T·Q. `at(start, size)` says
+    where rows start .. start + size of the block lie in `k_ref`,
+    `v_ref` and the accumulators.
 
-    q_ref: (1, S, D); do_ref: (1, S, Dv); k_ref/dk_ref: (1, BLK_K, D);
-    v_ref/dv_ref: (1, BLK_K, Dv);
+    q_ref: (1, S, D); do_ref: (1, S, Dv);
     lse_ref/rt_ref: (1, S/BLK_Q, 1, BLK_Q) fp32, a q block's values
     along the lanes (picked by an index of an untiled dimension: a
     dynamic row of a tile does not compile at every width) — lse from
@@ -455,9 +553,7 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
     rowsum(dO*O)), precomputed in XLA so one kernel serves both the
     plain and the lse-cotangent vjp. `sub` as in `_fwd_kernel`.
     """
-    blk_k = k_ref.shape[1]
     _, n_q, _, blk_q = lse_ref.shape
-    ki = pl.program_id(1)
     to = v_ref.dtype
 
     def pair(keys, q_at, qi, lanes, ahead, cut=_CAUSAL):
@@ -474,11 +570,9 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
         dk_acc[keys, :] += _dot(dst.astype(to), q)  # x scale: at the end
 
     def block(qi, ahead, cut=_CAUSAL):
-        pair(slice(None), pl.ds(qi * blk_q, blk_q), qi, slice(None), ahead,
+        pair(at(0, blk_k), pl.ds(qi * blk_q, blk_q), qi, slice(None), ahead,
              cut)
 
-    dk_acc[...] = jnp.zeros_like(dk_acc)
-    dv_acc[...] = jnp.zeros_like(dv_acc)
     first_full = 0
     if window is not None:
         # the forward's walk mirrored over the q blocks: the pair(s) the
@@ -496,7 +590,8 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
                 for i in range(j, blk_k // sub):
                     cut = _sub_cut(i, j, sub, window)
                     if cut is not None:
-                        pair(_rows(j, sub), pl.ds(ki * blk_q + i * sub, sub),
+                        pair(at(j * sub, sub),
+                             pl.ds(ki * blk_q + i * sub, sub),
                              ki, _rows(i, sub),
                              (i - j) * sub if cut else None, cut)
         else:
@@ -508,8 +603,6 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
                       lambda qi, _: block(qi, None), None)
         lax.fori_loop(jnp.maximum(first_full, far), end,
                       lambda qi, _: block(qi, qi * blk_q - k0, _BAND), None)
-        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
         return
     if blocks is not None:
         # the forward's walk mirrored: the q block(s) the staircase
@@ -522,7 +615,7 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
             for j in range(blk_k // sub):
                 for i in range(j, blk_k // sub):
                     q0 = ki * blk_q + i * sub
-                    pair(_rows(j, sub), pl.ds(q0, sub), ki, _rows(i, sub),
+                    pair(at(j * sub, sub), pl.ds(q0, sub), ki, _rows(i, sub),
                          (q0, k0 + j * sub) if i == j else None)
         else:
             first_seen = jnp.minimum(
@@ -538,15 +631,61 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
             # is seen by query sub-blocks i > j whole, i == j masked
             for j in range(blk_k // sub):
                 for i in range(j, blk_k // sub):
-                    pair(_rows(j, sub), pl.ds(ki * blk_q + i * sub, sub),
+                    pair(at(j * sub, sub), pl.ds(ki * blk_q + i * sub, sub),
                          ki, _rows(i, sub), 0 if i == j else None)
         else:
             lax.fori_loop(
                 lax.div(ki * blk_k, blk_q), first_full,
                 lambda qi, _: block(qi, qi * blk_q - ki * blk_k), None)
     lax.fori_loop(first_full, n_q, lambda qi, _: block(qi, None), None)
+
+
+def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
+                     dk_ref, dv_ref, dk_acc, dv_acc, *, sub: int,
+                     scale: float, **mask):
+    """One (batch*head, kv-block) program: K/V block resident,
+    accumulate dK/dV in fp32 VMEM scratch (dk_acc: (BLK_K, D), dv_acc:
+    (BLK_K, Dv)). k_ref/dk_ref: (1, BLK_K, D); v_ref/dv_ref: (1, BLK_K,
+    Dv); the rest as `_dkdv_block` says."""
+    ki = pl.program_id(1)
+    dk_acc[...] = jnp.zeros_like(dk_acc)
+    dv_acc[...] = jnp.zeros_like(dv_acc)
+    _dkdv_block(ki, lambda start, size: slice(start, start + size), q_ref,
+                k_ref, v_ref, do_ref, lse_ref, rt_ref, dk_acc, dv_acc,
+                blk_k=k_ref.shape[1], sub=sub, scale=scale, **mask)
     dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _bwd_dkdv_group_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref,
+                           dk_ref, dv_ref, dk_acc, dv_acc, *, blk_k: int,
+                           sub: int, scale: float, **mask):
+    """`_bwd_dkdv_kernel` where key/value heads serve groups of query
+    heads: one (batch*kv head, place in the group) program over the
+    whole sequence. K, V, dK, dV (1, S, .) stay resident for the
+    group's programs, each of which streams its own query head's Q and
+    dO once and adds every kv block's dK/dV into the accumulators,
+    (S, D) and (S, Dv) fp32: the sum over the group is made here, and
+    written once, by the group's last program."""
+    place = pl.program_id(1)
+
+    @pl.when(place == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def kv_block(ki, _):
+        base = pl.multiple_of(ki * blk_k, blk_k)
+        _dkdv_block(ki, lambda start, size: pl.ds(base + start, size),
+                    q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref, dk_acc,
+                    dv_acc, blk_k=blk_k, sub=sub, scale=scale, **mask)
+
+    lax.fori_loop(0, k_ref.shape[1] // blk_k, kv_block, None)
+
+    @pl.when(place == pl.num_programs(1) - 1)
+    def _():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, rt_ref, dq_ref,
@@ -588,15 +727,12 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
     2x the needed work)."""
     b, s, h, d = q.shape
     dv = v.shape[-1]
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
-    dot = do.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
+    at = _Heads.of(q, k)
+    qt, kt, vt, dot = at.view(q), at.view(k), at.view(v), at.view(do)
     # row term = delta - dlse, delta_i = rowsum(dO_i * O_i): cheap
     # elementwise XLA; folding it here keeps the kernels single-purpose
     rt = jnp.sum(dot.astype(jnp.float32)
-                 * o.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
-                 .astype(jnp.float32), axis=-1)
+                 * at.view(o).astype(jnp.float32), axis=-1)
     if dlse is not None:
         rt = rt - dlse.astype(jnp.float32)
     sub = _diag_sub(blk_q, blk_k)
@@ -604,64 +740,86 @@ def _bwd_pallas(q, k, v, o, lse, do, *, blk_q: int, blk_k: int,
     def along_lanes(x):  # a q block's values in one row of lanes
         return x.reshape(b * h, s // blk_q, 1, blk_q)
 
-    dk, dv_ = pl.pallas_call(
-        functools.partial(_bwd_dkdv_kernel, sub=sub, scale=scale,
-                          causal=causal, window=window, blocks=blocks),
-        grid=(b * h, s // blk_k),
-        in_specs=[
-            pl.BlockSpec((1, s, d), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, blk_k, dv), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, s, dv), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, s // blk_q, 1, blk_q),
-                         lambda bh, ki: (bh, 0, 0, 0)),
-            pl.BlockSpec((1, s // blk_q, 1, blk_q),
-                         lambda bh, ki: (bh, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, blk_k, dv), lambda bh, ki: (bh, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, s, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, s, dv), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
-                        pltpu.VMEM((blk_k, dv), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dkdv",
-    )(qt, kt, vt, dot, along_lanes(lse), along_lanes(rt))
+    mask = dict(sub=sub, scale=scale, causal=causal, window=window,
+                blocks=blocks)
+    if at.sums_in_kernel(s, d, dv, q.dtype.itemsize):
+        kv, group = k.shape[2], at.group
+        rows_of = pl.BlockSpec(
+            (1, s // blk_q, 1, blk_q),
+            lambda bk, place: (bk * group + place, 0, 0, 0))
+        dk, dv_ = pl.pallas_call(
+            functools.partial(_bwd_dkdv_group_kernel, blk_k=blk_k, **mask),
+            grid=(b * kv, group),
+            in_specs=[
+                at.group_spec(s, d),
+                at.group_spec(s, d, kv=True),
+                at.group_spec(s, dv, kv=True),
+                at.group_spec(s, dv),
+                rows_of, rows_of,
+            ],
+            out_specs=[at.group_spec(s, d, kv=True),
+                       at.group_spec(s, dv, kv=True)],
+            out_shape=[jax.ShapeDtypeStruct((b * kv, s, d), k.dtype),
+                       jax.ShapeDtypeStruct((b * kv, s, dv), v.dtype)],
+            scratch_shapes=[pltpu.VMEM((s, d), jnp.float32),
+                            pltpu.VMEM((s, dv), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_GROUP_VMEM),
+            interpret=interpret,
+            name="flash_bwd_dkdv",
+        )(qt, kt, vt, dot, along_lanes(lse), along_lanes(rt))
+        join = at.back
+    else:
+        rows_of = pl.BlockSpec((1, s // blk_q, 1, blk_q),
+                               lambda bh, ki: (bh, 0, 0, 0))
+        dk, dv_ = pl.pallas_call(
+            functools.partial(_bwd_dkdv_kernel, **mask),
+            grid=(b * h, s // blk_k),
+            in_specs=[
+                at.spec(s, d, whole=True),
+                at.spec(blk_k, d, kv=True),
+                at.spec(blk_k, dv, kv=True),
+                at.spec(s, dv, whole=True),
+                rows_of, rows_of,
+            ],
+            out_specs=[at.slab_spec(blk_k, d), at.slab_spec(blk_k, dv)],
+            out_shape=[at.slab_shape(b, s, d, k.dtype),
+                       at.slab_shape(b, s, dv, v.dtype)],
+            scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
+                            pltpu.VMEM((blk_k, dv), jnp.float32)],
+            interpret=interpret,
+            name="flash_bwd_dkdv",
+        )(qt, kt, vt, dot, along_lanes(lse), along_lanes(rt))
+        join = at.slab_sum
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, blk_k=blk_k, sub=sub,
                           scale=scale, causal=causal, window=window,
                           blocks=blocks),
         grid=(b * h, s // blk_q),
         in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, s, dv), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, blk_q, dv), lambda bh, qi: (bh, qi, 0)),
+            at.spec(blk_q, d),
+            at.spec(s, d, kv=True, whole=True),
+            at.spec(s, dv, kv=True, whole=True),
+            at.spec(blk_q, dv),
             pl.BlockSpec((1, blk_q, 1), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, blk_q, 1), lambda bh, qi: (bh, qi, 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
+        out_specs=at.spec(blk_q, d),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse[..., None], rt[..., None])
-
-    def back(x):
-        return x.reshape(b, h, s, x.shape[-1]).transpose(0, 2, 1, 3)
-
-    return back(dq), back(dk), back(dv_)
+    return at.back(dq, b), join(dk, b), join(dv_, b)
 
 
 def _bwd_blockwise(q, k, v, o, lse, do, *, blk: int, scale: float,
                    causal: bool, dlse=None, window: int | None = None,
                    blocks: tuple | None = None):
-    """Flash backward in plain XLA, scanning KV blocks. q, k (B,S,H,D);
-    v, o, do (B,S,H,Dv).
+    """Flash backward in plain XLA, scanning KV blocks. q, o, do
+    (B,S,H,.); k, v (B,S,KV,.), repeated here a query head each and
+    dK, dV summed back over each group.
 
     With `dlse` (a (B*H, S) cotangent on the log-sum-exp output), the
     score gradient gains the softmax term: d(lse)/d(s_ij) = p_ij, so
@@ -670,8 +828,8 @@ def _bwd_blockwise(q, k, v, o, lse, do, *, blk: int, scale: float,
     """
     b, s, h, d = q.shape
     q32 = q.astype(jnp.float32)
-    k32 = k.astype(jnp.float32)
-    v32 = v.astype(jnp.float32)
+    k32 = _repeated(k, h).astype(jnp.float32)
+    v32 = _repeated(v, h).astype(jnp.float32)
     do32 = do.astype(jnp.float32)
     # delta_i = rowsum(dO_i * O_i)  (B,S,H)
     delta = jnp.sum(do32 * o.astype(jnp.float32), axis=-1)
@@ -714,7 +872,9 @@ def _bwd_blockwise(q, k, v, o, lse, do, *, blk: int, scale: float,
         kv_step, jnp.zeros_like(q32), jnp.arange(n_blocks))
     dk = dk_blocks.transpose(1, 0, 2, 3, 4).reshape(b, s, h, d)
     dv = dv_blocks.transpose(1, 0, 2, 3, 4).reshape(b, s, h, v.shape[-1])
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    kv = k.shape[2]
+    return (dq.astype(q.dtype), _group_sum(dk, kv).astype(k.dtype),
+            _group_sum(dv, kv).astype(v.dtype))
 
 
 def _fit_block(s: int, want: int) -> int:
@@ -831,7 +991,7 @@ def force_interpret_kernels():
         _FORCE_INTERPRET = False
 
 
-def _kernel_interpret(what: str, q, blk_q: int, blk_k: int,
+def _kernel_interpret(what: str, q, kv: int, blk_q: int, blk_k: int,
                       causal: bool, window: int | None = None,
                       blocks: tuple | None = None) -> bool | None:
     """Which path this trace takes: the Pallas `interpret` flag (False
@@ -839,7 +999,9 @@ def _kernel_interpret(what: str, q, blk_q: int, blk_k: int,
     XLA blockwise paths — off-TPU, where interpret-mode Pallas is
     orders of magnitude slower and would throttle the CPU
     elastic/multipod worlds. Logged per trace, so a trainer's log says
-    which attention its step was built from, and in which blocks."""
+    which attention its step was built from, in which blocks, and (a
+    line of its own, with the forward's) how many key/value heads the
+    kernels serve the query heads from."""
     if jax.default_backend() == "tpu":
         mode, interpret = "pallas kernel, compiled", False
     elif _FORCE_INTERPRET:
@@ -854,12 +1016,15 @@ def _kernel_interpret(what: str, q, blk_q: int, blk_k: int,
     elif blocks is not None:
         mode += f", by blocks of {blocks[0]}" + ", strictly" * blocks[1]
     log.info("flash attention %s %s: %s", what, tuple(q.shape), mode)
+    if interpret is not None and what == "fwd":
+        log.info("flash %s kv %d: a key/value head by its index, one to "
+                 "%d query heads", tuple(q.shape), kv, q.shape[2] // kv)
     return interpret
 
 
 def _fwd_dispatch(q, k, v, blk_q, blk_k, scale, causal, window, blocks):
-    interpret = _kernel_interpret("fwd", q, blk_q, blk_k, causal, window,
-                                  blocks)
+    interpret = _kernel_interpret("fwd", q, k.shape[2], blk_q, blk_k,
+                                  causal, window, blocks)
     if interpret is None:
         return _fwd_blockwise(q, k, v, blk=blk_k, scale=scale,
                               causal=causal, window=window, blocks=blocks)
@@ -895,8 +1060,8 @@ def _flash_lse_bwd(blk_q, blk_k, scale, causal, window, blocks, res,
                    cotangents):
     q, k, v, o, lse = res
     do, dlse = cotangents
-    interpret = _kernel_interpret("bwd", q, blk_q, blk_k, causal, window,
-                                  blocks)
+    interpret = _kernel_interpret("bwd", q, k.shape[2], blk_q, blk_k,
+                                  causal, window, blocks)
     if interpret is None:
         return _bwd_blockwise(q, k, v, o, lse, do, blk=blk_k,
                               scale=scale, causal=causal, dlse=dlse,
@@ -922,11 +1087,14 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     Fully differentiable through both outputs.
     """
     b, s, h, d = q.shape
-    if k.shape != q.shape or v.ndim != 4 or v.shape[:3] != q.shape[:3]:
+    if (k.ndim != 4 or v.ndim != 4 or k.shape[:2] != (b, s)
+            or k.shape[3] != d or v.shape[:3] != k.shape[:3]
+            or h % k.shape[2]):
         raise ValueError(
             f"q/k/v shape mismatch: q {q.shape}, k {k.shape}, v {v.shape}: "
-            "q and k are one shape (B, S, H, D), and v is (B, S, H, Dv) "
-            "with a head size of its own or the same")
+            "q is (B, S, H, D), k is (B, S, KV, D) and v is (B, S, KV, Dv) "
+            "with a head size of its own or the same; KV divides H, and "
+            "query head h reads key/value head h // (H / KV)")
     if window is not None and (not causal or window < 1):
         raise ValueError(f"window={window} counts the keys up to and "
                          "including a query's own: it needs causal=True "
@@ -955,11 +1123,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: int = 512, block_k: int = 512,
                     window: int | None = None,
                     blocks: tuple[int, bool] | None = None) -> jax.Array:
-    """Fused causal attention. q, k: (B, S, H, D); v: (B, S, H, Dv), a
-    head size of its own (latent attention: keys of 192, values of 128)
-    or the same -> (B, S, H, Dv). The scale, where not given, is from
-    the key size: 1 / sqrt(D). D need not be whole 128-lane registers:
-    the products contract it as it is (PERF.md §5).
+    """Fused causal attention. q: (B, S, H, D); k: (B, S, KV, D); v:
+    (B, S, KV, Dv), a head size of its own (latent attention: keys of
+    192, values of 128) or the same -> (B, S, H, Dv). KV divides H:
+    grouped-query attention hands its key/value heads over unrepeated,
+    and dK, dV come back at KV heads. The scale, where not given, is
+    from the key size: 1 / sqrt(D). D need not be whole 128-lane
+    registers: the products contract it as it is (PERF.md §5).
 
     `window` (static; None = every earlier key): query i sees keys
     i - window + 1 .. i, and the kernels visit only the block pairs

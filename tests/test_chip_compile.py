@@ -92,7 +92,8 @@ def custom_calls(compiled) -> int:
 
 # (batch, seq, heads, head dim): LM-large, the d_model-1024 shape, and
 # the benchmark's cells: d8, a chip of fsdp4, OLMoE, the hybrid's one
-# attention layer (its 8 key/value heads repeated to 32); a fifth entry
+# attention layer (as many key/value heads as query heads here: the
+# grouped shapes follow below); a fifth entry
 # is a sliding window: the afmoe share's global layer and its sliding ones
 FLASH_SHAPES = [(8, 1024, 16, 128), (16, 1024, 16, 64),
                 (6, 2048, 16, 128), (2, 2048, 16, 128),
@@ -146,6 +147,41 @@ def test_flash_kernels_compile_by_block_index(one_chip, no_persistent_cache,
                 q, k, v, o, lse, do, dlse=dlse, **kw),
             x, x, x, x, lse, x, lse)
         assert custom_calls(compiled) == 2
+
+
+# grouped-query attention as the cells run it, the key/value heads
+# unrepeated: (batch, seq, heads, key/value heads, head dim, mask). The
+# afmoe share's sliding layers, a row of the block-diffusion cell, the
+# hybrid's one attention layer
+GROUPED_SHAPES = [(2, 8192, 32, 4, 128, {"window": 2048}),
+                  (1, 8192, 32, 4, 128, {"blocks": (4, True)}),
+                  (2, 8192, 32, 8, 64, {})]
+
+
+@pytest.mark.parametrize("shape", GROUPED_SHAPES, ids=str)
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_flash_kernels_compile_with_a_key_value_head_by_index(
+        one_chip, no_persistent_cache, kernel, shape):
+    b, s, h, kv, d, mask = shape
+    kw = dict(blk_q=512, blk_k=512, scale=d ** -0.5, causal=True,
+              interpret=False, **mask)
+    q, k = sds((b, s, h, d), jnp.bfloat16), sds((b, s, kv, d), jnp.bfloat16)
+    lse = sds((b * h, s), F32)
+    if kernel == "fwd":
+        compiled = compile_for(one_chip, functools.partial(fa._fwd, **kw),
+                               q, k, k)
+        assert custom_calls(compiled) == 1
+    else:
+        compiled = compile_for(
+            one_chip,
+            lambda q, k, v, o, lse, do, dlse: fa._bwd_pallas(
+                q, k, v, o, lse, do, dlse=dlse, **kw),
+            q, k, k, q, lse, q, lse)
+        assert custom_calls(compiled) == 2
+        assert [x.shape for x in compiled.out_info] == [
+            q.shape, k.shape, k.shape]
+        # nothing of a whole (B, S, H, D) is made from k or v
+        assert "broadcast(" not in compiled.as_text()
 
 
 # latent attention at the joyai cell's shape: keys and queries of 192

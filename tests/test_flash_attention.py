@@ -9,6 +9,7 @@ online-softmax accumulation, and both backwards stay covered on CPU.
 """
 
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -232,11 +233,7 @@ class TestKernelBodies:
 
     @staticmethod
     def _walk(jaxpr):
-        """Every equation of a jaxpr and of all it holds."""
-        for e in jaxpr.eqns:
-            yield e
-            for sub in jax.core.jaxprs_in_params(e.params):
-                yield from TestKernelBodies._walk(sub)
+        return _walk(jaxpr)
 
     @pytest.mark.parametrize("blk", [128, 256])
     def test_operands_as_they_arrive_and_no_transpose(self, blk):
@@ -317,6 +314,8 @@ class TestKernelBodies:
         assert text == [
             f"flash attention fwd (1, 512, 1, 64): pallas kernel, "
             f"interpret mode; {pairs}",
+            "flash (1, 512, 1, 64) kv 1: a key/value head by its index, "
+            "one to 1 query heads",
             f"flash attention bwd (1, 512, 1, 64): pallas kernel, "
             f"interpret mode; {pairs}",
             "flash attention fwd (1, 512, 1, 64): xla blockwise"]
@@ -387,6 +386,70 @@ class TestTransformerIntegration:
         out_d = m_dense.apply(variables, toks, train=False)
         out_f = m_flash.apply(variables, toks, train=False)
         np.testing.assert_allclose(out_d, out_f, atol=1e-4)
+
+
+class TestNoRepeatInTheBlocks:
+    """The grouped-query blocks hand the flash kernels their key/value
+    heads as projected: traced at the cells' head counts (a group of 8,
+    the hybrid's 4 at a head of 64) and a small sequence, an attention
+    layer's forward and backward hold no broadcast of k or v over a
+    group (how `jnp.repeat` lowers) and every flash call takes B * KV
+    key/value heads. The dense path, which is XLA's, keeps the repeat."""
+
+    @staticmethod
+    def _layer(name, attention):
+        from edl_tpu.models import transformer as tfm
+        small = dict(vocab_size=64, d_model=256, n_layers=1, d_ff=64,
+                     max_len=512, dtype=jnp.float32, attention=attention)
+        if name == "sdar":
+            return tfm.Attention(tfm.sdar_config(
+                n_heads=8, n_kv_heads=1, n_experts=2, moe_top_k=1,
+                **small)), 1
+        if name == "hybrid":  # one attention layer's shape: head of 64
+            return tfm.Attention(tfm.TransformerConfig(
+                n_heads=4, n_kv_heads=1, **small)), 1
+        kind = name.split("-")[1]
+        return tfm.Attention(tfm.afmoe_config(
+            n_heads=8, n_kv_heads=1, window=128, n_experts=2, moe_top_k=1,
+            **small), kind), 1
+
+    @classmethod
+    def _eqns(cls, name, attention):
+        from edl_tpu.ops import rope
+        layer, kv = cls._layer(name, attention)
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 512, 256))
+        params = layer.init(jax.random.PRNGKey(1), x)
+
+        def loss(params, x):
+            return jnp.sum(jnp.sin(layer.apply(params, x)))
+        with force_interpret_kernels(), rope.force_interpret_kernel():
+            jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x)
+        eqns = list(_walk(jaxpr.jaxpr, into_kernels=False))
+        # a (B, S, KV, D) made (B, S, KV, group, D): heads' whole rows
+        over_groups = [e for e in eqns
+                       if e.primitive.name == "broadcast_in_dim"
+                       and e.invars[0].aval.ndim >= 4
+                       and e.outvars[0].aval.ndim == 5
+                       and e.outvars[0].aval.shape[-1] in (64, 128)
+                       and e.outvars[0].aval.size > e.invars[0].aval.size]
+        flash = [e for e in eqns if e.primitive.name == "pallas_call"
+                 and e.params["name"].startswith("flash_")]
+        return over_groups, flash, x.shape[0] * kv
+
+    @pytest.mark.parametrize("name", ["trinity-sliding", "trinity-full",
+                                      "sdar", "hybrid"])
+    def test_the_flash_path_repeats_nothing(self, name):
+        over_groups, flash, heads = self._eqns(name, "flash")
+        assert not over_groups
+        # sdar: the clean copy's call and the noised queries', each
+        # forward, replayed by nothing here, and backward
+        assert len(flash) == (6 if name == "sdar" else 3)
+        for e in flash:
+            assert [x.aval.shape[0] for x in e.invars[1:3]] == [heads, heads]
+
+    def test_the_dense_path_keeps_its_repeat(self):
+        over_groups, flash, _ = self._eqns("trinity-sliding", "dense")
+        assert len(over_groups) >= 2 and not flash
 
 
 class TestWindow:
@@ -524,6 +587,225 @@ def _masked_dense(q, k, v, *, window=None, blocks=None):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _walk(jaxpr, into_kernels=True):
+    """Every equation of a jaxpr and of all it holds."""
+    for e in jaxpr.eqns:
+        yield e
+        if into_kernels or e.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from _walk(sub, into_kernels)
+
+
+class TestGroupedHeads:
+    """Grouped-query attention inside the kernels: k and v come with
+    their own KV heads, a query head reads key/value head h // (H / KV)
+    by index, and dK, dV come back summed over each group. Nothing is
+    written once a query head on the way in."""
+
+    # (batch, query heads, key/value heads, D, Dv, mask)
+    CASES = {
+        "equal": (2, 2, 2, 128, 128, {}),
+        "group8": (1, 8, 1, 128, 128, {}),
+        "group4": (2, 8, 2, 128, 128, {}),
+        "window": (1, 4, 1, 128, 128, {"window": 100}),
+        "blocks-own": (1, 4, 1, 128, 128, {"blocks": (4, False)}),
+        "blocks-before": (1, 8, 2, 128, 128, {"blocks": (4, True)}),
+        "values-of-their-own": (1, 4, 2, 256, 128, {}),
+        "head64": (2, 4, 1, 64, 64, {}),
+        "head64-window": (1, 8, 2, 64, 64, {"window": 100}),
+        "head192": (1, 4, 2, 192, 128, {}),
+    }
+    GROUPED = sorted(c for c in CASES if c != "equal")
+    S, BLK = 256, 128
+
+    @classmethod
+    def _operands(cls, case, dtype=jnp.float32):
+        b, h, kv, d, dv, mask = cls.CASES[case]
+        key = jax.random.PRNGKey(sum(map(ord, case)))
+        shapes = [(h, d), (kv, d), (kv, dv), (h, dv)]
+        q, k, v, do = (jax.random.normal(jax.random.fold_in(key, i),
+                                         (b, cls.S, *x), dtype)
+                       for i, x in enumerate(shapes))
+        dlse = jax.random.normal(jax.random.fold_in(key, 4),
+                                 (b * h, cls.S), jnp.float32)
+        # a strict query of the first block sees no key: what it gets is
+        # the kernels' own convention (tests/test_sdar.py), left out here
+        first = 4 if mask.get("blocks", (0, False))[1] else 0
+        return (q, k, v, do.at[:, :first].set(0),
+                dlse.at[:, :first].set(0)), mask, first
+
+    @classmethod
+    def _kernels(cls, q, k, v, do, dlse, mask):
+        """(o, lse, dq, dk, dv) of the three kernels, interpret mode."""
+        from edl_tpu.ops.flash_attention import _bwd_pallas, _fwd
+        kw = dict(blk_q=cls.BLK, blk_k=cls.BLK, scale=q.shape[-1] ** -0.5,
+                  causal=True, interpret=True, **mask)
+        o, lse = _fwd(q, k, v, **kw)
+        return (o, lse) + _bwd_pallas(q, k, v, o, lse, do, dlse=dlse, **kw)
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_kernels_match_the_blockwise_scans(self, case):
+        """Forward, lse and all of dq, dk, dv under a non-zero `dlse`,
+        against `_fwd_blockwise` / `_bwd_blockwise`, which repeat the
+        heads inside themselves."""
+        from edl_tpu.ops.flash_attention import (_bwd_blockwise,
+                                                 _fwd_blockwise)
+        (q, k, v, do, dlse), mask, first = self._operands(case)
+        kw = dict(blk=self.BLK, scale=q.shape[-1] ** -0.5, causal=True,
+                  **mask)
+        o, lse, *grads = self._kernels(q, k, v, do, dlse, mask)
+        o_x, lse_x = _fwd_blockwise(q, k, v, **kw)
+        assert o.shape == do.shape and lse.shape == dlse.shape
+        np.testing.assert_allclose(o[:, first:], o_x[:, first:], atol=2e-5)
+        np.testing.assert_allclose(lse[:, first:], lse_x[:, first:],
+                                   atol=2e-5)
+        want = _bwd_blockwise(q, k, v, o_x, lse_x, do, dlse=dlse, **kw)
+        for g, w, x in zip(grads, want, (q, k, v)):
+            assert g.shape == x.shape
+            np.testing.assert_allclose(g, w, atol=1e-4)
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_both_paths_match_dense_attention_on_repeated_heads(
+            self, case, attn_path):
+        """The public function against the dense oracle handed every
+        key/value head once a query head, whose autodiff sums dK, dV."""
+        (q, k, v, _, _), mask, first = self._operands(case)
+        group = q.shape[2] // k.shape[2]
+
+        def oracle(q, k, v):
+            return _masked_dense(q, jnp.repeat(k, group, 2),
+                                 jnp.repeat(v, group, 2), **mask)
+
+        def loss(fn):
+            return lambda *a: jnp.sum(jnp.sin(fn(*a)[:, first:]))
+        kw = dict(block_q=self.BLK, block_k=self.BLK, **mask)
+        np.testing.assert_allclose(
+            flash_attention(q, k, v, **kw)[:, first:],
+            oracle(q, k, v)[:, first:], atol=2e-5)
+        got = jax.grad(loss(functools.partial(flash_attention, **kw)),
+                       argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape
+            np.testing.assert_allclose(g, w, atol=1e-4)
+
+    @pytest.mark.parametrize("case", GROUPED)
+    def test_a_head_by_index_is_the_repeated_head_to_the_bit(self, case):
+        """The same kernels on the same numbers, bfloat16 operands: o,
+        lse and dq equal what repeated heads give, and dK, dV are the
+        sums of what each query head of a group gave."""
+        (q, k, v, do, dlse), mask, _ = self._operands(case, jnp.bfloat16)
+        b, s, h, kv = *q.shape[:3], k.shape[2]
+        by_index = self._kernels(q, k, v, do, dlse, mask)
+        repeated = self._kernels(q, jnp.repeat(k, h // kv, 2),
+                                 jnp.repeat(v, h // kv, 2), do, dlse, mask)
+        for got, want in zip(by_index[:3], repeated[:3]):
+            np.testing.assert_array_equal(got.astype(jnp.float32),
+                                          want.astype(jnp.float32))
+        for got, want in zip(by_index[3:], repeated[3:]):
+            assert got.shape[2] == kv and got.dtype == jnp.bfloat16
+            heads = want.astype(jnp.float32).reshape(b, s, kv, h // kv, -1)
+            # each query head's part was rounded to bfloat16 there; here
+            # the sum is float32 until it is written
+            np.testing.assert_allclose(
+                got.astype(jnp.float32), heads.sum(3), rtol=2 ** -8,
+                atol=(h // kv) * 2 ** -9 * float(jnp.max(jnp.abs(heads))))
+
+    @pytest.mark.parametrize("case", GROUPED)
+    def test_the_sum_over_a_group_in_slabs_is_the_kernels_own(
+            self, case, monkeypatch):
+        """Where a whole sequence does not fit the kernel's VMEM, dK
+        and dV leave it a query head, in slabs, and XLA sums them: the
+        same gradients as the kernel that sums over the group itself."""
+        import importlib
+        fa = importlib.import_module("edl_tpu.ops.flash_attention")
+        (q, k, v, do, dlse), mask, _ = self._operands(case)
+        kw = dict(blk_q=self.BLK, blk_k=self.BLK, scale=0.1, causal=True,
+                  interpret=True, dlse=dlse, **mask)
+        o, lse = fa._fwd(q, k, v, **{x: kw[x] for x in kw if x != "dlse"})
+
+        def backward(*a):  # untraced each time: the rule is asked again
+            return fa._bwd_pallas.__wrapped__(*a, o, lse, do, **kw)
+
+        def sums(fn):  # a new function each time: traces are kept
+            return [e.primitive.name for e in _walk(
+                jax.make_jaxpr(lambda *a: fn(*a))(q, k, v).jaxpr,
+                into_kernels=False)
+                if e.primitive.name == "reduce_sum"
+                and e.invars[0].aval.ndim == 4]
+        in_kernel = backward(q, k, v)
+        assert not sums(backward)
+        monkeypatch.setattr(fa, "_GROUP_VMEM", 0)
+        assert len(sums(backward)) == 2
+        for got, want in zip(backward(q, k, v), in_kernel):
+            assert got.shape == want.shape
+            np.testing.assert_allclose(got, want, atol=1e-5)
+
+    @pytest.mark.parametrize("shape, fits", [
+        ((8192, 128, 128, 2), True),      # the afmoe and diffusion cells
+        ((8192, 64, 64, 2), True),        # the hybrid's layer: 128 lanes
+        ((16384, 128, 128, 2), False),
+        ((8192, 192, 128, 2), False),
+        ((4096, 128, 128, 4), True),
+    ], ids=str)
+    def test_where_the_kernel_sums_is_a_matter_of_vmem(self, shape, fits):
+        from edl_tpu.ops.flash_attention import _Heads
+        assert _Heads(32, 8).sums_in_kernel(*shape) is fits
+        assert not _Heads(32, 1).sums_in_kernel(*shape)
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_the_trace_says_how_many_heads_a_key_serves(self, case,
+                                                        caplog):
+        import logging
+        (q, k, v, _, _), mask, _ = self._operands(case)
+        b, h, kv, d, _, _ = self.CASES[case]
+        fa_log = logging.getLogger("edl_tpu.ops.flash_attention")
+        fa_log.addHandler(caplog.handler)
+        try:
+            with force_interpret_kernels():
+                jax.eval_shape(functools.partial(
+                    flash_attention, block_q=self.BLK, block_k=self.BLK,
+                    **mask), q, k, v)
+        finally:
+            fa_log.removeHandler(caplog.handler)
+        assert (f"flash ({b}, {self.S}, {h}, {d}) kv {kv}: a key/value "
+                f"head by its index, one to {h // kv} query heads"
+                in [r.getMessage() for r in caplog.records])
+
+    @pytest.mark.parametrize("case", GROUPED)
+    def test_no_key_or_value_is_written_once_a_query_head(self, case):
+        """The traced forward and backward around the three kernels:
+        the kernels are handed B * KV key/value heads, and nothing of
+        a whole (B, S, H, D) is made from k or v (no broadcast over a
+        group, which is how `jnp.repeat` lowers; no gather)."""
+        (q, k, v, do, dlse), mask, _ = self._operands(case)
+        b, s, h, kv = *q.shape[:3], k.shape[2]
+        eqns = list(_walk(jax.make_jaxpr(
+            lambda *a: self._kernels(*a, mask))(q, k, v, do, dlse).jaxpr,
+            into_kernels=False))
+        calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert [e.params["name"] for e in calls] == [
+            "flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"]
+        for e in calls:
+            assert [x.aval.shape[0] for x in e.invars[:3]] == [
+                b * h, b * kv, b * kv]
+        assert not [e for e in eqns if e.primitive.name in (
+            "broadcast_in_dim", "gather", "concatenate")
+            and e.outvars[0].aval.ndim >= 4]
+
+    @pytest.mark.parametrize("bad", ["kv_heads", "v_heads", "k_size"])
+    def test_heads_that_do_not_group_are_refused(self, bad):
+        (q, k, v, _, _), _, _ = self._operands("group4")
+        if bad == "kv_heads":  # 8 query heads on 3
+            k, v = (jnp.concatenate([x, x[:, :, :1]], 2) for x in (k, v))
+        elif bad == "v_heads":
+            v = v[:, :, :1]
+        else:
+            k = k[..., :64]
+        with pytest.raises(ValueError, match="KV divides H"):
+            flash_attention(q, k, v)
+
+
 class TestValueHeadSize:
     """v with a head size of its own (latent attention: keys of 192,
     values of 128): q, k (B, S, H, D), v, o, dO, dV (B, S, H, Dv), the
@@ -629,7 +911,7 @@ class TestValueHeadSize:
             v = v[:, :128]
         else:
             v = v[..., 0]
-        with pytest.raises(ValueError, match=r"v is \(B, S, H, Dv\)"):
+        with pytest.raises(ValueError, match=r"v is \(B, S, KV, Dv\)"):
             flash_attention(q, k, v)
 
     # sha256[:16] of str(jax.make_jaxpr(...)) of the forward and the
